@@ -27,10 +27,7 @@ fleet_shard_scaling measurement (the batch-8 rung-1 fleet sharded over
 1/4/8 devices, shard x vmap — DESIGN.md §22; also skipped with a null
 metric when fewer than 8 devices are visible — CI pins
 XLA_FLAGS=--xla_force_host_platform_device_count=8 for a virtual mesh).
-`PRIMETPU_BENCH_COLDSTART=0` skips the cold_start_speedup measurement
-(the shipped rung-3 config through two fresh `--exec-cache on`
-subprocesses against one cache dir: compile wall bought vs deserialize
-wall paid, DESIGN.md §23). `PRIMETPU_BENCH_ATTEST=0` skips the
+`PRIMETPU_BENCH_ATTEST=0` skips the
 attest_overhead_pct measurement (the per-chunk fingerprint chain vs
 the same chunked dispatch with attest off, DESIGN.md §24; advisory
 gate < 3%).
@@ -44,6 +41,17 @@ while single-core CPU containers land ~30x lower across the board — so
 the auto floor is 2.0 on TPU and 0.15x the same-run headline elsewhere
 (rung 3 within ~7x of the fast path proves the O(E log E) ranking holds
 regardless of absolute machine speed; pre-rework it sat at ~0.02x).
+
+ONE PROCESS PER CHIP. This process holds the chip from its first
+measurement on, so it launches no child that needs one: the two
+multi-process protocol-economics sections (pool_sweep, unified_serve)
+pin their 4-core children to the CPU (`"child_platform": "cpu"` in
+their detail), and the cold-start measurement — whose children need the
+chip — left this script (the `exec-cache-smoke` CI job keeps the
+miss/hit flow). Every result names the device its arrays lived on
+(`platform`/`device_kind`/`n_devices`), and the run FAILS on a platform
+other than `tpu` unless the caller set `JAX_PLATFORMS=cpu` explicitly:
+without a reachable chip this JAX silently falls back to the CPU.
 
 `vs_baseline` compares against 20 MIPS — the upper end of the reference
 simulator's published multi-host aggregate throughput (ISPASS'14 paper,
@@ -126,6 +134,22 @@ def _measure_fleet(cfg, traces, chunk: int, runs: int = 2, mesh=None) -> float:
     return min(walls)
 
 
+def _device_or_die(arr) -> dict:
+    """platform/device_kind/n_devices of the devices `arr` lives on;
+    exits when that is not a TPU and the caller did not ask for the CPU."""
+    from primesim_tpu.util.device import cpu_requested, device_fields
+
+    dev = device_fields(arr)
+    if dev["platform"] != "tpu" and not cpu_requested():
+        sys.exit(
+            f"bench.py: measuring on {dev['platform']!r} "
+            f"({dev['device_kind']}) but JAX_PLATFORMS=cpu was not set — "
+            "the chip was lost or never found; a speed from this run "
+            "would not be a device metric"
+        )
+    return dev
+
+
 def main() -> None:
     import numpy as np
 
@@ -136,6 +160,12 @@ def main() -> None:
     )
     from primesim_tpu.trace import synth
     from primesim_tpu.trace.format import fold_ins
+    from primesim_tpu.util.device import configure_compile_cache
+
+    configure_compile_cache()
+    import jax
+
+    device = _device_or_die(jax.device_put(0))  # before compiling anything
 
     C = 1024
     CHUNK = int(os.environ.get("PRIMETPU_BENCH_CHUNK", "512"))
@@ -194,9 +224,7 @@ def main() -> None:
         if floor_env is not None:
             floor, hard = float(floor_env), True
         else:
-            import jax
-
-            on_tpu = jax.default_backend() == "tpu"
+            on_tpu = device["platform"] == "tpu"
             floor = 2.0 if on_tpu else round(0.15 * mips, 3)
             hard = False
         r3_gate = {
@@ -625,7 +653,11 @@ def main() -> None:
                 "rungs": degrade_rungs,
             }
 
-    # LIVE per-phase cuts (scripts/prof/prof_phase.py source surgery) on
+    # This process holds the chip by now, and a chip belongs to one
+    # process: the multi-process sections below measure PROTOCOL
+    # economics on a 4-core machine, so their children run on the CPU.
+    CHILD_ENV = dict(os.environ, JAX_PLATFORMS="cpu")
+
     # elastic pool scaling (DESIGN.md §17): the same 16-element campaign
     # through `sweep --workers 1` vs `--workers 3` — real worker
     # processes over the unix socket, so the measurement prices the
@@ -660,7 +692,7 @@ def main() -> None:
             subprocess.run(
                 pool_cmd + ["--workers", str(workers)],
                 check=True, stdout=subprocess.DEVNULL,
-                stderr=subprocess.DEVNULL,
+                stderr=subprocess.DEVNULL, env=CHILD_ENV,
             )
             return time.perf_counter() - t0
 
@@ -668,6 +700,7 @@ def main() -> None:
         pool_wall_3 = _pool_campaign(3)
         pool_speedup = pool_wall_1 / pool_wall_3
         pool_detail = {
+            "child_platform": "cpu",
             "elements": 16,
             "wall_s_workers1": round(pool_wall_1, 3),
             "wall_s_workers3": round(pool_wall_3, 3),
@@ -715,6 +748,7 @@ def main() -> None:
                  "--pool-dir", os.path.join(sdir, "pool"),
                  "--workers", str(workers), "--chunk-steps", "64"],
                 stdout=subprocess.DEVNULL, stderr=open(err_path, "w"),
+                env=CHILD_ENV,
             )
             try:
                 target = None
@@ -754,6 +788,7 @@ def main() -> None:
         uni_wall_3 = _unified_campaign(3)
         uni_speedup = uni_wall_1 / uni_wall_3
         unified_detail = {
+            "child_platform": "cpu",
             "jobs": UNI_JOBS,
             "wall_s_workers1": round(uni_wall_1, 3),
             "wall_s_workers3": round(uni_wall_3, 3),
@@ -765,77 +800,7 @@ def main() -> None:
             "passed": bool(uni_speedup >= 2.0),
         }
 
-    # cold-start economics (DESIGN.md §23): the SHIPPED rung-3 config
-    # through two fresh `primetpu run --exec-cache on` subprocesses
-    # against one empty cache dir — run 1 pays XLA compilation and
-    # persists the executables, run 2 deserializes them. The speedup is
-    # compile wall bought vs deserialize wall paid; time-to-first-step
-    # rides alongside (it additionally carries trace synthesis + device
-    # upload, which the cache does not touch). Advisory at 5.0x (the
-    # acceptance bar; absolute compile walls are backend- and
-    # core-count-relative). PRIMETPU_BENCH_COLDSTART=0 skips (metric
-    # reports null).
-    cold_detail = None
-    cold_gate = None
-    if os.environ.get("PRIMETPU_BENCH_COLDSTART", "1") != "0":
-        import shutil
-        import subprocess
-        import tempfile
-
-        cs_cache = tempfile.mkdtemp(prefix="primetpu-bench-exec-")
-        cs_cmd = [
-            sys.executable, "-m", "primesim_tpu.cli", "run",
-            os.path.join(os.path.dirname(__file__), "configs",
-                         "rung3_1024core_o3.json"),
-            "--synth", "fft_like:n_phases=1,points_per_core=8,ins_per_mem=4",
-            "--fold", "--max-steps", "64", "--chunk-steps", "32",
-            "--exec-cache", "on",
-        ]
-
-        def _fresh_process_run() -> dict:
-            env = dict(os.environ, PRIMETPU_CACHE_DIR=cs_cache)
-            out = subprocess.run(
-                cs_cmd, check=True, capture_output=True, text=True, env=env
-            ).stdout
-            metrics = {}
-            for line in out.splitlines():
-                try:
-                    rec = json.loads(line)
-                except json.JSONDecodeError:
-                    continue
-                if isinstance(rec, dict) and "metric" in rec:
-                    metrics[rec["metric"]] = rec
-            return metrics
-
-        try:
-            cold_m = _fresh_process_run()   # empty dir: compile + persist
-            warm_m = _fresh_process_run()   # same dir: deserialize
-            cold_ec = cold_m["exec_cache"]["detail"]
-            warm_ec = warm_m["exec_cache"]["detail"]
-            cold_compile = float(cold_ec["compile_wall_s"])
-            warm_paid = (float(warm_ec["compile_wall_s"])
-                         + float(warm_ec["load_wall_s"]))
-            cs_speedup = cold_compile / max(warm_paid, 1e-9)
-            cold_detail = {
-                "config": "configs/rung3_1024core_o3.json",
-                "cold_ttfs_s": cold_m["time_to_first_step"]["value"],
-                "warm_ttfs_s": warm_m["time_to_first_step"]["value"],
-                "cold_compile_wall_s": round(cold_compile, 3),
-                "warm_load_wall_s": round(
-                    float(warm_ec["load_wall_s"]), 3),
-                "warm_hits": int(warm_ec["hits"]),
-                "warm_misses": int(warm_ec["misses"]),
-                "speedup_x": round(cs_speedup, 3),
-            }
-            cold_gate = {
-                "floor_x": 5.0,
-                "hard": False,
-                "passed": bool(cs_speedup >= 5.0
-                               and warm_ec["misses"] == 0),
-            }
-        finally:
-            shutil.rmtree(cs_cache, ignore_errors=True)
-
+    # LIVE per-phase cuts (scripts/prof/prof_phase.py source surgery) on
     # the headline machine: cumulative ms/step at each phase marker, so
     # every bench artifact carries the serial-chain decomposition next to
     # the static r5 record. PRIMETPU_BENCH_PHASE_CUTS=0 skips (each cut
@@ -900,12 +865,6 @@ def main() -> None:
                         unified_detail["speedup_x"]
                         if unified_detail else None
                     ),
-                    # rung-3 compile wall bought by the AOT executable
-                    # cache across fresh processes (null when
-                    # PRIMETPU_BENCH_COLDSTART=0; advisory gate >= 5.0x)
-                    "cold_start_speedup": (
-                        cold_detail["speedup_x"] if cold_detail else None
-                    ),
                     # per-chunk fingerprint-chain wall cost over the
                     # same chunked dispatch with attest off (null when
                     # PRIMETPU_BENCH_ATTEST=0; advisory gate < 3%)
@@ -922,6 +881,9 @@ def main() -> None:
                     ),
                 },
                 "detail": {
+                    # where the headline run's arrays lived, read AFTER
+                    # the run — checked again, like at the start
+                    **_device_or_die(eng.state.cycles),
                     "n_cores": C,
                     "instructions": int(n_instructions),
                     "wall_s": round(wall, 2),
@@ -990,11 +952,6 @@ def main() -> None:
                     # workers (null when PRIMETPU_BENCH_UNIFIED=0)
                     "unified_serve": unified_detail,
                     "unified_serve_gate": unified_gate,
-                    # cold-start economics (DESIGN.md §23): two fresh
-                    # rung-3 processes vs one exec-cache dir (null when
-                    # PRIMETPU_BENCH_COLDSTART=0)
-                    "cold_start": cold_detail,
-                    "cold_start_gate": cold_gate,
                     # device-loss recovery cost on a sharded supervised
                     # run (DESIGN.md §26); advisory, null when
                     # PRIMETPU_BENCH_DEGRADE=0 or < 2 visible devices

@@ -91,11 +91,15 @@ def _load_config(path: str):
 
 def _emit_summary(
     ns, cfg, engine_name, counters, cycles, wall, extra=None,
-    resilience=None, timeline=None,
+    resilience=None, timeline=None, eng=None,
 ):
     """Shared one-line JSON summary + optional text report (the single
-    emission contract for every engine path)."""
+    emission contract for every engine path). `eng` is the engine that
+    ran (None for the golden oracle): the summary names the device its
+    state arrays ENDED on, so a run that lost the chip — silently at
+    start-up, or through a supervisor's CPU-fallback rung — says so."""
     from ..stats.report import write_report
+    from ..util.device import NO_DEVICE, device_fields
 
     tot_ins = int(counters["instructions"].sum())
     detail = {
@@ -106,6 +110,7 @@ def _emit_summary(
         "max_core_cycles": int(max(cycles)),
         "wall_s": round(wall, 3),
         "noc_msgs": int(counters["noc_msgs"].sum()),
+        **(NO_DEVICE if eng is None else device_fields(eng.state.cycles)),
     }
     if extra:
         detail.update(extra)
@@ -216,6 +221,7 @@ def _run_supervised(ns, cfg, eng, rec=None) -> int:
         ns, cfg, ns.engine, eng.counters, eng.cycles, wall,
         extra=extra, resilience=sup.log_lines(),
         timeline=rec.timeline_summary() if rec is not None else None,
+        eng=eng,
     )
     _finalize_obs(rec)
     return 0
@@ -267,13 +273,7 @@ def _build_mesh(ns, cfg):
     from ..parallel.sharding import tile_mesh, validate_devices
 
     validate_devices(cfg, ns.devices)
-    mesh = tile_mesh(ns.devices)
-    print(
-        f"mesh: {ns.devices} devices "
-        f"({mesh.devices.flat[0].platform})",
-        file=sys.stderr,
-    )
-    return mesh
+    return tile_mesh(ns.devices)
 
 
 def _run_pipelined_cli(ns, cfg, tr, mesh, rec) -> int:
@@ -346,6 +346,7 @@ def _run_pipelined_cli(ns, cfg, tr, mesh, rec) -> int:
         ns, cfg, ns.engine, eng.counters, eng.cycles, wall,
         extra=sup.summary(),
         timeline=rec.timeline_summary() if rec is not None else None,
+        eng=eng,
     )
     _finalize_obs(rec)
     return 0
@@ -502,6 +503,10 @@ def cmd_run(ns) -> int:
             )
             np.asarray(out[0].cycles)
         _emit_ttfs_line(cache, t_start)
+        # the warm-up's state and result are two more copies of the
+        # machine in HBM: at rung 5 (3.4 GB each) keeping them made the
+        # timed run's program fail to load on a 16 GB chip (PR 21 probe)
+        del warm, out
         eng = Engine(cfg, tr, chunk_steps=ns.chunk_steps, mesh=mesh)
         eng.overlap = overlap
         if attest_on:
@@ -541,6 +546,7 @@ def cmd_run(ns) -> int:
         ns, cfg, ns.engine, counters, cycles, wall,
         extra={"attest": eng.attest.payload()} if attest_on else None,
         timeline=rec.timeline_summary() if rec is not None else None,
+        eng=None if ns.engine == "golden" else eng,
     )
     _emit_exec_cache_line(cache)
     _finalize_obs(rec)
@@ -600,6 +606,7 @@ def cmd_capture(ns) -> int:
         _emit_summary(
             ns, cfg, "online", eng.counters, eng.cycles, wall,
             extra={"events": int(src.total.sum()), "target_rc": rc},
+            eng=eng,
         )
         return 0
     finally:
@@ -1418,9 +1425,11 @@ def cmd_serve(ns) -> int:
             "fleets live on pool workers, not in the front-end process"
         )
     if getattr(ns, "devices", 0):
-        from ..parallel.sharding import validate_devices
+        # shape only: the front-end never enumerates devices (a chip
+        # belongs to one process — the workers)
+        from ..parallel.sharding import validate_mesh_shape
 
-        validate_devices(cfg, ns.devices)
+        validate_mesh_shape(cfg, ns.devices)
     replicas = [t.strip() for t in (ns.replicas or "").split(",")
                 if t.strip()]
     if ns.standby_of:
@@ -2561,6 +2570,9 @@ def build_parser() -> argparse.ArgumentParser:
 
 
 def main(argv=None) -> int:
+    from ..util.device import configure_compile_cache
+
+    configure_compile_cache()
     # subprocess chaos activation: a campaign exporting
     # PRIMETPU_CHAOS_PLAN makes every spawned worker/coordinator/server
     # inherit the fault plan (no-op when the var is unset)

@@ -274,8 +274,8 @@ class MachineConfig:
     # Route the dense sharer-expansion reductions through the Pallas TPU
     # kernel (primesim_tpu/ops/reductions.py) instead of the jnp path —
     # bit-identical results; full-map vectors only (the coarse/chunked
-    # modes have their own reduction shapes). On non-TPU backends the
-    # kernel runs interpreted, so tests exercise it everywhere.
+    # modes have their own reduction shapes). On the CPU the kernel runs
+    # interpreted, so tests exercise it; on a TPU Mosaic compiles it.
     pallas_reduce: bool = False
     quantum: int = 1000  # relaxed-sync quantum, cycles (the fidelity/speed knob)
     # Local-run length: how many LOCAL events (INS batches, L1 hits) each
@@ -319,7 +319,8 @@ class MachineConfig:
     # either way (tests/test_step_pallas.py proves golden/xla/pallas
     # three-way parity); a GEOMETRY selector, so it is part of the jit
     # key but timing knobs stay traced — fleet sweeps still compile once.
-    # On non-TPU backends the kernels run in Pallas interpreter mode.
+    # On the CPU the kernels run in Pallas interpreter mode; on a TPU
+    # Mosaic compiles them (kernels/layouts.py::interpret_mode).
     step_impl: str = "xla"
     # ---- machine zoo selectors (DESIGN.md §25) --------------------------
     # STATIC coherence selector: "mesi" (the default pull-based protocol)

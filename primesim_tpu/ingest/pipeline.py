@@ -239,6 +239,7 @@ class PipelineStreamEngine(StreamEngine):
 
 
 def _spawn_ingest_worker(socket_path: str, worker_id: str):
+    import os
     import subprocess
     import sys
 
@@ -247,8 +248,13 @@ def _spawn_ingest_worker(socket_path: str, worker_id: str):
         "--connect", socket_path,
         "--worker-id", worker_id,
     ]
-    # stdout is the run's JSON surface — workers must not write to it
-    return subprocess.Popen(cmd, stdout=subprocess.DEVNULL)
+    # stdout is the run's JSON surface — workers must not write to it.
+    # Ingest is host-only numpy work and THIS process holds the chip, so
+    # the children are pinned off it (a chip belongs to one process)
+    return subprocess.Popen(
+        cmd, stdout=subprocess.DEVNULL,
+        env={**os.environ, "JAX_PLATFORMS": "cpu"},
+    )
 
 
 def run_pipelined(

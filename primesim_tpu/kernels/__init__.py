@@ -17,7 +17,9 @@ kernels, selected by `MachineConfig.step_impl == "pallas"`:
 `layouts.py` pins the shared block geometry (core-block size, plane and
 directory-row column maps) and the Mosaic-safe select/reduce idioms all
 three kernels are written in. Every kernel is bit-exact vs the XLA step
-(tests/test_step_pallas.py) and runs in interpreter mode off-TPU.
+(tests/test_step_pallas.py); on the CPU it runs in interpreter mode, on
+a TPU Mosaic compiles it, and any other platform is an error
+(`layouts.interpret_mode`).
 """
 
 from .layouts import core_block  # noqa: F401

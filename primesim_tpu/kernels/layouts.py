@@ -48,10 +48,22 @@ def core_block(C: int) -> int:
     return 128 if C % 128 == 0 else C
 
 
-def interpret_mode() -> bool:
-    """Run kernels in Pallas interpreter mode off-TPU so the identical
-    kernel logic is exercised (and tier-1-gated) on CPU."""
-    return jax.default_backend() != "tpu"
+def interpret_mode(platform: str | None = None) -> bool:
+    """Whether the kernels run in Pallas interpreter mode, decided from
+    the platform of the default device: `cpu` interprets (the identical
+    kernel logic, tier-1-gated), `tpu` compiles through Mosaic, anything
+    else is an error — no kernel silently falls back on a device nobody
+    tested. `platform` overrides the lookup (tests)."""
+    if platform is None:
+        platform = jax.devices()[0].platform
+    if platform == "cpu":
+        return True
+    if platform == "tpu":
+        return False
+    raise RuntimeError(
+        f"primesim_tpu kernels support platforms 'cpu' (interpreted) and "
+        f"'tpu' (Mosaic-compiled); found {platform!r}"
+    )
 
 
 def block_spec(width: int):

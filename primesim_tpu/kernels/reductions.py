@@ -20,8 +20,8 @@ kwargs: the fleet engine's jit key is the timing-normalized geometry and
 real timing lives in the traced knob pytree, so baking `cfg.noc` values
 into the kernel would silently mistime every swept element.
 
-On non-TPU backends the kernel runs in Pallas interpreter mode, so the
-parity suite exercises the identical kernel logic on CPU.
+On the CPU the kernel runs in Pallas interpreter mode, so the parity
+suite exercises the identical kernel logic; on a TPU Mosaic compiles it.
 """
 
 from __future__ import annotations

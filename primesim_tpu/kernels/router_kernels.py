@@ -34,8 +34,8 @@ Legs chain exactly like the XLA path: the reply leg starts at
 t_req_end + service, the barrier-arrival leg (compiled only when the
 trace has sync events — `has_sync` is jit-static) at t0.  All int32;
 bit-exact vs XLA and the golden scalar walk (tests/test_router_pallas.py
-three-way parity).  On non-TPU backends the kernel runs in Pallas
-interpreter mode, tier-1-gated on CPU.
+three-way parity).  On the CPU the kernel runs in Pallas interpreter
+mode (tier-1-gated); on a TPU Mosaic compiles it.
 """
 
 from __future__ import annotations

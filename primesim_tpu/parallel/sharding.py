@@ -80,15 +80,30 @@ class DeviceMeshError(ValueError):
         return loc
 
 
+def validate_mesh_shape(cfg, n_devices: int, visible: int | None = None) -> None:
+    """The part of `validate_devices` that needs no backend: N >= 1 and
+    N divides the core and bank axes. A parent that spawns the workers
+    which own the chips calls only this (a chip belongs to one process;
+    each worker validates visibility for itself)."""
+    if n_devices < 1:
+        raise DeviceMeshError(
+            f"--devices must be >= 1, got {n_devices}", devices=n_devices
+        )
+    for name, extent in (("n_cores", cfg.n_cores), ("n_banks", cfg.n_banks)):
+        if extent % n_devices != 0:
+            raise DeviceMeshError(
+                f"--devices {n_devices} does not divide {name}={extent}; "
+                f"the {AXIS!r} mesh axis shards cores and banks evenly",
+                devices=n_devices,
+                visible=visible,
+            )
+
+
 def validate_devices(cfg, n_devices: int) -> None:
     """Validate a `--devices N` request against the machine geometry and
     the visible device set. Raises DeviceMeshError (exit 2 at the CLI)
     on any mismatch; returns None when a tile_mesh(n_devices) run of this
     config is shape-sound."""
-    if n_devices < 1:
-        raise DeviceMeshError(
-            f"--devices must be >= 1, got {n_devices}", devices=n_devices
-        )
     visible = len(jax.devices())
     if n_devices > visible:
         raise DeviceMeshError(
@@ -98,13 +113,27 @@ def validate_devices(cfg, n_devices: int) -> None:
             devices=n_devices,
             visible=visible,
         )
-    for name, extent in (("n_cores", cfg.n_cores), ("n_banks", cfg.n_banks)):
-        if extent % n_devices != 0:
-            raise DeviceMeshError(
-                f"--devices {n_devices} does not divide {name}={extent}; "
-                f"the {AXIS!r} mesh axis shards cores and banks evenly",
-                devices=n_devices,
-                visible=visible,
+    validate_mesh_shape(cfg, n_devices, visible)
+    if n_devices > 1 and (cfg.step_impl == "pallas" or cfg.pallas_reduce):
+        from ..config.machine import ConfigError
+        from ..kernels.layouts import interpret_mode
+
+        if not interpret_mode():
+            # measured on the v5e toolchain (PR 21): lowering a sharded
+            # step refuses with "Mosaic kernels cannot be automatically
+            # partitioned. Please wrap the call in a shard_map." Say so
+            # up front, typed, instead of mid-lowering — and never as a
+            # silent all-gather around a replicated kernel.
+            selector = (
+                "step_impl" if cfg.step_impl == "pallas" else "pallas_reduce"
+            )
+            raise ConfigError(
+                f"the Pallas kernels cannot run sharded over {n_devices} "
+                "TPU devices: a Mosaic kernel has no partitioning rule; "
+                "use step_impl='xla' and pallas_reduce=false with "
+                "--devices, or run on one device",
+                selector=selector,
+                value=getattr(cfg, selector),
             )
 
 

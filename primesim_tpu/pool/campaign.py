@@ -103,7 +103,9 @@ def _crash_flag(worker_id: str) -> list[str]:
     return []
 
 
-def _spawn_worker(ns, socket_path: str, worker_id: str):
+def _spawn_worker(ns, socket_path: str, worker_id: str, chip_env=None):
+    """`chip_env` is this worker's slot from `plan_worker_chips`: the
+    environment that makes its own chips the only ones it can see."""
     cmd = [
         sys.executable, "-m", "primesim_tpu.cli", "worker",
         "--connect", socket_path,
@@ -116,7 +118,8 @@ def _spawn_worker(ns, socket_path: str, worker_id: str):
     ]
     # stdout is the campaign's JSON surface — workers must not write to
     # it; their stderr (JAX warnings, tracebacks) passes through
-    return subprocess.Popen(cmd, stdout=subprocess.DEVNULL)
+    env = {**os.environ, **chip_env} if chip_env else None
+    return subprocess.Popen(cmd, stdout=subprocess.DEVNULL, env=env)
 
 
 def run_pooled_sweep(ns, cfg) -> int:
@@ -131,10 +134,15 @@ def run_pooled_sweep(ns, cfg) -> int:
     devices = int(getattr(ns, "devices", 0) or 0)
     if devices:
         # fail the campaign up front (exit 2, typed) rather than letting
-        # every worker quarantine its first unit on the same bad mesh
-        from ..parallel.sharding import validate_devices
+        # every worker quarantine its first unit on the same bad mesh —
+        # WITHOUT enumerating devices: this parent must never initialise
+        # a backend, or it holds the chip its workers need
+        from ..parallel.sharding import validate_mesh_shape
 
-        validate_devices(cfg, devices)
+        validate_mesh_shape(cfg, devices)
+    from ..util.device import plan_worker_chips
+
+    chip_plan = plan_worker_chips(ns.workers, devices)
     units = build_units(
         cfg, traces, synths, ovs,
         fold=ns.fold,
@@ -170,7 +178,8 @@ def run_pooled_sweep(ns, cfg) -> int:
         file=sys.stderr,
     )
     workers = [
-        _spawn_worker(ns, coord.socket_path, f"w{k}")
+        _spawn_worker(ns, coord.socket_path, f"w{k}",
+                      chip_plan[k] if chip_plan else None)
         for k in range(ns.workers)
     ]
     respawns = 0
@@ -194,7 +203,10 @@ def run_pooled_sweep(ns, cfg) -> int:
                 wid = f"w{ns.workers + respawns - 1}"
                 print(f"sweep: all workers dead; spawning {wid}",
                       file=sys.stderr)
-                workers.append(_spawn_worker(ns, coord.socket_path, wid))
+                # every slot is free (all its holders are dead): take 0
+                workers.append(_spawn_worker(
+                    ns, coord.socket_path, wid,
+                    chip_plan[0] if chip_plan else None))
             time.sleep(0.05)
         wall = time.perf_counter() - t0
         # campaign done: workers see {done: true} on their next lease

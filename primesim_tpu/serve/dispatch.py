@@ -95,10 +95,22 @@ class DispatchScheduler:
         self.devices = int(devices)
         if self.devices:
             # fail service bring-up on a bad mesh shape, not every
-            # leased unit on every worker
-            from ..parallel.sharding import validate_devices
+            # leased unit on every worker — without enumerating devices:
+            # the front-end never initialises a backend, or it would hold
+            # the chip its workers need
+            from ..parallel.sharding import validate_mesh_shape
 
-            validate_devices(cfg, self.devices)
+            validate_mesh_shape(cfg, self.devices)
+        # one environment overlay per worker slot, each with its own
+        # chips (None: CPU, nothing pinned); more workers than the chips
+        # can hold is a typed error here, at bring-up
+        self._chip_plan = None
+        if self.spawn:
+            from ..util.device import plan_worker_chips
+
+            self._chip_plan = plan_worker_chips(
+                self.max_workers, self.devices
+            )
 
         self.jobs: dict[str, J.Job] = {}
         self.queue: list[str] = []  # accepted, not yet enqueued remotely
@@ -343,6 +355,11 @@ class DispatchScheduler:
         while len(self._workers) < want:
             self._worker_seq += 1
             wid = f"dw{self._worker_seq}"
+            # lowest slot no live worker holds (len < want <= max_workers)
+            slot = min(
+                set(range(self.max_workers))
+                - {w.chip_slot for w in self._workers}
+            )
             argv = [
                 sys.executable, "-m", "primesim_tpu.cli", "worker",
                 "--connect", self.pool_socket,
@@ -356,7 +373,11 @@ class DispatchScheduler:
 
             if exec_cache.active() is not None:
                 argv += ["--exec-cache", "on"]
-            proc = subprocess.Popen(argv, stdout=subprocess.DEVNULL)
+            env = None
+            if self._chip_plan:
+                env = {**os.environ, **self._chip_plan[slot]}
+            proc = subprocess.Popen(argv, stdout=subprocess.DEVNULL, env=env)
+            proc.chip_slot = slot
             self._workers.append(proc)
             self._serve_event("spawn_worker", worker=wid, pid=proc.pid)
 

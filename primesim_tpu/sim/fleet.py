@@ -1,7 +1,7 @@
 """FleetEngine — batch B independent simulations through ONE program.
 
-Round-5 profiling (BENCH_r05.json) pinned the ~2.8 ms/step floor on the
-step's SERIAL kernel-chain depth, not bytes: isolated gathers/scatters of
+Round-5 profiling (the pre-round r05 record, ROADMAP Queue S) pinned the
+~2.8 ms/step floor on the step's SERIAL kernel-chain depth, not bytes: isolated gathers/scatters of
 any tested shape cost ~0.02 ms, so each kernel launch is mostly idle
 capacity. PriME's headline use case is throughput across many concurrent
 runs (the ISPASS'14 multi-host aggregate bench.py baselines against), and

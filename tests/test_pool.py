@@ -553,6 +553,32 @@ def _parse_elements(out):
     return sorted(elems, key=lambda r: r["detail"]["fleet_index"])
 
 
+def test_pooled_sweep_parent_never_initialises_a_backend(tmp_path):
+    """One process per chip: a parent that initialised a JAX backend
+    would hold the chip its workers need. The `sweep --workers` parent —
+    argument parsing, mesh-shape validation for `--devices`, coordinator,
+    spawning — must finish a campaign without ever creating a backend."""
+    code = (
+        "import sys\n"
+        "from primesim_tpu.cli import main\n"
+        "rc = main(sys.argv[1:])\n"
+        "from jax._src import xla_bridge\n"
+        "assert not xla_bridge.backends_are_initialized(), 'parent touched JAX'\n"
+        "sys.exit(rc)\n"
+    )
+    argv = _sweep_cmd(  # minus its `python -m primesim_tpu.cli` prefix
+        _write_cfg(tmp_path), [SMALL_SYNTH.format(0)],
+        extra=("--workers", "1", "--devices", "2"),
+    )[3:]
+    out = subprocess.run(
+        [sys.executable, "-c", code, *argv],
+        cwd=REPO, env={**os.environ, "JAX_PLATFORMS": "cpu"},
+        capture_output=True, text=True, timeout=300,
+    )
+    assert out.returncode == 0, out.stderr[-2000:]
+    assert '"units_done": 1' in out.stdout
+
+
 @pytest.mark.slow
 def test_subprocess_worker_kill9_campaign_bit_exact(tmp_path):
     """Chaos acceptance: one of three workers SIGKILLs itself mid-unit
