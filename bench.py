@@ -800,33 +800,6 @@ def main() -> None:
             "passed": bool(uni_speedup >= 2.0),
         }
 
-    # LIVE per-phase cuts (scripts/prof/prof_phase.py source surgery) on
-    # the headline machine: cumulative ms/step at each phase marker, so
-    # every bench artifact carries the serial-chain decomposition next to
-    # the static r5 record. PRIMETPU_BENCH_PHASE_CUTS=0 skips (each cut
-    # recompiles the truncated step — ~10 extra compiles).
-    phase_ms = None
-    if os.environ.get("PRIMETPU_BENCH_PHASE_CUTS", "1") != "0":
-        import importlib.util
-
-        pp_path = os.path.join(
-            os.path.dirname(__file__), "scripts", "prof", "prof_phase.py"
-        )
-        spec = importlib.util.spec_from_file_location("prof_phase", pp_path)
-        pp = importlib.util.module_from_spec(spec)
-        spec.loader.exec_module(pp)
-        cut_trace = fold_ins(
-            synth.fft_like(
-                C, n_phases=2, points_per_core=16, ins_per_mem=8, seed=42
-            )
-        )
-        phase_ms = {
-            k: round(v, 3)
-            for k, v in pp.phase_cuts(
-                cfg, cut_trace, n_steps=64, repeats=2
-            ).items()
-        }
-
     print(
         json.dumps(
             {
@@ -904,9 +877,6 @@ def main() -> None:
                     # pre-fault step graph (DESIGN.md §12 zero-overhead
                     # contract)
                     "faults_enabled": cfg.faults_enabled,
-                    # live cumulative phase cuts on THIS machine/backend
-                    # (None when PRIMETPU_BENCH_PHASE_CUTS=0)
-                    "phase_ms_cuts_measured": phase_ms,
                     "rung3_shipped_config": detail_r3,
                     "rung3_regression_gate": r3_gate,
                     # telemetry overhead contract (DESIGN.md §15): the
@@ -957,9 +927,10 @@ def main() -> None:
                     # PRIMETPU_BENCH_DEGRADE=0 or < 2 visible devices
                     "degrade_recovery": degrade_detail,
                     # STATIC RECORD: round-5 restructure evidence measured
-                    # on TPU 2026-07-30 (scripts/prof/prof_phase.py
-                    # cumulative cuts / prof_bisect.py ablations,
-                    # flagship shapes, rl=8).
+                    # on TPU 2026-07-30 (cumulative cuts and single-op
+                    # ablations of a source-edited step, flagship shapes,
+                    # rl=8; the tools left in PR 25, the step's phases
+                    # are named scopes in a profiler trace now).
                     # Per-KERNEL overhead dominates this workload; the
                     # remaining floor is the step's serial kernel chain.
                     "perf_evidence_static_r5": {

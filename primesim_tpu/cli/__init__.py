@@ -1951,7 +1951,12 @@ def build_parser() -> argparse.ArgumentParser:
     r.add_argument(
         "--xprof",
         help="write a JAX profiler trace of the run to this directory "
-             "(jax engine; inspect with xprof/tensorboard)",
+             "(jax engine; inspect with xprof/tensorboard). Each device "
+             "op's name path holds its phase of the step: s.local, "
+             "s.probe, s.arb, s.dir, s.noc[/rank], s.dram[/rank], "
+             "s.commit, s.sync, s.fault, and s.chunk for the per-chunk "
+             "housekeeping (DESIGN.md §15); the host spans engine.dispatch "
+             "and engine.readback lie on the same timeline",
     )
     r.add_argument(
         "--stream-window", type=int, default=0, metavar="N",

@@ -10,10 +10,10 @@ directory rows STAGED into VMEM by two XLA row gathers (the one access
 shape Pallas cannot beat XLA at; see the fusion-boundary contract in
 DESIGN.md §11).
 
-`commit_step` fuses the back half ("scatters+tail", the ~1.0 ms cut in
-scripts/prof/prof_phase.py): all 7 + 2*rl L1 plane writes, the winner's
-full directory-row delta + join contributions, and the stacked counter
-fold — emitting the new L1 block, the per-core [DW] row delta (the
+`commit_step` fuses the back half (the L1 and directory scatters and the
+counter fold at the end of the scope `s.commit`): all 7 + 2*rl L1 plane
+writes, the winner's full directory-row delta + join contributions, and
+the stacked counter fold — emitting the new L1 block, the per-core [DW] row delta (the
 engine applies the one remaining data-dependent row scatter-add), and
 the folded counters.
 

@@ -46,14 +46,24 @@ def configure_compile_cache() -> str:
 
     `JAX_COMPILATION_CACHE_DIR` set: JAX reads it itself and no other
     directory is set in code. Unset: `<checkout>/.jax_cache`, derived
-    from the package location so two runs and two processes agree."""
+    from the package location so two runs and two processes agree.
+
+    Either way the cache key includes each instruction's metadata. JAX's
+    default leaves it out, and a cache that holds the executable of a
+    program differing only there (another commit's `named_scope`s and
+    source lines) then hands that one back: the run is right, but the
+    profiler trace and the compiled text name every op as the OTHER
+    commit did (measured on the v5e in PR 25: the phase scopes of `step`
+    were absent from a trace taken beside a cache the parent had
+    filled). The price is a compile where only metadata changed."""
+    import jax
+
+    jax.config.update("jax_compilation_cache_include_metadata_in_key", True)
     path = os.environ.get(CACHE_ENV)
     if path:
         return path
     here = os.path.dirname(os.path.abspath(__file__))
     path = os.path.join(os.path.dirname(os.path.dirname(here)), ".jax_cache")
-    import jax
-
     jax.config.update("jax_compilation_cache_dir", path)
     return path
 

@@ -1,7 +1,7 @@
 """Micro-benchmark: TPU gather/scatter cost vs index count, row width,
 and operand size — the data behind the engine's array-layout choices.
 
-Hypothesis from prof_bisect deltas: cost ~= per-INDEX overhead (~80 ns),
+Hypothesis from single-op ablations of the step: cost ~= per-INDEX overhead (~80 ns),
 mostly independent of row width and operand bytes; windowed (dynamic
 column) forms are pathological. If true, fusing metadata columns into the
 sharers rows (one gather per probe instead of three) is the right call.

@@ -85,11 +85,15 @@ def test_run_summary_names_the_device(tmp_path, capsys):
 
 def test_compile_cache_helper(monkeypatch, tmp_path):
     before = jax.config.jax_compilation_cache_dir
+    keyed = jax.config.jax_compilation_cache_include_metadata_in_key
     try:
-        # placed from outside: JAX reads the variable, code sets nothing
+        # placed from outside: JAX reads the variable, code sets no directory
         monkeypatch.setenv(device.CACHE_ENV, str(tmp_path))
         assert device.configure_compile_cache() == str(tmp_path)
         assert jax.config.jax_compilation_cache_dir == before
+        # wherever it lies, an executable is found by its metadata too: a
+        # program that differs only in named scopes is not another's
+        assert jax.config.jax_compilation_cache_include_metadata_in_key
         # unset: the fixed checkout path, every call and every process
         monkeypatch.delenv(device.CACHE_ENV)
         want = os.path.join(REPO, ".jax_cache")
@@ -106,6 +110,7 @@ def test_compile_cache_helper(monkeypatch, tmp_path):
         assert out.stdout.strip() == want
     finally:
         jax.config.update("jax_compilation_cache_dir", before)
+        jax.config.update("jax_compilation_cache_include_metadata_in_key", keyed)
 
 
 def test_worker_chip_plan(monkeypatch):

@@ -1,0 +1,106 @@
+"""The named scopes of `step` and `run_loop` (sim/engine.py::PHASES,
+DESIGN.md §15) reach the compiled program: every phase a machine enables
+is in some instruction's `op_name`, a phase it lacks is in none, and each
+`rank` scope sits under its own phase. Read from the compiled text, which
+is what a profiler trace and the benchmark's per-phase metrics read.
+"""
+
+import dataclasses
+import functools
+import glob
+import os
+import re
+
+import jax.numpy as jnp
+import pytest
+
+from primesim_tpu.config.machine import MachineConfig
+from primesim_tpu.sim.engine import PHASES, Engine, run_loop
+from primesim_tpu.trace import synth
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+N = 16
+_MESH = {"mesh_x": 4, "mesh_y": 4}
+
+# machine -> (config, has_sync, the optional phases it enables); every
+# machine runs local, probe, arb, dir, commit and chunk
+ALWAYS = {"s.local", "s.probe", "s.arb", "s.dir", "s.commit", "s.chunk"}
+MACHINES = {
+    # no contention model, no DRAM queue, a trace without locks or barriers
+    "plain": (dict(n_cores=N, n_banks=N, noc=_MESH, local_run_len=8), False,
+              set()),
+    # rung 3's selectors at 16 cores, and the sync phase with it
+    "rung3": (
+        dict(n_cores=N, n_banks=N, local_run_len=8, dram_queue=True,
+             core={"cpi": 1, "o3_overlap_256": 128},
+             noc=dict(_MESH, contention=True, contention_model="router",
+                      contention_lat=1)),
+        True, {"s.noc", "s.noc/rank", "s.dram", "s.dram/rank", "s.sync"},
+    ),
+    # the tile-count contention model (no ranking) on a faulty machine
+    "faulty": (
+        dict(n_cores=N, n_banks=N, local_run_len=8,
+             noc=dict(_MESH, contention=True, contention_model="tile",
+                      contention_lat=1)),
+        False, {"s.fault", "s.noc"},
+    ),
+}
+
+
+@functools.lru_cache(maxsize=None)
+def scope_paths(machine: str) -> frozenset:
+    """Every `op_name` of the machine's compiled `run_loop`."""
+    d, has_sync, _ = MACHINES[machine]
+    cfg = MachineConfig.from_dict(d)
+    if machine == "faulty":
+        cfg = dataclasses.replace(cfg, faults_enabled=True)
+    eng = Engine(cfg, synth.fft_like(N, n_phases=2, points_per_core=8, seed=3),
+                 chunk_steps=8)
+    text = run_loop.lower(
+        cfg, 8, eng.events, eng.state, jnp.asarray(1, jnp.int32),
+        has_sync=has_sync).compile().as_text()
+    return frozenset(re.findall(r'op_name="([^"]*)"', text))
+
+
+def _has(paths, name: str) -> bool:
+    return any(f"/{name}/" in p for p in paths)
+
+
+def test_phases_are_short_and_shallow():
+    assert len(set(PHASES)) == len(PHASES)
+    for name in PHASES:
+        parts = name.split("/")
+        assert len(parts) <= 2 and all(len(p) <= 8 for p in parts), name
+        assert parts[0].startswith("s."), name
+    assert ALWAYS | set().union(*(m[2] for m in MACHINES.values())) == set(PHASES)
+
+
+@pytest.mark.parametrize("name", PHASES)
+@pytest.mark.parametrize("machine", sorted(MACHINES))
+def test_scope_in_compiled_program_iff_enabled(machine, name):
+    enabled = name in ALWAYS | MACHINES[machine][2]
+    assert _has(scope_paths(machine), name) == enabled
+
+
+def test_rank_scopes_hold_the_ranking_under_their_own_phase():
+    paths = scope_paths("rung3")
+    for phase in ("s.noc", "s.dram"):
+        assert any(f"/{phase}/rank/jit(searchsorted)/" in p for p in paths)
+    # nothing of the ranking outside a rank scope, no scope inside another
+    assert not any("searchsorted" in p and "/rank/" not in p for p in paths)
+    assert not any(len(re.findall(r"/s\.\w+", p)) > 1 for p in paths)
+
+
+def test_benchmark_needles_are_phase_names():
+    """Every scope a per-phase metric reader spells is a name in PHASES."""
+    files = sorted(
+        glob.glob(os.path.join(ROOT, "benchmark", "metrics", "ph_*.py"))
+        + [os.path.join(ROOT, "benchmark", "metrics", "rank_noc_ms_step.py"),
+           os.path.join(ROOT, "benchmark", "phase_ops.py")])
+    spelled = {}
+    for path in files:
+        with open(path) as f:
+            for needle in re.findall(r'"/?(s\.[\w./]*?)/?"', f.read()):
+                spelled.setdefault(needle, path)
+    assert spelled, "no reader spells a phase"
+    assert not {n: p for n, p in spelled.items() if n not in PHASES}
