@@ -12,8 +12,8 @@ as an int8 one-hot matmul: a [C, C] `kless` comparison matrix contracted
 against a [C, n_seg] membership one-hot — O(C² · n_seg) int-MACs
 (~4×10⁹ per step at C=1024, n_seg≈4096).  `segmented_rank` computes the
 identical int32 counts in O(E log E) over the E = C·S flattened
-(segment, key) entries: one sort, one binary-search gather, one
-segment-start histogram.
+(segment, key) entries: one sort that carries each entry's flat index,
+two running maxima over the sorted order, one sort back by that index.
 
 EXACT-EQUIVALENCE ARGUMENT (why the counts are integer-equal to the
 matmul's, including duplicate keys):
@@ -21,15 +21,22 @@ matmul's, including duplicate keys):
 1. `lane_order` maps each lane's key to its dense first-occurrence rank
    ``ord[i] = #{j : key[j] < key[i]}``.  ord is monotone in key and
    collapses ties, so ``key[j] < key[i]  ⟺  ord[j] < ord[i]``.
-2. Each entry packs to ``seg·C + ord`` (strictly ordered by (seg, ord));
-   after one flat sort, ``searchsorted(side="left")`` returns the count
-   of entries with a strictly smaller packed value — all entries of
-   earlier segments plus same-segment entries with strictly smaller ord.
-   Equal keys share one packed value, so tied lanes never count each
+2. Each entry packs to ``seg·C + ord`` (strictly ordered by (seg, ord);
+   or keeps the pair as two sort keys).  After one flat sort an entry's
+   sorted position is the count of entries before it, and every member
+   of a (seg, ord) group — a run of equal sorted keys, found by a compare
+   against the left neighbour — shares the group's FIRST position
+   ``grp0`` (a running max of the group-start positions): the count of
+   entries with a strictly smaller (seg, ord), i.e. all entries of
+   earlier segments plus same-segment entries with strictly smaller
+   ord.  Equal keys share one group, so tied lanes never count each
    other, exactly like the matmul's strict `<`.
-3. Subtracting the segment's start offset (an exclusive cumsum of the
-   per-segment histogram = the count of entries in earlier segments)
-   leaves the same-segment strictly-smaller count: the matmul rank.
+3. Subtracting the first position of the entry's segment ``seg0`` (the
+   same running max over segment starts = the count of entries in
+   earlier segments) leaves the same-segment strictly-smaller count:
+   the matmul rank.  The flat index the sort carried as payload is a
+   permutation; sorting ``grp0 - seg0`` by it returns the ranks to
+   entry order.
 
 CONTRACT: one entry per (lane, segment) — a lane may not enter the same
 segment's FIFO twice in one step, or the sort counts it twice while the
@@ -53,28 +60,51 @@ import jax.numpy as jnp
 INT32_MAX = jnp.iinfo(jnp.int32).max
 
 
+_LANES = 128  # a TPU vector register's lane count: the row length of a scan
+
+
+def _running_max(x):
+    """Running max of a 1-D array of non-negative ints.
+
+    Written as XLA's TPU pipeline would rewrite a long 1-D ``cummax``
+    anyway — rows of 128, then the scan of the row maxima — because that
+    rewrite drops the ops' ``op_name``, and with it the phase scope a
+    profile bills them to (PERF.md §6, PR 26)."""
+    n = x.shape[0]
+    if n <= _LANES:
+        return jax.lax.cummax(x)
+    rows = jax.lax.cummax(
+        jnp.pad(x, (0, -n % _LANES)).reshape(-1, _LANES), axis=1
+    )
+    before = _running_max(rows[:, -1])[:-1]  # max of all earlier rows
+    before = jnp.concatenate([jnp.zeros((1,), x.dtype), before])
+    return jnp.maximum(rows, before[:, None]).reshape(-1)[:n]
+
+
+def _run_starts(sorted_vals):
+    """Position of the first element of each element's run of equal
+    values in a sorted 1-D array: a compare against the left neighbour
+    marks the starts, and since start positions only grow, a running max
+    carries each one forward over its run."""
+    pos = jnp.arange(sorted_vals.shape[0], dtype=jnp.int32)
+    start = jnp.concatenate(
+        [jnp.ones((1,), jnp.bool_), sorted_vals[1:] != sorted_vals[:-1]]
+    )
+    return _running_max(jnp.where(start, pos, 0))
+
+
 def lane_order(key):
     """Dense first-occurrence rank of each lane's arbitration key:
     ``ord[i] = #{j : key[j] < key[i]}`` — [C] int32 in [0, C).
 
     Monotone in key with ties collapsed, so strict key comparisons and
     strict ord comparisons agree; computed with one C-element sort plus
-    a group-start cummax (duplicates inherit their group's start)."""
+    a running max of the group starts (duplicates inherit their group's
+    start)."""
     C = key.shape[0]
     pos = jnp.arange(C, dtype=jnp.int32)
     sk, sl = jax.lax.sort((key.astype(jnp.int32), pos), num_keys=1)
-    grp_start = jnp.concatenate(
-        [jnp.ones((1,), jnp.bool_), sk[1:] != sk[:-1]]
-    )
-    gstart = jax.lax.cummax(jnp.where(grp_start, pos, 0))
-    return jnp.zeros((C,), jnp.int32).at[sl].set(gstart)
-
-
-def _segment_starts(seg_flat, n_seg: int):
-    """Exclusive per-segment start offsets: start[s] = # entries with
-    segment id < s, via histogram + exclusive cumsum ([n_seg + 1])."""
-    h = jnp.zeros((n_seg + 1,), jnp.int32).at[seg_flat].add(1, mode="drop")
-    return jnp.cumsum(h) - h
+    return jnp.zeros((C,), jnp.int32).at[sl].set(_run_starts(sk))
 
 
 def segmented_rank(seg, key=None, n_seg=None, *, order=None, method="auto"):
@@ -94,38 +124,35 @@ def segmented_rank(seg, key=None, n_seg=None, *, order=None, method="auto"):
 
     method="packed" sorts ``seg·C + ord`` as ONE int32 key (requires
     (n_seg + 1)·C ≤ int32 max — true for every shipped geometry);
-    "lex" is the general two-key lexicographic sort; "auto" picks.
+    "lex" sorts the two keys (seg, ord) lexicographically; "auto" picks
+    by that guard.  The key tuple is all the two forms differ in.
     """
     if n_seg is None:
         raise TypeError("segmented_rank: n_seg is required")
     C, S = seg.shape
+    E = C * S
     if order is None:
         order = lane_order(key)
-    seg = seg.astype(jnp.int32)
-    seg_flat = seg.reshape(C * S)
+    seg_flat = seg.astype(jnp.int32).reshape(E)
+    ord_flat = jnp.broadcast_to(order[:, None], (C, S)).reshape(E)
     if method == "auto":
         method = "packed" if (n_seg + 1) * C <= int(INT32_MAX) else "lex"
     if method == "packed":
-        packed = (seg * jnp.int32(C) + order[:, None]).reshape(C * S)
-        sp = jax.lax.sort(packed)
-        first = jnp.searchsorted(sp, packed, side="left").astype(jnp.int32)
-        start = _segment_starts(seg_flat, n_seg)
-        return (
-            first - start[jnp.clip(seg_flat, 0, n_seg)]
-        ).reshape(C, S)
-    if method == "lex":
-        E = C * S
-        pos = jnp.arange(E, dtype=jnp.int32)
-        ord_flat = jnp.broadcast_to(order[:, None], (C, S)).reshape(E)
-        sseg, sord, sidx = jax.lax.sort(
-            (seg_flat, ord_flat, pos), num_keys=2
-        )
-        one = jnp.ones((1,), jnp.bool_)
-        seg_start = jnp.concatenate([one, sseg[1:] != sseg[:-1]])
-        grp_start = seg_start | jnp.concatenate([one, sord[1:] != sord[:-1]])
-        seg0 = jax.lax.cummax(jnp.where(seg_start, pos, 0))
-        grp0 = jax.lax.cummax(jnp.where(grp_start, pos, 0))
-        return jnp.zeros((E,), jnp.int32).at[sidx].set(grp0 - seg0).reshape(
-            C, S
-        )
-    raise ValueError(f"segmented_rank: unknown method {method!r}")
+        keys = (seg_flat * jnp.int32(C) + ord_flat,)
+    elif method == "lex":
+        keys = (seg_flat, ord_flat)
+    else:
+        raise ValueError(f"segmented_rank: unknown method {method!r}")
+    pos = jnp.arange(E, dtype=jnp.int32)
+    # no order is needed among ties: tied entries share their rank
+    *skeys, sidx = jax.lax.sort(
+        (*keys, pos), num_keys=len(keys), is_stable=False
+    )
+    # sorted position of the first entry of each entry's segment, and of
+    # its (segment, ord) group: the later of the segment's start and the
+    # start of the run of equal last keys
+    seg0 = _run_starts(skeys[0] // C if method == "packed" else skeys[0])
+    grp0 = jnp.maximum(seg0, _run_starts(skeys[-1]))
+    # back to entry order: sidx is a permutation, sorting by it inverts it
+    _, rank = jax.lax.sort((sidx, grp0 - seg0), num_keys=1, is_stable=False)
+    return rank.reshape(C, S)

@@ -66,7 +66,7 @@ _ACC_BITS = 30  # device counter accumulators carry into hi above 2^30
 
 # The phases of `step` and `run_loop` as `jax.named_scope` names (DESIGN.md
 # §15): trace-time metadata that lands in every instruction's `op_name`
-# (`jit(run_loop)/.../s.noc/rank/jit(searchsorted)/...`) and changes nothing
+# (`jit(run_loop)/.../s.noc/rank/sort`) and changes nothing
 # the chip executes. This tuple is the one place that spells them; the
 # profiler trace of `--xprof`, the benchmark's per-phase metrics and
 # tests/test_phase_scopes.py read them from the compiled program. One

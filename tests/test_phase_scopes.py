@@ -85,10 +85,21 @@ def test_scope_in_compiled_program_iff_enabled(machine, name):
 def test_rank_scopes_hold_the_ranking_under_their_own_phase():
     paths = scope_paths("rung3")
     for phase in ("s.noc", "s.dram"):
-        assert any(f"/{phase}/rank/jit(searchsorted)/" in p for p in paths)
+        assert any(f"/{phase}/rank/sort" in p for p in paths)
     # nothing of the ranking outside a rank scope, no scope inside another
-    assert not any("searchsorted" in p and "/rank/" not in p for p in paths)
+    assert not any(p.endswith("/sort") and "/rank/" not in p for p in paths)
     assert not any(len(re.findall(r"/s\.\w+", p)) > 1 for p in paths)
+
+
+def test_rung3_loop_ranks_without_a_search():
+    """Each entry's rank is read from the sort's own output: no
+    `searchsorted` anywhere, and no loop (a bisection is a `while` where
+    it is not unrolled) under a rank scope."""
+    paths = scope_paths("rung3")
+    ranked = [p.split("/rank/", 1)[1] for p in paths if "/rank/" in p]
+    assert any(p.startswith("sort") for p in ranked)
+    assert not [p for p in paths if "searchsorted" in p]
+    assert not [p for p in ranked if "while" in p.split("/")]
 
 
 def test_benchmark_needles_are_phase_names():

@@ -32,7 +32,8 @@ def matmul_oracle(seg, key, n_seg, competitor=None):
     kless = (key[None, :] < key[:, None]) & comp[None, :]
     U = np.zeros((C, n_seg + 1), np.int32)
     U[np.arange(C)[:, None], np.clip(seg, 0, n_seg)] = 1
-    ranks = kless.astype(np.int32) @ U  # [C, n_seg + 1]
+    # float32 BLAS product: exact, the counts stay under 2^24
+    ranks = (kless.astype(np.float32) @ U.astype(np.float32)).astype(np.int32)
     out = np.take_along_axis(ranks, np.clip(seg, 0, n_seg), axis=1)
     return out  # valid wherever seg < n_seg
 
@@ -196,3 +197,64 @@ def test_packed_and_lex_agree_on_engine_scale():
                                   method="lex"))
     valid = seg < n_seg
     np.testing.assert_array_equal(a[valid], b[valid])
+
+
+def _case_engine_sentinel_heavy(rng):
+    # the rung-3 router shape: 1024 lanes x 124 (leg, hop) slots over the
+    # 4096 directed links of a 32x32 mesh, 85 % of the entries masked
+    C, S, n_seg = 1024, 124, 4096
+    seg = rng.integers(0, n_seg - S, (C, 1)) + np.arange(S)[None, :]
+    seg = np.where(rng.random((C, S)) < 0.85, n_seg, seg)
+    assert (seg == n_seg).mean() >= 0.8
+    return seg, rng.integers(0, 300, C), n_seg
+
+
+def _case_all_keys_equal(rng):
+    C, S, n_seg = 48, 5, 11
+    return _unique_segs(rng, C, S, n_seg), np.full(C, 7), n_seg
+
+
+def _case_one_segment_holds_every_entry(rng):
+    # every lane enters segment 5 once; the other slots are masked
+    C, S, n_seg = 40, 3, 9
+    seg = np.full((C, S), n_seg)
+    seg[:, 1] = 5
+    return seg, rng.integers(0, 12, C), n_seg
+
+
+def _case_dram_caller_one_slot(rng):
+    # engine.py's DRAM queue: S = 1, a bank or the sentinel per lane
+    C, n_seg = 1024, 1024
+    seg = rng.integers(0, 64, (C, 1))  # hot banks: long FIFOs
+    seg = np.where(rng.random((C, 1)) < 0.5, n_seg, seg)
+    return seg, rng.integers(0, 200, C), n_seg
+
+
+def _case_entries_not_a_multiple_of_128(rng):
+    C, S, n_seg = 50, 7, 23  # E = 350
+    return _unique_segs(rng, C, S, n_seg), rng.integers(0, 20, C), n_seg
+
+
+@pytest.mark.parametrize("method", ["packed", "lex"])
+@pytest.mark.parametrize(
+    "case",
+    [
+        _case_engine_sentinel_heavy,
+        _case_all_keys_equal,
+        _case_one_segment_holds_every_entry,
+        _case_dram_caller_one_slot,
+        _case_entries_not_a_multiple_of_128,
+    ],
+    ids=lambda f: f.__name__[len("_case_"):],
+)
+def test_shapes_the_sorted_order_rank_has_to_survive(case, method):
+    seg, key, n_seg = case(np.random.default_rng(26))
+    seg, key = seg.astype(np.int32), key.astype(np.int32)
+    got = np.asarray(
+        segmented_rank(jnp.asarray(seg), jnp.asarray(key), n_seg,
+                       method=method)
+    )
+    want = matmul_oracle(seg, key, n_seg)
+    valid = seg < n_seg
+    assert valid.any()
+    np.testing.assert_array_equal(got[valid], want[valid])
