@@ -73,6 +73,7 @@ _ACC_BITS = 30  # device counter accumulators carry into hi above 2^30
 # prefix, at most 8 characters and two levels: the benchmark's breakdown
 # keeps 64 characters of a label.
 _RANK = "rank"  # second level: the calls into ops/ranking.py
+_GRP = "grp"  # second level: the coarse vector's per-group reductions
 PHASES = (
     "s.fault",  # phase -1: fault injection
     "s.local",  # phase 0 quantum barrier + 0.5 local runs
@@ -80,6 +81,7 @@ PHASES = (
     "s.arb",  # 2: read-join coalescing, per-(bank,set) arbitration
     "s.dir",  # 3: directory transition, grants, victim, invalidation targets,
     #            prefetcher
+    "s.dir/" + _GRP,  # sharer_group > 1 only
     "s.noc",  # NoC contention: tile/link counts, or the hop-by-hop router
     "s.noc/" + _RANK,
     "s.dram",  # memory-controller queue
@@ -89,7 +91,7 @@ PHASES = (
     "s.sync",  # 2.7: locks and barriers
     "s.chunk",  # run_loop's per-chunk drain, rebase and termination test
 )
-(P_FAULT, P_LOCAL, P_PROBE, P_ARB, P_DIR, P_NOC, _, P_DRAM, _, P_COMMIT,
+(P_FAULT, P_LOCAL, P_PROBE, P_ARB, P_DIR, _, P_NOC, _, P_DRAM, _, P_COMMIT,
  P_SYNC, P_CHUNK) = PHASES
 
 @functools.lru_cache(maxsize=None)
@@ -109,23 +111,24 @@ def _group_tables(cfg: MachineConfig):
     mx = cfg.noc.mesh_x
     ids = np.arange(n_grp)[:, None] * G + np.arange(G)[None, :]  # [n_grp, G]
     valid = ids < C
-    mt = (ids % nt).astype(np.int64)
+    mt = (ids % nt).astype(np.int32)
     gx, gy = mt % mx, mt // mx
     members = valid.sum(1).astype(np.int32)  # [n_grp]
     max2hops = np.zeros((nt, n_grp), np.int32)
     sum2hops = np.zeros((nt, n_grp), np.int32)
-    step = max(1, (1 << 24) // (n_grp * G))  # bound temporaries to ~16M
+    # int32 temporaries of ~1M elements stay in the host's cache: rung 5's
+    # 16384 x 256 x 64 pairs take 1 s so, 14-23 s as int64 blocks of 16M
+    step = max(1, (1 << 20) // (n_grp * G))
     for lo in range(0, nt, step):
-        t = np.arange(lo, min(lo + step, nt))
+        t = np.arange(lo, min(lo + step, nt), dtype=np.int32)
         tx, ty = (t % mx)[:, None, None], (t // mx)[:, None, None]
         h = _topo.coord_hops(  # [T, n_grp, G]
             cfg.noc.topology, tx, ty, gx[None], gy[None],
             mx, cfg.noc.mesh_y, xp=np,
         )
-        max2hops[t] = np.where(valid[None], h, 0).max(2).astype(np.int32)
-        sum2hops[t] = (
-            np.where(valid[None], 2 * h, 0).sum(2).astype(np.int32)
-        )
+        h = np.where(valid[None], h, 0)
+        max2hops[t] = h.max(2)
+        sum2hops[t] = 2 * h.sum(2, dtype=np.int32)
     # NumPy out (converted at each use site): caching jnp arrays created
     # inside a trace would leak that trace's tracers into later jits
     return members, max2hops, sum2hops
@@ -944,73 +947,74 @@ def step(
         # under the same config.
         inv_row = write_w & llc_hit
         if cfg.sharer_group > 1:
-            n_grp = cfg.n_sharer_groups
-            memb_n, max2hops_n, sum2hops_n = _group_tables(cfg)
-            memb = jnp.asarray(memb_n)
-            max2hops = jnp.asarray(max2hops_n)
-            sum2hops = jnp.asarray(sum2hops_n)
-            bit5 = jnp.arange(32, dtype=jnp.int32)
+            with jax.named_scope(_GRP):
+                n_grp = cfg.n_sharer_groups
+                memb_n, max2hops_n, sum2hops_n = _group_tables(cfg)
+                memb = jnp.asarray(memb_n)
+                max2hops = jnp.asarray(max2hops_n)
+                sum2hops = jnp.asarray(sum2hops_n)
+                bit5 = jnp.arange(32, dtype=jnp.int32)
 
-            def _group_bools(words):  # [C, NW] -> [C, n_grp]
-                b = (words[:, :, None] >> bit5[None, None, :]) & 1
-                return b.reshape(C, NW * 32)[:, :n_grp] != 0
+                def _group_bools(words):  # [C, NW] -> [C, n_grp]
+                    b = (words[:, :, None] >> bit5[None, None, :]) & 1
+                    return b.reshape(C, NW * 32)[:, :n_grp] != 0
 
-            grp = _group_bools(shw)
-            vic_grp = _group_bools(vic_shw)
-            # round-trip latency 2*(h*link + (h+1)*router) is monotone
-            # nondecreasing in hop count, so the per-group max over members
-            # is the latency AT the max hop count — the geometry-only hops
-            # table composes with the TRACED link/router knobs here
-            mh_rows = max2hops[btile]  # [C, n_grp]
-            ml_rows = 2 * (mh_rows * kn.link_lat + (mh_rows + 1) * kn.router_lat)
-            sumh_rows = sum2hops[btile]
-            selfg = jnp.arange(n_grp, dtype=jnp.int32)[None, :] == g_c[:, None]
-            self_rec = jnp.any(grp & selfg, axis=1)  # requester's group flagged
-            # serialization latency spans every recorded core of flagged
-            # groups INCLUDING the requester's slot (golden: the home node
-            # serializes the whole group broadcast); messages/counters skip
-            # the requester
-            inv_lat = jnp.where(
-                inv_row,
-                jnp.max(jnp.where(grp, ml_rows, 0), axis=1),
-                0,
-            )
-            inv_count = jnp.where(
-                inv_row,
-                jnp.sum(jnp.where(grp, memb[None, :], 0), axis=1)
-                - self_rec.astype(jnp.int32),
-                0,
-            )
-            _, self_hops = _one_way(btile, ctile, cfg, kn)
-            inv_hops = jnp.where(
-                inv_row,
-                jnp.sum(jnp.where(grp, sumh_rows, 0), axis=1)
-                - jnp.where(self_rec, 2 * self_hops, 0),
-                0,
-            )
-            # back-invalidation: every recorded core of the victim's flagged
-            # groups, plus its owner when not already recorded
-            og = jnp.maximum(vic_owner, 0) >> logG
-            own_rec = (
-                jnp.take_along_axis(vic_grp, og[:, None], axis=1)[:, 0]
-                & (vic_owner >= 0)
-            )
-            own_extra = (vic_owner >= 0) & ~own_rec
-            _, own_hops = _one_way(
-                btile, jnp.maximum(vic_owner, 0) % n_tiles, cfg, kn
-            )
-            back_count = jnp.where(
-                vic_valid,
-                jnp.sum(jnp.where(vic_grp, memb[None, :], 0), axis=1)
-                + own_extra.astype(jnp.int32),
-                0,
-            )
-            back_hops = jnp.where(
-                vic_valid,
-                jnp.sum(jnp.where(vic_grp, sumh_rows, 0), axis=1)
-                + jnp.where(own_extra, 2 * own_hops, 0),
-                0,
-            )
+                grp = _group_bools(shw)
+                vic_grp = _group_bools(vic_shw)
+                # round-trip latency 2*(h*link + (h+1)*router) is monotone
+                # nondecreasing in hop count, so the per-group max over members
+                # is the latency AT the max hop count — the geometry-only hops
+                # table composes with the TRACED link/router knobs here
+                mh_rows = max2hops[btile]  # [C, n_grp]
+                ml_rows = 2 * (mh_rows * kn.link_lat + (mh_rows + 1) * kn.router_lat)
+                sumh_rows = sum2hops[btile]
+                selfg = jnp.arange(n_grp, dtype=jnp.int32)[None, :] == g_c[:, None]
+                self_rec = jnp.any(grp & selfg, axis=1)  # requester's group flagged
+                # serialization latency spans every recorded core of flagged
+                # groups INCLUDING the requester's slot (golden: the home node
+                # serializes the whole group broadcast); messages/counters skip
+                # the requester
+                inv_lat = jnp.where(
+                    inv_row,
+                    jnp.max(jnp.where(grp, ml_rows, 0), axis=1),
+                    0,
+                )
+                inv_count = jnp.where(
+                    inv_row,
+                    jnp.sum(jnp.where(grp, memb[None, :], 0), axis=1)
+                    - self_rec.astype(jnp.int32),
+                    0,
+                )
+                _, self_hops = _one_way(btile, ctile, cfg, kn)
+                inv_hops = jnp.where(
+                    inv_row,
+                    jnp.sum(jnp.where(grp, sumh_rows, 0), axis=1)
+                    - jnp.where(self_rec, 2 * self_hops, 0),
+                    0,
+                )
+                # back-invalidation: every recorded core of the victim's flagged
+                # groups, plus its owner when not already recorded
+                og = jnp.maximum(vic_owner, 0) >> logG
+                own_rec = (
+                    jnp.take_along_axis(vic_grp, og[:, None], axis=1)[:, 0]
+                    & (vic_owner >= 0)
+                )
+                own_extra = (vic_owner >= 0) & ~own_rec
+                _, own_hops = _one_way(
+                    btile, jnp.maximum(vic_owner, 0) % n_tiles, cfg, kn
+                )
+                back_count = jnp.where(
+                    vic_valid,
+                    jnp.sum(jnp.where(vic_grp, memb[None, :], 0), axis=1)
+                    + own_extra.astype(jnp.int32),
+                    0,
+                )
+                back_hops = jnp.where(
+                    vic_valid,
+                    jnp.sum(jnp.where(vic_grp, sumh_rows, 0), axis=1)
+                    + jnp.where(own_extra, 2 * own_hops, 0),
+                    0,
+                )
         elif cfg.sharer_chunk_words:
             K = cfg.sharer_chunk_words
             nblk = NW // K

@@ -1,0 +1,41 @@
+"""The benchmark's own modules (`benchmark/*.py`) and its own tests
+(`benchmark/tests/`), for the tier-1 tests that hold the benchmark's forks
+and references to the program. Importing this puts `benchmark/` on the
+path, as `benchmark/run.py` does for itself."""
+
+import importlib.util
+import os
+import sys
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+BENCH = os.path.join(ROOT, "benchmark")
+if BENCH not in sys.path:
+    sys.path.insert(0, BENCH)
+
+
+def _load(name: str, path: str):
+    spec = importlib.util.spec_from_file_location(name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+def load_benchmark_tests():
+    """`benchmark/tests/test_benchmark.py` as a module. It says `from
+    conftest import BENCH, ROOT` and means the benchmark's conftest, while
+    under `pytest tests/` that name is this directory's: the benchmark's
+    stands in under it for as long as the module loads."""
+    tests = os.path.join(BENCH, "tests")
+    mine = sys.modules.get("conftest")
+    sys.modules["conftest"] = _load("benchmark_tests_conftest",
+                                    os.path.join(tests, "conftest.py"))
+    sys.path.insert(0, tests)  # its helper `tinycell`
+    try:
+        return _load("benchmark_tests_test_benchmark",
+                     os.path.join(tests, "test_benchmark.py"))
+    finally:
+        sys.path.remove(tests)
+        if mine is None:
+            del sys.modules["conftest"]
+        else:
+            sys.modules["conftest"] = mine

@@ -44,6 +44,9 @@ MACHINES = {
                       contention_lat=1)),
         False, {"s.fault", "s.noc"},
     ),
+    # the coarse sharer vector (Dir-G): the only machine with group work
+    "coarse": (dict(n_cores=N, n_banks=N, noc=_MESH, local_run_len=8,
+                    sharer_group=4), False, {"s.dir/grp"}),
 }
 
 
@@ -80,6 +83,17 @@ def test_phases_are_short_and_shallow():
 def test_scope_in_compiled_program_iff_enabled(machine, name):
     enabled = name in ALWAYS | MACHINES[machine][2]
     assert _has(scope_paths(machine), name) == enabled
+
+
+def test_group_scope_holds_the_coarse_vectors_reductions_and_only_there():
+    """`s.dir/grp` sits under `s.dir` and holds the group-table gathers and
+    the masked reductions; a machine with `sharer_group` 1 compiles to a
+    text without it (the parametrised test above, for every other machine)."""
+    paths = scope_paths("coarse")
+    grp = [p.split("/s.dir/grp/", 1)[1] for p in paths if "/s.dir/grp/" in p]
+    assert any(p.startswith("reduce_max") for p in grp)
+    assert any(p.startswith("reduce_sum") for p in grp)
+    assert not any("/grp/" in p and "s.dir/grp/" not in p for p in paths)
 
 
 def test_rank_scopes_hold_the_ranking_under_their_own_phase():
