@@ -158,6 +158,46 @@ from ..noc.topology import path_links as _path_links  # noqa: E402
 from ..ops.ranking import lane_order, segmented_rank  # noqa: E402
 
 
+def _pick(x, idx):
+    """`x[..., idx]` along the last axis, `idx` of `x`'s shape less that
+    axis (or broadcasting to it: one index for several rows): a compare
+    against an iota and a masked sum instead of a gather.
+    Exactly one position matches (`0 <= idx < n`), so the sum is the picked
+    word to the bit. For a pick out of a row the core already holds: on the
+    v5e an XLA `gather` costs 7-14 ns an ELEMENT whatever the row, while
+    this is dense vector work that fuses into its producer."""
+    oh = jnp.arange(x.shape[-1], dtype=jnp.int32) == idx[..., None]
+    return jnp.sum(jnp.where(oh, x, 0), axis=-1)
+
+
+def _l1_set_read(cfg: MachineConfig, l1, sets, planes):
+    """The `planes` (static plane numbers of the fused L1 array) of each
+    core's L1 sets `sets` [C, K] -> [C, K, len(planes), W1].
+
+    The core's own row is read WHOLE and the set selected on the chip: for
+    each (plane, way) the static slice `l1[:, c0 : c0 + S1]` (a view of
+    the row; reshaping `l1` to put S1 on an axis of its own re-tiles the
+    array, a 168 MB copy a step on rung 5) is masked by `iota(S1) == set`
+    and summed over the set axis. One lane matches, so the int32 sum is
+    the stored word to the bit. Dense vector work: 34 us at 1024 cores
+    for the local run's 73728 words, which as one element
+    `take_along_axis` cost 1021 us; the select's work grows with S1 and
+    the gather's does not, and at 2048 sets x 16384 cores it still wins,
+    6.9 against 16.9 ms (scripts/prof/prof_gather.py, PERF.md section 6),
+    so there is no second form."""
+    S1, W1 = cfg.l1.sets, cfg.l1.ways
+    FS = W1 * S1
+    oh = sets[:, :, None] == jnp.arange(S1, dtype=jnp.int32)  # [C, K, S1]
+    words = [
+        jnp.sum(
+            jnp.where(oh, jax.lax.slice_in_dim(l1, c0, c0 + S1, axis=1)[:, None], 0),
+            axis=2,
+        )
+        for c0 in (p * FS + w * S1 for p in planes for w in range(W1))
+    ]
+    return jnp.stack(words, axis=2).reshape(*sets.shape, len(planes), W1)
+
+
 def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
               run_patch=None, step_no=None):
     """Gather the accessed L1 set and derive each way's EFFECTIVE MESI state.
@@ -192,23 +232,16 @@ def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
     tags, LRU stamps, and effective per-way MESI states, all [C, W1].
     """
     S1, W1 = cfg.l1.sets, cfg.l1.ways
-    FS = W1 * S1
     l1s = line & (S1 - 1)
-    # the fused L1 array holds four planes (tag/state/lru/ptr) at a
-    # FS-column stride; ONE take_along over the concatenated plane
-    # columns fetches the accessed set's whole bookkeeping
+    # the fused L1 array holds four planes (tag/state/lru/ptr; a fifth,
+    # the fill-time epoch, under the coarse vector) at a W1*S1-column
+    # stride: the accessed set's whole bookkeeping in one read of the row
     w1cols = jnp.arange(W1, dtype=jnp.int32)[None, :] * S1 + l1s[:, None]
-    planes = [w1cols, w1cols + FS, w1cols + 2 * FS, w1cols + 3 * FS]
-    if cfg.sharer_group > 1:
-        planes.append(w1cols + 4 * FS)  # fill-time epoch plane
-    rows = jnp.take_along_axis(
-        l1, jnp.concatenate(planes, axis=1), axis=1
-    )  # [C, 4*W1] or [C, 5*W1]
-    tag_rows = rows[:, :W1]
-    state_rows = rows[:, W1 : 2 * W1]
-    lru_rows = rows[:, 2 * W1 : 3 * W1]
-    ptr_rows = rows[:, 3 * W1 : 4 * W1]
-    eph_rows = rows[:, 4 * W1 :] if cfg.sharer_group > 1 else None
+    rows = _l1_set_read(
+        cfg, l1, l1s[:, None], range(5 if cfg.sharer_group > 1 else 4)
+    )[:, 0]  # [C, 4 or 5, W1]
+    tag_rows, state_rows, lru_rows, ptr_rows = (rows[:, p] for p in range(4))
+    eph_rows = rows[:, 4] if cfg.sharer_group > 1 else None
     if run_patch is not None:
         # the local run's deferred L1 writes (applied only in phase 4.A's
         # fused scatter) patched in-register: silent E->M at wm columns,
@@ -427,25 +460,15 @@ def step(
             pev = _pev0  # [C, rl+1, 4] — gathered once in phase 0
             pline = pev[:, :, 2]  # line-granular (Trace.line_events)
             ps = pline & (S1 - 1)
-            pcols = (
-                jnp.arange(W1, dtype=jnp.int32)[None, None, :] * S1
-                + ps[:, :, None]
-            )  # [C, rl+1, W1]
-            pcf = pcols.reshape(C, (rl + 1) * W1)
-            # tag + state planes of every candidate's set in ONE take_along
-            # (lru/ptr aren't needed for run hit probes; feeding them to the
-            # arbitration probe too was tried and measured SLOWER — the extra
-            # select/patch kernels outweighed the saved gathers). The coarse
-            # vector additionally needs the fill-time epoch plane.
-            KW = (rl + 1) * W1
-            pl_cols = [pcf, pcf + FS]
-            if cfg.sharer_group > 1:
-                pl_cols.append(pcf + 4 * FS)
-            pts = jnp.take_along_axis(
-                st.l1, jnp.concatenate(pl_cols, axis=1), axis=1
-            )
-            ptagr = pts[:, :KW].reshape(C, rl + 1, W1)
-            pstater = pts[:, KW : 2 * KW].reshape(C, rl + 1, W1)
+            # tag + state planes of every candidate's set in ONE read of the
+            # core's row (lru/ptr aren't needed for run hit probes; feeding
+            # them to the arbitration probe too was tried and measured SLOWER
+            # — the extra select/patch kernels outweighed the saved reads).
+            # The coarse vector additionally needs the fill-time epoch plane.
+            pts = _l1_set_read(
+                cfg, st.l1, ps, (0, 1, 4) if cfg.sharer_group > 1 else (0, 1)
+            )  # [C, rl+1, 2 or 3, W1]
+            ptagr, pstater = pts[:, :, 0], pts[:, :, 1]
             pbank = pline & (B - 1)
             pbset = (pline >> logB) & (S2 - 1)
             pslot = pbank * S2 + pbset
@@ -454,35 +477,23 @@ def step(
             pmmatch = pmeta[..., 0] == pline[:, :, None]
             pmhas = jnp.any(pmmatch, axis=2)
             pmway = jnp.argmax(pmmatch, axis=2).astype(jnp.int32)
-            pown = jnp.take_along_axis(pmeta[..., 1], pmway[:, :, None], axis=2)[
-                :, :, 0
-            ]
+            # way and word picks out of rows already in hand: selects, not
+            # gathers (`_pick`); `argmax` keeps first-match order
+            pown = _pick(pmeta[..., 1], pmway)
             g_c0 = arange_c >> (cfg.sharer_group.bit_length() - 1)
             # the self sharer word rides the row gather: in-register select
-            pshw = jnp.take_along_axis(
-                pmrows[:, :, MW:],
-                (pmway * NW + (g_c0[:, None] >> 5))[:, :, None],
-                axis=2,
-            )[:, :, 0]
+            pshw = _pick(pmrows[:, :, MW:], pmway * NW + (g_c0[:, None] >> 5))
             pbit = ((pshw >> (g_c0[:, None] & 31)) & 1) != 0
             pmatch_l = (ptagr == pline[:, :, None]) & (pstater != I)
             plhit = jnp.any(pmatch_l, axis=2)
             plway = jnp.argmax(pmatch_l, axis=2).astype(jnp.int32)
-            plstate = jnp.take_along_axis(pstater, plway[:, :, None], axis=2)[
-                :, :, 0
-            ]
+            plstate = _pick(pstater, plway)
             if cfg.sharer_group > 1:
                 # epoch guard (see _validate_ways): the group bit only keeps
                 # this core's S line alive if no sharer-clearing transition
                 # happened since its fill
-                pleph = jnp.take_along_axis(
-                    pts[:, 2 * KW :].reshape(C, rl + 1, W1),
-                    plway[:, :, None],
-                    axis=2,
-                )[:, :, 0]
-                pveph = jnp.take_along_axis(
-                    pmrows[:, :, 3 * W2 : 4 * W2], pmway[:, :, None], axis=2
-                )[:, :, 0]
+                pleph = _pick(pts[:, :, 2], plway)
+                pveph = _pick(pmrows[:, :, 3 * W2 : 4 * W2], pmway)
                 pbit = pbit & (pveph == pleph)
             peff = jnp.where(
                 ~(plhit & pmhas),
@@ -501,9 +512,9 @@ def step(
                 # moesi (config validation), so pbit IS the self bit and the
                 # word popcount is an exact sharer count.
                 psh_all = pmrows[:, :, MW:].reshape(C, rl + 1, W2, NW)
-                pwords = jnp.take_along_axis(
-                    psh_all, pmway[:, :, None, None], axis=2
-                )[:, :, 0]  # [C, rl+1, NW]
+                pwords = _pick(
+                    jnp.swapaxes(psh_all, 2, 3), pmway[:, :, None]
+                )  # [C, rl+1, NW]: the matching way's sharer words
                 ptot = jnp.sum(jax.lax.population_count(pwords), axis=2)
                 pothers = (ptot - pbit.astype(jnp.int32)) > 0
                 peff = jnp.where(
@@ -591,8 +602,8 @@ def step(
             # of the prefetch here (classification, L1 planes, home metadata
             # row) was tried and measured slower: the select/patch kernels
             # cost more than the gathers they replaced.
-            consumed = (ptr_c - st.ptr)[:, None, None]
-            ev = jnp.take_along_axis(pev, consumed, axis=1)[:, 0]  # [C, 4]
+            consumed = (ptr_c - st.ptr)[:, None]
+            ev = _pick(jnp.swapaxes(pev, 1, 2), consumed)  # [C, 4]
         else:
             p = jnp.minimum(ptr_c, T - 1)
             ev = events[arange_c, p]  # [C, 4]

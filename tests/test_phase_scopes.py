@@ -11,11 +11,12 @@ import glob
 import os
 import re
 
+import jax
 import jax.numpy as jnp
 import pytest
 
 from primesim_tpu.config.machine import MachineConfig
-from primesim_tpu.sim.engine import PHASES, Engine, run_loop
+from primesim_tpu.sim.engine import PHASES, Engine, run_loop, step
 from primesim_tpu.trace import synth
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -114,6 +115,40 @@ def test_rung3_loop_ranks_without_a_search():
     assert any(p.startswith("sort") for p in ranked)
     assert not [p for p in paths if "searchsorted" in p]
     assert not [p for p in ranked if "while" in p.split("/")]
+
+
+@pytest.mark.parametrize("machine", ["plain", "coarse"])
+def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
+    """The core's own L1 row is read whole and the set selected
+    (`_l1_set_read`), and the local run's way and word picks are selects
+    (`_pick`): no `gather` of `step` has the L1 array as its operand, with
+    four planes or, under the coarse vector, five; and `s.local` holds two
+    gathers, of rows the core does not hold: its events and the home
+    sets' directory rows."""
+    d, has_sync, _ = MACHINES[machine]
+    cfg = MachineConfig.from_dict(d)
+    eng = Engine(cfg, synth.fft_like(N, n_phases=2, points_per_core=8, seed=3),
+                 chunk_steps=8)
+    shapes = {"l1": eng.state.l1.shape, "events": eng.events.shape,
+              "dirm": eng.state.dirm.shape}
+    assert len(set(shapes.values())) == 3
+    gathers = []  # (scope path, operand shape), through every sub-jaxpr
+
+    def walk(jaxpr, prefix):
+        for eqn in jaxpr.eqns:
+            path = f"{prefix}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "gather":
+                gathers.append((path, eqn.invars[0].aval.shape))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, path)
+
+    walk(jax.make_jaxpr(
+        lambda ev, st: step(cfg, ev, st, has_sync=has_sync))(
+            eng.events, eng.state).jaxpr, "")
+    assert any("s.probe" in p for p, _ in gathers)  # the walk sees scopes
+    assert not [p for p, shape in gathers if shape == shapes["l1"]]
+    assert sorted(shape for p, shape in gathers if "s.local" in p) == sorted(
+        [shapes["events"], shapes["dirm"]])
 
 
 def test_benchmark_needles_are_phase_names():
