@@ -305,8 +305,8 @@ def phase_kernels(platform: str, meter: CompileMeter, rung3: str) -> dict:
     print(f"[kernels] rung-3 run_loop: {n} Mosaic custom calls "
           f"{json.dumps(meter.lap())}", flush=True)
 
-    # sharer_reductions alone: the plain 1024-core machine (bench.py's
-    # headline geometry), pallas_reduce on, XLA step otherwise
+    # sharer_reductions alone: the plain 1024-core machine
+    # (benchmark/configs/mesh1024.json), pallas_reduce on, XLA step otherwise
     plain = MachineConfig(
         n_cores=1024, n_banks=1024,
         l1=CacheConfig(size=32 * 1024, ways=4, line=64, latency=2),

@@ -14,8 +14,7 @@ It snapshots the jit compile-cache entry count (`fn._cache_size()`,
 present on jax's jitted callables) of the watched entry points on
 entry and asserts on exit that no watched function grew by more than
 `allowed` entries. `allowed=1` permits the first compile of a fresh
-geometry; `allowed=0` guards an already-warm measurement loop
-(bench.py's timed sections). If the running jax build doesn't expose
+geometry; `allowed=0` guards an already-warm measurement loop. If the running jax build doesn't expose
 `_cache_size` the sentinel degrades to a no-op rather than failing.
 """
 

@@ -11,7 +11,7 @@ one CLI:
     primetpu info configs/rung3_1024core_o3.json
 
 `run` simulates a trace (from a PTPU file or a named synthetic generator)
-on a machine config, prints a one-line JSON summary (the bench.py format),
+on a machine config, prints a one-line JSON summary,
 and optionally writes the reference-style text report. Synth specs are
 `name[:key=int,...]` over primesim_tpu.trace.synth.GENERATORS.
 """
@@ -476,8 +476,8 @@ def cmd_run(ns) -> int:
 
         # warm the jit cache at the measured shapes (one chunk) so the
         # reported MIPS measures simulation, not compilation — the same
-        # protocol as bench.py; comparable numbers matter more than the
-        # one-off compile cost shown to an interactive user. The debug
+        # protocol as benchmark/measure.py; comparable numbers matter more
+        # than the one-off compile cost shown to an interactive user. The debug
         # path dispatches run_chunk, not the fused run_loop — warm the
         # function the run will actually use.
         warm = Engine(cfg, tr, chunk_steps=ns.chunk_steps, mesh=mesh)

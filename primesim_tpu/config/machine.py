@@ -272,7 +272,7 @@ class MachineConfig:
     dram_queue: bool = False
     dram_service: int = 0
     # Route the dense sharer-expansion reductions through the Pallas TPU
-    # kernel (primesim_tpu/ops/reductions.py) instead of the jnp path —
+    # kernel (primesim_tpu/kernels/reductions.py) instead of the jnp path —
     # bit-identical results; full-map vectors only (the coarse/chunked
     # modes have their own reduction shapes). On the CPU the kernel runs
     # interpreted, so tests exercise it; on a TPU Mosaic compiles it.

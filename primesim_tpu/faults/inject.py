@@ -1,7 +1,7 @@
 """Step-time fault injection: scheduled events, ECC draws, dead-core
 scrubbing, and link-detour penalties (DESIGN.md §12).
 
-Everything here is called from inside `sim.engine.step` under the STATIC
+Everything here is called from inside `sim.step.step` under the STATIC
 `cfg.faults_enabled` gate, on TRACED values only — no host randomness, no
 data-dependent shapes — so a fault-enabled program still compiles once
 per geometry and vmaps over the fleet's batch axis unchanged.

@@ -1,7 +1,7 @@
 """Golden reference simulator: slow, scalar, obviously correct.
 
 Implements DESIGN.md's step semantics with plain Python/NumPy loops. This is
-the oracle the vectorized JAX engine (`primesim_tpu/sim/engine.py`) must
+the oracle the vectorized JAX engine (`primesim_tpu/sim/step.py`, run by `sim/engine.py`) must
 match BIT-EXACTLY on per-core cycles, cache/directory state, and counters
 (SURVEY.md §4: the single highest-value test asset the reference lacks).
 

@@ -12,7 +12,7 @@ kernels, selected by `MachineConfig.step_impl == "pallas"`:
 - `step_kernels.commit_step` — phase 4.A + the counter fold: the fused
   L1 writes, the directory row delta, and the stacked counter add.
 - `reductions.sharer_reductions` — the dense invalidation /
-  back-invalidation reductions (absorbed from ops/reductions.py).
+  back-invalidation reductions.
 
 `layouts.py` pins the shared block geometry (core-block size, plane and
 directory-row column maps) and the Mosaic-safe select/reduce idioms all

@@ -1,7 +1,6 @@
 """Pallas TPU kernel for the dense sharer-expansion reductions
 (SURVEY.md §2 #4/#6's "part of the Pallas uncore kernel" column) — the
-third resident kernel of the step subsystem (absorbed from
-ops/reductions.py, which remains as an import shim).
+third resident kernel of the step subsystem.
 
 The step's invalidation / back-invalidation reductions expand each
 winner's packed sharer words into per-target-core booleans and reduce

@@ -3,7 +3,7 @@ block (ISSUE 6 second prong; DESIGN.md §13) — the fourth resident kernel
 of the step subsystem, behind the same config-gated `step_impl="pallas"`
 selector as probe/classify and commit.
 
-The router walk (sim/engine.py, NocConfig contention_model="router")
+The router walk (sim/step.py::_router_walk, NocConfig contention_model="router")
 composes, per leg of every home transaction, the same-step FIFO wait
 floors F_k = max(link_free, base) + rank·link_lat at each hop k, runs
 the closed-form contention cascade

@@ -170,7 +170,7 @@ def _probe_kernel(
                     (hmm[:, k : k + 1] != 0) & mk, step_no, lru_w[w]
                 )
 
-    # ---- pointer validation (sim/engine._validate_ways semantics) ------
+    # ---- pointer validation (sim/step._validate_ways semantics) ------
     logG = G.bit_length() - 1
     g_c = cid >> logG
     u_w = g_c >> 5  # self -> sharer word / bit (group id under Dir-G)

@@ -104,7 +104,7 @@ def concat_legs(legs):
     occupancy count and the hop-by-hop router block run every per-link
     operation ONCE over this concatenation (one scatter, one rank, one
     gather pair) — per-kernel overhead is the budget, so per-path loops
-    become per-path kernels (sim/engine.py)."""
+    become per-path kernels (sim/step.py::_router_walk)."""
     pths = [p for p, _ in legs]
     masks = [jnp.broadcast_to(m[:, None], p.shape) for p, m in legs]
     return jnp.concatenate(pths, axis=1), jnp.concatenate(masks, axis=1)

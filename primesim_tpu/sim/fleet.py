@@ -4,7 +4,7 @@ Round-5 profiling (the pre-round r05 record, ROADMAP Queue S) pinned the
 ~2.8 ms/step floor on the step's SERIAL kernel-chain depth, not bytes: isolated gathers/scatters of
 any tested shape cost ~0.02 ms, so each kernel launch is mostly idle
 capacity. PriME's headline use case is throughput across many concurrent
-runs (the ISPASS'14 multi-host aggregate bench.py baselines against), and
+runs (the ISPASS'14 multi-host aggregate), and
 a parameter sweep is the common shape of that traffic. So: `jax.vmap` the
 existing `run_chunk`/`run_loop` over a leading batch axis of B independent
 simulations sharing one GEOMETRY (core count, cache shapes, mesh), and one

@@ -1,7 +1,7 @@
 """Observable-state helpers for the pull-based engine (NumPy, host-side).
 
 The vectorized engine keeps only locally-written L1 state and derives each
-way's effective MESI state from the directory on access (engine.py phase 1).
+way's effective MESI state from the directory on access (sim/step.py::_l1_probe).
 `effective_l1_state` re-derives that mapping on host arrays so tests and
 debug invariants can compare the engine's *observable* cache contents
 against the eager golden model bit-for-bit: at every (core, set, way) the
@@ -127,7 +127,7 @@ def effective_l1_state(
     shbit = ((shword >> (gbit & 31).astype(np.uint32)) & 1) != 0
     if cfg.sharer_group > 1:
         # coarse vector: the group bit only validates an entry filled at
-        # the directory entry's CURRENT invalidation epoch (engine.py
+        # the directory entry's CURRENT invalidation epoch (step.py
         # `_validate_ways` — a neighbor's re-share must not resurrect an
         # invalidated copy)
         if l1_eph is None or llc_eph is None:

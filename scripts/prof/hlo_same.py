@@ -1,16 +1,22 @@
 """Did a change alter the program the chip executes, or only its metadata?
 
-    PYTHONPATH=<checkout> python scripts/prof/hlo_same.py dump <benchmark config .json> <out.txt>
+    PYTHONPATH=<checkout> python scripts/prof/hlo_same.py dump <config .json> <out.txt> [--sync]
     python scripts/prof/hlo_same.py compare <a.txt> <b.txt>
 
-`dump` compiles the `run_loop` of the `primesim_tpu` it imports for the
-configuration's machine, `step_impl` and `chunk_steps` on the present
-default device (one device; shapes only, nothing runs) and writes the
-compiled module's text. Run it once for each checkout, then `compare` the
+`dump` compiles the `run_loop` of the `primesim_tpu` it imports on the
+present default device (one device; shapes only, nothing runs) and writes
+the compiled module's text. The file is a benchmark configuration
+(`benchmark/configs/*.json`: its machine, `step_impl` and `chunk_steps`)
+or a plain machine file (`configs/*.json`: every static selector is a
+field of it; chunks of 8 steps); `--sync` compiles the step for a trace
+with locks and barriers (`has_sync` true). Run it once for each checkout, then `compare` the
 two files with everything that is only
 metadata removed: every instruction's `metadata={...}` (`op_name`, source
-line, stack frame) and the file/function/stack-frame tables those point
-into. It says whether the rest is byte-identical and, where it is not,
+line, stack frame), the file/function/stack-frame tables those point
+into, and the debug locations inside a Mosaic kernel (a Pallas
+`tpu_custom_call` carries its kernel as serialized MLIR, call-site lines
+and all: it is read back and stands in the comparison as the digest of
+its text without them). It says whether the rest is byte-identical and, where it is not,
 whether the two texts still agree in everything but instruction names
 (XLA numbers the instructions it creates late after the names the front
 end gave, and a `jax.named_scope` reaches a few of those), naming what
@@ -19,7 +25,9 @@ differs. Exit code 0: identical, or identical up to names; 1: not.
 
 from __future__ import annotations
 
+import base64
 import collections
+import hashlib
 import json
 import re
 import sys
@@ -28,13 +36,26 @@ _METADATA = re.compile(r",? ?metadata=\{[^}]*\}")
 _TABLES = re.compile(
     r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:\d+ .*\n)*")
 _NAME = re.compile(r"%[\w.\-]+")
+_MOSAIC = re.compile(r'("custom_call_config":\{"body":")([A-Za-z0-9+/=]+)"')
+
+
+def _kernel_digest(found: re.Match) -> str:
+    from jaxlib.mlir import ir
+
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    kernel = ir.Module.parse(base64.b64decode(found.group(2)), context=ctx)
+    asm = kernel.operation.get_asm(enable_debug_info=False)
+    return f'{found.group(1)}sha256:{hashlib.sha256(asm.encode()).hexdigest()}"'
 
 
 def strip(text: str) -> str:
-    return _TABLES.sub("\n", _METADATA.sub("", text))
+    text = _TABLES.sub("\n", _METADATA.sub("", text))
+    return _MOSAIC.sub(_kernel_digest, text)
 
 
-def dump(config_path: str, out_path: str, trace_len: int = 546) -> None:
+def dump(config_path: str, out_path: str, has_sync: bool = False,
+         trace_len: int = 546) -> None:
     import jax
     import jax.numpy as jnp
 
@@ -47,16 +68,21 @@ def dump(config_path: str, out_path: str, trace_len: int = 546) -> None:
     jax.config.update("jax_enable_compilation_cache", False)
     with open(config_path) as f:
         conf = json.load(f)
-    cfg = MachineConfig.from_dict(
-        {**conf["machine"], "step_impl": conf["run"]["step_impl"]})
+    if "machine" in conf:
+        machine = {**conf["machine"], "step_impl": conf["run"]["step_impl"]}
+        chunk_steps = int(conf["run"]["chunk_steps"])
+    else:
+        machine, chunk_steps = conf, 8
+    cfg = MachineConfig.from_dict(machine)
     st = jax.eval_shape(lambda: init_state(cfg))
     ev = jax.ShapeDtypeStruct((cfg.n_cores, trace_len, 4), jnp.int32)
     text = run_loop.lower(
-        cfg, int(conf["run"]["chunk_steps"]), ev, st,
-        jax.ShapeDtypeStruct((), jnp.int32), has_sync=False).compile().as_text()
+        cfg, chunk_steps, ev, st,
+        jax.ShapeDtypeStruct((), jnp.int32), has_sync=has_sync).compile().as_text()
     with open(out_path, "w") as f:
         f.write(text)
-    print(f"{out_path}: {len(text)} bytes, {jax.devices()[0].device_kind}")
+    print(f"{out_path}: {len(text)} bytes, has_sync {has_sync}, "
+          f"{jax.devices()[0].device_kind}")
 
 
 def compare(a_path: str, b_path: str) -> int:
@@ -86,8 +112,9 @@ def compare(a_path: str, b_path: str) -> int:
 
 
 def main(argv: list) -> int:
-    if len(argv) == 3 and argv[0] == "dump":
-        dump(argv[1], argv[2])
+    if argv[:1] == ["dump"] and (
+            len(argv) == 3 or (len(argv) == 4 and argv[3] == "--sync")):
+        dump(argv[1], argv[2], has_sync=len(argv) == 4)
         return 0
     if len(argv) == 3 and argv[0] == "compare":
         return compare(argv[1], argv[2])

@@ -1,5 +1,5 @@
 """Micro-benchmarks of TPU gathers — the data behind the engine's
-array-layout choices and behind `sim/engine.py::_l1_set_read` having one form.
+array-layout choices and behind `sim/step.py::_l1_set_read` having one form.
 
     python scripts/prof/prof_gather.py          # the L1 set read's two forms
     python scripts/prof/prof_gather.py rows     # row / element gather, row scatter
@@ -51,7 +51,7 @@ def set_gather(cfg, l1, sets, planes):
 
 def set_read_forms():
     from primesim_tpu.config.machine import CacheConfig, MachineConfig
-    from primesim_tpu.sim.engine import _l1_set_read
+    from primesim_tpu.sim.step import _l1_set_read
 
     rng = np.random.default_rng(0)
     print(f"device {jax.devices()[0].device_kind}; us an iteration, {ITER} in a loop")

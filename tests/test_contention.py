@@ -108,7 +108,7 @@ def test_engine_path_links_match_scalar_walk():
     import numpy as np
 
     from primesim_tpu.noc.mesh import xy_links
-    from primesim_tpu.sim.engine import _path_links
+    from primesim_tpu.noc.topology import path_links as _path_links
     import jax.numpy as jnp
 
     cfg = small_test_config(4, noc=NocConfig(mesh_x=4, mesh_y=3))

@@ -1,4 +1,4 @@
-"""Pallas reduction kernel (ops/reductions.py — SURVEY.md §2 #4's Pallas
+"""Pallas reduction kernel (kernels/reductions.py — SURVEY.md §2 #4's Pallas
 uncore piece): the engine's dense sharer-expansion reductions routed
 through one Pallas kernel must stay BIT-EXACT against the golden model
 on the same workloads that prove the jnp path (interpreter mode on CPU,

@@ -1,8 +1,10 @@
-"""The named scopes of `step` and `run_loop` (sim/engine.py::PHASES,
+"""The named scopes of `step` and `run_loop` (sim/step.py::PHASES,
 DESIGN.md §15) reach the compiled program: every phase a machine enables
 is in some instruction's `op_name`, a phase it lacks is in none, and each
 `rank` scope sits under its own phase. Read from the compiled text, which
-is what a profiler trace and the benchmark's per-phase metrics read.
+is what a profiler trace and the benchmark's per-phase metrics read. And
+every equation under a phase was written by one of that phase's
+functions (sim/step.py::PHASE_FUNCTIONS), read from the jaxpr.
 """
 
 import dataclasses
@@ -16,7 +18,8 @@ import jax.numpy as jnp
 import pytest
 
 from primesim_tpu.config.machine import MachineConfig
-from primesim_tpu.sim.engine import PHASES, Engine, run_loop, step
+from primesim_tpu.sim.engine import Engine, run_loop
+from primesim_tpu.sim.step import PHASE_FUNCTIONS, PHASES, step
 from primesim_tpu.trace import synth
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -51,19 +54,29 @@ MACHINES = {
 }
 
 
-@functools.lru_cache(maxsize=None)
-def scope_paths(machine: str) -> frozenset:
-    """Every `op_name` of the machine's compiled `run_loop`."""
-    d, has_sync, _ = MACHINES[machine]
-    cfg = MachineConfig.from_dict(d)
+def build(machine: str):
+    """The machine's config and an `Engine` on the files' one trace."""
+    cfg = MachineConfig.from_dict(MACHINES[machine][0])
     if machine == "faulty":
         cfg = dataclasses.replace(cfg, faults_enabled=True)
-    eng = Engine(cfg, synth.fft_like(N, n_phases=2, points_per_core=8, seed=3),
-                 chunk_steps=8)
-    text = run_loop.lower(
+    return cfg, Engine(
+        cfg, synth.fft_like(N, n_phases=2, points_per_core=8, seed=3),
+        chunk_steps=8)
+
+
+@functools.lru_cache(maxsize=None)
+def compiled_text(machine: str) -> str:
+    """The machine's compiled `run_loop`, as text."""
+    has_sync = MACHINES[machine][1]
+    cfg, eng = build(machine)
+    return run_loop.lower(
         cfg, 8, eng.events, eng.state, jnp.asarray(1, jnp.int32),
         has_sync=has_sync).compile().as_text()
-    return frozenset(re.findall(r'op_name="([^"]*)"', text))
+
+
+def scope_paths(machine: str) -> frozenset:
+    """Every `op_name` of the machine's compiled `run_loop`."""
+    return frozenset(re.findall(r'op_name="([^"]*)"', compiled_text(machine)))
 
 
 def _has(paths, name: str) -> bool:
@@ -84,6 +97,45 @@ def test_phases_are_short_and_shallow():
 def test_scope_in_compiled_program_iff_enabled(machine, name):
     enabled = name in ALWAYS | MACHINES[machine][2]
     assert _has(scope_paths(machine), name) == enabled
+
+
+@pytest.mark.parametrize("machine", sorted(MACHINES))
+def test_every_op_under_a_phase_comes_from_that_phases_functions(machine):
+    """Every equation of `run_loop` whose name stack holds a phase was
+    written by one of the functions PHASE_FUNCTIONS gives that phase: it
+    is on the equation's traceback (helpers such as `_pick` are inner
+    frames, so the whole stack is looked up, not its top). A phase's work
+    written into another phase's function fails here and not in a
+    metric's reader. Read from the jaxpr: in the compiled text XLA has
+    merged equal instructions of different phases and kept one's frames.
+    Entered: the loops and branches, whose bodies are traced where they
+    stand. Not entered: what JAX traces once and caches (a `jnp` function
+    such as `jit(remainder)`, a scatter's or a reduction's combiner),
+    since its inside keeps the frames of whichever phase called it first;
+    the call itself is looked at."""
+    _, has_sync, optional = MACHINES[machine]
+    cfg, eng = build(machine)
+    checked = set()
+
+    def walk(jaxpr, prefix):
+        for eqn in jaxpr.eqns:
+            path = f"{prefix}/{eqn.source_info.name_stack}"
+            found = re.search(r"/(s\.\w+)", path)
+            if found:
+                fns = [f.function_name for f in eqn.source_info.traceback.frames]
+                assert any(f == own or f.startswith(own + ".") for f in fns
+                           for own in PHASE_FUNCTIONS[found.group(1)]), (
+                    path, eqn.primitive.name, fns)
+                checked.add(found.group(1))
+            if eqn.primitive.name in ("while", "scan", "cond") or (
+                    eqn.primitive.name == "jit"
+                    and eqn.params["name"] == "run_loop"):
+                for sub in jax.core.jaxprs_in_params(eqn.params):
+                    walk(sub, path)
+
+    walk(jax.make_jaxpr(functools.partial(run_loop, cfg, 8, has_sync=has_sync))(
+        eng.events, eng.state, jnp.asarray(1, jnp.int32)).jaxpr, "")
+    assert checked == {p for p in ALWAYS | optional if "/" not in p}
 
 
 def test_group_scope_holds_the_coarse_vectors_reductions_and_only_there():
@@ -125,10 +177,8 @@ def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
     four planes or, under the coarse vector, five; and `s.local` holds two
     gathers, of rows the core does not hold: its events and the home
     sets' directory rows."""
-    d, has_sync, _ = MACHINES[machine]
-    cfg = MachineConfig.from_dict(d)
-    eng = Engine(cfg, synth.fft_like(N, n_phases=2, points_per_core=8, seed=3),
-                 chunk_steps=8)
+    has_sync = MACHINES[machine][1]
+    cfg, eng = build(machine)
     shapes = {"l1": eng.state.l1.shape, "events": eng.events.shape,
               "dirm": eng.state.dirm.shape}
     assert len(set(shapes.values())) == 3

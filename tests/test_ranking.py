@@ -223,7 +223,7 @@ def _case_one_segment_holds_every_entry(rng):
 
 
 def _case_dram_caller_one_slot(rng):
-    # engine.py's DRAM queue: S = 1, a bank or the sentinel per lane
+    # step.py's DRAM queue: S = 1, a bank or the sentinel per lane
     C, n_seg = 1024, 1024
     seg = rng.integers(0, 64, (C, 1))  # hot banks: long FIFOs
     seg = np.where(rng.random((C, 1)) < 0.5, n_seg, seg)

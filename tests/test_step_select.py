@@ -1,4 +1,4 @@
-"""The picks of `step` that are selects, not gathers (sim/engine.py:
+"""The picks of `step` that are selects, not gathers (sim/step.py:
 `_l1_set_read`, `_pick`): each equals the `take_along_axis` it replaced
 to the bit, on every word an int32 can hold, under the fleet's `vmap`.
 """
@@ -11,7 +11,7 @@ import numpy as np
 import pytest
 
 from primesim_tpu.config.machine import CacheConfig, MachineConfig
-from primesim_tpu.sim.engine import _l1_set_read, _pick
+from primesim_tpu.sim.step import _l1_set_read, _pick
 
 C, BATCH = 8, 2
 EDGES = np.array([-(2**31), -1, 0, 1, 2**31 - 1], np.int32)
