@@ -15,11 +15,11 @@ and emits per-hop link departures plus each leg's end time.  That is a
 dense [BC, H] VMEM shape with NO data-dependent indexing — exactly what
 the block model handles — so this kernel fuses the wait-floor selects,
 three per-leg cummax cascades (request, reply, barrier-arrival), and
-the departure composition into one pallas_call.  The surrounding
-data-dependent pieces stay XLA on purpose: the per-hop link_free/base
-row GATHERS feeding the kernel and the departure scatter-max back into
-link_free are the one access shape the block model cannot express
-(same boundary the commit kernel draws at the dirm row scatter).
+the departure composition into one pallas_call.  The sorted passes on
+either side stay XLA (ops/ranking.py): the one that hands the kernel
+each hop's rank and floor term max(link_free, base), and the one that
+raises link_free to the departures the kernel returns.  The floor term
+arrives as both `lf_all` and `bs_all`: the kernel forms their maximum.
 
 VMEM LAYOUT (layouts.py geometry): every per-leg operand is a [BC, H]
 core-axis block (H = mesh diameter, the -1-padded XY path width); lane
@@ -105,7 +105,8 @@ def router_cascade(
     req_hops, rep_hops, arr_hops, link_lat, router_lat, *, has_sync: bool,
 ):
     """Fused wait-floor + cascade + departures: takes the XLA-staged
-    [C, legs·H] per-hop gathers (link_free, base), ranks, and hop masks,
+    [C, legs·H] per-hop link_free and base (or their maximum as both:
+    only `max(lf_all, bs_all)` is read), ranks, and hop masks,
     returns (t_rep_end [C], t_arr_end [C] | None, departs [C, legs·H])
     — bit-identical to the engine's XLA `_cascade` path.  `link_lat` /
     `router_lat` are the TRACED knob scalars."""

@@ -102,9 +102,10 @@ def concat_legs(legs):
     contention models' [C, legs·H] layout: ``legs`` is a sequence of
     (path_links result [C, H], lane mask [C]) pairs.  Both the "link"
     occupancy count and the hop-by-hop router block run every per-link
-    operation ONCE over this concatenation (one scatter, one rank, one
-    gather pair) — per-kernel overhead is the budget, so per-path loops
-    become per-path kernels (sim/step.py::_router_walk)."""
+    operation ONCE over this concatenation (one scatter-add; or one
+    sorted pass for rank and link state, one for the departures) —
+    per-kernel overhead is the budget, so per-path loops become per-path
+    kernels (sim/step.py::_router_walk)."""
     pths = [p for p, _ in legs]
     masks = [jnp.broadcast_to(m[:, None], p.shape) for p, m in legs]
     return jnp.concatenate(pths, axis=1), jnp.concatenate(masks, axis=1)

@@ -44,7 +44,12 @@ from ..noc import topology as _topo
 from ..noc.mesh import concat_legs as _concat_legs
 from ..noc.mesh import n_links
 from ..noc.topology import path_links as _path_links
-from ..ops.ranking import lane_order, segmented_rank
+from ..ops.ranking import (
+    lane_order,
+    segmented_rank,
+    segmented_rank_floor,
+    segmented_table_max,
+)
 from ..stats.counters import COUNTER_NAMES
 from ..trace.format import (
     EV_BARRIER,
@@ -1418,12 +1423,12 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
     closed form: with F_k the wait floor at hop k and c = link_lat +
     router_lat,
       t_k = max(t0 + router_lat, cummax_{k'<=k}(F_k' - k'c)) + kc
-    so one cummax per path replaces the sequential walk, and the per-link
-    departures feed one scatter-max into link_free. Ranks come from the
-    shared sort-based segmented-rank primitive (ops/ranking.py, DESIGN.md
-    §13): O(E log E) over the flattened (link, key) entries, in the key
-    order `ord_c`. Bit-exact vs the golden scalar walk
-    (tests/test_router.py)."""
+    so one cummax per path replaces the sequential walk. Everything per
+    link (rank, base, the clock's lookup, the departures' max into the
+    clocks) is taken in the sorted order of the flattened (link, key)
+    entries, key order `ord_c`: ops/ranking.py, DESIGN.md §13, O(E log E)
+    and no table indexed entry by entry. Bit-exact vs the golden scalar
+    walk (tests/test_router.py)."""
     pallas_step = cfg.step_impl == "pallas"
     cpi_vec, l1_lat, llc_lat = kn.cpi, kn.l1_lat, kn.llc_lat
     epre, is_lock, is_unlock, is_barrier = (
@@ -1460,14 +1465,19 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
             + hidx * c_hop
         )
         # EVERY per-link operation runs once over the concatenated paths
-        # ([C, 2H] legs, or [C, 3H] with the barrier-arrival leg): one
-        # segmented rank, one base scatter-min, one link_free/base gather
-        # pair — per-kernel overhead is the budget, so per-path loops are
-        # per-path kernels. The per-(lane, segment) uniqueness contract
-        # of segmented_rank holds by construction: request and reply
-        # legs traverse reversed DIRECTED links (distinct ids), and the
-        # barrier-arrival leg is masked to barrier lanes, disjoint from
-        # home-transaction lanes.
+        # ([C, 2H] legs, or [C, 3H] with the barrier-arrival leg), and in
+        # the order ONE sort gives them: sorted by (link, key) a link's
+        # entries are one contiguous run, so its rank, its earliest
+        # nominal arrival and its next-free clock are scans over that
+        # run, and a sort back by the carried index returns them to
+        # [C, legs*H] (ops/ranking.py). A table of NL words indexed entry
+        # by entry is the slow form on the chip: an element gather or
+        # scatter costs ten times a sort of the same entries (PERF.md §6,
+        # PR 31). The per-(lane, segment) uniqueness contract of the
+        # rank holds by construction: request and reply legs traverse
+        # reversed DIRECTED links (distinct ids), and the barrier-arrival
+        # leg is masked to barrier lanes, disjoint from home-transaction
+        # lanes.
         pth_all, mask_all = _concat_legs(
             [(req_p, home_txn), (rep_p, home_txn)]
             + ([(arr_p, is_barrier)] if has_sync else [])
@@ -1477,34 +1487,32 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
         )
         ok_all = mask_all & (pth_all >= 0)
         tgt_all = jnp.where(ok_all, pth_all, NL)
-        base = jnp.full(NL, INT32_MAX, jnp.int32).at[tgt_all].min(
-            a_all, mode="drop"
-        )
-        # packets ahead of lane i in each hop's same-step FIFO, ordered
-        # by the phase-2 arbitration key (masked slots carry garbage the
-        # SENT select below discards)
+        # r_all: packets ahead of lane i in each hop's same-step FIFO,
+        # ordered by the phase-2 arbitration key; fl_all: the wait
+        # floor's first term max(link_free[l], base[l]), base the link's
+        # earliest nominal arrival of this step (masked slots carry
+        # garbage the SENT select below discards)
         with jax.named_scope(_RANK):
-            r_all = segmented_rank(tgt_all, n_seg=NL, order=ord_c)
-        pc_all = jnp.where(pth_all >= 0, pth_all, 0)
-        lf_g = link_free[pc_all]  # [C, legs*H] per-hop gather pair —
-        bs_g = base[pc_all]  # data-dependent rows, staged in XLA (§13)
+            r_all, fl_all, runs = segmented_rank_floor(
+                tgt_all, a_all, link_free, order=ord_c
+            )
         arr_lat_a, arr_hops = _one_way(ctile, htile, cfg, kn)
         if pallas_step:
             # [PALLAS] wait floors + per-leg cummax cascades + departure
             # composition fused in one VMEM kernel (router_kernels.py);
-            # the link_free/base row gathers above and the departure
-            # scatter-max below stay XLA — the one access shape the
-            # block model cannot express (same boundary as the commit
-            # kernel's dirm row scatter)
+            # the sorted passes above and below stay XLA: a sort is not
+            # a block the kernel's model holds. The kernel keeps its
+            # signature and forms max(lf, bs) itself: the floor term,
+            # which is that maximum already, is handed in as both
             from ..kernels.router_kernels import router_cascade
 
             t_rep_end, t_arr_end, d_all = router_cascade(
-                lf_g, bs_g, r_all, ok_all, t0, service, req_hops,
+                fl_all, fl_all, r_all, ok_all, t0, service, req_hops,
                 rep_hops, arr_hops, L_lat, R_lat, has_sync=has_sync,
             )
         else:
             F_all = jnp.where(
-                ok_all, jnp.maximum(lf_g, bs_g) + r_all * L_lat, SENT
+                ok_all, fl_all + r_all * L_lat, SENT
             )  # [C, legs*H] wait floors
 
             def _cascade(t_start, F, nh):
@@ -1531,7 +1539,11 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
         if has_sync:
             raw_arr = t_arr_end - t0  # valid on barrier lanes
             extra_bar = raw_arr - arr_lat_a
-        link_free_n = link_free.at[tgt_all].max(d_all, mode="drop")
+        # every link's clock raised to its latest departure, in the
+        # sorted order the rank built (masked slots sit in the sentinel
+        # link's run, which no clock reads)
+        with jax.named_scope(_RANK):
+            link_free_n = segmented_table_max(runs, d_all, link_free)
         _count(
             acc,
             "noc_contention_cycles",

@@ -14,9 +14,15 @@ costs of the router + DRAM-queue step tail (DESIGN.md §13):
   closed form vs the fused Pallas kernel (`kernels.router_kernels`,
   interpreter mode off-TPU — so on CPU this row measures the interpreter,
   not Mosaic; compare on TPU for the real kernel number).
-- `scatter`: the data-dependent edges that stay XLA on purpose — the
-  base scatter-min, the per-hop link_free/base gather pair, and the
-  departure scatter-max back into link_free.
+- `links`: the walk's per-link state. Shipped (PR 31): it rides the
+  rank's sorted order, `ops.ranking.segmented_rank_floor` (rank, the
+  link's earliest nominal arrival and its next-free clock out of one
+  sort, two segmented scans and one sort back) and
+  `segmented_table_max` (the departures into the clocks: one sort, one
+  scan, NL reads); its pieces alone (`sort`, `scan`) beside it. Against
+  the retired element form it replaced: the base scatter-min, the per-hop
+  link_free/base gather pair and the departure scatter-max, each over
+  all C * legs * H slots of a table of NL words.
 
 Plus whole-step ms/step on the full rung-3 machine for both
 `step_impl=xla` and `=pallas` (the end-to-end number the components
@@ -25,7 +31,7 @@ entry points, so this tool cannot rot silently.
 
 Usage: `python scripts/prof/prof_router.py` · env:
 `PRIMETPU_PROF_MATMUL=0` skips the retired-matmul reference row (it is
-deliberately the slow one), `PRIMETPU_PROF_STEPS` (default 16) sizes
+deliberately the slow one), `PRIMETPU_PROF_WHOLE=0` the whole-step rows, `PRIMETPU_PROF_STEPS` (default 16) sizes
 the whole-step chunks.
 """
 import functools
@@ -38,7 +44,13 @@ import numpy as np
 
 from primesim_tpu.config.machine import MachineConfig
 from primesim_tpu.kernels.router_kernels import SENT, router_cascade
-from primesim_tpu.ops.ranking import lane_order, segmented_rank
+from primesim_tpu.ops import ranking
+from primesim_tpu.ops.ranking import (
+    lane_order,
+    segmented_rank,
+    segmented_rank_floor,
+    segmented_table_max,
+)
 from primesim_tpu.sim.engine import run_chunk
 from primesim_tpu.sim.state import init_state
 from primesim_tpu.trace import synth
@@ -147,24 +159,84 @@ def cascade_cuts(s, cfg):
     timed(pallas_cascade, *a, tag=f"cascade: pallas kernel ({kind})")
 
 
-def scatter_cuts(s):
-    NL, LT = s["NL"], s["LT"]
-    link_free = jnp.zeros(NL, jnp.int32)
-    d_all = s["lf"] + 7
+ITER = 50
 
-    def base_min_gather(key, tgt, ok):
-        key_s = jnp.where(ok, key[:, None], jnp.int32((1 << 31) - 1))
-        base = jnp.full(NL + 1, (1 << 31) - 1, jnp.int32)
-        base = base.at[tgt].min(key_s, mode="drop")[:NL]
+
+def timed_loop(body, init, tag):
+    """`body` (iteration, carry -> carry) ITER times inside one
+    `fori_loop`, its outputs the next iteration's inputs: the time is the
+    device's and holds no dispatch (prof_gather.py's way). ms an
+    iteration."""
+    f = jax.jit(lambda c: jax.lax.fori_loop(0, ITER, body, c))
+    jax.block_until_ready(f(init))
+    walls = []
+    for _ in range(3):
+        t0 = time.perf_counter()
+        jax.block_until_ready(f(init))
+        walls.append(time.perf_counter() - t0)
+    ms = min(walls) / ITER * 1e3
+    print(f"[{tag}] {ms:.4f} ms", flush=True)
+    return ms
+
+
+def link_cuts(s):
+    # request and reply legs alone, as a trace without locks or barriers
+    # has them (both benchmark traffic mixes): E = 126976 at rung 3
+    NL, S = s["NL"], 2 * s["H"]
+    E = s["C"] * S
+    rng = np.random.default_rng(1)
+    lf0 = jnp.asarray(rng.integers(-(1 << 30), 1000, NL).astype(np.int32))
+    tgt, a0, d0 = s["tgt"][:, :S], s["bs"][:, :S], s["lf"][:, :S] + 7
+    ordr = lane_order(s["key"])
+    edge = jnp.asarray(rng.random(E) < 0.03)
+
+    def sorted_floor(i, c):
+        a, lf = c
+        r, fl, _ = segmented_rank_floor(tgt, a, lf, order=ordr)
+        return fl + r, lf + i
+
+    def sorted_both(i, c):
+        a, d, lf = c
+        r, fl, runs = segmented_rank_floor(tgt, a, lf, order=ordr)
+        return fl + r, d + i, segmented_table_max(runs, d, lf)
+
+    def rank_alone(i, o):  # the key order turns, so no iteration is hoisted
+        return (o + segmented_rank(tgt, n_seg=NL, order=o)[:, 0]) % s["C"]
+
+    def sort_alone(i, c):
+        k, a = c
+        _, sidx, sa = jax.lax.sort(
+            (k, jnp.arange(E, dtype=jnp.int32), a), num_keys=1,
+            is_stable=False)
+        return sa ^ i, sidx
+
+    def scan_alone(i, x):
+        return ranking._segmented_scan(x, edge, jnp.minimum) ^ i
+
+    def retired_floor(i, c):
+        a, lf = c
+        base = jnp.full(NL, (1 << 31) - 1, jnp.int32)
+        base = base.at[tgt].min(a, mode="drop")
         pc = jnp.clip(tgt, 0, NL - 1)
-        return link_free[pc], base[pc]
+        return jnp.maximum(lf[pc], base[pc]) + i, lf + i
 
-    def depart_max(tgt, d):
-        return link_free.at[tgt].max(d, mode="drop")
+    def retired_max(i, c):
+        d, lf = c
+        return d + i, lf.at[tgt].max(d, mode="drop")
 
-    timed(base_min_gather, s["key"], s["tgt"], s["ok"],
-          tag="scatter: base min + per-hop gather pair")
-    timed(depart_max, s["tgt"], d_all, tag="scatter: departure max")
+    timed_loop(rank_alone, ordr, "links: the rank alone (segmented_rank)")
+    timed_loop(sorted_floor, (a0, lf0),
+               "links: sorted rank + floor (segmented_rank_floor)")
+    timed_loop(sorted_both, (a0, d0, lf0),
+               "links: sorted, both passes (+ segmented_table_max)")
+    timed_loop(sort_alone, (tgt.reshape(-1), a0.reshape(-1)),
+               "links: one sort of the entries, two payloads")
+    timed_loop(scan_alone, a0.reshape(-1),
+               "links: one segmented scan of the entries")
+    timed_loop(retired_floor, (a0, lf0),
+               "links: retired base scatter-min + per-hop gather pair")
+    timed_loop(retired_max, (d0, lf0),
+               "links: retired departure scatter-max")
 
 
 def whole_step(cfg, step_impl, n_steps):
@@ -192,7 +264,8 @@ if __name__ == "__main__":
     print(f"shapes: C={s['C']} NL={s['NL']} H={s['H']} legs*H={s['LT']}")
     rank_cuts(s)
     cascade_cuts(s, cfg)
-    scatter_cuts(s)
-    n = int(os.environ.get("PRIMETPU_PROF_STEPS", "16"))
-    whole_step(cfg, "xla", n)
-    whole_step(cfg, "pallas", n)
+    link_cuts(s)
+    if os.environ.get("PRIMETPU_PROF_WHOLE", "1") != "0":
+        n = int(os.environ.get("PRIMETPU_PROF_STEPS", "16"))
+        whole_step(cfg, "xla", n)
+        whole_step(cfg, "pallas", n)

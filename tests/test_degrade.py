@@ -56,7 +56,13 @@ def _revoke_plan(n: int = 1, occurrence: int = 2) -> CP.FaultPlan:
 
 
 @pytest.fixture(autouse=True)
-def _clean_slate():
+def _clean_slate(monkeypatch):
+    # a snapshot store registers an evictor for good (sim/supervisor.py), so
+    # a worker that ran tests/test_supervisor.py first holds dozens; each
+    # burns probes of a chaos `enospc_window`, which then closes before an
+    # append of this file is refused. The ladder these tests see is theirs.
+    monkeypatch.setattr(diskpressure, "_EVICTORS", {})
+    monkeypatch.setattr(diskpressure, "_COMPACTORS", {})
     yield
     CS.deactivate()
     sharding.restore_devices()

@@ -10,6 +10,7 @@ functions (sim/step.py::PHASE_FUNCTIONS), read from the jaxpr.
 import dataclasses
 import functools
 import glob
+import math
 import os
 import re
 
@@ -169,6 +170,30 @@ def test_rung3_loop_ranks_without_a_search():
     assert not [p for p in ranked if "while" in p.split("/")]
 
 
+def indexed_ops(machine: str) -> list:
+    """Every `gather` and `scatter*` equation of the machine's `step`,
+    through every sub-jaxpr: (primitive, scope path, operand shape, number
+    of indices)."""
+    has_sync = MACHINES[machine][1]
+    cfg, eng = build(machine)
+    found = []
+
+    def walk(jaxpr, prefix):
+        for eqn in jaxpr.eqns:
+            path = f"{prefix}/{eqn.source_info.name_stack}"
+            name = eqn.primitive.name
+            if name == "gather" or name.startswith("scatter"):
+                operand, indices = (v.aval.shape for v in eqn.invars[:2])
+                found.append((name, path, operand, math.prod(indices[:-1])))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, path)
+
+    walk(jax.make_jaxpr(
+        lambda ev, st: step(cfg, ev, st, has_sync=has_sync))(
+            eng.events, eng.state).jaxpr, "")
+    return found
+
+
 @pytest.mark.parametrize("machine", ["plain", "coarse"])
 def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
     """The core's own L1 row is read whole and the set selected
@@ -177,28 +202,36 @@ def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
     four planes or, under the coarse vector, five; and `s.local` holds two
     gathers, of rows the core does not hold: its events and the home
     sets' directory rows."""
-    has_sync = MACHINES[machine][1]
     cfg, eng = build(machine)
     shapes = {"l1": eng.state.l1.shape, "events": eng.events.shape,
               "dirm": eng.state.dirm.shape}
     assert len(set(shapes.values())) == 3
-    gathers = []  # (scope path, operand shape), through every sub-jaxpr
-
-    def walk(jaxpr, prefix):
-        for eqn in jaxpr.eqns:
-            path = f"{prefix}/{eqn.source_info.name_stack}"
-            if eqn.primitive.name == "gather":
-                gathers.append((path, eqn.invars[0].aval.shape))
-            for sub in jax.core.jaxprs_in_params(eqn.params):
-                walk(sub, path)
-
-    walk(jax.make_jaxpr(
-        lambda ev, st: step(cfg, ev, st, has_sync=has_sync))(
-            eng.events, eng.state).jaxpr, "")
+    gathers = [(path, shape) for name, path, shape, _ in indexed_ops(machine)
+               if name == "gather"]
     assert any("s.probe" in p for p, _ in gathers)  # the walk sees scopes
     assert not [p for p, shape in gathers if shape == shapes["l1"]]
     assert sorted(shape for p, shape in gathers if "s.local" in p) == sorted(
         [shapes["events"], shapes["dirm"]])
+
+
+def test_rung3_walk_indexes_no_table_entry_by_entry():
+    """The router walk's per-link state rides the rank's sorted order
+    (`segmented_rank_floor`, `segmented_table_max`): under `s.noc` no
+    `gather` and no `scatter` of any kind has more indices than the
+    machine has links. The element forms over all C * legs * H slots (a
+    scatter-min for `base`, a gather pair, the departures' scatter-max)
+    were three quarters of the rung-3 step on the chip (PERF.md section 6,
+    PR 31); what stays reads or writes NL words or fewer."""
+    from primesim_tpu.noc.mesh import n_links
+
+    cfg, _ = build("rung3")
+    n_slots = cfg.n_cores * 2 * 6  # two legs of a 4x4 mesh's 6 hops
+    assert n_links(cfg) < n_slots
+    indexed = indexed_ops("rung3")
+    assert any("s.noc" in p for _, p, _, _ in indexed)  # the walk sees scopes
+    assert any(n >= n_slots for _, _, _, n in indexed)  # and counts indices
+    assert not [(name, p, n) for name, p, _, n in indexed
+                if "s.noc" in p and n > n_links(cfg)]
 
 
 def test_benchmark_needles_are_phase_names():

@@ -6,6 +6,8 @@ the NOMINAL path by design), and fleet-vmapped batches — the sort path
 must return the EXACT int32 counts of the historical one-hot-matmul
 path it replaced (DESIGN.md §13 equivalence argument)."""
 
+import functools
+
 import numpy as np
 import pytest
 
@@ -18,7 +20,15 @@ from primesim_tpu.config.machine import (
     small_test_config,
 )
 from primesim_tpu.noc.mesh import n_links, path_links
-from primesim_tpu.ops.ranking import lane_order, segmented_rank
+from primesim_tpu.ops.ranking import (
+    lane_order,
+    segmented_rank,
+    segmented_rank_floor,
+    segmented_table_max,
+)
+
+INT32_MAX = np.iinfo(np.int32).max
+CLOCK_LO = -(1 << 30)  # where engine.py's rebase clamps a link's clock
 
 
 def matmul_oracle(seg, key, n_seg, competitor=None):
@@ -38,6 +48,54 @@ def matmul_oracle(seg, key, n_seg, competitor=None):
     return out  # valid wherever seg < n_seg
 
 
+def link_oracle(seg, val, table, dep):
+    """The element form the sorted passes replaced, in numpy: a segment's
+    floor is the larger of its table word and the least `val` of its
+    entries (`minimum.at`, then a lookup per entry), and the table is
+    raised to the largest `dep` of its entries (`maximum.at`)."""
+    n_seg = table.shape[0]
+    low = np.full(n_seg + 1, INT32_MAX, np.int32)
+    np.minimum.at(low, seg.ravel(), val.ravel())
+    floor = np.maximum(np.append(table, 0), low)[seg]
+    raised = np.append(table, 0)
+    np.maximum.at(raised, seg.ravel(), dep.ravel())
+    return floor, raised[:n_seg]
+
+
+def _clocks(rng, shape):
+    """int32 values over the whole range a clock takes: down to the
+    rebase clamp, up to near INT32_MAX, a few at the ends themselves."""
+    v = rng.integers(CLOCK_LO, INT32_MAX, shape, dtype=np.int64)
+    ends = rng.random(shape)
+    v = np.where(ends < 0.05, CLOCK_LO, np.where(ends > 0.95, INT32_MAX - 1, v))
+    return v.astype(np.int32)
+
+
+@functools.partial(jax.jit, static_argnames="method")
+def _link_passes(seg, key, val, dep, table, method="auto"):
+    """Both sorted passes as the router walk chains them."""
+    rank, floor, runs = segmented_rank_floor(
+        seg, val, table, order=lane_order(key), method=method)
+    return rank, floor, segmented_table_max(runs, dep, table)
+
+
+def check_link_values(seg, key, n_seg, method, rng):
+    """`segmented_rank_floor` and `segmented_table_max` on (seg, key):
+    the rank is `segmented_rank`'s, floor and table the oracle's."""
+    val, dep = _clocks(rng, seg.shape), _clocks(rng, seg.shape)
+    table = _clocks(rng, n_seg)
+    rank, floor, raised = _link_passes(
+        *(jnp.asarray(a) for a in (seg, key, val, dep, table)), method=method)
+    want_floor, want_raised = link_oracle(seg, val, table, dep)
+    valid = seg < n_seg
+    np.testing.assert_array_equal(
+        np.asarray(rank)[valid], matmul_oracle(seg, key, n_seg)[valid])
+    np.testing.assert_array_equal(np.asarray(floor)[valid], want_floor[valid])
+    np.testing.assert_array_equal(np.asarray(raised), want_raised)
+    unused = np.setdiff1d(np.arange(n_seg), seg[valid])
+    np.testing.assert_array_equal(np.asarray(raised)[unused], table[unused])
+
+
 def _unique_segs(rng, C, S, n_seg, mask_p=0.4):
     """Per-lane DISTINCT segment ids (the engine contract: one entry per
     (lane, segment)), with a random fraction masked to the sentinel."""
@@ -47,13 +105,16 @@ def _unique_segs(rng, C, S, n_seg, mask_p=0.4):
     return np.where(rng.random((C, S)) < mask_p, n_seg, seg).astype(np.int32)
 
 
+@pytest.mark.parametrize("values", ["rank", "link-values"])
 @pytest.mark.parametrize("method", ["packed", "lex"])
 @pytest.mark.parametrize("seed", [0, 1, 2, 3])
-def test_random_matches_oracle(method, seed):
+def test_random_matches_oracle(method, seed, values):
     rng = np.random.default_rng(seed)
     C, S, n_seg = 64, 9, 37
     seg = _unique_segs(rng, C, S, n_seg)
     key = rng.integers(0, 500, C).astype(np.int32)  # dense => duplicates
+    if values == "link-values":
+        return check_link_values(seg, key, n_seg, method, rng)
     got = np.asarray(
         segmented_rank(jnp.asarray(seg), jnp.asarray(key), n_seg,
                        method=method)
@@ -235,6 +296,25 @@ def _case_entries_not_a_multiple_of_128(rng):
     return _unique_segs(rng, C, S, n_seg), rng.integers(0, 20, C), n_seg
 
 
+def _case_every_slot_masked(rng):
+    # a step in which no lane has a home transaction: only table entries
+    C, S, n_seg = 20, 6, 13
+    return np.full((C, S), n_seg), rng.integers(0, 9, C), n_seg
+
+
+def _case_one_link_used_by_all_lanes_others_by_none(rng):
+    # the longest FIFO a step can hold, and n_seg - 1 untouched clocks
+    C, S, n_seg = 300, 1, 140
+    return np.full((C, S), 77), rng.integers(0, 50, C), n_seg
+
+
+def _case_more_segments_than_entries(rng):
+    # runs of one table entry alone, many in a row of the scan
+    C, S, n_seg = 6, 2, 700
+    return _unique_segs(rng, C, S, n_seg), rng.integers(0, 4, C), n_seg
+
+
+@pytest.mark.parametrize("values", ["rank", "link-values"])
 @pytest.mark.parametrize("method", ["packed", "lex"])
 @pytest.mark.parametrize(
     "case",
@@ -244,17 +324,68 @@ def _case_entries_not_a_multiple_of_128(rng):
         _case_one_segment_holds_every_entry,
         _case_dram_caller_one_slot,
         _case_entries_not_a_multiple_of_128,
+        _case_every_slot_masked,
+        _case_one_link_used_by_all_lanes_others_by_none,
+        _case_more_segments_than_entries,
     ],
     ids=lambda f: f.__name__[len("_case_"):],
 )
-def test_shapes_the_sorted_order_rank_has_to_survive(case, method):
+def test_shapes_the_sorted_order_rank_has_to_survive(case, method, values):
     seg, key, n_seg = case(np.random.default_rng(26))
     seg, key = seg.astype(np.int32), key.astype(np.int32)
+    if values == "link-values":
+        return check_link_values(seg, key, n_seg, method,
+                                 np.random.default_rng(31))
     got = np.asarray(
         segmented_rank(jnp.asarray(seg), jnp.asarray(key), n_seg,
                        method=method)
     )
     want = matmul_oracle(seg, key, n_seg)
-    valid = seg < n_seg
-    assert valid.any()
+    valid = seg < n_seg  # the rank of a masked slot is unspecified
+    assert valid.any() or case is _case_every_slot_masked
     np.testing.assert_array_equal(got[valid], want[valid])
+
+
+@pytest.mark.parametrize("table_value", [CLOCK_LO, -1, 0, INT32_MAX - 1])
+def test_link_values_at_the_ends_of_the_clock_range(table_value):
+    """A link's clock at the rebase clamp, just under zero, zero and near
+    INT32_MAX, its entries' values over the whole range: `_running_max`
+    is written for non-negative ints, the segmented scans for all."""
+    rng = np.random.default_rng(abs(table_value) % 97)
+    C, S, n_seg = 96, 5, 41
+    seg = _unique_segs(rng, C, S, n_seg, mask_p=0.3)
+    key = rng.integers(0, 40, C).astype(np.int32)
+    val, dep = _clocks(rng, seg.shape), _clocks(rng, seg.shape)
+    table = np.full(n_seg, table_value, np.int32)
+    _, floor, raised = _link_passes(
+        *(jnp.asarray(a) for a in (seg, key, val, dep, table)))
+    want_floor, want_raised = link_oracle(seg, val, table, dep)
+    valid = seg < n_seg
+    np.testing.assert_array_equal(np.asarray(floor)[valid], want_floor[valid])
+    np.testing.assert_array_equal(np.asarray(raised), want_raised)
+
+
+def test_fleet_vmapped_link_values_match_solo():
+    # as test_fleet_vmapped_batches_match_solo, for the carried values:
+    # the batched sorts, scans and table reads equal per-element calls
+    rng = np.random.default_rng(23)
+    B, C, S, n_seg = 3, 40, 7, 150  # E + n_seg = 430: four rows of a scan
+    segs = np.stack([_unique_segs(rng, C, S, n_seg) for _ in range(B)])
+    keys = rng.integers(0, 200, (B, C)).astype(np.int32)
+    vals, deps = _clocks(rng, segs.shape), _clocks(rng, segs.shape)
+    tables = _clocks(rng, (B, n_seg))
+
+    args = [jnp.asarray(a) for a in (segs, keys, vals, deps, tables)]
+    batched = jax.vmap(_link_passes)(*args)
+    for b in range(B):
+        solo = _link_passes(*(a[b] for a in args))
+        valid = segs[b] < n_seg
+        for got, want, mask in zip(batched, solo, (valid, valid, slice(None))):
+            np.testing.assert_array_equal(
+                np.asarray(got[b])[mask], np.asarray(want)[mask],
+                err_msg=f"elem {b}")
+        want_floor, want_raised = link_oracle(
+            segs[b], vals[b], tables[b], deps[b])
+        np.testing.assert_array_equal(
+            np.asarray(batched[1][b])[valid], want_floor[valid])
+        np.testing.assert_array_equal(np.asarray(batched[2][b]), want_raised)
