@@ -26,12 +26,14 @@ driver's `dryrun_multichip` validate multi-chip behavior without hardware.
 
 from __future__ import annotations
 
+import functools
+
 import jax
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..faults.schedule import FaultState
-from ..sim.state import MachineState, TimingKnobs
+from ..sim.state import MachineState, TimingKnobs, init_state
 
 AXIS = "tiles"
 
@@ -230,11 +232,39 @@ def events_pspec() -> P:
     return P(AXIS)  # events[C, T, 3] sharded by core
 
 
-def shard_state(mesh: Mesh, st: MachineState) -> MachineState:
-    specs = state_pspecs()
+def state_shardings(mesh: Mesh) -> MachineState:
+    """`state_pspecs()` on `mesh`: a NamedSharding per MachineState field."""
     return jax.tree.map(
-        lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)), st, specs
+        lambda spec: NamedSharding(mesh, spec),
+        state_pspecs(),
+        is_leaf=lambda x: isinstance(x, P),
     )
+
+
+def shard_state(mesh: Mesh, st: MachineState) -> MachineState:
+    return jax.tree.map(jax.device_put, st, state_shardings(mesh))
+
+
+@functools.lru_cache(maxsize=None)
+def _state_builder(mesh: Mesh):
+    return jax.jit(
+        init_state, static_argnums=0, out_shardings=state_shardings(mesh)
+    )
+
+
+def build_state(cfg, mesh: Mesh | None = None) -> MachineState:
+    """A machine's initial state, born in the layout it runs in. On a
+    mesh: `init_state` as one compiled program a geometry whose outputs
+    are laid out by `state_pspecs()`, so each device fills only its own
+    shard and none ever holds a whole `dirm` or `l1` (rung 4's directory
+    is 9.66 GB: `init_state` then `shard_state` fails on a 16 GB chip).
+    Without a mesh: `init_state` itself, array by array on the default
+    device. The compiled builder gives the same bytes there, but lays
+    them elsewhere in HBM, and the step's speed follows that placement:
+    one one-chip cell lost 5 % to it (PERF.md section 6, PR 33)."""
+    if mesh is None:
+        return init_state(cfg)
+    return _state_builder(mesh)(cfg)
 
 
 def shard_events(mesh: Mesh, events) -> jax.Array:

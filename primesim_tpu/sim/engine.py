@@ -29,6 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.machine import MachineConfig
+from ..parallel.sharding import build_state, shard_events
 from ..stats.counters import COUNTER_NAMES, zero_counters
 from ..trace.format import (
     EV_BARRIER,
@@ -38,7 +39,7 @@ from ..trace.format import (
     Trace,
 )
 from . import exec_cache
-from .state import MachineState, init_state
+from .state import MachineState
 from .step import INT32_MAX, P_CHUNK, step
 
 _ACC_BITS = 30  # device counter accumulators carry into hi above 2^30
@@ -281,15 +282,16 @@ class Engine:
         self.has_sync = bool(
             ((t == EV_LOCK) | (t == EV_UNLOCK) | (t == EV_BARRIER)).any()
         )
-        self.events = jnp.asarray(trace.line_events(cfg.line_bits))
-        self.state = init_state(cfg)
         self.mesh = mesh
-        if mesh is not None:
-            # multi-chip: lay cores/banks out over the tile axis (parallel/)
-            from ..parallel.sharding import shard_events, shard_state
-
-            self.events = shard_events(mesh, self.events)
-            self.state = shard_state(mesh, self.state)
+        with jax.profiler.TraceAnnotation("engine.init"):
+            # multi-chip: cores/banks laid out over the tile axis
+            # (parallel/); events and state go into that layout from their
+            # first byte, never whole onto one device
+            events = trace.line_events(cfg.line_bits)
+            self.events = (
+                jnp.asarray(events) if mesh is None else shard_events(mesh, events)
+            )
+            self.state = build_state(cfg, mesh)
         self.chunk_steps = chunk_steps
         # Counter-accumulator guard (run_loop drains int32 step counters
         # into (lo, hi) pairs whose hi carries above 2^30): any per-core

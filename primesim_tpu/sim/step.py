@@ -83,6 +83,7 @@ INT32_MAX = np.int32(2**31 - 1)
 # keeps 64 characters of a label.
 _RANK = "rank"  # second level: the calls into ops/ranking.py
 _GRP = "grp"  # second level: the coarse vector's per-group reductions
+_CHUNK = "chunk"  # second level: the full map's reductions in blocks of words
 PHASES = (
     "s.fault",  # phase -1: fault injection
     "s.local",  # phase 0 quantum barrier + 0.5 local runs
@@ -91,6 +92,7 @@ PHASES = (
     "s.dir",  # 3: directory transition, grants, victim, invalidation targets,
     #            prefetcher
     "s.dir/" + _GRP,  # sharer_group > 1 only
+    "s.dir/" + _CHUNK,  # sharer_chunk_words > 0 only
     "s.noc",  # NoC contention: tile/link counts, or the hop-by-hop router
     "s.noc/" + _RANK,
     "s.dram",  # memory-controller queue
@@ -100,14 +102,14 @@ PHASES = (
     "s.sync",  # 2.7: locks and barriers
     "s.chunk",  # run_loop's per-chunk drain, rebase and termination test
 )
-(P_FAULT, P_LOCAL, P_PROBE, P_ARB, P_DIR, _, P_NOC, _, P_DRAM, _, P_COMMIT,
+(P_FAULT, P_LOCAL, P_PROBE, P_ARB, P_DIR, _, _, P_NOC, _, P_DRAM, _, P_COMMIT,
  P_SYNC, P_CHUNK) = PHASES
 
 # Which functions write under which scope: every instruction whose
 # `op_name` holds a phase has one of these on its call stack, so a phase's
 # work written into another phase's function fails a test
 # (tests/test_phase_scopes.py) and not a metric's reader. Second levels
-# (`/rank`, `/grp`) belong to their phase. `s.chunk` is run_loop's own.
+# (`/rank`, `/grp`, `/chunk`) belong to their phase. `s.chunk` is run_loop's own.
 PHASE_FUNCTIONS = {
     P_FAULT: ("_fault",),
     P_LOCAL: ("_local",),
@@ -1199,51 +1201,52 @@ def _dir_transition(cfg: MachineConfig, st: MachineState, arange_c,
                     0,
                 )
         elif cfg.sharer_chunk_words:
-            K = cfg.sharer_chunk_words
-            nblk = NW // K
-            bit5 = jnp.arange(32, dtype=jnp.int32)
+            with jax.named_scope(_CHUNK):
+                K = cfg.sharer_chunk_words
+                nblk = NW // K
+                bit5 = jnp.arange(32, dtype=jnp.int32)
 
-            def _blk(carry, b):
-                il, ic, ih, bc, bh = carry
-                off = b * K
-                sw = jax.lax.dynamic_slice_in_dim(shw, off, K, axis=1)
-                vw = jax.lax.dynamic_slice_in_dim(vic_shw, off, K, axis=1)
-                tt = off * 32 + jnp.arange(K * 32, dtype=jnp.int32)  # target ids
-                tvalid = tt[None, :] < C  # padding bits beyond core C-1
-                bits = (
-                    ((sw[:, :, None] >> bit5[None, None, :]) & 1).reshape(C, K * 32)
-                    != 0
-                )
-                vbits = (
-                    ((vw[:, :, None] >> bit5[None, None, :]) & 1).reshape(C, K * 32)
-                    != 0
-                )
-                plat, phops = _one_way(
-                    btile[:, None], (tt % n_tiles)[None, :], cfg, kn
-                )
-                sh_b = (
-                    bits
-                    & (tt[None, :] != arange_c[:, None])
-                    & inv_row[:, None]
-                    & tvalid
-                )
-                il = jnp.maximum(il, jnp.max(jnp.where(sh_b, 2 * plat, 0), axis=1))
-                ic = ic + jnp.sum(sh_b, axis=1).astype(jnp.int32)
-                ih = ih + jnp.sum(jnp.where(sh_b, 2 * phops, 0), axis=1).astype(
-                    jnp.int32
-                )
-                ob = (tt[None, :] == vic_owner[:, None]) & (vic_owner >= 0)[:, None]
-                bk_b = (vbits | ob) & vic_valid[:, None] & tvalid
-                bc = bc + jnp.sum(bk_b, axis=1).astype(jnp.int32)
-                bh = bh + jnp.sum(jnp.where(bk_b, 2 * phops, 0), axis=1).astype(
-                    jnp.int32
-                )
-                return (il, ic, ih, bc, bh), None
+                def _blk(carry, b):
+                    il, ic, ih, bc, bh = carry
+                    off = b * K
+                    sw = jax.lax.dynamic_slice_in_dim(shw, off, K, axis=1)
+                    vw = jax.lax.dynamic_slice_in_dim(vic_shw, off, K, axis=1)
+                    tt = off * 32 + jnp.arange(K * 32, dtype=jnp.int32)  # target ids
+                    tvalid = tt[None, :] < C  # padding bits beyond core C-1
+                    bits = (
+                        ((sw[:, :, None] >> bit5[None, None, :]) & 1).reshape(C, K * 32)
+                        != 0
+                    )
+                    vbits = (
+                        ((vw[:, :, None] >> bit5[None, None, :]) & 1).reshape(C, K * 32)
+                        != 0
+                    )
+                    plat, phops = _one_way(
+                        btile[:, None], (tt % n_tiles)[None, :], cfg, kn
+                    )
+                    sh_b = (
+                        bits
+                        & (tt[None, :] != arange_c[:, None])
+                        & inv_row[:, None]
+                        & tvalid
+                    )
+                    il = jnp.maximum(il, jnp.max(jnp.where(sh_b, 2 * plat, 0), axis=1))
+                    ic = ic + jnp.sum(sh_b, axis=1).astype(jnp.int32)
+                    ih = ih + jnp.sum(jnp.where(sh_b, 2 * phops, 0), axis=1).astype(
+                        jnp.int32
+                    )
+                    ob = (tt[None, :] == vic_owner[:, None]) & (vic_owner >= 0)[:, None]
+                    bk_b = (vbits | ob) & vic_valid[:, None] & tvalid
+                    bc = bc + jnp.sum(bk_b, axis=1).astype(jnp.int32)
+                    bh = bh + jnp.sum(jnp.where(bk_b, 2 * phops, 0), axis=1).astype(
+                        jnp.int32
+                    )
+                    return (il, ic, ih, bc, bh), None
 
-            z5 = jnp.zeros(C, jnp.int32)
-            (inv_lat, inv_count, inv_hops, back_count, back_hops), _ = jax.lax.scan(
-                _blk, (z5, z5, z5, z5, z5), jnp.arange(nblk, dtype=jnp.int32)
-            )
+                z5 = jnp.zeros(C, jnp.int32)
+                (inv_lat, inv_count, inv_hops, back_count, back_hops), _ = jax.lax.scan(
+                    _blk, (z5, z5, z5, z5, z5), jnp.arange(nblk, dtype=jnp.int32)
+                )
         elif cfg.pallas_reduce or pallas_step:
             # same dense reduction as the branch below, as ONE Pallas kernel
             # (SURVEY §2 #4's Pallas uncore piece; the step subsystem's third

@@ -39,3 +39,30 @@ def load_benchmark_tests():
             del sys.modules["conftest"]
         else:
             sys.modules["conftest"] = mine
+
+
+def assert_reference_equals_golden(reference, machine: dict, ev):
+    """A plain reference (a module with `RefSim` and `COUNTERS`) against
+    the golden model on one machine and one folded trace: the step count,
+    every core's cycles, every counter it models, and zero in every
+    counter it does not. Returns the reference's finished simulation."""
+    import numpy as np
+
+    import trafficgen
+    from primesim_tpu.config.machine import MachineConfig
+    from primesim_tpu.golden.sim import GoldenSim
+    from primesim_tpu.trace.format import Trace
+
+    lengths = (ev[:, :, 0] != trafficgen.EV_END).sum(1) + 1
+    gold = GoldenSim(MachineConfig.from_dict(machine), Trace(ev, lengths))
+    gold.run()
+    ref = reference.RefSim(machine, ev)
+    ref.run()
+    assert ref.step_count == gold.step_count
+    assert np.array_equal(np.asarray(ref.cycles), gold.cycles)
+    for k, v in gold.counters.items():
+        if k in reference.COUNTERS:
+            assert np.array_equal(np.asarray(ref.counters[k]), v), k
+        else:
+            assert not v.any(), k
+    return ref

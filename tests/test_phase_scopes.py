@@ -52,6 +52,13 @@ MACHINES = {
     # the coarse sharer vector (Dir-G): the only machine with group work
     "coarse": (dict(n_cores=N, n_banks=N, noc=_MESH, local_run_len=8,
                     sharer_group=4), False, {"s.dir/grp"}),
+    # rung 4's selectors at 64 cores: a CPI a core and the full sharer map
+    # (two words) reduced in blocks of one word
+    "chunked": (dict(n_cores=64, n_banks=16, noc={"mesh_x": 8, "mesh_y": 8},
+                     local_run_len=8, sharer_chunk_words=1,
+                     core={"cpi": 1, "cpi_pattern": [1, 1, 3, 3],
+                           "o3_overlap_256": 64}),
+                False, {"s.dir/chunk"}),
 }
 
 
@@ -61,7 +68,7 @@ def build(machine: str):
     if machine == "faulty":
         cfg = dataclasses.replace(cfg, faults_enabled=True)
     return cfg, Engine(
-        cfg, synth.fft_like(N, n_phases=2, points_per_core=8, seed=3),
+        cfg, synth.fft_like(cfg.n_cores, n_phases=2, points_per_core=8, seed=3),
         chunk_steps=8)
 
 
@@ -148,6 +155,21 @@ def test_group_scope_holds_the_coarse_vectors_reductions_and_only_there():
     assert any(p.startswith("reduce_max") for p in grp)
     assert any(p.startswith("reduce_sum") for p in grp)
     assert not any("/grp/" in p and "s.dir/grp/" not in p for p in paths)
+
+
+def test_chunk_scope_holds_the_full_maps_blockwise_reductions_and_only_there():
+    """`s.dir/chunk` sits under `s.dir` and holds the scan over blocks of
+    sharer words with its masked reductions; a machine without
+    `sharer_chunk_words` compiles to a text without it (the parametrised
+    test above, for every other machine), and `s.chunk`, run_loop's own
+    scope, is another thing."""
+    paths = scope_paths("chunked")
+    chunk = [p.split("/s.dir/chunk/", 1)[1] for p in paths if "/s.dir/chunk/" in p]
+    assert any(p.startswith("while/body/") for p in chunk)  # the scan over the blocks
+    assert any(p.endswith("reduce_max") for p in chunk)
+    assert any(p.endswith("reduce_sum") for p in chunk)
+    assert not any("/chunk/" in p and "s.dir/chunk/" not in p for p in paths)
+    assert not any("/s.chunk/" in p and "/s.dir/" in p for p in paths)
 
 
 def test_rank_scopes_hold_the_ranking_under_their_own_phase():
@@ -239,6 +261,7 @@ def test_benchmark_needles_are_phase_names():
     files = sorted(
         glob.glob(os.path.join(ROOT, "benchmark", "metrics", "ph_*.py"))
         + [os.path.join(ROOT, "benchmark", "metrics", "rank_noc_ms_step.py"),
+           os.path.join(ROOT, "benchmark", "metrics", "collective_dirm_ms_step.py"),
            os.path.join(ROOT, "benchmark", "phase_ops.py")])
     spelled = {}
     for path in files:

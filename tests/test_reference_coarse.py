@@ -7,14 +7,11 @@ vetting of its imports runs too."""
 import numpy as np
 import pytest
 
-from benchmark_modules import ROOT  # puts benchmark/ on the path
+from benchmark_modules import ROOT, assert_reference_equals_golden  # puts benchmark/ on the path
 
 import cells
 import reference
 import trafficgen
-from primesim_tpu.config.machine import MachineConfig
-from primesim_tpu.golden.sim import GoldenSim
-from primesim_tpu.trace.format import Trace
 
 coarse = cells.load_reference("coarse_dir", ROOT)
 
@@ -41,19 +38,7 @@ def _trace(gen, n=64):
 
 
 def _assert_equals_golden(machine, ev):
-    lengths = (ev[:, :, 0] != trafficgen.EV_END).sum(1) + 1
-    gold = GoldenSim(MachineConfig.from_dict(machine), Trace(ev, lengths))
-    gold.run()
-    ref = coarse.RefSim(machine, ev)
-    ref.run()
-    assert ref.step_count == gold.step_count
-    assert np.array_equal(np.asarray(ref.cycles), gold.cycles)
-    for k, v in gold.counters.items():
-        if k in coarse.COUNTERS:
-            assert np.array_equal(np.asarray(ref.counters[k]), v), k
-        else:
-            assert not v.any(), k
-    return ref
+    return assert_reference_equals_golden(coarse, machine, ev)
 
 
 @pytest.mark.parametrize("G,gen,full", [
