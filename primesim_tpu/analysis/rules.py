@@ -127,12 +127,14 @@ def check_traced_branch(tree, ctx):
 
 
 def _is_jax_jit(node: ast.AST) -> bool:
+    """`jax.jit`, or `mesh_jit` (parallel/sharding.py: `jax.jit` with the
+    mesh as one more static argument): a use of either is a jit site."""
     return (
         isinstance(node, ast.Attribute)
         and node.attr == "jit"
         and isinstance(node.value, ast.Name)
         and node.value.id == "jax"
-    )
+    ) or (isinstance(node, ast.Name) and node.id == "mesh_jit")
 
 
 @rule(

@@ -17,7 +17,12 @@ invalidations back to remote cores) is NOT hand-written message passing:
 the step function stays pure and global, and XLA's SPMD partitioner inserts
 the all-gathers/reduce-scatters that realize it over ICI (multi-host: DCN).
 The per-step `lax.scan` boundary doubles as the quantum barrier collective
-(SURVEY.md §2 #10 [DRIVER]).
+(SURVEY.md §2 #10 [DRIVER]). One seam is written by hand, `read_rows`: a
+read of whole directory rows by slot, of which the reader wants a few
+words. The partitioner sends the rows; `read_rows` reduces each row on the
+chip that holds it and sends the words. The device loops learn their mesh
+from their arguments (`mesh_jit`), as `build_state` learns it from
+`Engine`: no configuration field says it.
 
 Works identically on real TPU meshes and on virtual CPU meshes
 (``--xla_force_host_platform_device_count``), which is how tests and the
@@ -29,6 +34,7 @@ from __future__ import annotations
 import functools
 
 import jax
+import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
@@ -269,6 +275,101 @@ def build_state(cfg, mesh: Mesh | None = None) -> MachineState:
 
 def shard_events(mesh: Mesh, events) -> jax.Array:
     return jax.device_put(events, NamedSharding(mesh, events_pspec()))
+
+
+def mesh_of(*trees) -> Mesh | None:
+    """The tile mesh the arrays (or `ShapeDtypeStruct`s) of `trees` are
+    laid out over, None where none is: the first leaf whose sharding names
+    `AXIS` decides. Tracers carry no layout, so inside a `jit` or a `vmap`
+    the answer is None and the caller hands its mesh on by name."""
+    for leaf in jax.tree.leaves(trees):
+        sh = getattr(leaf, "sharding", None)
+        if isinstance(sh, NamedSharding) and AXIS in sh.mesh.axis_names:
+            return sh.mesh
+    return None
+
+
+class mesh_jit:
+    """`jax.jit` of a device loop that takes its mesh as the static keyword
+    `mesh`, filled in from the arguments' layout (`mesh_of`) where the
+    caller does not name it: `run_loop(cfg, n, events, st, k)` on a
+    sharded state compiles the sharded step, on a plain one today's
+    program, and the jit key tells the two apart, and one mesh from
+    another. Keeps what callers use of a jitted function: the call,
+    `lower`, `_cache_size`."""
+
+    def __init__(self, fn, **jit_kwargs):
+        names = tuple(jit_kwargs.pop("static_argnames", ()))
+        self._jit = jax.jit(
+            fn, static_argnames=(*names, "mesh"), **jit_kwargs
+        )
+        functools.update_wrapper(self, fn)
+
+    def _bind(self, args, kwargs):
+        if "mesh" not in kwargs:
+            kwargs = {**kwargs, "mesh": mesh_of(args)}
+        return kwargs
+
+    def __call__(self, *args, **kwargs):
+        return self._jit(*args, **self._bind(args, kwargs))
+
+    def lower(self, *args, **kwargs):
+        return self._jit.lower(*args, **self._bind(args, kwargs))
+
+    def _cache_size(self) -> int:
+        return self._jit._cache_size()
+
+
+def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
+              whole=()):
+    """`reduce_rows(table[slot], *per_slot, *whole, core_axis=0)`: a tuple
+    of `[C, K]` arrays, the few words a reader wants of the rows `slot`
+    [C, K] of `table` [R, W]. `per_slot` are `[C, K]` int32 values the
+    reduction needs beside the rows, `whole` values every chip has in full
+    (an iota, a replicated table). `reduce_rows` always sees every core's
+    rows, in core order, along the axis `core_axis` of `slot`'s two.
+
+    Without a mesh it is exactly that expression. On a mesh `table` is
+    sharded by row and `slot` names any row, and left to the partitioner
+    the expression has every chip gather all C*K rows, masked to its own
+    shard, and all-reduce the WHOLE rows (rung 4's local run: 170 MB a
+    step at 31 GB/s, two fifths of the step; PERF.md section 6, PR 34).
+    Here each chip takes every core's `slot` and `per_slot` (one
+    all-gather of words), gathers from its own shard the rows it holds
+    (the index clamped for the others), reduces each row there, zeroes
+    the RECORD (not the row: a zero row could match a line 0) where the
+    slot is another chip's, and the records are summed. One chip holds
+    each slot, so the sum is that chip's record to the bit, and
+    C*K*len(record) words cross chips. The chip works on `[K, C, ...]`
+    (`core_axis=1`): its gather yields `[K*C, W]`, which splits into
+    `[K, C, W]` as it lies, where `[C, K, W]` is a copy that pads K to
+    the tile's 8 rows."""
+    if mesh is None:
+        return reduce_rows(table[slot], *per_slot, *whole, core_axis=0)
+    rows = table.shape[0] // mesh.shape[AXIS]
+
+    def on_chip(tab, packed, *whole):
+        packed = jax.lax.all_gather(packed, AXIS, axis=1, tiled=True)
+        slot, *per_slot = (packed[..., i] for i in range(packed.shape[-1]))
+        local = slot - jax.lax.axis_index(AXIS) * rows
+        mine = (local >= 0) & (local < rows)
+        record = reduce_rows(
+            tab[jnp.clip(local, 0, rows - 1)], *per_slot, *whole, core_axis=1
+        )
+        dtypes[:] = [r.dtype for r in record]
+        record = jnp.stack(
+            [jnp.where(mine, r.astype(jnp.int32), 0) for r in record], axis=-1
+        )
+        return jax.lax.psum(record, AXIS)
+
+    dtypes: list = []  # of the record's fields, as `reduce_rows` gives them
+    packed = jnp.swapaxes(jnp.stack((slot, *per_slot), axis=-1), 0, 1)
+    record = jax.shard_map(
+        on_chip, mesh=mesh, out_specs=P(),
+        in_specs=(P(AXIS), P(None, AXIS), *(P() for _ in whole)),
+    )(table, packed, *whole)
+    record = jnp.swapaxes(record, 0, 1)
+    return tuple(record[..., i].astype(dt) for i, dt in enumerate(dtypes))
 
 
 def fleet_state_pspecs() -> MachineState:

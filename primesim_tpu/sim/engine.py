@@ -29,7 +29,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.machine import MachineConfig
-from ..parallel.sharding import build_state, shard_events
+from ..parallel.sharding import build_state, mesh_jit, shard_events
 from ..stats.counters import COUNTER_NAMES, zero_counters
 from ..trace.format import (
     EV_BARRIER,
@@ -46,16 +46,18 @@ _ACC_BITS = 30  # device counter accumulators carry into hi above 2^30
 
 
 @functools.partial(
-    jax.jit, static_argnums=(0, 1), static_argnames=("has_sync",)
+    mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
 )
 def run_chunk(
     cfg: MachineConfig, n_steps: int, events, st: MachineState,
-    has_sync: bool = True,
+    has_sync: bool = True, mesh=None,
 ):
-    """lax.scan over `n_steps` steps — the jitted hot loop."""
+    """lax.scan over `n_steps` steps — the jitted hot loop. `mesh`, here
+    and in the loops below: the tile mesh of `events` and `st`, which
+    `mesh_jit` reads off them where the caller names none."""
 
     def body(carry, _):
-        return step(cfg, events, carry, has_sync=has_sync), None
+        return step(cfg, events, carry, has_sync=has_sync, mesh=mesh), None
 
     st, _ = jax.lax.scan(body, st, None, length=n_steps)
     return st
@@ -127,10 +129,10 @@ def _drain_and_rebase(cfg, st, acc_lo, acc_hi, base_lo, base_hi, nd):
 
 
 @functools.partial(
-    jax.jit, static_argnums=(0, 1), static_argnames=("has_sync",)
+    mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
 )
 def run_loop(cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
-             max_chunks, has_sync: bool = True):
+             max_chunks, has_sync: bool = True, mesh=None):
     """ONE dispatched device program for a whole simulation run.
 
     `lax.while_loop` over scan chunks; after each chunk, ON DEVICE: drain
@@ -156,7 +158,7 @@ def run_loop(cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
         st, acc_lo, acc_hi, base_lo, base_hi, k = carry
 
         def sbody(c, _):
-            return step(cfg, events, c, has_sync=has_sync), None
+            return step(cfg, events, c, has_sync=has_sync, mesh=mesh), None
 
         st, _ = jax.lax.scan(sbody, st, None, length=chunk_steps)
         with jax.named_scope(P_CHUNK):
@@ -183,10 +185,10 @@ def run_loop(cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
 
 
 @functools.partial(
-    jax.jit, static_argnums=(0,), static_argnames=("has_sync",)
+    mesh_jit, static_argnums=(0,), static_argnames=("has_sync",)
 )
 def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
-                filled, max_steps, has_sync: bool = True):
+                filled, max_steps, has_sync: bool = True, mesh=None):
     """Device loop for WINDOWED (streaming) ingest — SURVEY.md §2 #8's
     bounded-buffer hand-off: the events array holds only a window of each
     core's stream, END-padded; `exhausted[c]` marks cores with no events
@@ -226,7 +228,7 @@ def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
 
     def body(carry):
         st, acc_lo, acc_hi, base_lo, base_hi, k = carry
-        st = step(cfg, events, st, has_sync=has_sync)
+        st = step(cfg, events, st, has_sync=has_sync, mesh=mesh)
         # not-done for the rebase: a core at its window's fake END padding
         # (ptr past `filled` but the stream continues, ~exhausted) is LIVE —
         # it must still bound the rebase minimum, else the uniform shift

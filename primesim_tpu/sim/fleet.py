@@ -44,6 +44,7 @@ import numpy as np
 
 from ..chaos import sites as chaos
 from ..config.machine import MachineConfig
+from ..parallel.sharding import mesh_jit
 from ..stats.counters import COUNTER_NAMES
 from ..trace.format import EV_BARRIER, EV_END, EV_LOCK, EV_UNLOCK, Trace
 from . import exec_cache
@@ -157,25 +158,29 @@ def apply_overrides(cfg: MachineConfig, ov: dict | None) -> MachineConfig:
 
 
 @functools.partial(
-    jax.jit, static_argnums=(0, 1), static_argnames=("has_sync",)
+    mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
 )
 def fleet_run_chunk(
     cfg: MachineConfig, n_steps: int, events, st: MachineState,
-    has_sync: bool = True,
+    has_sync: bool = True, mesh=None,
 ):
     """`run_chunk` vmapped over the leading batch axis. `cfg` must be the
-    TIMING-NORMALIZED geometry config — timing comes from st.knobs."""
+    TIMING-NORMALIZED geometry config — timing comes from st.knobs. Under
+    the `vmap` the elements are tracers, so the fleet's mesh (read off the
+    batched arguments by `mesh_jit`) is handed on by name."""
     return jax.vmap(
-        lambda ev, s: run_chunk(cfg, n_steps, ev, s, has_sync=has_sync)
+        lambda ev, s: run_chunk(
+            cfg, n_steps, ev, s, has_sync=has_sync, mesh=mesh
+        )
     )(events, st)
 
 
 @functools.partial(
-    jax.jit, static_argnums=(0, 1), static_argnames=("has_sync",)
+    mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
 )
 def fleet_run_loop(
     cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
-    max_chunks, has_sync: bool = True,
+    max_chunks, has_sync: bool = True, mesh=None,
 ):
     """`run_loop` vmapped over the leading batch axis: one dispatched
     device program for a whole FLEET run. Per-element drain/rebase and
@@ -185,7 +190,7 @@ def fleet_run_loop(
     moment it finishes."""
     return jax.vmap(
         lambda ev, s: run_loop(
-            cfg, chunk_steps, ev, s, max_chunks, has_sync=has_sync
+            cfg, chunk_steps, ev, s, max_chunks, has_sync=has_sync, mesh=mesh
         )
     )(events, st)
 

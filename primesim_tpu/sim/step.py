@@ -50,6 +50,7 @@ from ..ops.ranking import (
     segmented_rank_floor,
     segmented_table_max,
 )
+from ..parallel.sharding import read_rows
 from ..stats.counters import COUNTER_NAMES
 from ..trace.format import (
     EV_BARRIER,
@@ -481,7 +482,44 @@ def _fault(cfg: MachineConfig, events, st: MachineState, arange_c, acc):
     return st, deadb
 
 
-def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc):
+def _run_record(cfg: MachineConfig, rows, line, core, core_axis=0):
+    """What a local run reads of its candidates' home rows `rows`
+    [C, K, DW] (`dirm` rows: metadata AND sharers) for the lines `line`
+    [C, K] of the cores `core` [C]: whether a way holds the line, that
+    way's owner, the core's own sharer bit; under the coarse vector the
+    way's invalidation epoch too, under moesi the number of sharers
+    recorded. A tuple of [C, K] arrays; [K, C, DW], [K, C] in and [K, C]
+    out where `core_axis` is 1. A function of one row and of values every
+    chip has, so `sharding.read_rows` runs it on the chip that holds the
+    row."""
+    W2, NW = cfg.llc.ways, cfg.n_sharer_words
+    MW = llc_meta_width(cfg)
+    pmeta = rows[:, :, : 2 * W2].reshape(*line.shape, W2, 2)
+    pmmatch = pmeta[..., 0] == line[:, :, None]
+    pmhas = jnp.any(pmmatch, axis=2)
+    pmway = jnp.argmax(pmmatch, axis=2).astype(jnp.int32)
+    # way and word picks out of rows already in hand: selects, not
+    # gathers (`_pick`); `argmax` keeps first-match order
+    pown = _pick(pmeta[..., 1], pmway)
+    g_c0 = core >> (cfg.sharer_group.bit_length() - 1)
+    along = (slice(None), None) if core_axis == 0 else (None, slice(None))
+    # the self sharer word rides the row gather: in-register select
+    pshw = _pick(rows[:, :, MW:], pmway * NW + (g_c0[along] >> 5))
+    pbit = ((pshw >> (g_c0[along] & 31)) & 1) != 0
+    record = [pmhas, pown, pbit]
+    if cfg.sharer_group > 1:
+        record.append(_pick(rows[:, :, 3 * W2 : 4 * W2], pmway))
+    if cfg.coherence == "moesi":
+        psh_all = rows[:, :, MW:].reshape(*line.shape, W2, NW)
+        pwords = _pick(
+            jnp.swapaxes(psh_all, 2, 3), pmway[:, :, None]
+        )  # [C, K, NW]: the matching way's sharer words
+        record.append(jnp.sum(jax.lax.population_count(pwords), axis=2))
+    return tuple(record)
+
+
+def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
+           mesh=None):
     """Phase 0, the quantum barrier, and phase 0.5, the local runs ->
     `quantum_end` (scalar), the clocks and trace pointers after the runs
     `cycles_c`, `ptr_c` [C], the prefetched candidate events `pev`
@@ -491,9 +529,7 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc):
     last two are None where `local_run_len` is 0."""
     C, B = cfg.n_cores, cfg.n_banks
     S1 = cfg.l1.sets
-    S2, W2 = cfg.llc.sets, cfg.llc.ways
-    NW = cfg.n_sharer_words
-    MW = llc_meta_width(cfg)
+    S2 = cfg.llc.sets
     T = events.shape[1]
     kn = st.knobs
     Q, cpi_vec, l1_lat = kn.quantum, kn.cpi, kn.l1_lat
@@ -567,18 +603,13 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc):
             pbank = pline & (B - 1)
             pbset = (pline >> logB) & (S2 - 1)
             pslot = pbank * S2 + pbset
-            pmrows = st.dirm[pslot]  # [C, rl+1, DW] — metadata AND sharers
-            pmeta = pmrows[:, :, : 2 * W2].reshape(C, rl + 1, W2, 2)
-            pmmatch = pmeta[..., 0] == pline[:, :, None]
-            pmhas = jnp.any(pmmatch, axis=2)
-            pmway = jnp.argmax(pmmatch, axis=2).astype(jnp.int32)
-            # way and word picks out of rows already in hand: selects, not
-            # gathers (`_pick`); `argmax` keeps first-match order
-            pown = _pick(pmeta[..., 1], pmway)
-            g_c0 = arange_c >> (cfg.sharer_group.bit_length() - 1)
-            # the self sharer word rides the row gather: in-register select
-            pshw = _pick(pmrows[:, :, MW:], pmway * NW + (g_c0[:, None] >> 5))
-            pbit = ((pshw >> (g_c0[:, None] & 31)) & 1) != 0
+            # the home rows are read where they live (`_run_record`): of a
+            # row the run wants the words below, and on a mesh only those
+            # cross chips
+            pmhas, pown, pbit, *prest = read_rows(
+                mesh, st.dirm, pslot, functools.partial(_run_record, cfg),
+                per_slot=(pline,), whole=(arange_c,),
+            )
             pmatch_l = (ptagr == pline[:, :, None]) & (pstater != I)
             plhit = jnp.any(pmatch_l, axis=2)
             plway = jnp.argmax(pmatch_l, axis=2).astype(jnp.int32)
@@ -588,8 +619,7 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc):
                 # this core's S line alive if no sharer-clearing transition
                 # happened since its fill
                 pleph = _pick(pts[:, :, 2], plway)
-                pveph = _pick(pmrows[:, :, 3 * W2 : 4 * W2], pmway)
-                pbit = pbit & (pveph == pleph)
+                pbit = pbit & (prest[0] == pleph)  # the home way's epoch
             peff = jnp.where(
                 ~(plhit & pmhas),
                 I,
@@ -606,12 +636,7 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc):
                 # probe's effective E/M demotes to O. sharer_group == 1 under
                 # moesi (config validation), so pbit IS the self bit and the
                 # word popcount is an exact sharer count.
-                psh_all = pmrows[:, :, MW:].reshape(C, rl + 1, W2, NW)
-                pwords = _pick(
-                    jnp.swapaxes(psh_all, 2, 3), pmway[:, :, None]
-                )  # [C, rl+1, NW]: the matching way's sharer words
-                ptot = jnp.sum(jax.lax.population_count(pwords), axis=2)
-                pothers = (ptot - pbit.astype(jnp.int32)) > 0
+                pothers = (prest[-1] - pbit.astype(jnp.int32)) > 0  # sharers
                 peff = jnp.where(
                     pothers & pmhas & (pown == arange_c[:, None]) & (peff >= E),
                     O,
@@ -2199,10 +2224,13 @@ def step(
     events: jnp.ndarray,
     st: MachineState,
     has_sync: bool = True,
+    mesh=None,
 ) -> MachineState:
     """One simulation step: the phases in execution order. The static
     selectors of `cfg` (and `has_sync`) decide which phases a machine
-    compiles; everything a phase reads or hands on is in its call."""
+    compiles; everything a phase reads or hands on is in its call. `mesh`
+    is the tile mesh the state is sharded over, None on one device: the
+    one phase that reads it is `_local` (`sharding.read_rows`)."""
     C = cfg.n_cores
     arange_c = jnp.arange(C, dtype=jnp.int32)
     # TIMING comes from the TRACED knob pytree carried in state, never
@@ -2218,7 +2246,7 @@ def step(
     if cfg.faults_enabled:
         st, deadb = _fault(cfg, events, st, arange_c, acc)
     quantum_end, cycles_c, ptr_c, pev, run_patch = _local(
-        cfg, events, st, arange_c, deadb, acc)
+        cfg, events, st, arange_c, deadb, acc, mesh)
     rq = _probe(cfg, events, st, arange_c, cycles_c, ptr_c, quantum_end, pev,
                 run_patch, deadb)
     winner, join, key = _arb(cfg, kn, arange_c, rq, cycles_c, quantum_end, acc)

@@ -35,7 +35,9 @@ import sys
 _METADATA = re.compile(r",? ?metadata=\{[^}]*\}")
 _TABLES = re.compile(
     r"\n(?:FileNames|FunctionNames|FileLocations|StackFrames)\n(?:\d+ .*\n)*")
-_NAME = re.compile(r"%[\w.\-]+")
+# a name where it is used (`%add.7`) and where a computation's header
+# declares it as a parameter (`(reduce_sum.108: s32[], ...)`)
+_NAME = re.compile(r"%[\w.\-]+|\b[\w.\-]+(?=: )")
 _MOSAIC = re.compile(r'("custom_call_config":\{"body":")([A-Za-z0-9+/=]+)"')
 
 
@@ -54,26 +56,33 @@ def strip(text: str) -> str:
     return _MOSAIC.sub(_kernel_digest, text)
 
 
+def load_config(config_path: str):
+    """(`MachineConfig`, `chunk_steps`, `devices`) of a benchmark
+    configuration, or of a plain machine file (chunks of 8, one device)."""
+    from primesim_tpu.config.machine import MachineConfig
+
+    with open(config_path) as f:
+        conf = json.load(f)
+    if "machine" not in conf:
+        return MachineConfig.from_dict(conf), 8, 1
+    run = conf["run"]
+    machine = {**conf["machine"], "step_impl": run["step_impl"]}
+    return (MachineConfig.from_dict(machine), int(run["chunk_steps"]),
+            int(run.get("devices") or 1))
+
+
 def dump(config_path: str, out_path: str, has_sync: bool = False,
          trace_len: int = 546) -> None:
     import jax
     import jax.numpy as jnp
 
-    from primesim_tpu.config.machine import MachineConfig
     from primesim_tpu.sim.engine import run_loop
     from primesim_tpu.sim.state import init_state
 
     # what THIS checkout compiles: a persistent cache keyed without
     # metadata would hand back another checkout's text
     jax.config.update("jax_enable_compilation_cache", False)
-    with open(config_path) as f:
-        conf = json.load(f)
-    if "machine" in conf:
-        machine = {**conf["machine"], "step_impl": conf["run"]["step_impl"]}
-        chunk_steps = int(conf["run"]["chunk_steps"])
-    else:
-        machine, chunk_steps = conf, 8
-    cfg = MachineConfig.from_dict(machine)
+    cfg, chunk_steps, _ = load_config(config_path)
     st = jax.eval_shape(lambda: init_state(cfg))
     ev = jax.ShapeDtypeStruct((cfg.n_cores, trace_len, 4), jnp.int32)
     text = run_loop.lower(

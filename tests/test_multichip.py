@@ -40,15 +40,24 @@ def test_eight_device_mesh_exists():
 
 # the sharded parity matrix: machine x trace shape. `chunked` is rung 4's
 # pair of selectors (a CPI a core, the full sharer map reduced in blocks:
-# two words, blocks of one), `coarse` rung 5's (one sharer bit to four cores)
+# two words, blocks of one), `coarse` rung 5's (one sharer bit to four
+# cores), `moesi` the protocol whose local run also counts a line's sharers.
+# All with local runs, as every shipped machine has them: the run's
+# directory rows are the one read the step places by hand on a mesh
+# (`sharding.read_rows`)
 MACHINES = {
-    "plain": lambda: small_test_config(n_cores=16, n_banks=8),
+    "plain": lambda: small_test_config(n_cores=16, n_banks=8, local_run_len=4),
     "chunked": lambda: small_test_config(
-        n_cores=64, n_banks=16, sharer_chunk_words=1,
+        n_cores=64, n_banks=16, sharer_chunk_words=1, local_run_len=4,
         core=CoreConfig(cpi_pattern=(1, 1, 3, 3), o3_overlap_256=64),
         noc=NocConfig(mesh_x=8, mesh_y=8, link_lat=1, router_lat=1),
     ),
-    "coarse": lambda: small_test_config(n_cores=16, n_banks=8, sharer_group=4),
+    "coarse": lambda: small_test_config(
+        n_cores=16, n_banks=8, sharer_group=4, local_run_len=4
+    ),
+    "moesi": lambda: small_test_config(
+        n_cores=16, n_banks=8, coherence="moesi", local_run_len=4
+    ),
 }
 GENERATORS = {
     "uniform_random": lambda n: synth.uniform_random(n, n_mem_ops=80, seed=7),
@@ -70,6 +79,146 @@ def test_sharded_parity(machine, gen):
     for k in c_g:
         np.testing.assert_array_equal(c_8[k], c_g[k], err_msg=k)
         np.testing.assert_array_equal(c_8[k], c_1[k], err_msg=k)
+
+
+def _run_record_oracle(cfg, dirm, pslot, pline):
+    """numpy: `dirm[pslot]` and what the local run reads of those rows
+    (`sim/step.py::_run_record`), an element gather a field."""
+    from primesim_tpu.sim.state import llc_meta_width
+
+    C, K = pline.shape
+    W2, NW, MW = cfg.llc.ways, cfg.n_sharer_words, llc_meta_width(cfg)
+    rows = dirm[pslot]
+    meta = rows[:, :, : 2 * W2].reshape(C, K, W2, 2)
+    match = meta[..., 0] == pline[:, :, None]
+    way = match.argmax(2)
+    pick = lambda x, i: np.take_along_axis(x, i[..., None], 2)[..., 0]  # noqa: E731
+    g = (np.arange(C) >> (cfg.sharer_group.bit_length() - 1))[:, None]
+    word = pick(rows[:, :, MW:], way * NW + (g >> 5))
+    out = [match.any(2), pick(meta[..., 1], way), ((word >> (g & 31)) & 1) != 0]
+    if cfg.sharer_group > 1:
+        out.append(pick(rows[:, :, 3 * W2 : 4 * W2], way))
+    return out
+
+
+@pytest.mark.parametrize("machine", ["plain", "chunked", "coarse"])
+def test_run_record_on_the_rows_own_chip(machine):
+    """`read_rows` + `_run_record` alone, with and without a mesh, against
+    the numpy oracle on a random directory. Core 0's candidates ask for
+    line 0 in a slot of the LAST shard whose row holds other tags, while
+    every other shard's row at the clamped index is all zeros: a chip that
+    let such a row answer (masking the row and not the record) would find
+    a tag 0 there and report a hit."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from primesim_tpu.parallel.sharding import read_rows, state_shardings
+    from primesim_tpu.sim.state import dirm_width
+    from primesim_tpu.sim.step import _run_record
+
+    cfg = MACHINES[machine]()
+    C, K, W2 = cfg.n_cores, cfg.local_run_len + 1, cfg.llc.ways
+    R, DW = cfg.n_banks * cfg.llc.sets, dirm_width(cfg)
+    rng = np.random.default_rng(34)
+    dirm = rng.integers(0, 2**31 - 1, (R, DW), dtype=np.int32)
+    pslot = rng.integers(0, R, (C, K), dtype=np.int32)
+    # half the candidates ask for a tag their row holds, half for another
+    held = dirm[pslot][:, :, 0 : 2 * W2 : 2]
+    way = rng.integers(0, W2, (C, K))
+    pline = np.where(
+        rng.random((C, K)) < 0.5,
+        np.take_along_axis(held, way[..., None], 2)[..., 0],
+        rng.integers(0, 2**31 - 1, (C, K), dtype=np.int32),
+    ).astype(np.int32)
+    per = R // 8
+    dirm[per - 1 :: per] = 0  # the row every chip reads past its own shard
+    dirm[0::per] = 0  # ... and before it
+    dirm[R - 2, 0 : 2 * W2 : 2] = np.arange(1, W2 + 1)  # tags, none of them 0
+    pslot[0], pline[0] = R - 2, 0
+    want = _run_record_oracle(cfg, dirm, pslot, pline)
+    assert not want[0][0].any()  # line 0 is in no way of its home row
+
+    reduce_rows = functools.partial(_run_record, cfg)
+    core = jnp.arange(C, dtype=jnp.int32)
+    for mesh in (None, tile_mesh(8)):
+        table = jnp.asarray(dirm)
+        if mesh is not None:
+            table = jax.device_put(table, state_shardings(mesh).dirm)
+        got = jax.jit(
+            lambda t, sl, ln, mesh=mesh: read_rows(
+                mesh, t, sl, reduce_rows, per_slot=(ln,), whole=(core,)
+            )
+        )(table, jnp.asarray(pslot), jnp.asarray(pline))
+        assert len(got) == len(want)
+        for i, (g, w) in enumerate(zip(got, want)):
+            assert g.dtype == (jnp.bool_ if w.dtype == bool else jnp.int32)
+            np.testing.assert_array_equal(
+                np.asarray(g), w, err_msg=f"field {i}, mesh {mesh is not None}"
+            )
+
+
+@pytest.mark.parametrize("engine", ["fleet", "stream"])
+def test_sharded_local_runs_under_vmap_and_windows(engine):
+    """The run's row read is a `shard_map`: it has to compose with the
+    fleet's `vmap` of `step` and with the windowed loop, bit for bit."""
+    cfg = small_test_config(16, n_banks=8, quantum=200, local_run_len=4)
+    if engine == "fleet":
+        from primesim_tpu.sim.fleet import FleetEngine
+
+        traces = [
+            synth.false_sharing(16, n_mem_ops=40, seed=11),
+            synth.fft_like(16, n_phases=2, points_per_core=8, seed=14),
+        ]
+        ovs = [{}, {"dram_lat": 140}]
+        make = lambda mesh: FleetEngine(  # noqa: E731
+            cfg, traces, ovs, chunk_steps=32, mesh=mesh
+        )
+    else:
+        from primesim_tpu.ingest.stream import StreamEngine
+
+        tr = synth.fft_like(16, n_phases=2, points_per_core=12, seed=31)
+        make = lambda mesh: StreamEngine(  # noqa: E731
+            cfg, tr, window_events=32, mesh=mesh
+        )
+    plain, sharded = make(None), make(tile_mesh(8))
+    for e in (plain, sharded):
+        e.run()
+    np.testing.assert_array_equal(sharded.cycles, plain.cycles)
+    assert int(plain.counters["l1_read_hits"].sum()) > 0  # runs did retire
+    for k, v in plain.counters.items():
+        np.testing.assert_array_equal(sharded.counters[k], v, err_msg=k)
+
+
+def test_device_loops_key_on_the_mesh_of_their_arguments():
+    """`run_loop` is told no mesh: `mesh_jit` reads it off the state's
+    layout. One program without a mesh, one a mesh, and a direct call on
+    an engine's arrays (the benchmark's warm-up) is the engine's program."""
+    import jax.numpy as jnp
+
+    from primesim_tpu.parallel.sharding import mesh_of
+    from primesim_tpu.sim.engine import run_loop
+
+    cfg = small_test_config(n_cores=16, n_banks=8, local_run_len=2)
+    trace = synth.stream(16)
+    n0 = run_loop._cache_size()
+    engines = [
+        Engine(cfg, trace, chunk_steps=8, mesh=mesh)
+        for mesh in (None, tile_mesh(8), tile_mesh(4))
+    ]
+    assert [mesh_of(e.events, e.state) for e in engines] == [
+        e.mesh for e in engines
+    ]
+    for i, e in enumerate(engines):
+        e.run()
+        assert run_loop._cache_size() == n0 + i + 1
+    for e in engines:  # fresh arrays of the same layouts: nothing compiles
+        again = Engine(cfg, trace, chunk_steps=8, mesh=e.mesh)
+        run_loop(cfg, 8, again.events, again.state, jnp.asarray(1, jnp.int32),
+                 has_sync=again.has_sync)
+    assert run_loop._cache_size() == n0 + 3
+    np.testing.assert_array_equal(engines[1].cycles, engines[0].cycles)
+    np.testing.assert_array_equal(engines[2].cycles, engines[0].cycles)
 
 
 def test_state_is_actually_sharded():
@@ -171,12 +320,11 @@ def test_sharded_parity_256core():
         np.testing.assert_array_equal(ec[k], v, err_msg=k)
 
 
-def test_sharded_step_never_allgathers_directory():
-    # the round-2 regression's failure mode: a layout/sharding slip that
-    # makes XLA materialize the FULL sharers/llc_meta array on every
-    # device each step. Compile the sharded chunk and assert no
-    # all-gather/all-reduce touches a directory-shaped operand.
-    import re
+def _sharded_chunk_text(**machine):
+    """A 256-core machine and the compiled text of its `run_chunk` sharded
+    over the eight devices: what the partitioner (and `read_rows`) made of
+    the step."""
+    import jax.numpy as jnp
 
     from primesim_tpu.parallel.sharding import shard_events, shard_state
     from primesim_tpu.sim.engine import run_chunk
@@ -187,15 +335,25 @@ def test_sharded_step_never_allgathers_directory():
         l1=CacheConfig(size=1024, ways=2, line=64, latency=2),
         llc=CacheConfig(size=4096, ways=4, line=64, latency=12),
         noc=NocConfig(mesh_x=16, mesh_y=16),
-        quantum=600,
+        quantum=600, **machine,
     )
     tr = synth.false_sharing(256, n_mem_ops=8, seed=94)
     mesh = tile_mesh(8)
-    import jax.numpy as jnp
-
     events = shard_events(mesh, jnp.asarray(tr.line_events(cfg.line_bits)))
     st = shard_state(mesh, init_state(cfg))
-    txt = run_chunk.lower(cfg, 4, events, st, has_sync=False).compile().as_text()
+    return cfg, run_chunk.lower(
+        cfg, 4, events, st, has_sync=False
+    ).compile().as_text()
+
+
+def test_sharded_step_never_allgathers_directory():
+    # the round-2 regression's failure mode: a layout/sharding slip that
+    # makes XLA materialize the FULL sharers/llc_meta array on every
+    # device each step. Compile the sharded chunk and assert no
+    # all-gather/all-reduce touches a directory-shaped operand.
+    import re
+
+    cfg, txt = _sharded_chunk_text()
     B_S2 = cfg.n_banks * cfg.llc.sets  # full (unsharded) leading dim
     bad = [
         l
@@ -203,3 +361,37 @@ def test_sharded_step_never_allgathers_directory():
         if re.search(r"all-gather|all-reduce", l) and f"[{B_S2}," in l
     ]
     assert not bad, "directory arrays all-gathered:\n" + "\n".join(bad[:5])
+
+
+def test_sharded_local_run_sends_records_not_rows():
+    """The local run reads 17 words of each of its C*(rl+1) candidate rows.
+    Left to the partitioner, `st.dirm[pslot]` all-reduces the whole rows
+    (PERF.md section 6, PR 34: two fifths of rung 4's step); through
+    `sharding.read_rows` each chip reduces its own rows and a few words a
+    candidate cross chips. Held on the compiled sharded chunk: no
+    collective under `s.local` has an operand as wide as a `dirm` row, and
+    all of them together carry O(C*(rl+1)) words."""
+    import re
+
+    from primesim_tpu.sim.state import dirm_width
+
+    cfg, txt = _sharded_chunk_text(local_run_len=4)
+    collective = re.compile(
+        r" = (.*?) (?:all-reduce|all-gather|reduce-scatter|all-to-all"
+        r"|collective-permute)(?:-start)?\("
+    )
+    words, wide = 0, []
+    for line in txt.splitlines():
+        found = collective.search(line)
+        if not found or "s.local" not in line:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]*)\]", found.group(1)):
+            shape = [int(d) for d in dims.split(",") if d]
+            words += int(np.prod(shape))
+            if shape and shape[-1] == dirm_width(cfg):
+                wide.append(line.strip()[:200])
+    assert not wide, "whole directory rows cross chips:\n" + "\n".join(wide)
+    # slots and lines out, records back; on the CPU the partitioner also
+    # sums the candidates' event records (4 words) across devices
+    candidates = cfg.n_cores * (cfg.local_run_len + 1)
+    assert 0 < words <= 16 * candidates, (words, candidates)
