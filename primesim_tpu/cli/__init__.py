@@ -1954,7 +1954,7 @@ def build_parser() -> argparse.ArgumentParser:
              "(jax engine; inspect with xprof/tensorboard). Each device "
              "op's name path holds its phase of the step: s.local, "
              "s.probe, s.arb, s.dir, s.noc[/rank], s.dram[/rank], "
-             "s.commit, s.sync, s.fault, and s.chunk for the per-chunk "
+             "s.commit, s.sync[/lock|/barrier], s.fault, and s.chunk for the per-chunk "
              "housekeeping (DESIGN.md §15); the host spans engine.dispatch "
              "and engine.readback lie on the same timeline",
     )

@@ -85,6 +85,8 @@ INT32_MAX = np.int32(2**31 - 1)
 _RANK = "rank"  # second level: the calls into ops/ranking.py
 _GRP = "grp"  # second level: the coarse vector's per-group reductions
 _CHUNK = "chunk"  # second level: the full map's reductions in blocks of words
+_LOCK = "lock"  # second level: unlocks and lock grants
+_BARRIER = "barrier"  # second level: barrier arrivals and releases
 PHASES = (
     "s.fault",  # phase -1: fault injection
     "s.local",  # phase 0 quantum barrier + 0.5 local runs
@@ -101,16 +103,19 @@ PHASES = (
     "s.commit",  # latency composition, granted state, counters, phase 4.A,
     #               the end-of-step commit
     "s.sync",  # 2.7: locks and barriers
+    "s.sync/" + _LOCK,
+    "s.sync/" + _BARRIER,
     "s.chunk",  # run_loop's per-chunk drain, rebase and termination test
 )
 (P_FAULT, P_LOCAL, P_PROBE, P_ARB, P_DIR, _, _, P_NOC, _, P_DRAM, _, P_COMMIT,
- P_SYNC, P_CHUNK) = PHASES
+ P_SYNC, _, _, P_CHUNK) = PHASES
 
 # Which functions write under which scope: every instruction whose
 # `op_name` holds a phase has one of these on its call stack, so a phase's
 # work written into another phase's function fails a test
 # (tests/test_phase_scopes.py) and not a metric's reader. Second levels
-# (`/rank`, `/grp`, `/chunk`) belong to their phase. `s.chunk` is run_loop's own.
+# (`/rank`, `/grp`, `/chunk`, `/lock`, `/barrier`) belong to their phase.
+# `s.chunk` is run_loop's own.
 PHASE_FUNCTIONS = {
     P_FAULT: ("_fault",),
     P_LOCAL: ("_local",),
@@ -2053,141 +2058,143 @@ def _sync(cfg: MachineConfig, st: MachineState, arange_c, rq: Request, cycles,
             # memory path: same round-trip fault extra
             lat_rt = lat_rt + flt_rt
 
-        # unlocks: every unlock is a charged RMW round trip to the lock's
-        # home; the slot is released only if this core actually holds it
-        cycles = cycles + jnp.where(is_unlock, epre * cpi_vec + lat_rt, 0)
-        ptr = ptr + is_unlock.astype(jnp.int32)
-        _count(acc, "instructions", jnp.where(is_unlock, epre + 1, 0))
-        _count(acc, "noc_msgs", jnp.where(is_unlock, 2, 0))
-        _count(acc, "noc_hops", jnp.where(is_unlock, lreq_hops + lrep_hops, 0))
-        held = lock_holder[lslot] == arange_c
-        lock_holder = lock_holder.at[
-            jnp.where(is_unlock & held, lslot, L)
-        ].set(-1, mode="drop")
+        with jax.named_scope(_LOCK):
+            # unlocks: every unlock is a charged RMW round trip to the lock's
+            # home; the slot is released only if this core actually holds it
+            cycles = cycles + jnp.where(is_unlock, epre * cpi_vec + lat_rt, 0)
+            ptr = ptr + is_unlock.astype(jnp.int32)
+            _count(acc, "instructions", jnp.where(is_unlock, epre + 1, 0))
+            _count(acc, "noc_msgs", jnp.where(is_unlock, 2, 0))
+            _count(acc, "noc_hops", jnp.where(is_unlock, lreq_hops + lrep_hops, 0))
+            held = lock_holder[lslot] == arange_c
+            lock_holder = lock_holder.at[
+                jnp.where(is_unlock & held, lslot, L)
+            ].set(-1, mode="drop")
 
-        # lock grants: per-slot scatter-min arbitration on (cycles, core_id)
-        # — the golden sort order, same key packing as the (bank,set) table
-        # above (the same clock-window invariant covers it). Grant iff the
-        # slot is free AFTER unlocks and this core holds the minimum key,
-        # OR the core already holds the lock (re-acquire). At most one
-        # grant per slot: free excludes re-acquire.
-        rel_l = cycles_c - (quantum_end - Q)
-        lkey = rel_l * C + arange_c
-        ltable = jnp.full(L, INT32_MAX, jnp.int32)
-        ltable = ltable.at[jnp.where(is_lock, lslot, L)].min(lkey, mode="drop")
-        lwin = is_lock & (ltable[lslot] == lkey)
-        holder1 = lock_holder[lslot]
-        grant = is_lock & ((holder1 == arange_c) | ((holder1 == -1) & lwin))
-        spin = is_lock & ~grant
-        # every attempt (grant or spin) is a charged round trip; the pre
-        # batch is charged only on the FIRST attempt (sync_flag still 0)
-        first = is_lock & (st.sync_flag == 0)
-        cycles = (
-            cycles
-            + jnp.where(first, epre * cpi_vec, 0)
-            + jnp.where(is_lock, lat_rt, 0)
-        )
-        _count(
-            acc,
-            "instructions",
-            jnp.where(first, epre, 0) + grant.astype(jnp.int32),
-        )
-        _count(acc, "lock_acquires", grant)
-        _count(acc, "lock_spins", spin)
-        _count(acc, "noc_msgs", jnp.where(is_lock, 2, 0))
-        _count(acc, "noc_hops", jnp.where(is_lock, lreq_hops + lrep_hops, 0))
-        if cfg.faults_enabled:
+            # lock grants: per-slot scatter-min arbitration on (cycles, core_id)
+            # — the golden sort order, same key packing as the (bank,set) table
+            # above (the same clock-window invariant covers it). Grant iff the
+            # slot is free AFTER unlocks and this core holds the minimum key,
+            # OR the core already holds the lock (re-acquire). At most one
+            # grant per slot: free excludes re-acquire.
+            rel_l = cycles_c - (quantum_end - Q)
+            lkey = rel_l * C + arange_c
+            ltable = jnp.full(L, INT32_MAX, jnp.int32)
+            ltable = ltable.at[jnp.where(is_lock, lslot, L)].min(lkey, mode="drop")
+            lwin = is_lock & (ltable[lslot] == lkey)
+            holder1 = lock_holder[lslot]
+            grant = is_lock & ((holder1 == arange_c) | ((holder1 == -1) & lwin))
+            spin = is_lock & ~grant
+            # every attempt (grant or spin) is a charged round trip; the pre
+            # batch is charged only on the FIRST attempt (sync_flag still 0)
+            first = is_lock & (st.sync_flag == 0)
+            cycles = (
+                cycles
+                + jnp.where(first, epre * cpi_vec, 0)
+                + jnp.where(is_lock, lat_rt, 0)
+            )
             _count(
                 acc,
-                "noc_reroutes",
-                jnp.where(is_unlock | is_lock, rr_req + rr_rep, 0),
+                "instructions",
+                jnp.where(first, epre, 0) + grant.astype(jnp.int32),
             )
-        lock_holder = lock_holder.at[jnp.where(grant, lslot, L)].set(
-            arange_c, mode="drop"
-        )
-        sync_flag = jnp.where(grant, 0, jnp.where(spin, 1, sync_flag))
-        ptr = ptr + grant.astype(jnp.int32)
+            _count(acc, "lock_acquires", grant)
+            _count(acc, "lock_spins", spin)
+            _count(acc, "noc_msgs", jnp.where(is_lock, 2, 0))
+            _count(acc, "noc_hops", jnp.where(is_lock, lreq_hops + lrep_hops, 0))
+            if cfg.faults_enabled:
+                _count(
+                    acc,
+                    "noc_reroutes",
+                    jnp.where(is_unlock | is_lock, rr_req + rr_rep, 0),
+                )
+            lock_holder = lock_holder.at[jnp.where(grant, lslot, L)].set(
+                arange_c, mode="drop"
+            )
+            sync_flag = jnp.where(grant, 0, jnp.where(spin, 1, sync_flag))
+            ptr = ptr + grant.astype(jnp.int32)
 
-        # barrier arrivals: charge pre + the arrival message, freeze the
-        # core, bump the slot's count and max-arrival clock (bid/htile
-        # hoisted above the contention block)
-        barr_lat, barr_hops = _one_way(ctile, htile, cfg, kn)
-        wake_lat, wake_hops = _one_way(htile, ctile, cfg, kn)
-        barr_charge = raw_arr if _is_router(cfg) else barr_lat + extra_bar
-        if cfg.faults_enabled:
-            # barrier arrival and wake-up legs detour like any message
-            fx_arr, fh_arr, rr_arr = leg_fault_penalty(
-                cfg, st.faults, kn, ctile, htile
+        with jax.named_scope(_BARRIER):
+            # barrier arrivals: charge pre + the arrival message, freeze the
+            # core, bump the slot's count and max-arrival clock (bid/htile
+            # hoisted above the contention block)
+            barr_lat, barr_hops = _one_way(ctile, htile, cfg, kn)
+            wake_lat, wake_hops = _one_way(htile, ctile, cfg, kn)
+            barr_charge = raw_arr if _is_router(cfg) else barr_lat + extra_bar
+            if cfg.faults_enabled:
+                # barrier arrival and wake-up legs detour like any message
+                fx_arr, fh_arr, rr_arr = leg_fault_penalty(
+                    cfg, st.faults, kn, ctile, htile
+                )
+                fx_wk, fh_wk, rr_wk = leg_fault_penalty(
+                    cfg, st.faults, kn, htile, ctile
+                )
+                barr_charge = barr_charge + fx_arr
+                barr_hops = barr_hops + fh_arr
+                wake_lat = wake_lat + fx_wk
+                wake_hops = wake_hops + fh_wk
+            cycles = cycles + jnp.where(
+                is_barrier, epre * cpi_vec + barr_charge, 0
             )
-            fx_wk, fh_wk, rr_wk = leg_fault_penalty(
-                cfg, st.faults, kn, htile, ctile
-            )
-            barr_charge = barr_charge + fx_arr
-            barr_hops = barr_hops + fh_arr
-            wake_lat = wake_lat + fx_wk
-            wake_hops = wake_hops + fh_wk
-        cycles = cycles + jnp.where(
-            is_barrier, epre * cpi_vec + barr_charge, 0
-        )
-        _count(acc, "instructions", jnp.where(is_barrier, epre, 0))
-        _count(acc, "barrier_waits", is_barrier)
-        _count(acc, "noc_msgs", is_barrier)
-        _count(acc, "noc_hops", jnp.where(is_barrier, barr_hops, 0))
-        if cfg.faults_enabled:
-            _count(acc, "noc_reroutes", jnp.where(is_barrier, rr_arr, 0)
-            )
-        sync_flag = jnp.where(is_barrier, 1, sync_flag)
-        barrier_count = barrier_count.at[
-            jnp.where(is_barrier, bid, BS)
-        ].add(1, mode="drop")
-        barrier_time = barrier_time.at[
-            jnp.where(is_barrier, bid, BS)
-        ].max(cycles, mode="drop")
+            _count(acc, "instructions", jnp.where(is_barrier, epre, 0))
+            _count(acc, "barrier_waits", is_barrier)
+            _count(acc, "noc_msgs", is_barrier)
+            _count(acc, "noc_hops", jnp.where(is_barrier, barr_hops, 0))
+            if cfg.faults_enabled:
+                _count(acc, "noc_reroutes", jnp.where(is_barrier, rr_arr, 0)
+                )
+            sync_flag = jnp.where(is_barrier, 1, sync_flag)
+            barrier_count = barrier_count.at[
+                jnp.where(is_barrier, bid, BS)
+            ].add(1, mode="drop")
+            barrier_time = barrier_time.at[
+                jnp.where(is_barrier, bid, BS)
+            ].max(cycles, mode="drop")
 
-        # releases: every waiter (frozen earlier or arrived this step) whose
-        # slot count reached ITS participant count resumes at the slot's
-        # max arrival clock + wake-up message. Waiters' ptr/event are
-        # unchanged this step (frozen lanes retire nothing), so the phase-0.9
-        # gather is still current for them.
-        wait_m = (et == EV_BARRIER) & (sync_flag == 1)
-        if cfg.faults_enabled:
-            # fail-stop barrier relief (DESIGN.md §12): a dead core will
-            # never arrive, so waiters must not require its arrival — the
-            # barrier twin of the dead-holder lock release above. A dead
-            # core ALREADY counted in a slot (it arrived, froze, then
-            # died) still satisfies its own arrival, so it grants no
-            # relief there. Like the lock idealization this is a recovery
-            # semantics choice: exact for global barriers; a subset
-            # barrier is relieved even by a dead non-participant (the
-            # trace encodes participant COUNTS, not sets) — chaos mode
-            # favors forward progress over subset fidelity.
-            dead_counted = (
+            # releases: every waiter (frozen earlier or arrived this step) whose
+            # slot count reached ITS participant count resumes at the slot's
+            # max arrival clock + wake-up message. Waiters' ptr/event are
+            # unchanged this step (frozen lanes retire nothing), so the phase-0.9
+            # gather is still current for them.
+            wait_m = (et == EV_BARRIER) & (sync_flag == 1)
+            if cfg.faults_enabled:
+                # fail-stop barrier relief (DESIGN.md §12): a dead core will
+                # never arrive, so waiters must not require its arrival — the
+                # barrier twin of the dead-holder lock release above. A dead
+                # core ALREADY counted in a slot (it arrived, froze, then
+                # died) still satisfies its own arrival, so it grants no
+                # relief there. Like the lock idealization this is a recovery
+                # semantics choice: exact for global barriers; a subset
+                # barrier is relieved even by a dead non-participant (the
+                # trace encodes participant COUNTS, not sets) — chaos mode
+                # favors forward progress over subset fidelity.
+                dead_counted = (
+                    jnp.zeros(BS, jnp.int32)
+                    .at[jnp.where(wait_m & deadb, bid, BS)]
+                    .add(1, mode="drop")
+                )
+                missing = jnp.sum(deadb.astype(jnp.int32)) - dead_counted[bid]
+                released = wait_m & (barrier_count[bid] + missing >= earg)
+            else:
+                released = wait_m & (barrier_count[bid] >= earg)
+            cycles = jnp.where(released, barrier_time[bid] + wake_lat, cycles)
+            _count(acc, "instructions", released)
+            _count(acc, "noc_msgs", released)
+            _count(acc, "noc_hops", jnp.where(released, wake_hops, 0))
+            if cfg.faults_enabled:
+                _count(acc, "noc_reroutes", jnp.where(released, rr_wk, 0)
+                )
+            sync_flag = jnp.where(released, 0, sync_flag)
+            ptr = ptr + released.astype(jnp.int32)
+            nrel = (
                 jnp.zeros(BS, jnp.int32)
-                .at[jnp.where(wait_m & deadb, bid, BS)]
+                .at[jnp.where(released, bid, BS)]
                 .add(1, mode="drop")
             )
-            missing = jnp.sum(deadb.astype(jnp.int32)) - dead_counted[bid]
-            released = wait_m & (barrier_count[bid] + missing >= earg)
-        else:
-            released = wait_m & (barrier_count[bid] >= earg)
-        cycles = jnp.where(released, barrier_time[bid] + wake_lat, cycles)
-        _count(acc, "instructions", released)
-        _count(acc, "noc_msgs", released)
-        _count(acc, "noc_hops", jnp.where(released, wake_hops, 0))
-        if cfg.faults_enabled:
-            _count(acc, "noc_reroutes", jnp.where(released, rr_wk, 0)
-            )
-        sync_flag = jnp.where(released, 0, sync_flag)
-        ptr = ptr + released.astype(jnp.int32)
-        nrel = (
-            jnp.zeros(BS, jnp.int32)
-            .at[jnp.where(released, bid, BS)]
-            .add(1, mode="drop")
-        )
-        barrier_count = barrier_count - nrel
-        drained = barrier_count <= 0
-        barrier_count = jnp.where(drained, 0, barrier_count)
-        barrier_time = jnp.where(drained, 0, barrier_time)
+            barrier_count = barrier_count - nrel
+            drained = barrier_count <= 0
+            barrier_count = jnp.where(drained, 0, barrier_count)
+            barrier_time = jnp.where(drained, 0, barrier_time)
     return cycles, ptr, lock_holder, barrier_count, barrier_time, sync_flag
 
 

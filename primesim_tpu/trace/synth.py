@@ -18,6 +18,9 @@ expected statistics are analyzable:
                         critical sections (pthread_mutex shape; LOCK/UNLOCK)
 - ``barrier_phases``  — bulk-synchronous phases of private work separated
                         by global (or subset) barriers (SPLASH-2 phase shape)
+- ``ocean_like``      — SPLASH-2 OCEAN's multigrid solver: square subgrids,
+                        border exchange with four neighbours, red-black
+                        relaxations on a V-cycle, a barrier after every phase
 
 All generators are deterministic given ``seed``.
 """
@@ -265,6 +268,146 @@ def barrier_phases(
     return from_event_lists(per_core)
 
 
+def _ocean_visits(levels: int, visits: int) -> list[int]:
+    """The levels one V-cycle visits, cut to its first `visits`."""
+    cycle = list(range(levels)) + list(range(levels - 2, -1, -1))
+    if not 1 <= visits <= len(cycle):
+        raise ValueError(f"visits must be 1..{len(cycle)} at {levels} levels")
+    return cycle[:visits]
+
+
+def ocean_like(
+    n_cores: int,
+    seed: int = 0,
+    grid_n: int = 258,
+    levels: int = 4,
+    visits: int = 7,
+    ins_per_mem: int = 3,
+    barrier_ids: int = 8,
+    lock_reductions: int = 0,
+    line: int = 64,
+) -> Trace:
+    """SPLASH-2 OCEAN's multigrid solver (`multig.c`, `slave2.c`,
+    contiguous partitions) on a `grid_n` x `grid_n` grid.
+
+    The cores form a square; core (px, py) owns a square subgrid of side
+    s0 = (grid_n - 2) / sqrt(n_cores) at level 0 and s0 >> l at level l.
+    A level has two arrays of 8-byte elements, `q` and `rhs`; a core's
+    block of (s + 2)^2 elements holds its points and their ghost border,
+    is rounded to lines and padded by one, and the blocks lie core after
+    core. A visit to a level is, for red then black: copy the borders (LD
+    the neighbour's edge element, ST the own ghost element, s times a
+    neighbour), BARRIER, relax the colour's own points (LD `rhs`, LD the
+    four neighbours, ST the point; every point where s = 1), BARRIER; then
+    the error phase (with a global lock reduction in the first
+    `lock_reductions` visits: LOCK, LD, ST, UNLOCK) and a BARRIER. One
+    V-cycle visits the levels 0 .. levels-1 .. 0 with a restrict (LD four
+    fine `q`, ST the coarse `rhs`) before each step down and an
+    interpolate (LD the coarse `q`, LD and ST four fine `q`) before each
+    step up, a BARRIER after either; `visits` takes the first n of its
+    visits. Every barrier is global, ids cycling over `barrier_ids`.
+    Before each LD, ST, LOCK and UNLOCK a batch of `ins_per_mem` - 1 .. + 1
+    instructions is drawn from the seed, which changes nothing else.
+    """
+    side = int(round(n_cores ** 0.5))
+    if side * side != n_cores:
+        raise ValueError("ocean_like needs a square number of cores")
+    s0, rem = divmod(grid_n - 2, side)
+    if rem or s0 < 1 or s0 % (1 << (levels - 1)):
+        raise ValueError(
+            f"a {grid_n} x {grid_n} grid does not give {side} x {side} cores "
+            f"square subgrids that halve {levels - 1} times")
+    if ins_per_mem < 1 or barrier_ids < 1 or lock_reductions < 0:
+        raise ValueError("ins_per_mem, barrier_ids >= 1; lock_reductions >= 0")
+    rng = _rng(seed)
+    lock_addr, err_addr = 0x1000, 0x2000
+    sides = [s0 >> l for l in range(levels)]
+    # level -> (base of q, base of rhs, bytes a core's block takes)
+    layout, top = [], 0x10000
+    for s in sides:
+        block = -(-(s + 2) ** 2 * 8 // line) * line + line
+        layout.append((top, top + n_cores * block, block))
+        top += 2 * n_cores * block
+
+    def at(level, array, core, i, j):
+        return (layout[level][array] + core * layout[level][2]
+                + (i * (sides[level] + 2) + j) * 8)
+
+    per_core = []
+    for c in range(n_cores):
+        px, py = c % side, c // side
+        evs: list[tuple] = []
+        n_bar = 0
+
+        def mem(t, addr):
+            k = int(rng.integers(ins_per_mem - 1, ins_per_mem + 2))
+            if k:
+                evs.append((EV_INS, k, 0))
+            evs.append((t, 8 if t in (EV_LD, EV_ST) else 0, addr))
+
+        def barrier():
+            nonlocal n_bar
+            evs.append((EV_BARRIER, n_cores, n_bar % barrier_ids))
+            n_bar += 1
+
+        def transfer(fine, coarse, restrict):
+            sc = sides[coarse]
+            for ci in range(1, sc + 1):
+                for cj in range(1, sc + 1):
+                    pts = [(2 * ci - 1 + a, 2 * cj - 1 + b)
+                           for a in (0, 1) for b in (0, 1)]
+                    if restrict:
+                        for i, j in pts:
+                            mem(EV_LD, at(fine, 0, c, i, j))
+                        mem(EV_ST, at(coarse, 1, c, ci, cj))
+                    else:
+                        mem(EV_LD, at(coarse, 0, c, ci, cj))
+                        for i, j in pts:
+                            mem(EV_LD, at(fine, 0, c, i, j))
+                            mem(EV_ST, at(fine, 0, c, i, j))
+            barrier()
+
+        order = _ocean_visits(levels, visits)
+        for v, l in enumerate(order):
+            if v:
+                prev = order[v - 1]
+                transfer(min(prev, l), max(prev, l), restrict=l > prev)
+            s = sides[l]
+            # (the neighbour, its edge element, the own ghost element) at k = 1..s
+            borders = []
+            if px > 0:
+                borders.append((c - 1, lambda k: (k, s), lambda k: (k, 0)))
+            if px < side - 1:
+                borders.append((c + 1, lambda k: (k, 1), lambda k: (k, s + 1)))
+            if py > 0:
+                borders.append((c - side, lambda k: (s, k), lambda k: (0, k)))
+            if py < side - 1:
+                borders.append((c + side, lambda k: (1, k), lambda k: (s + 1, k)))
+            for colour in (0, 1):
+                for nb, edge, ghost in borders:
+                    for k in range(1, s + 1):
+                        mem(EV_LD, at(l, 0, nb, *edge(k)))
+                        mem(EV_ST, at(l, 0, c, *ghost(k)))
+                barrier()
+                for i in range(1, s + 1):
+                    for j in range(1, s + 1):
+                        if s > 1 and (i + j) % 2 != colour:
+                            continue
+                        mem(EV_LD, at(l, 1, c, i, j))
+                        for di, dj in ((-1, 0), (1, 0), (0, -1), (0, 1)):
+                            mem(EV_LD, at(l, 0, c, i + di, j + dj))
+                        mem(EV_ST, at(l, 0, c, i, j))
+                barrier()
+            if v < lock_reductions:
+                mem(EV_LOCK, lock_addr)
+                mem(EV_LD, err_addr)
+                mem(EV_ST, err_addr)
+                mem(EV_UNLOCK, lock_addr)
+            barrier()
+        per_core.append(evs)
+    return from_event_lists(per_core)
+
+
 GENERATORS = {
     "uniform_random": uniform_random,
     "stream": stream,
@@ -274,4 +417,5 @@ GENERATORS = {
     "readers_writer": readers_writer,
     "lock_contention": lock_contention,
     "barrier_phases": barrier_phases,
+    "ocean_like": ocean_like,
 }

@@ -3,12 +3,13 @@ DESCRIBED v5e, no chip attached: what a four-chip call would compile,
 before the call.
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python scripts/prof/compile_v5e.py \\
-        <benchmark/configs/*.json or configs/*.json> <out.txt> [devices]
+        <benchmark/configs/*.json or configs/*.json> <out.txt> [devices] [--sync]
 
 `devices` defaults to the configuration's `run.devices` (1 where it has
 none): above 1 the state and the events get their `NamedSharding` over a
 1-D tile mesh of the first `devices` chips of a v5e 2x2, as `Engine`
-lays them out. Writes the compiled module's text (for
+lays them out; `--sync` compiles the step for a trace with locks and
+barriers (`has_sync` true), as `hlo_same.py dump --sync` does. Writes the compiled module's text (for
 `hlo_same.py compare`), and prints the compiler's bytes a chip
 (arguments, outputs, temporaries) and every collective with its shape
 and the tail of its `op_name`, which holds the phase scope. Nothing
@@ -30,7 +31,7 @@ _COLLECTIVE = re.compile(
 
 
 def main(conf_path: str, out_path: str, devices: int | None = None,
-         trace_len: int = 546) -> None:
+         trace_len: int = 546, has_sync: bool = False) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -64,7 +65,7 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
     t0 = time.perf_counter()
     compiled = run_loop.lower(
         cfg, chunk_steps, ev, st,
-        jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar), has_sync=False).compile()
+        jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar), has_sync=has_sync).compile()
     text = compiled.as_text()
     with open(out_path, "w") as f:
         f.write(text)
@@ -83,7 +84,9 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
 
 
 if __name__ == "__main__":
-    if len(sys.argv) not in (3, 4):
+    args = [a for a in sys.argv[1:] if a != "--sync"]
+    if len(args) not in (2, 3):
         print(__doc__, file=sys.stderr)
         raise SystemExit(2)
-    main(sys.argv[1], sys.argv[2], int(sys.argv[3]) if len(sys.argv) == 4 else None)
+    main(args[0], args[1], int(args[2]) if len(args) == 3 else None,
+         has_sync="--sync" in sys.argv[1:])

@@ -81,6 +81,35 @@ def test_sharded_parity(machine, gen):
         np.testing.assert_array_equal(c_8[k], c_1[k], err_msg=k)
 
 
+def test_sharded_parity_with_barriers():
+    """A `has_sync` machine on the mesh: rung 3's selectors (router walk,
+    DRAM queue, O3 window, local runs) at 16 cores, sharded over four
+    devices, on `ocean_like`: the lock table and the barrier slots are
+    replicated, the lanes that read and write them are sharded by core,
+    and every arrival is a third leg in the link walk. No cell of the
+    benchmark shards a program with sync events: this test is the guard."""
+    from primesim_tpu.trace.format import EV_BARRIER, EV_LOCK, fold_ins
+
+    cfg = small_test_config(
+        n_cores=16, n_banks=8, local_run_len=4, dram_queue=True,
+        core=CoreConfig(o3_overlap_256=128),
+        noc=NocConfig(mesh_x=4, mesh_y=4, link_lat=1, router_lat=1, contention=True,
+                      contention_model="router", contention_lat=1),
+    )
+    trace = fold_ins(synth.ocean_like(16, seed=5, grid_n=34, levels=2, visits=2,
+                                      lock_reductions=1))
+    g, e1, e4 = _run_all(cfg, trace, tile_mesh(4))
+    assert e4.has_sync and len(e4.state.cycles.devices()) == 4
+    np.testing.assert_array_equal(e4.cycles, g.cycles)
+    np.testing.assert_array_equal(e4.cycles, e1.cycles)
+    for k in g.counters:
+        np.testing.assert_array_equal(e4.counters[k], g.counters[k], err_msg=k)
+        np.testing.assert_array_equal(e4.counters[k], e1.counters[k], err_msg=k)
+    t = trace.events[:, :, 0]
+    assert int(g.counters["barrier_waits"].sum()) == int((t == EV_BARRIER).sum()) == 11 * 16
+    assert int(g.counters["lock_acquires"].sum()) == int((t == EV_LOCK).sum()) == 16
+
+
 def _run_record_oracle(cfg, dirm, pslot, pline):
     """numpy: `dirm[pslot]` and what the local run reads of those rows
     (`sim/step.py::_run_record`), an element gather a field."""

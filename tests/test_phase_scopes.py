@@ -40,7 +40,8 @@ MACHINES = {
              core={"cpi": 1, "o3_overlap_256": 128},
              noc=dict(_MESH, contention=True, contention_model="router",
                       contention_lat=1)),
-        True, {"s.noc", "s.noc/rank", "s.dram", "s.dram/rank", "s.sync"},
+        True, {"s.noc", "s.noc/rank", "s.dram", "s.dram/rank", "s.sync",
+               "s.sync/lock", "s.sync/barrier"},
     ),
     # the tile-count contention model (no ranking) on a faulty machine
     "faulty": (

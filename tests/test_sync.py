@@ -296,6 +296,29 @@ def test_parity_mixed_barrier_then_locks():
     assert_parity(cfg, tr, chunk_steps=50)
 
 
+@pytest.mark.parametrize("lock_reductions", [0, 1])
+def test_parity_ocean_like_on_rung3_cut_to_8x8(lock_reductions):
+    """The benchmark's `rung3.ocean-n258` at 64 cores: rung 3's machine
+    file (router walk, DRAM queue, O3 window, local runs) cut to an 8 x 8
+    mesh, on the cell's shape of trace (8 x 8 points a core, four levels,
+    the descent of one V-cycle, 23 global barriers): every arrival is a
+    third leg in the step's link walk, and a frozen core sits out the
+    quantum barrier. With one global lock reduction too."""
+    import json
+    import os
+
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    with open(os.path.join(root, "configs", "rung3_1024core_o3.json")) as f:
+        machine = json.load(f)
+    machine.update(n_cores=64, n_banks=64)
+    machine["noc"].update(mesh_x=8, mesh_y=8)
+    cfg = MachineConfig.from_dict(machine)
+    tr = fold_ins(synth.ocean_like(64, seed=404, grid_n=66, levels=4, visits=4,
+                                   lock_reductions=lock_reductions))
+    assert int((tr.events[:, :, 0] == EV_BARRIER).sum()) == 23 * 64
+    assert_parity(cfg, tr, chunk_steps=8)
+
+
 def test_trace_rejects_bad_barrier_ids():
     from primesim_tpu.sim.engine import Engine
 
