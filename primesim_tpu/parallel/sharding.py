@@ -321,13 +321,15 @@ class mesh_jit:
 
 
 def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
-              whole=()):
-    """`reduce_rows(table[slot], *per_slot, *whole, core_axis=0)`: a tuple
-    of `[C, K]` arrays, the few words a reader wants of the rows `slot`
-    [C, K] of `table` [R, W]. `per_slot` are `[C, K]` int32 values the
-    reduction needs beside the rows, `whole` values every chip has in full
-    (an iota, a replicated table). `reduce_rows` always sees every core's
-    rows, in core order, along the axis `core_axis` of `slot`'s two.
+              whole=(), core_axis=0):
+    """`reduce_rows(table[slot], *per_slot, *whole, core_axis=core_axis)`:
+    a tuple of arrays of `slot`'s shape, the few words a reader wants of
+    the rows `slot` of `table` [R, W]. `slot` is `[C, K]` (`core_axis` 0,
+    K rows a core) or `[K, C]` (`core_axis` 1); `per_slot` are int32
+    values of its shape the reduction needs beside the rows, `whole`
+    values every chip has in full (an iota, a replicated table).
+    `reduce_rows` always sees every core's rows, in core order, along the
+    axis it is told.
 
     Without a mesh it is exactly that expression. On a mesh `table` is
     sharded by row and `slot` names any row, and left to the partitioner
@@ -341,11 +343,12 @@ def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
     slot is another chip's, and the records are summed. One chip holds
     each slot, so the sum is that chip's record to the bit, and
     C*K*len(record) words cross chips. The chip works on `[K, C, ...]`
-    (`core_axis=1`): its gather yields `[K*C, W]`, which splits into
-    `[K, C, W]` as it lies, where `[C, K, W]` is a copy that pads K to
-    the tile's 8 rows."""
+    whatever the caller's order: its gather yields `[K*C, W]`, which
+    splits into `[K, C, W]` as it lies, where `[C, K, W]` is a copy that
+    pads K to the tile's 8 rows. A caller that hands `[K, C]` slots
+    (`core_axis` 1) has the same on one device."""
     if mesh is None:
-        return reduce_rows(table[slot], *per_slot, *whole, core_axis=0)
+        return reduce_rows(table[slot], *per_slot, *whole, core_axis=core_axis)
     rows = table.shape[0] // mesh.shape[AXIS]
 
     def on_chip(tab, packed, *whole):
@@ -363,12 +366,15 @@ def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
         return jax.lax.psum(record, AXIS)
 
     dtypes: list = []  # of the record's fields, as `reduce_rows` gives them
-    packed = jnp.swapaxes(jnp.stack((slot, *per_slot), axis=-1), 0, 1)
+    packed = jnp.stack((slot, *per_slot), axis=-1)
+    if core_axis == 0:
+        packed = jnp.swapaxes(packed, 0, 1)
     record = jax.shard_map(
         on_chip, mesh=mesh, out_specs=P(),
         in_specs=(P(AXIS), P(None, AXIS), *(P() for _ in whole)),
     )(table, packed, *whole)
-    record = jnp.swapaxes(record, 0, 1)
+    if core_axis == 0:
+        record = jnp.swapaxes(record, 0, 1)
     return tuple(record[..., i].astype(dt) for i, dt in enumerate(dtypes))
 
 

@@ -220,7 +220,7 @@ def _l1_set_read(cfg: MachineConfig, l1, sets, planes):
 
 
 def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
-              run_patch=None, step_no=None):
+              run_patch=None, step_no=None, mesh=None):
     """Gather the accessed L1 set and derive each way's EFFECTIVE MESI state.
 
     PULL-BASED COHERENCE (the TPU-native shape of MESI): remote
@@ -238,16 +238,16 @@ def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
     golden model + parity tests prove it on every workload.
 
     The directory entry is located through the way pointer (`l1_ptr`,
-    recorded at fill time) — one paired tag/owner gather plus one sharer
-    -word gather — instead of a W2-wide tag search of the home set; a
-    stale pointer self-detects by tag mismatch and yields exactly the
-    search result (DESIGN.md §7).
+    recorded at fill time) — ONE gather of the whole `dirm` rows the W1
+    pointers name, the entry's words selected out of each row
+    (`_validate_ways`, `_way_record`) — instead of a W2-wide tag search of
+    the home set; a stale pointer self-detects by tag mismatch and yields
+    exactly the search result (DESIGN.md §7).
 
-    The pointer is decomposed into (bank, in-row offset) coordinates and
-    the gathers index the LLC/sharer arrays in their NATIVE layouts: a
-    `reshape(-1)` flat view of a TPU-tiled array is a physical relayout —
-    XLA materializes a full copy of the (537 MB at 1024 cores) sharers
-    array every step.
+    The pointer is decomposed into (row, way) coordinates and the gather
+    indexes `dirm` in its NATIVE layout: a `reshape(-1)` flat view of a
+    TPU-tiled array is a physical relayout — XLA materializes a full copy
+    of the (537 MB at 1024 cores) sharers array every step.
 
     Returns (w1cols, tag_rows, lru_rows, weff): the set's column indices,
     tags, LRU stamps, and effective per-way MESI states, all [C, W1].
@@ -277,16 +277,62 @@ def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
             jnp.any(hm[:, :, None] & colmatch, axis=1), step_no, lru_rows
         )
     weff = _validate_ways(
-        cfg, arange_c, tag_rows, state_rows, ptr_rows, eph_rows, dirm,
+        cfg, arange_c, tag_rows, state_rows, ptr_rows, eph_rows, dirm, mesh,
     )
     return w1cols, tag_rows, lru_rows, weff
 
 
+def _way_record(cfg: MachineConfig, rows, pway, core, core_axis=1):
+    """What the probe reads of the directory rows `rows` [W1, C, DW] its
+    way pointers name, at the way `pway` [W1, C] within each row, for the
+    cores `core` [C]: the entry's tag, its owner, the core's own sharer
+    bit; under the coarse vector the entry's invalidation epoch too. A
+    tuple of [W1, C] arrays ([C, W1, DW], [C, W1] in and [C, W1] out where
+    `core_axis` is 0). The sibling of `_run_record`: a function of one
+    row and of values every chip has, so `sharding.read_rows` runs it on
+    the chip that holds the row. Every word is a select out of the row in
+    hand (`_pick`)."""
+    W2, NW = cfg.llc.ways, cfg.n_sharer_words
+    MW = llc_meta_width(cfg)
+    pairs = rows[..., : 2 * W2]  # (tag, owner) a way
+    g_c = core >> (cfg.sharer_group.bit_length() - 1)
+    g_c = g_c[None, :] if core_axis == 1 else g_c[:, None]
+    vsh = _pick(rows[..., MW:], pway * NW + (g_c >> 5))
+    record = [
+        _pick(pairs, 2 * pway),
+        _pick(pairs, 2 * pway + 1),
+        ((vsh >> (g_c & 31)) & 1) != 0,
+    ]
+    if cfg.sharer_group > 1:
+        record.append(_pick(rows[..., 3 * W2 : 4 * W2], pway))
+    return tuple(record)
+
+
 def _validate_ways(cfg, arange_c, tag_rows, state_rows, ptr_rows, eph_rows,
-                   dirm):
+                   dirm, mesh=None):
     """Pull-validate each way's locally-written state against the
-    directory entry its fill-time way pointer names (see `_l1_probe`):
-    two tag/owner element gathers + one sharer-word gather, all [C, W1].
+    directory entry its fill-time way pointer names (see `_l1_probe`).
+
+    The W1 entries of a core are read as ONE gather of whole `dirm` rows
+    at the pointers' rows, ways first (`[W1, C]` slots: the gather's
+    `[W1*C, DW]` result splits into `[W1, C, DW]` as it lies), through
+    `sharding.read_rows`: the tag, the owner, the core's sharer bit and
+    the epoch are selects out of the row in hand (`_way_record`), and on
+    a mesh each chip reduces the rows of its own shard and the 3 or 4
+    words a (core, way) cross chips. Until PR 36 they were three (coarse:
+    four) ELEMENT gathers at the same rows. On the v5e an element of
+    `dirm` costs 15-22 ns whatever the row (14.8 alone in a loop; in the
+    step 0.089 ms for 4096 at 1024 cores, 1.17 ms for 65536 on rung 5,
+    each of the three or four), a whole row with its selects 12-14 ns at
+    768 and 1536 bytes, 21-32 at rung 4's 4608, 35-54 at 8704, 59-99 at
+    16896 (cores first 1.7-3.3 times that). The row read grows with the row
+    and the elements do not: at 4096 cores and more they cross near rows
+    of 7 KB (at 4608 bytes the rows still win x1.4-2.1, at 8704 they lose
+    x0.8; at 1024 cores they win there too), a full-map directory of
+    about 6500 cores at 8 ways (scripts/prof/prof_gather.py rows, PERF.md
+    section 6, PR 36). No shipped machine has such rows: the widest is
+    rung 4's 4608 bytes, and rung 5's 16384 cores are coarse, 768. So
+    there is no second form.
 
     Under the coarse sharer vector (sharer_group > 1) the core checks
     its GROUP's bit, which may stay set on a NEIGHBOR's behalf after
@@ -297,20 +343,16 @@ def _validate_ways(cfg, arange_c, tag_rows, state_rows, ptr_rows, eph_rows,
     every S grant after the last clearing records the current epoch, and
     anything older was invalidated by that clearing. The owner path
     needs no epoch (owner identity is exact)."""
-    S2, W2 = cfg.llc.sets, cfg.llc.ways
-    NW = cfg.n_sharer_words
-    logG = cfg.sharer_group.bit_length() - 1
-    g_c = arange_c >> logG
-    pway = ptr_rows % W2  # ptr = (bank*S2 + set)*W2 + way
-    pslot = ptr_rows // W2
-    MW = llc_meta_width(cfg)
-    vtag = dirm[pslot, 2 * pway]  # [C, W1]
-    vown = dirm[pslot, 2 * pway + 1]
-    vsh = dirm[pslot, MW + pway * NW + (g_c[:, None] >> 5)]
-    vbit = ((vsh >> (g_c[:, None] & 31)) & 1) != 0
+    W2 = cfg.llc.ways
+    ptr_w = ptr_rows.T  # [W1, C]; ptr = (bank*S2 + set)*W2 + way
+    vtag, vown, vbit, *veph = (
+        v.T for v in read_rows(
+            mesh, dirm, ptr_w // W2, functools.partial(_way_record, cfg),
+            per_slot=(ptr_w % W2,), whole=(arange_c,), core_axis=1,
+        )
+    )  # [C, W1] each
     if cfg.sharer_group > 1:
-        veph = dirm[pslot, 3 * W2 + pway]
-        vbit = vbit & (veph == eph_rows)
+        vbit = vbit & (veph[0] == eph_rows)
     return jnp.where(
         (state_rows == I) | (vtag != tag_rows),
         I,
@@ -320,7 +362,6 @@ def _validate_ways(cfg, arange_c, tag_rows, state_rows, ptr_rows, eph_rows,
             jnp.where(vbit, S, I),
         ),
     )  # [C, W1] effective MESI per way
-
 
 
 class Request(NamedTuple):
@@ -720,7 +761,7 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
 
 
 def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
-           ptr_c, quantum_end, pev, run_patch, deadb) -> Request:
+           ptr_c, quantum_end, pev, run_patch, deadb, mesh=None) -> Request:
     """Phases 0.9 and 1: the event each core arbitrates with (the
     candidate after its local run), its L1 probe, the parse of its home
     set's directory row, and its classification -> the `Request`.
@@ -794,6 +835,7 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
                 cfg, arange_c, l1_c, st.dirm, line,
                 run_patch=run_patch,
                 step_no=step_no,
+                mesh=mesh,
             )
             l1_match = (tag_rows == line[:, None]) & (weff != I)
             hit_any = jnp.any(l1_match, axis=1)
@@ -2237,7 +2279,8 @@ def step(
     selectors of `cfg` (and `has_sync`) decide which phases a machine
     compiles; everything a phase reads or hands on is in its call. `mesh`
     is the tile mesh the state is sharded over, None on one device: the
-    one phase that reads it is `_local` (`sharding.read_rows`)."""
+    two phases that read it are `_local` and `_probe`, for their reads of
+    whole `dirm` rows (`sharding.read_rows`)."""
     C = cfg.n_cores
     arange_c = jnp.arange(C, dtype=jnp.int32)
     # TIMING comes from the TRACED knob pytree carried in state, never
@@ -2255,7 +2298,7 @@ def step(
     quantum_end, cycles_c, ptr_c, pev, run_patch = _local(
         cfg, events, st, arange_c, deadb, acc, mesh)
     rq = _probe(cfg, events, st, arange_c, cycles_c, ptr_c, quantum_end, pev,
-                run_patch, deadb)
+                run_patch, deadb, mesh)
     winner, join, key = _arb(cfg, kn, arange_c, rq, cycles_c, quantum_end, acc)
     (ctile, btile, htile, bid, home_txn, req_lat, req_hops, rep_lat, rep_hops,
      flt) = _dir_legs(cfg, kn, st.faults, arange_c, rq, winner, join, has_sync)

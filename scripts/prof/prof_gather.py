@@ -2,7 +2,8 @@
 array-layout choices and behind `sim/step.py::_l1_set_read` having one form.
 
     python scripts/prof/prof_gather.py          # the L1 set read's two forms
-    python scripts/prof/prof_gather.py rows     # row / element gather, row scatter
+    python scripts/prof/prof_gather.py rows     # the probe's way read: rows / elements
+    python scripts/prof/prof_gather.py raw      # row / element gather, row scatter
 
 Default: the L1 set read's two forms alone (select: `_l1_set_read`, the core's
 row read whole and the set picked by a compare and a masked sum; gather: the
@@ -12,11 +13,23 @@ candidates, tag and state planes) and the probe's (K = 1, four planes). Each for
 on sets that change every iteration, so the time is the device's and holds no
 dispatch; us an iteration, and ns a gathered word for the gather.
 
-`rows`: cost against index count, row width and operand size. Hypothesis from
+`rows`: the probe's read of the directory entries its W1 way pointers name
+(`sim/step.py::_validate_ways`), in the form the step has (ONE gather of whole
+`dirm` rows at `[W1, C]` slots through `sharding.read_rows`, the words selected
+out of each row: `_way_record`), ways first and cores first, against the three
+(coarse vector: four) ELEMENT gathers at the same rows that it replaced in
+PR 36, kept here as `ways_elements`; at rows of 768 / 1536 / 4608 / 8704 /
+16896 bytes x C in {1024, 4096, 16384}, a table of 524288 rows (262144 of the
+widest), inside one `fori_loop` on pointers that change every iteration. The
+evidence that `_validate_ways` needs no second form, and where the two would
+cross (PERF.md section 6, PR 36).
+
+`raw`: cost against index count, row width and operand size. Hypothesis from
 single-op ablations of the step: cost ~= per-INDEX overhead, mostly independent
 of row width and operand bytes; windowed (dynamic column) forms are
 pathological.
 """
+import functools
 import sys
 import time
 
@@ -82,7 +95,87 @@ def set_read_forms():
             del l1
 
 
-def rows():
+def ways_elements(cfg, dirm, ptr_rows, core):
+    """`_way_record`'s record as `_validate_ways` read it until PR 36: an
+    element gather of `dirm` a field, all at `[C, W1]`."""
+    from primesim_tpu.sim.state import llc_meta_width
+
+    W2, NW, MW = cfg.llc.ways, cfg.n_sharer_words, llc_meta_width(cfg)
+    g_c = (core >> (cfg.sharer_group.bit_length() - 1))[:, None]
+    pway, pslot = ptr_rows % W2, ptr_rows // W2
+    vsh = dirm[pslot, MW + pway * NW + (g_c >> 5)]
+    record = [dirm[pslot, 2 * pway], dirm[pslot, 2 * pway + 1],
+              ((vsh >> (g_c & 31)) & 1) != 0]
+    if cfg.sharer_group > 1:
+        record.append(dirm[pslot, 3 * W2 + pway])
+    return tuple(record)
+
+
+def way_read_forms(widths=(768, 1536, 4608, 8704, 16896),
+                   cores=(1024, 4096, 16384), table_bytes=4.6e9):
+    from types import SimpleNamespace
+
+    from primesim_tpu.parallel.sharding import read_rows
+    from primesim_tpu.sim.step import _way_record
+
+    W2 = 8
+
+    def rows_form(core_axis, cfg, dirm, ptr_rows, core):
+        ptr = ptr_rows.T if core_axis == 1 else ptr_rows
+        return read_rows(
+            None, dirm, ptr // W2, functools.partial(_way_record, cfg),
+            per_slot=(ptr % W2,), whole=(core,), core_axis=core_axis)
+
+    # a full map's record (3 words), then the coarse vector's (the epoch too)
+    forms = (("ways1st", functools.partial(rows_form, 1), 1),
+             ("cores1st", functools.partial(rows_form, 0), 1),
+             ("elements", ways_elements, 1),
+             ("ways1st_c", functools.partial(rows_form, 1), 64),
+             ("elements_c", ways_elements, 64))
+    rng = np.random.default_rng(0)
+    print(f"device {jax.devices()[0].device_kind}; us an iteration, {ITER} in "
+          f"a loop; W1 {W1}, W2 {W2}")
+    print(" row_B    rows      C  " + "  ".join(f"{n:>10s}" for n, _, _ in forms)
+          + "  ns_row  ns_elem")
+    for width in widths:
+        DW = width // 4
+        NW = (DW - 128) // W2
+        R = min(524288, 1 << int(np.log2(table_bytes / width)))
+        # one fused pass: built eagerly the two iotas and their sum are
+        # three tables, and the widest is 4.6 GB
+        dirm = jax.jit(lambda R=R, DW=DW: (
+            jax.lax.broadcasted_iota(jnp.int32, (R, DW), 0) * 40503
+            + jax.lax.broadcasted_iota(jnp.int32, (R, DW), 1)))()
+        for C in cores:
+            ptr0 = jnp.asarray(rng.integers(0, R * W2, (C, W1), dtype=np.int32))
+            # the core's sharer word has to lie inside the row
+            core = jnp.arange(C, dtype=jnp.int32) % (32 * NW)
+            us = {}
+            for name, form, group in forms:
+                # only these three fields are read
+                cfg = SimpleNamespace(llc=SimpleNamespace(ways=W2),
+                                      n_sharer_words=NW, sharer_group=group)
+
+                def loop(dirm, ptr0, core, form=form, cfg=cfg):
+                    def body(i, acc):
+                        ptr = (ptr0 + i * 7919) % (R * W2)
+                        rec = [r.astype(jnp.int32)
+                               for r in form(cfg, dirm, ptr, core)]
+                        if rec[0].shape[0] != C:
+                            rec = [r.T for r in rec]
+                        return acc ^ functools.reduce(jnp.bitwise_xor, rec)
+                    return jax.lax.fori_loop(
+                        0, ITER, body, jnp.zeros((C, W1), jnp.int32))
+                us[name] = timeit(loop, dirm, ptr0, core, n=3) / ITER * 1e6
+            n = C * W1
+            print(f"{width:6d} {R:7d} {C:6d}  " + "  ".join(
+                f"{us[name]:10.1f}" for name, _, _ in forms)
+                  + f"  {us['ways1st'] * 1e3 / n:6.1f}"
+                  f"  {us['elements'] * 1e3 / (3 * n):7.1f}", flush=True)
+        del dirm
+
+
+def raw():
     rng = np.random.default_rng(0)
     R = 524288
     for width in (8, 24, 128, 280, 384):
@@ -107,4 +200,5 @@ def rows():
 
 
 if __name__ == "__main__":
-    rows() if sys.argv[1:] == ["rows"] else set_read_forms()
+    {("rows",): way_read_forms, ("raw",): raw}.get(
+        tuple(sys.argv[1:]), set_read_forms)()

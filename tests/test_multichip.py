@@ -187,6 +187,154 @@ def test_run_record_on_the_rows_own_chip(machine):
             )
 
 
+def _validate_ways_oracle(cfg, dirm, core, tag_rows, state_rows, ptr_rows,
+                          eph_rows):
+    """numpy: the record `_way_record` reads and `_validate_ways`' effective
+    states as the step computed them until PR 36, an element gather of
+    `dirm` a field at the rows and ways the pointers `ptr_rows` [C, W1]
+    name."""
+    from primesim_tpu.sim.state import I, S, llc_meta_width
+
+    W2, NW, MW = cfg.llc.ways, cfg.n_sharer_words, llc_meta_width(cfg)
+    g_c = (core >> (cfg.sharer_group.bit_length() - 1))[:, None]
+    pway, pslot = ptr_rows % W2, ptr_rows // W2
+    vtag = dirm[pslot, 2 * pway]
+    vown = dirm[pslot, 2 * pway + 1]
+    vsh = dirm[pslot, MW + pway * NW + (g_c >> 5)]
+    vbit = ((vsh >> (g_c & 31)) & 1) != 0
+    record = [vtag, vown, vbit]
+    if cfg.sharer_group > 1:
+        veph = dirm[pslot, 3 * W2 + pway]
+        record.append(veph)
+        vbit = vbit & (veph == eph_rows)
+    weff = np.where(
+        (state_rows == I) | (vtag != tag_rows), I,
+        np.where(vown == core[:, None], state_rows, np.where(vbit, S, I)))
+    return record, weff
+
+
+# what the entry a way pointer names holds -> the state the way validates to
+# ("own": what the core wrote itself)
+WAY_CASES = {
+    "owner": "own",  # the directory names the core owner: no epoch asked
+    "sharer_bit_set": "S",
+    "sharer_bit_clear": "I",
+    # the coarse vector: the group's bit stands for a neighbour, the entry's
+    # epoch has moved on since the core's fill; a full map: the neighbour's
+    # bit beside the core's own, which is clear
+    "neighbours_bit": "I",
+    "stale_pointer": "I",  # the way was refilled: another tag
+    # the core holds line 0 and its pointer names an all-zero row (tag 0,
+    # owner 0: core 0 alone keeps its state), while the rows every other
+    # chip reads at its clamped index hold words
+    "line0_zero_row": "own0",
+}
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("case", sorted(WAY_CASES))
+@pytest.mark.parametrize("machine", ["plain", "coarse", "moesi"])
+def test_validate_ways_reads_rows_like_elements(machine, case, devices):
+    """`_validate_ways`' one row read (`read_rows` + `_way_record`, the
+    words picked out of the row in hand) against the element gathers it
+    replaced, on a random directory in which every (core, way) entry is
+    shaped to `case`: the record to the bit, the effective states, and the
+    state the case must give. On four devices the pointers name rows of
+    every chip and each chip's first and last row (what the others read at
+    their clamped index) are never zero."""
+    import functools
+
+    import jax.numpy as jnp
+
+    from primesim_tpu.parallel.sharding import read_rows, state_shardings
+    from primesim_tpu.sim.state import E, I, M, S, dirm_width, llc_meta_width
+    from primesim_tpu.sim.step import _validate_ways, _way_record
+
+    cfg = MACHINES[machine]()
+    C, W1, W2, NW = cfg.n_cores, cfg.l1.ways, cfg.llc.ways, cfg.n_sharer_words
+    R, DW, MW = cfg.n_banks * cfg.llc.sets, dirm_width(cfg), llc_meta_width(cfg)
+    G = cfg.sharer_group
+    rng = np.random.default_rng(36 + sorted(WAY_CASES).index(case))
+    dirm = rng.integers(1, 2**31 - 1, (R, DW), dtype=np.int32)
+    core = np.arange(C, dtype=np.int32)
+    # distinct rows, none a shard's first or last, some in every quarter
+    per = R // 4
+    seeded = [q * per + 1 for q in range(4)]
+    inner = np.setdiff1d(
+        np.arange(R), [0, *range(per - 1, R, per), *range(per, R, per), *seeded])
+    pslot = rng.permutation(inner)[: C * W1].reshape(C, W1).astype(np.int32)
+    pslot[:4, 0] = seeded
+    assert len(np.unique(pslot)) == C * W1
+    assert set(np.unique(pslot // per)) == {0, 1, 2, 3}
+    pway = rng.integers(0, W2, (C, W1), dtype=np.int32)
+    ptr_rows = pslot * W2 + pway
+    state_rows = rng.choice(np.array([S, E, M], np.int32), (C, W1))
+    state_rows[:, -1] = I  # a way the core does not hold stays I
+    tag_rows = dirm[pslot, 2 * pway].copy()
+    eph_rows = dirm[pslot, 3 * W2 + pway].copy() if G > 1 else None
+    g = (core // G)[:, None]
+    word = MW + pway * NW + (g >> 5)
+    bit = (np.int32(1) << (g & 31)).astype(np.int32)
+    other = (core[:, None] + 1) % C
+    if case == "owner":
+        dirm[pslot, 2 * pway + 1] = core[:, None]
+        dirm[pslot, word] &= ~bit  # nor is the bit asked
+    else:
+        dirm[pslot, 2 * pway + 1] = other
+    if case == "sharer_bit_set":
+        dirm[pslot, word] |= bit
+    elif case in ("sharer_bit_clear", "stale_pointer"):
+        dirm[pslot, word] &= ~bit
+    elif case == "neighbours_bit" and G > 1:
+        dirm[pslot, word] |= bit
+        dirm[pslot, 3 * W2 + pway] += 1  # a clearing since the fill
+    elif case == "neighbours_bit":
+        ng = (other // G)
+        dirm[pslot, MW + pway * NW + (ng >> 5)] |= np.int32(1) << (ng & 31)
+        dirm[pslot, word] &= ~bit
+    if case == "stale_pointer":
+        tag_rows = tag_rows + 1
+    if case == "line0_zero_row":
+        dirm[pslot] = 0  # tag 0, owner 0, no sharer, epoch 0
+        tag_rows[:] = 0
+        if G > 1:
+            eph_rows[:] = 0
+    record, weff = _validate_ways_oracle(
+        cfg, dirm, core, tag_rows, state_rows, ptr_rows, eph_rows)
+    want = {"own": state_rows, "S": np.full_like(state_rows, S),
+            "I": np.full_like(state_rows, I),
+            "own0": np.where(core[:, None] == 0, state_rows, I),
+            }[WAY_CASES[case]]
+    want = np.where(state_rows == I, I, want)
+    np.testing.assert_array_equal(weff, want)
+
+    mesh = tile_mesh(devices) if devices > 1 else None
+    table = jnp.asarray(dirm)
+    if mesh is not None:
+        table = jax.device_put(table, state_shardings(mesh).dirm)
+    args = (tag_rows, state_rows, ptr_rows) + ((eph_rows,) if G > 1 else ())
+
+    def both(table, tag_rows, state_rows, ptr_rows, eph_rows=None):
+        rec = read_rows(
+            mesh, table, ptr_rows.T // W2, functools.partial(_way_record, cfg),
+            per_slot=(ptr_rows.T % W2,), whole=(jnp.asarray(core),), core_axis=1)
+        return rec, _validate_ways(
+            cfg, jnp.asarray(core), tag_rows, state_rows, ptr_rows, eph_rows,
+            table, mesh)
+
+    got_rec, got = jax.jit(both)(table, *map(jnp.asarray, args))
+    np.testing.assert_array_equal(np.asarray(got), weff)
+    assert len(got_rec) == len(record) == (4 if G > 1 else 3)
+    for i, (g_, w_) in enumerate(zip(got_rec, record)):
+        assert g_.dtype == (jnp.bool_ if w_.dtype == bool else jnp.int32)
+        np.testing.assert_array_equal(np.asarray(g_).T, w_, err_msg=f"field {i}")
+    if devices == 1:  # cores first is the same record, transposed
+        rec0 = _way_record(cfg, table[ptr_rows // W2], jnp.asarray(ptr_rows % W2),
+                           jnp.asarray(core), core_axis=0)
+        for g_, w_ in zip(rec0, record):
+            np.testing.assert_array_equal(np.asarray(g_), w_)
+
+
 @pytest.mark.parametrize("engine", ["fleet", "stream"])
 def test_sharded_local_runs_under_vmap_and_windows(engine):
     """The run's row read is a `shard_map`: it has to compose with the
@@ -390,6 +538,38 @@ def test_sharded_step_never_allgathers_directory():
         if re.search(r"all-gather|all-reduce", l) and f"[{B_S2}," in l
     ]
     assert not bad, "directory arrays all-gathered:\n" + "\n".join(bad[:5])
+
+
+def test_sharded_probe_sends_way_records_not_way_rows():
+    """The probe reads 3 words of each of the C*W1 directory rows its way
+    pointers name (`_validate_ways`, through `sharding.read_rows`), and the
+    C home rows whole. Held on the compiled sharded chunk: no collective
+    under `s.probe` carries more than C rows of `dirm` width (the home
+    rows may cross; nothing of `[W1*C, DW]`), and the way read's two
+    collectives are there, slots out and records back."""
+    import re
+
+    from primesim_tpu.sim.state import dirm_width
+
+    cfg, txt = _sharded_chunk_text(local_run_len=4)
+    collective = re.compile(
+        r" = (.*?) (?:all-reduce|all-gather|reduce-scatter|all-to-all"
+        r"|collective-permute)(?:-start)?\("
+    )
+    C, W1, DW = cfg.n_cores, cfg.l1.ways, dirm_width(cfg)
+    wide, shapes = [], []
+    for line in txt.splitlines():
+        found = collective.search(line)
+        if not found or "s.probe" not in line:
+            continue
+        for dims in re.findall(r"\w+\[([\d,]*)\]", found.group(1)):
+            shape = [int(d) for d in dims.split(",") if d]
+            shapes.append(shape)
+            if shape and shape[-1] == DW and np.prod(shape[:-1]) > C:
+                wide.append(line.strip()[:200])
+    assert W1 > 1 and not wide, "way rows cross chips whole:\n" + "\n".join(wide)
+    # ways first: (slot, way) out, (tag, owner, bit) back
+    assert [W1, C, 2] in shapes and [W1, C, 3] in shapes, shapes
 
 
 def test_sharded_local_run_sends_records_not_rows():

@@ -20,6 +20,7 @@ import pytest
 
 from primesim_tpu.config.machine import MachineConfig
 from primesim_tpu.sim.engine import Engine, run_loop
+from primesim_tpu.sim.state import dirm_width
 from primesim_tpu.sim.step import PHASE_FUNCTIONS, PHASES, step
 from primesim_tpu.trace import synth
 
@@ -196,7 +197,7 @@ def test_rung3_loop_ranks_without_a_search():
 def indexed_ops(machine: str) -> list:
     """Every `gather` and `scatter*` equation of the machine's `step`,
     through every sub-jaxpr: (primitive, scope path, operand shape, number
-    of indices)."""
+    of indices, a gather's slice sizes)."""
     has_sync = MACHINES[machine][1]
     cfg, eng = build(machine)
     found = []
@@ -207,7 +208,8 @@ def indexed_ops(machine: str) -> list:
             name = eqn.primitive.name
             if name == "gather" or name.startswith("scatter"):
                 operand, indices = (v.aval.shape for v in eqn.invars[:2])
-                found.append((name, path, operand, math.prod(indices[:-1])))
+                found.append((name, path, operand, math.prod(indices[:-1]),
+                              eqn.params.get("slice_sizes")))
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub, path)
 
@@ -224,17 +226,26 @@ def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
     (`_pick`): no `gather` of `step` has the L1 array as its operand, with
     four planes or, under the coarse vector, five; and `s.local` holds two
     gathers, of rows the core does not hold: its events and the home
-    sets' directory rows."""
+    sets' directory rows. Every gather of the directory takes whole rows
+    (`_validate_ways` reads the rows its way pointers name and selects:
+    `_way_record`), and `s.probe` holds exactly two of them: the home
+    rows, C of them, and the way rows, W1 * C."""
     cfg, eng = build(machine)
     shapes = {"l1": eng.state.l1.shape, "events": eng.events.shape,
               "dirm": eng.state.dirm.shape}
     assert len(set(shapes.values())) == 3
-    gathers = [(path, shape) for name, path, shape, _ in indexed_ops(machine)
-               if name == "gather"]
+    indexed = [op for op in indexed_ops(machine) if op[0] == "gather"]
+    gathers = [(path, shape) for _, path, shape, _, _ in indexed]
     assert any("s.probe" in p for p, _ in gathers)  # the walk sees scopes
     assert not [p for p, shape in gathers if shape == shapes["l1"]]
     assert sorted(shape for p, shape in gathers if "s.local" in p) == sorted(
         [shapes["events"], shapes["dirm"]])
+    of_dirm = [(path, n, sizes) for _, path, shape, n, sizes in indexed
+               if shape == shapes["dirm"]]
+    assert shapes["dirm"][1] == dirm_width(cfg)
+    assert {sizes for _, _, sizes in of_dirm} == {(1, dirm_width(cfg))}, of_dirm
+    assert sorted(n for path, n, _ in of_dirm if "s.probe" in path) == [
+        cfg.n_cores, cfg.l1.ways * cfg.n_cores]
 
 
 def test_rung3_walk_indexes_no_table_entry_by_entry():
@@ -251,9 +262,9 @@ def test_rung3_walk_indexes_no_table_entry_by_entry():
     n_slots = cfg.n_cores * 2 * 6  # two legs of a 4x4 mesh's 6 hops
     assert n_links(cfg) < n_slots
     indexed = indexed_ops("rung3")
-    assert any("s.noc" in p for _, p, _, _ in indexed)  # the walk sees scopes
-    assert any(n >= n_slots for _, _, _, n in indexed)  # and counts indices
-    assert not [(name, p, n) for name, p, _, n in indexed
+    assert any("s.noc" in p for _, p, _, _, _ in indexed)  # the walk sees scopes
+    assert any(n >= n_slots for _, _, _, n, _ in indexed)  # and counts indices
+    assert not [(name, p, n) for name, p, _, n, _ in indexed
                 if "s.noc" in p and n > n_links(cfg)]
 
 
