@@ -601,7 +601,7 @@ def _check_npz(path: str, rel: str) -> list:
         CheckpointCorrupt,
         load_verified_npz,
     )
-    from ..stats.counters import COUNTER_NAMES
+    from ..stats.counters import COUNTER_NAMES, N_BLOCK_ROWS
 
     try:
         z = load_verified_npz(path)
@@ -631,11 +631,12 @@ def _check_npz(path: str, rel: str) -> list:
         return findings
     axis = 1 if kind == "fleet" else 0
     rows = z["state_counters"].shape[axis]
-    if rows != len(COUNTER_NAMES):
+    # a job a mesh ran carries the counters' rows alone (DESIGN.md §15)
+    if rows not in (len(COUNTER_NAMES), N_BLOCK_ROWS):
         findings.append(Finding(
             "checkpoint", rel,
             f"{kind} checkpoint carries {rows} counter rows but this "
-            f"build defines {len(COUNTER_NAMES)}", corrupt=True,
+            f"build defines {N_BLOCK_ROWS}", corrupt=True,
             repairable=True,
         ))
     if kind == "warm":

@@ -112,6 +112,11 @@ def _emit_summary(
         "noc_msgs": int(counters["noc_msgs"].sum()),
         **(NO_DEVICE if eng is None else device_fields(eng.state.cycles)),
     }
+    if hasattr(eng, "step_stats"):
+        from ..stats.counters import stat_totals
+
+        # where the step's own lane-slots went (DESIGN.md §15, STAT_NAMES)
+        detail["step_stats"] = stat_totals(eng.step_stats)
     if extra:
         detail.update(extra)
     if timeline:
@@ -1917,7 +1922,14 @@ def build_parser() -> argparse.ArgumentParser:
     )
     sub = p.add_subparsers(dest="cmd", required=True)
 
-    r = sub.add_parser("run", help="simulate a trace on a machine config")
+    r = sub.add_parser(
+        "run", help="simulate a trace on a machine config",
+        epilog="The JSON line's detail.step_stats says where the step's own "
+               "lane-slots went (core-steps active / ahead of the quantum / "
+               "frozen at a barrier, local-run events, "
+               "the router's real sorted entries; all zero under --devices): "
+               "DESIGN.md section 15.",
+    )
     r.add_argument("config", help="machine config (.json or reference-schema .xml)")
     r.add_argument(
         "--trace", action="append",

@@ -19,7 +19,7 @@ import numpy as np
 from ..config.machine import MachineConfig
 from ..noc import topology as _topo
 from ..noc.mesh import bank_tile, core_tile, n_links
-from ..stats.counters import zero_counters
+from ..stats.counters import zero_counters, zero_stats
 from ..trace.format import (
     EV_BARRIER,
     EV_END,
@@ -63,6 +63,11 @@ class GoldenSim:
         self.sharers = np.zeros((B, ls, lw, cfg.n_sharer_words), dtype=np.uint32)
 
         self.counters = zero_counters(C)
+        # the step's account of its own core-steps: the per-core rows of
+        # STAT_NAMES, the engine's specification as `counters` is (the
+        # histogram `noc_sort_log2` stays zero here: a test recounts it
+        # from the per-step sums of `noc_entries`)
+        self.stats = zero_stats(C)
         self.quantum_end = cfg.quantum
         self.step_count = 0
 
@@ -325,6 +330,7 @@ class GoldenSim:
         # quantum boundary, or after local_run_len events. The event then at
         # ptr enters the normal per-step phases below.
         for c in active:
+            ptr0 = int(self.ptr[c])
             for _ in range(cfg.local_run_len):
                 if self.cycles[c] >= self.quantum_end:
                     break
@@ -366,6 +372,7 @@ class GoldenSim:
                     self.l1_state[c, s, w] = M  # silent E->M
                 self.l1_lru[c, s, w] = step
                 self.ptr[c] += 1
+            self.stats["run_events"][c] += int(self.ptr[c]) - ptr0
         if cfg.local_run_len:
             # re-gather events and the active set at the post-run pointers
             cur = [
@@ -379,6 +386,19 @@ class GoldenSim:
                 and not _frozen(c)
                 and self.cycles[c] < self.quantum_end
             ]
+
+        # where this step's core-steps went: a core presents its event,
+        # is frozen at a barrier, or waits ahead of the quantum window;
+        # the rest are at END
+        for c in range(C):
+            if cur[c][0] == EV_END:
+                continue
+            if _frozen(c):
+                self.stats["slot_frozen"][c] += 1
+            elif self.cycles[c] < self.quantum_end:
+                self.stats["slot_active"][c] += 1
+            else:
+                self.stats["slot_quantum"][c] += 1
 
         # --- phase 0/1: classify against step-start state ------------------
         # Only the L1 tag/state arrays need step-start snapshots: phase-3
@@ -522,6 +542,8 @@ class GoldenSim:
                             )
                         )
                     seen = set()
+                    self.stats["noc_entries"][c] += sum(
+                        len(path) for path, _ in legs)
                     for path, leg_t0 in legs:
                         for k, l in enumerate(path):
                             a = leg_t0 + r_lat + k * c_hop

@@ -31,7 +31,7 @@ import numpy as np
 from ..config.machine import MachineConfig
 from ..sim.engine import _ACC_BITS, stream_loop
 from ..sim.state import init_state
-from ..stats.counters import zero_counters
+from ..stats.counters import zero_counters, zero_stats
 from ..trace.format import EV_BARRIER, EV_END
 from .stream import absorb_stream_outputs
 
@@ -256,6 +256,7 @@ class OnlineEngine:
         self.state = init_state(cfg)
         self.cycle_base = np.int64(0)
         self.host_counters = zero_counters(cfg.n_cores)
+        self.host_stats = zero_stats(cfg.n_cores)
         self.steps_run = 0
 
     def _fill_window(self, done_before_drain):

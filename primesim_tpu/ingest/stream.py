@@ -28,7 +28,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.machine import MachineConfig
-from ..stats.counters import COUNTER_NAMES, zero_counters
+from ..stats.counters import fold_block, zero_counters, zero_stats
 from ..sim import exec_cache
 from ..sim.engine import _ACC_BITS, stream_loop
 from ..sim.state import init_state
@@ -56,8 +56,7 @@ def absorb_stream_outputs(eng, out, buf):
         + np.asarray(acc_lo).astype(np.int64)
         + np.asarray(st.counters).astype(np.int64)
     )
-    for i, name in enumerate(COUNTER_NAMES):
-        eng.host_counters[name] += acc[i]
+    fold_block(eng.host_counters, eng.host_stats, acc)
     eng.cycle_base += (
         np.int64(np.asarray(base_hi)) << _ACC_BITS
     ) + np.int64(np.asarray(base_lo))
@@ -132,6 +131,7 @@ class StreamEngine:
             self.state = shard_state(mesh, self.state)
         self.cycle_base = np.int64(0)
         self.host_counters = zero_counters(cfg.n_cores)
+        self.host_stats = zero_stats(cfg.n_cores)  # STAT_NAMES, as Engine
         self.steps_run = 0
         # telemetry sink (obs.Recorder) — None skips every telemetry
         # branch in _advance_window
@@ -346,3 +346,7 @@ class StreamEngine:
     @property
     def counters(self):
         return self.host_counters
+
+    @property
+    def step_stats(self):
+        return self.host_stats

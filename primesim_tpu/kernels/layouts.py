@@ -3,7 +3,7 @@
 VMEM BLOCK LAYOUT (DESIGN.md §11). All step kernels block the CORE axis:
 grid = (C // core_block(C),), every per-core operand arrives as a
 [BC, width] VMEM block with index map `lambda i: (i, 0)` (the counter
-array, [n_counters, C], blocks its LANE axis instead: `lambda i: (0, i)`).
+array, [N_BLOCK_ROWS, C] (counters, then stat rows), blocks its LANE axis instead: `lambda i: (0, i)`).
 Widths are the engine's own fused-array layouts, staged verbatim:
 
 - L1 block: [BC, 5 * W1 * S1] — five planes (tag/state/lru/ptr/epoch) at
@@ -32,7 +32,8 @@ These layouts are also the reason fault injection (DESIGN.md §12) never
 touches kernel code: fault effects are expressed entirely on the staged
 operands (a pre-gather `dirm` scrub, lane-predicate masking, post-fold
 latency/counter addends), and the counter fold is width-generic over
-`counters.shape[0]` — adding the fault counters changed no block spec.
+`counters.shape[0]` — adding the fault counters changed no block spec,
+nor did the stat rows below them (PR 37).
 See the FAULT-LANE CONTRACT note in step_kernels.py.
 """
 

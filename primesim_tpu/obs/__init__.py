@@ -7,7 +7,12 @@ host bookkeeping at chunk boundaries the engines already cross):
 
 - **Metric time-series** (`metrics.MetricStore`): a bounded ring buffer
   of per-chunk samples — counter DELTAS plus wall-clock phase timings —
-  fed by the engine/fleet/stream chunk loops; dumpable as JSONL.
+  fed by the engine/fleet/stream chunk loops; dumpable as JSONL. The
+  fused `Engine.run` commits ONE sample a job, after its results are on
+  the host: the counters' and the stat rows' totals, its host spans'
+  seconds (`span.span`, the one helper that opens them), the sizes the
+  stat ratios divide by. It goes to the attached `Recorder`, else to
+  `process_store()`.
 - **Flight recorder** (`trace.TraceWriter`): Chrome trace-event JSON
   (loads in Perfetto / chrome://tracing) with B/E spans for sim chunks,
   instant events for supervisor decisions (checkpoint, retry, preempt,
@@ -22,10 +27,11 @@ host bookkeeping at chunk boundaries the engines already cross):
 
 `Recorder` is the facade the CLI wires in: one per run, levels
 `off|basic|full` (off = no Recorder at all — engines carry a plain
-`obs = None` attribute and skip every telemetry branch).
+`obs = None` attribute and the chunked loops skip every telemetry
+branch).
 """
 
-from .metrics import Histogram, MetricStore
+from .metrics import Histogram, MetricStore, process_store
 from .prom import render_prometheus
 from .recorder import LEVELS, Recorder
 from .trace import TraceWriter
@@ -36,5 +42,6 @@ __all__ = [
     "MetricStore",
     "Recorder",
     "TraceWriter",
+    "process_store",
     "render_prometheus",
 ]

@@ -1,4 +1,5 @@
-"""Per-chunk metric time-series: bounded ring buffer + fixed-bucket histogram.
+"""Metric time-series, a sample a chunk or a sample a fused job: bounded
+ring buffer + fixed-bucket histogram, and the process's own store.
 
 Deliberately numpy/jax-free so the serve daemon and the report renderer
 can import it without touching the device runtime.
@@ -11,13 +12,19 @@ from collections import deque
 
 
 class MetricStore:
-    """Bounded ring buffer of per-chunk samples.
+    """Bounded ring buffer of per-chunk (or, from the fused `Engine.run`,
+    per-job) samples.
 
     Each sample is a plain dict::
 
         {"seq": int, "t": float, "label": str, "steps": int,
          "wall_s": float, "deltas": {counter: int, ...},
-         "phases": {phase: float, ...}}   # phases optional
+         "phases": {phase: float, ...},   # optional
+         "caps": {size: int, ...}}        # optional, a job's sample only
+
+    A value of ``deltas`` is a total over the cores, but for a row that
+    is not per core (``noc_sort_log2``: a histogram, kept as a list).
+    ``caps`` holds the static sizes a job's stat deltas divide by.
 
     ``seq`` is a global monotonically increasing chunk index (it keeps
     counting even after the ring starts dropping, so the slowest-chunk
@@ -32,7 +39,7 @@ class MetricStore:
         self.seq = 0
         self.dropped = 0
 
-    def record(self, t, label, steps, wall_s, deltas, phases=None):
+    def record(self, t, label, steps, wall_s, deltas, phases=None, caps=None):
         if len(self._ring) == self._ring.maxlen:
             self.dropped += 1
         sample = {
@@ -41,10 +48,13 @@ class MetricStore:
             "label": str(label),
             "steps": int(steps),
             "wall_s": float(wall_s),
-            "deltas": {k: int(v) for k, v in deltas.items()},
+            "deltas": {k: [int(x) for x in v] if isinstance(v, list) else int(v)
+                       for k, v in deltas.items()},
         }
         if phases:
             sample["phases"] = {k: float(v) for k, v in phases.items()}
+        if caps:
+            sample["caps"] = {k: int(v) for k, v in caps.items()}
         self._ring.append(sample)
         self.seq += 1
         return sample
@@ -109,6 +119,19 @@ class MetricStore:
             for s in self._ring:
                 f.write(json.dumps(s, sort_keys=True) + "\n")
         return len(self._ring)
+
+
+_process_store: MetricStore | None = None
+
+
+def process_store() -> MetricStore:
+    """The process-wide store: where a fused `Engine.run` with no
+    `Recorder` attached commits its one sample a job. Bounded, in memory,
+    written nowhere unless someone asks (`dump_jsonl`)."""
+    global _process_store
+    if _process_store is None:
+        _process_store = MetricStore()
+    return _process_store
 
 
 # Default bucket bounds (seconds) shared by the serve latency and fsync

@@ -12,9 +12,11 @@ Chrome trace.
 Levels:
 
 - ``off``   — no Recorder is constructed at all; every engine-side
-  telemetry branch is a single ``is not None`` check that fails. The
-  fused `run()` paths never see a Recorder either way; `--obs off`
-  therefore cannot perturb results (bit-exact by construction).
+  telemetry branch is a single ``is not None`` check that fails, and a
+  fused `Engine.run()` commits its one sample a job to the process's
+  store instead (`metrics.process_store`), once its results are on the
+  host. Nothing recorded is ever read back into a simulation, so no
+  level can perturb results (bit-exact by construction).
 - ``basic`` — metric time-series only (ring buffer + JSONL dump).
 - ``full``  — basic + flight recorder (Chrome trace JSON).
 """
@@ -73,13 +75,30 @@ class Recorder:
         self._prev_totals[label] = totals
         self.store.record(time.time(), label, steps, wall_s, deltas,
                           phases=phases)
-        if self.trace is not None:
-            args = {"steps": int(steps),
-                    "instructions": deltas.get("instructions", 0)}
-            if phases:
-                args.update({f"{k}_ms": round(v * 1e3, 3)
-                             for k, v in phases.items()})
-            self.trace.complete(label, "chunk", wall_s, args)
+        self._trace_span(label, "chunk", steps, wall_s, deltas, phases)
+
+    def _trace_span(self, label, name, steps, wall_s, deltas, phases) -> None:
+        if self.trace is None:
+            return
+        args = {"steps": int(steps),
+                "instructions": deltas.get("instructions", 0)}
+        if phases:
+            args.update({f"{k}_ms": round(v * 1e3, 3)
+                         for k, v in phases.items()})
+        self.trace.complete(label, name, wall_s, args)
+
+    def job_committed(self, label, steps, wall_s, deltas, phases, caps) -> None:
+        """One fused run (`Engine.run`): a single sample for the whole
+        job. `deltas` are the job's own totals, not cumulative ones; the
+        label's cumulative totals move on by them, so that chunks of the
+        same engine committed before or after keep their deltas whole."""
+        prev = self._prev_totals.get(label)
+        if prev is not None:
+            for k in prev:
+                prev[k] += deltas.get(k, 0)
+        self.store.record(time.time(), label, steps, wall_s, deltas,
+                          phases=phases, caps=caps)
+        self._trace_span(label, "job", steps, wall_s, deltas, phases)
 
     # ---- supervisor / serve side ----------------------------------------
 

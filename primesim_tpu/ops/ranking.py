@@ -269,10 +269,14 @@ class SortedRuns(NamedTuple):
     """The sorted order `segmented_rank_floor` built, for the passes that
     ride it again: `spos` [E + n_seg] the sorted position of every entry
     and then of every segment's table entry; `ends` [E + n_seg] in sorted
-    order, true at the last element of a segment's run."""
+    order, true at the last element of a segment's run; `n_real` [] the
+    number of entries that were not masked, read off where the masked
+    ones' run starts (no pass over the entries, and no collective where
+    they came from several chips: the sorted order is whole on each)."""
 
     spos: jax.Array
     ends: jax.Array
+    n_real: jax.Array
 
 
 def segmented_rank_floor(seg, val, table, *, order, method="auto"):
@@ -307,9 +311,13 @@ def segmented_rank_floor(seg, val, table, *, order, method="auto"):
         (*keys, pos, jnp.concatenate([val.reshape(E), table])),
         num_keys=len(keys), is_stable=False,
     )
-    starts = _is_start(_sorted_seg(skeys, C + 1))
+    sseg = _sorted_seg(skeys, C + 1)
+    starts = _is_start(sseg)
     ends = jnp.concatenate([starts[1:], jnp.ones((1,), jnp.bool_)])
     seg0 = _running_max(jnp.where(starts, pos, 0))
+    # the masked entries sort last, after n_seg table entries and every
+    # real entry; where none is masked the last run is a real segment's
+    n_real = jnp.where(sseg[-1] == n_seg, seg0[-1] - n_seg, E)
     grp0 = jnp.maximum(seg0, _run_starts(skeys[-1]))
     # the segment's minimum arrives at the run's first element, the table
     # entry, scanning from the run's end; the floor is formed there, once
@@ -324,7 +332,7 @@ def segmented_rank_floor(seg, val, table, *, order, method="auto"):
     _, rank, floor, spos = jax.lax.sort(
         (sidx, grp0 - seg0 - 1, sfloor, pos), num_keys=1, is_stable=False)
     return (rank[:E].reshape(C, S), floor[:E].reshape(C, S),
-            SortedRuns(spos, ends))
+            SortedRuns(spos, ends, n_real))
 
 
 def segmented_table_max(runs, val, table):

@@ -253,8 +253,10 @@ def shard_state(mesh: Mesh, st: MachineState) -> MachineState:
 
 @functools.lru_cache(maxsize=None)
 def _state_builder(mesh: Mesh):
+    # no stat rows on a mesh: the block keeps the counters' height there
     return jax.jit(
-        init_state, static_argnums=0, out_shardings=state_shardings(mesh)
+        functools.partial(init_state, stat_rows=False), static_argnums=0,
+        out_shardings=state_shardings(mesh),
     )
 
 
@@ -267,7 +269,11 @@ def build_state(cfg, mesh: Mesh | None = None) -> MachineState:
     Without a mesh: `init_state` itself, array by array on the default
     device. The compiled builder gives the same bytes there, but lays
     them elsewhere in HBM, and the step's speed follows that placement:
-    one one-chip cell lost 5 % to it (PERF.md section 6, PR 33)."""
+    one one-chip cell lost 5 % to it (PERF.md section 6, PR 33). On a mesh
+    the counter block carries no stat rows (`init_state(stat_rows=False)`):
+    rung 4's row gathers on four chips lost 4 % to the taller block alone
+    and 10 % with the rows counted (PERF.md section 6, PR 37), so a sharded
+    run executes the program it executed before the rows were there."""
     if mesh is None:
         return init_state(cfg)
     return _state_builder(mesh)(cfg)

@@ -34,7 +34,12 @@ import numpy as np
 from ..chaos import sites as chaos
 from ..config.machine import MachineConfig
 from ..faults.schedule import FaultState
-from ..stats.counters import COUNTER_NAMES
+from ..stats.counters import (
+    COUNTER_NAMES,
+    N_BLOCK_ROWS,
+    stack_block,
+    unstack_block,
+)
 from ..util import diskpressure
 from .state import MachineState, TimingKnobs
 
@@ -53,6 +58,10 @@ _FORMAT = 7  # v3: fused dirm row (metadata + sharers) replaces
 # (prefetch_degree/prefetch_lat); older snapshots lack the arrays, so
 # the format bump keeps them from resuming with silently-zeroed
 # prefetcher state.
+# (PR 37: the counter block gained the stat rows, stats/counters.py::
+# BLOCK_NAMES. `state_counters` and `host_counters` hold the block's rows,
+# the stat totals below the counters'; a snapshot of the old height is
+# refused by the row-count checks below, so the format stays.)
 
 # nested-NamedTuple state fields and their types (flattened by
 # _state_arrays to `state_<field>__<sub>` keys; extend here when a new
@@ -281,9 +290,8 @@ def save_checkpoint(path: str, engine) -> None:
     """Snapshot an Engine mid-run (drains device counters first)."""
     engine._drain()
     arrays = _state_arrays(engine.state)
-    arrays["host_counters"] = np.stack(
-        [engine.host_counters[k] for k in COUNTER_NAMES]
-    )
+    arrays["host_counters"] = stack_block(
+        engine.host_counters, engine.host_stats)
     atomic_save_npz(
         path,
         format=np.int64(_FORMAT),
@@ -315,9 +323,7 @@ def save_stream_checkpoint(path: str, eng) -> None:
     i.e. between `_advance_window` dispatches (`run_events` pauses
     there)."""
     arrays = _state_arrays(eng.state)
-    arrays["host_counters"] = np.stack(
-        [eng.host_counters[k] for k in COUNTER_NAMES]
-    )
+    arrays["host_counters"] = stack_block(eng.host_counters, eng.host_stats)
     atomic_save_npz(
         path,
         format=np.int64(_FORMAT),
@@ -366,10 +372,7 @@ def load_stream_checkpoint(path: str, eng) -> None:
     eng.cursor = z["cursor"].astype(np.int64)
     eng.cycle_base = np.int64(z["cycle_base"])
     eng.steps_run = int(z["steps_run"])
-    hc = z["host_counters"]
-    eng.host_counters = {
-        k: hc[i].astype(np.int64) for i, k in enumerate(COUNTER_NAMES)
-    }
+    eng.host_counters, eng.host_stats = unstack_block(z["host_counters"])
     if getattr(eng, "attest", None) is not None:
         eng.attest.seed(_attest_from(z), int(z["steps_run"]))
 
@@ -401,13 +404,21 @@ def load_checkpoint(path: str, engine) -> None:
     sha = bytes(z["trace_sha"]).decode()
     if sha != trace_fingerprint(engine.trace):
         raise ValueError(f"{path}: checkpoint trace does not match engine trace")
-    if z["state_counters"].shape[0] != len(COUNTER_NAMES):
+    # the host totals always hold the whole block; the device block is
+    # N_BLOCK_ROWS, or the counters' rows alone where a mesh ran the job
+    rows = z["host_counters"].shape[0]
+    if rows != N_BLOCK_ROWS or z["state_counters"].shape[0] not in (
+            len(COUNTER_NAMES), N_BLOCK_ROWS):
         raise ValueError(
-            f"{path}: checkpoint has {z['state_counters'].shape[0]} counter "
-            f"rows but this build defines {len(COUNTER_NAMES)} — saved by an "
+            f"{path}: checkpoint has {rows} counter "
+            f"rows but this build defines {N_BLOCK_ROWS} — saved by an "
             "incompatible version"
         )
     st = _state_from(z)
+    if st.counters.shape != engine.state.counters.shape:
+        # saved on a mesh and resumed off one, or the reverse: the block
+        # was drained at the save, so it is zero at either height
+        st = st._replace(counters=np.zeros(engine.state.counters.shape, np.int32))
     if engine.mesh is not None:
         # restore the multi-chip layout Engine.__init__ applies — without
         # this the full state materializes unsharded on one device
@@ -419,10 +430,8 @@ def load_checkpoint(path: str, engine) -> None:
     engine.steps_run = int(z["steps_run"])
     engine.prefix_steps = int(z["prefix_steps"]) if "prefix_steps" in z else 0
     engine.prefix_cache_key = _str_field(z, "prefix_cache_key") or None
-    hc = z["host_counters"]
-    engine.host_counters = {
-        k: hc[i].astype(np.int64) for i, k in enumerate(COUNTER_NAMES)
-    }
+    engine.host_counters, engine.host_stats = unstack_block(
+        z["host_counters"])
     if getattr(engine, "attest", None) is not None:
         engine.attest.seed(_attest_from(z), int(z["steps_run"]))
 
@@ -442,9 +451,8 @@ def save_element_checkpoint(path: str, fleet, i: int, job_id: str = "",
     the trace the job will resume with, not the window splice."""
     fleet._drain()
     arrays = _state_arrays(fleet.element_state(i))
-    arrays["host_counters"] = np.stack(
-        [fleet.host_counters[k][i] for k in COUNTER_NAMES]
-    )  # [n_counters, C]
+    arrays["host_counters"] = stack_block(
+        fleet.host_counters, fleet.host_stats)[:, i]  # [N_BLOCK_ROWS, C]
     at = (fleet.attest.payload(i)
           if getattr(fleet, "attest", None) is not None else None)
     extra = _attest_members(at)
@@ -502,10 +510,10 @@ def load_element_checkpoint(path: str, cfg, trace) -> dict:
         raise ValueError(f"{path}: checkpoint config does not match job")
     if bytes(z["trace_sha"]).decode() != trace_fingerprint(trace):
         raise ValueError(f"{path}: checkpoint trace does not match job")
-    if z["state_counters"].shape[0] != len(COUNTER_NAMES):
+    if z["state_counters"].shape[0] != N_BLOCK_ROWS:
         raise ValueError(
             f"{path}: checkpoint has {z['state_counters'].shape[0]} counter "
-            f"rows but this build defines {len(COUNTER_NAMES)} — saved by an "
+            f"rows but this build defines {N_BLOCK_ROWS} — saved by an "
             "incompatible version"
         )
     if "attest_payload_sha" in z:
@@ -523,7 +531,7 @@ def load_element_checkpoint(path: str, cfg, trace) -> dict:
                 site="checkpoint.payload",
                 unit=_str_field(z, "job_id"),
             )
-    hc = z["host_counters"]
+    host_counters, host_stats = unstack_block(z["host_counters"])
     return {
         "state": _state_from(z),
         "cycle_base": np.int64(z["cycle_base"]),
@@ -531,9 +539,8 @@ def load_element_checkpoint(path: str, cfg, trace) -> dict:
         "job_id": bytes(z["job_id"]).decode(),
         "prefix_steps": int(z["prefix_steps"]) if "prefix_steps" in z else 0,
         "prefix_cache_key": _str_field(z, "prefix_cache_key") or None,
-        "host_counters": {
-            k: hc[i].astype(np.int64) for i, k in enumerate(COUNTER_NAMES)
-        },
+        "host_counters": host_counters,
+        "host_stats": host_stats,
         "attest": _attest_from(z),
     }
 
@@ -545,9 +552,8 @@ def save_fleet_checkpoint(path: str, fleet) -> None:
     boundary is a consistent cut, exactly as for the solo engine."""
     fleet._drain()
     arrays = _state_arrays(fleet.state)
-    arrays["host_counters"] = np.stack(
-        [fleet.host_counters[k] for k in COUNTER_NAMES]
-    )  # [n_counters, B, C]
+    arrays["host_counters"] = stack_block(
+        fleet.host_counters, fleet.host_stats)  # [N_BLOCK_ROWS, B, C]
     B = len(fleet.elem_cfgs)
     pre = getattr(fleet, "prefix_steps", None)
     if pre is None:
@@ -606,10 +612,10 @@ def load_fleet_checkpoint(path: str, fleet) -> None:
         raise ValueError(
             f"{path}: checkpoint element traces do not match fleet"
         )
-    if z["state_counters"].shape[1] != len(COUNTER_NAMES):
+    if z["state_counters"].shape[1] != fleet.state.counters.shape[1]:
         raise ValueError(
             f"{path}: checkpoint has {z['state_counters'].shape[1]} counter "
-            f"rows but this build defines {len(COUNTER_NAMES)} — saved by an "
+            f"rows but this build defines {fleet.state.counters.shape[1]} — saved by an "
             "incompatible version"
         )
     st = _state_from(z)
@@ -627,10 +633,7 @@ def load_fleet_checkpoint(path: str, fleet) -> None:
         fleet.prefix_cache_keys = json.loads(
             bytes(z["prefix_keys_json"]).decode()
         )
-    hc = z["host_counters"]
-    fleet.host_counters = {
-        k: hc[i].astype(np.int64) for i, k in enumerate(COUNTER_NAMES)
-    }
+    fleet.host_counters, fleet.host_stats = unstack_block(z["host_counters"])
     if getattr(fleet, "attest", None) is not None and "attest_json" in z:
         from ..attest import AttestChain
 
@@ -747,9 +750,8 @@ def save_warm_state(root: str, cfg, trace_fp: str, steps: int, snap: dict) -> st
     os.makedirs(root, exist_ok=True)
     npz_path, meta_path = _warm_paths(root, key)
     arrays = _state_arrays(snap["state"])
-    arrays["host_counters"] = np.stack(
-        [snap["host_counters"][k] for k in COUNTER_NAMES]
-    )
+    arrays["host_counters"] = stack_block(
+        snap["host_counters"], snap["host_stats"])
     atomic_save_npz(
         npz_path,
         format=np.int64(_FORMAT),
@@ -808,7 +810,7 @@ def load_warm_state(root: str, key: str, cfg, trace_fp: str, steps: int) -> dict
         raise ValueError(f"{npz_path}: entry trace does not match workload")
     if warm_key(cfg, trace_fp, steps) != key:
         raise ValueError(f"{npz_path}: entry key does not match workload")
-    if z["state_counters"].shape[0] != len(COUNTER_NAMES):
+    if z["state_counters"].shape[0] != N_BLOCK_ROWS:
         raise ValueError(
             f"{npz_path}: incompatible counter-row count "
             f"{z['state_counters'].shape[0]}"
@@ -818,14 +820,13 @@ def load_warm_state(root: str, key: str, cfg, trace_fp: str, steps: int) -> dict
         os.utime(npz_path, now)
     except OSError:
         pass
-    hc = z["host_counters"]
+    host_counters, host_stats = unstack_block(z["host_counters"])
     return {
         "state": _state_from(z),
         "cycle_base": np.int64(z["cycle_base"]),
         "steps_run": np.int64(z["steps_run"]),
-        "host_counters": {
-            k: hc[i].astype(np.int64) for i, k in enumerate(COUNTER_NAMES)
-        },
+        "host_counters": host_counters,
+        "host_stats": host_stats,
     }
 
 

@@ -29,8 +29,19 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..config.machine import MachineConfig
+from ..noc.topology import path_width
+from ..obs.metrics import process_store
+from ..obs.span import span
 from ..parallel.sharding import build_state, mesh_jit, shard_events
-from ..stats.counters import COUNTER_NAMES, zero_counters
+from ..stats.counters import (
+    BLOCK_NAMES,
+    COUNTER_NAMES,
+    STAT_NAMES,
+    fold_block,
+    stat_totals,
+    zero_counters,
+    zero_stats,
+)
 from ..trace.format import (
     EV_BARRIER,
     EV_END,
@@ -88,7 +99,8 @@ def _device_done(events, st, arange_c, faults_enabled=False):
 
 def _drain_and_rebase(cfg, st, acc_lo, acc_hi, base_lo, base_hi, nd):
     """On-device housekeeping shared by run_loop and stream_loop: drain
-    int32 step counters into (lo, hi) carry pairs (hi above 2^30), and
+    the int32 counter block (counters and stat rows) into (lo, hi) carry
+    pairs (hi above 2^30), and
     rebase the epoch-relative clocks by a whole number of quanta — the
     minimum over `nd` (not-done) lanes — including occupied barrier
     slots' arrival clocks."""
@@ -285,7 +297,7 @@ class Engine:
             ((t == EV_LOCK) | (t == EV_UNLOCK) | (t == EV_BARRIER)).any()
         )
         self.mesh = mesh
-        with jax.profiler.TraceAnnotation("engine.init"):
+        with span("engine.init") as init:
             # multi-chip: cores/banks laid out over the tile axis
             # (parallel/); events and state go into that layout from their
             # first byte, never whole onto one device
@@ -294,6 +306,7 @@ class Engine:
                 jnp.asarray(events) if mesh is None else shard_events(mesh, events)
             )
             self.state = build_state(cfg, mesh)
+        self._init_s = init.seconds  # reported with the first job's sample
         self.chunk_steps = chunk_steps
         # Counter-accumulator guard (run_loop drains int32 step counters
         # into (lo, hi) pairs whose hi carries above 2^30): any per-core
@@ -317,11 +330,14 @@ class Engine:
             )
         self.cycle_base = np.int64(0)
         self.host_counters = zero_counters(cfg.n_cores)
+        # the stat rows' totals (stats/counters.py::STAT_NAMES): drained
+        # with the counters, kept apart from them
+        self.host_stats = zero_stats(cfg.n_cores)
         self.steps_run = 0
         # telemetry sink (obs.Recorder) — None means the chunked loops
-        # report to nobody (they still read the clock at each cut); the
-        # fused run() never consults it at all (DESIGN.md §15 overhead
-        # contract)
+        # report to nobody (they still read the clock at each cut) and
+        # the fused run() commits its one sample a job to the process's
+        # store (DESIGN.md §15 overhead contract)
         self.obs = None
         self.obs_label = "engine"
         # attestation chain (attest.SoloAttest) — None means the chunked
@@ -346,9 +362,8 @@ class Engine:
         self._pending = None
 
     def _drain(self) -> None:
-        cnt = _np(self.state.counters)
-        for i, k in enumerate(COUNTER_NAMES):
-            self.host_counters[k] += cnt[i].astype(np.int64)
+        fold_block(self.host_counters, self.host_stats,
+                   _np(self.state.counters).astype(np.int64))
         self.state = self.state._replace(
             counters=jnp.zeros_like(self.state.counters)
         )
@@ -424,30 +439,65 @@ class Engine:
         to chunk_steps-1 extra steps may execute before the guard trips.
         """
         max_chunks = -(-max_steps // self.chunk_steps)
-        # the two host spans of a fused run, on the profiler's own clock
-        # beside the device ops (DESIGN.md §15); with no profiler attached
-        # a TraceAnnotation costs tens of nanoseconds
-        with jax.profiler.TraceAnnotation("engine.dispatch"):
+        # the three host spans of a fused run, on the profiler's own clock
+        # beside the device ops and, in seconds, in the job's sample
+        # (DESIGN.md §15): the enqueue, the wait for the device, and the
+        # transfers once it is done
+        with span("engine.dispatch") as dispatch:
             st, acc_lo, acc_hi, base_lo, base_hi, k = exec_cache.call(
                 run_loop, "engine.run_loop",
                 (self.cfg, self.chunk_steps),
                 (self.events, self.state, jnp.asarray(max_chunks, jnp.int32)),
                 {"has_sync": self.has_sync},
             )
-        with jax.profiler.TraceAnnotation("engine.readback"):
-            # one synchronizing transfer for everything the host needs
+        with span("engine.wait") as wait:
+            jax.block_until_ready(k)
+        with span("engine.readback") as readback:
+            # everything the host needs of the finished run
             acc_lo = _np(acc_lo).astype(np.int64)
             acc_hi = _np(acc_hi).astype(np.int64)
             total = (acc_hi << _ACC_BITS) + acc_lo
-            for i, name in enumerate(COUNTER_NAMES):
-                self.host_counters[name] += total[i]
+            fold_block(self.host_counters, self.host_stats, total)
             self.cycle_base += (
                 np.int64(np.asarray(base_hi)) << _ACC_BITS
             ) + np.int64(np.asarray(base_lo))
             self.state = st
-            self.steps_run += int(np.asarray(k)) * self.chunk_steps
+            steps = int(np.asarray(k)) * self.chunk_steps
+            self.steps_run += steps
+        self._commit_job(total, steps, {
+            "init": self._init_s, "dispatch": dispatch.seconds,
+            "wait": wait.seconds, "readback": readback.seconds})
+        self._init_s = 0.0  # the engine's build belongs to its first job
         if not self.done():
             raise RuntimeError("engine: max_steps exceeded (deadlock?)")
+
+    def _commit_job(self, total, steps, phases) -> None:
+        """The one sample of a fused run (DESIGN.md §15), committed once
+        its results are on the host: the job's totals row by row of the
+        block (the histogram row as its lanes), its host spans' seconds,
+        and the static sizes the stat ratios divide by. To the attached
+        `Recorder`, else to the process's store; nothing reads it back."""
+        cfg = self.cfg
+        rows = dict(zip(BLOCK_NAMES, total))  # the rows the block carries
+        deltas = {k: int(rows[k].sum()) for k in COUNTER_NAMES}
+        if len(rows) > len(COUNTER_NAMES):  # not on a mesh: no stat rows there
+            deltas.update(stat_totals({k: rows[k] for k in STAT_NAMES}))
+        router = cfg.noc.contention and cfg.noc.contention_model == "router"
+        caps = {
+            "n_cores": cfg.n_cores,
+            "local_run_len": cfg.local_run_len,
+            # the slots of the router walk's sort: a lane's legs, each
+            # padded to the longest path
+            "sort_entries": cfg.n_cores * (3 if self.has_sync else 2)
+            * path_width(cfg) if router else 0,
+        }
+        wall_s = sum(phases.values()) - phases["init"]
+        if self.obs is not None:
+            self.obs.job_committed(self.obs_label, steps, wall_s, deltas,
+                                   phases, caps)
+        else:
+            process_store().record(time.time(), self.obs_label, steps, wall_s,
+                                   deltas, phases=phases, caps=caps)
 
     def run_chunked(
         self, max_steps: int = 10_000_000, debug_invariants: bool = False
@@ -469,33 +519,29 @@ class Engine:
         run_steps(A) -> save_checkpoint -> (later) load_checkpoint ->
         run() is bit-exact with an uninterrupted run()."""
         target = self.steps_run + n_steps
-        span = jax.profiler.TraceAnnotation
         while self.steps_run < target and not self.done():
-            # the cuts of a chunk, each one interval twice over: a host
-            # span in the profiler's trace (DESIGN.md §15) and, under
-            # --obs, a phase timing. dispatch is the async enqueue; drain's
-            # host transfer synchronizes, so "drain" includes the device
-            # executing the chunk; rebase is pure host work
-            t0 = time.perf_counter()
-            with span("engine.chunk.dispatch"):
+            # the cuts of a chunk, each one interval read once (obs/span.py):
+            # a host span in the profiler's trace (DESIGN.md §15) and,
+            # under --obs, a phase timing. dispatch is the async enqueue;
+            # drain's host transfer synchronizes, so "drain" includes the
+            # device executing the chunk; rebase is pure host work
+            with span("engine.chunk.dispatch") as dispatch:
                 self._dispatch_chunk()
-            t1 = time.perf_counter()
             self.steps_run += self.chunk_steps
-            with span("engine.chunk.drain"):
+            with span("engine.chunk.drain") as drain:
                 self._drain()
-            t2 = time.perf_counter()
-            with span("engine.chunk.rebase"):
+            with span("engine.chunk.rebase") as rebase:
                 self._rebase()
-            t3 = time.perf_counter()
-            phases = {"dispatch": t1 - t0, "drain": t2 - t1,
-                      "rebase": t3 - t2}
+            phases = {"dispatch": dispatch.seconds, "drain": drain.seconds,
+                      "rebase": rebase.seconds}
             if self.overlap and not self.done():
-                with span("engine.chunk.prefetch"):
+                with span("engine.chunk.prefetch") as prefetch:
                     self._prefetch_chunk()
-                phases["prefetch"] = time.perf_counter() - t3
+                phases["prefetch"] = prefetch.seconds
             if self.obs is not None:
                 self.obs.chunk_committed(
-                    self.obs_label, self.chunk_steps, t3 - t0,
+                    self.obs_label, self.chunk_steps,
+                    dispatch.seconds + drain.seconds + rebase.seconds,
                     self.host_counters, phases=phases,
                 )
             if self.attest is not None:
@@ -577,5 +623,13 @@ class Engine:
 
     @property
     def counters(self) -> dict[str, np.ndarray]:
+        """The modelled machine's counters: COUNTER_NAMES, no stat row."""
         self._drain()
         return self.host_counters
+
+    @property
+    def step_stats(self) -> dict[str, np.ndarray]:
+        """The step's account of its own lane-slots (STAT_NAMES), [C]
+        each: per core, but `noc_sort_log2`, a histogram over its lanes."""
+        self._drain()
+        return self.host_stats

@@ -45,7 +45,7 @@ import numpy as np
 from ..chaos import sites as chaos
 from ..config.machine import MachineConfig
 from ..parallel.sharding import mesh_jit
-from ..stats.counters import COUNTER_NAMES
+from ..stats.counters import COUNTER_NAMES, STAT_NAMES, fold_block
 from ..trace.format import EV_BARRIER, EV_END, EV_LOCK, EV_UNLOCK, Trace
 from . import exec_cache
 from .engine import _ACC_BITS, _np, run_chunk, run_loop
@@ -298,6 +298,8 @@ class FleetEngine:
         self.host_counters = {
             k: np.zeros((B, C), np.int64) for k in COUNTER_NAMES
         }
+        # the stat rows' totals, [B, C] each, as Engine.host_stats
+        self.host_stats = {k: np.zeros((B, C), np.int64) for k in STAT_NAMES}
         self.steps_run = np.zeros(B, np.int64)
         # original (caller-side) index of each batch position; the fault
         # isolation builder (sim.supervisor.build_fleet_isolated) rewrites
@@ -350,9 +352,9 @@ class FleetEngine:
         return len(self.traces)
 
     def _drain(self) -> None:
-        cnt = _np(self.state.counters)  # [B, n_counters, C]
-        for i, k in enumerate(COUNTER_NAMES):
-            self.host_counters[k] += cnt[:, i].astype(np.int64)
+        cnt = _np(self.state.counters)  # [B, N_BLOCK_ROWS, C]
+        fold_block(self.host_counters, self.host_stats,
+                   np.swapaxes(cnt, 0, 1).astype(np.int64))
         self.state = self.state._replace(
             counters=jnp.zeros_like(self.state.counters)
         )
@@ -439,11 +441,11 @@ class FleetEngine:
             (self.events, self.state, jnp.asarray(max_chunks, jnp.int32)),
             {"has_sync": self.has_sync},
         )
-        acc_lo = _np(acc_lo).astype(np.int64)  # [B, n_counters, C]
+        acc_lo = _np(acc_lo).astype(np.int64)  # [B, N_BLOCK_ROWS, C]
         acc_hi = _np(acc_hi).astype(np.int64)
         total = (acc_hi << _ACC_BITS) + acc_lo
-        for i, name in enumerate(COUNTER_NAMES):
-            self.host_counters[name] += total[:, i]
+        fold_block(self.host_counters, self.host_stats,
+                   np.swapaxes(total, 0, 1))
         self.cycle_base += (
             _np(base_hi).astype(np.int64) << _ACC_BITS
         ) + _np(base_lo).astype(np.int64)
@@ -588,6 +590,13 @@ class FleetEngine:
         self._drain()
         return {k: v[i] for k, v in self.host_counters.items()}
 
+    @property
+    def step_stats(self) -> dict[str, np.ndarray]:
+        """name -> [B, C] int64: the stat rows (STAT_NAMES), as
+        `Engine.step_stats`."""
+        self._drain()
+        return self.host_stats
+
     # ---- checkpoint / resume --------------------------------------------
 
     def save_checkpoint(self, path: str) -> None:
@@ -699,8 +708,9 @@ class FleetEngine:
         self.steps_run[i] = 0
         self.prefix_steps[i] = 0
         self.prefix_cache_keys[i] = None
-        for k in self.host_counters:
-            self.host_counters[k][i] = 0
+        for totals in (self.host_counters, self.host_stats):
+            for k in totals:
+                totals[k][i] = 0
         # a new occupant never inherits the previous job's chain; the
         # owner re-tracks the slot if the new workload is attested
         if self.attest is not None:
@@ -731,6 +741,8 @@ class FleetEngine:
         self.steps_run[i] = snap["steps_run"]
         for k in COUNTER_NAMES:
             self.host_counters[k][i] = snap["host_counters"][k]
+        for k in STAT_NAMES:
+            self.host_stats[k][i] = snap["host_stats"][k]
         if self.mesh is not None:
             self._reshard()
 

@@ -258,6 +258,9 @@ def execute_prefix_plan(
                 "host_counters": {
                     k: v.copy() for k, v in eng.host_counters.items()
                 },
+                "host_stats": {
+                    k: v.copy() for k, v in eng.host_stats.items()
+                },
             }
             stats["prefix_wall_s"] += time.perf_counter() - t0
             if root is not None:

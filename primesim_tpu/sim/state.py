@@ -13,11 +13,10 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
-import numpy as np
 
 from ..config.machine import MachineConfig
 from ..faults.schedule import FaultState, fault_state_from_config
-from ..stats.counters import COUNTER_NAMES
+from ..stats.counters import COUNTER_NAMES, N_BLOCK_ROWS
 
 # MESI encoding (shared with primesim_tpu.golden.sim)
 I, S, E, M = 0, 1, 2, 3
@@ -165,8 +164,9 @@ class MachineState(NamedTuple):
     pf_line: jnp.ndarray  # [C] int32
     pf_stride: jnp.ndarray  # [C] int32
     pf_streak: jnp.ndarray  # [C] int32
-    # stat counters, one row per COUNTER_NAMES entry
-    counters: jnp.ndarray  # [n_counters, C] int32
+    # the counter block: one row per COUNTER_NAMES entry, then one per
+    # STAT_NAMES entry (stats/counters.py::BLOCK_NAMES)
+    counters: jnp.ndarray  # [N_BLOCK_ROWS, C] int32 (on a mesh the counters' rows alone)
     # traced per-simulation timing knobs (see TimingKnobs): constant
     # through a run (step passes them through), but TRACED so one
     # compiled program serves every timing variant of one geometry
@@ -180,7 +180,11 @@ class MachineState(NamedTuple):
     faults: FaultState
 
 
-def init_state(cfg: MachineConfig) -> MachineState:
+def init_state(cfg: MachineConfig, stat_rows: bool = True) -> MachineState:
+    """`stat_rows` false: the counter block holds COUNTER_NAMES alone, the
+    program then counts no stat row (`step` folds the rows the block has).
+    `build_state` asks for that on a mesh (DESIGN.md §15: rung 4's sharded
+    row gathers lost 4 % to the taller block and 10 % to the counts)."""
     C, B = cfg.n_cores, cfg.n_banks
     s1, w1 = cfg.l1.sets, cfg.l1.ways
     s2, w2 = cfg.llc.sets, cfg.llc.ways
@@ -221,11 +225,8 @@ def init_state(cfg: MachineConfig) -> MachineState:
         pf_streak=jnp.zeros(C, jnp.int32),
         quantum_end=jnp.asarray(cfg.quantum, jnp.int32),
         step=jnp.asarray(0, jnp.int32),
-        counters=jnp.zeros((len(COUNTER_NAMES), C), jnp.int32),
+        counters=jnp.zeros(
+            (N_BLOCK_ROWS if stat_rows else len(COUNTER_NAMES), C), jnp.int32),
         knobs=knobs_from_config(cfg),
         faults=fault_state_from_config(cfg),
     )
-
-
-def counters_to_dict(counters: np.ndarray) -> dict[str, np.ndarray]:
-    return {k: np.asarray(counters[i], dtype=np.int64) for i, k in enumerate(COUNTER_NAMES)}

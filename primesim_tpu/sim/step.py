@@ -51,7 +51,7 @@ from ..ops.ranking import (
     segmented_table_max,
 )
 from ..parallel.sharding import read_rows
-from ..stats.counters import COUNTER_NAMES
+from ..stats.counters import BLOCK_NAMES
 from ..trace.format import (
     EV_BARRIER,
     EV_END,
@@ -87,10 +87,12 @@ _GRP = "grp"  # second level: the coarse vector's per-group reductions
 _CHUNK = "chunk"  # second level: the full map's reductions in blocks of words
 _LOCK = "lock"  # second level: unlocks and lock grants
 _BARRIER = "barrier"  # second level: barrier arrivals and releases
+_STAT = "stat"  # second level: what only the stat rows need (STAT_NAMES)
 PHASES = (
     "s.fault",  # phase -1: fault injection
     "s.local",  # phase 0 quantum barrier + 0.5 local runs
     "s.probe",  # 0.9 + 1: the arbitration event, its L1 probe, classification
+    "s.probe/" + _STAT,  # where the core-steps that present no event went
     "s.arb",  # 2: read-join coalescing, per-(bank,set) arbitration
     "s.dir",  # 3: directory transition, grants, victim, invalidation targets,
     #            prefetcher
@@ -98,6 +100,7 @@ PHASES = (
     "s.dir/" + _CHUNK,  # sharer_chunk_words > 0 only
     "s.noc",  # NoC contention: tile/link counts, or the hop-by-hop router
     "s.noc/" + _RANK,
+    "s.noc/" + _STAT,  # router only: its real entries, and their histogram
     "s.dram",  # memory-controller queue
     "s.dram/" + _RANK,
     "s.commit",  # latency composition, granted state, counters, phase 4.A,
@@ -107,14 +110,15 @@ PHASES = (
     "s.sync/" + _BARRIER,
     "s.chunk",  # run_loop's per-chunk drain, rebase and termination test
 )
-(P_FAULT, P_LOCAL, P_PROBE, P_ARB, P_DIR, _, _, P_NOC, _, P_DRAM, _, P_COMMIT,
- P_SYNC, _, _, P_CHUNK) = PHASES
+(P_FAULT, P_LOCAL, P_PROBE, P_ARB, P_DIR, P_NOC, P_DRAM, P_COMMIT, P_SYNC,
+ P_CHUNK) = (p for p in PHASES if "/" not in p)
 
 # Which functions write under which scope: every instruction whose
 # `op_name` holds a phase has one of these on its call stack, so a phase's
 # work written into another phase's function fails a test
 # (tests/test_phase_scopes.py) and not a metric's reader. Second levels
-# (`/rank`, `/grp`, `/chunk`, `/lock`, `/barrier`) belong to their phase.
+# (`/rank`, `/grp`, `/chunk`, `/lock`, `/barrier`, `/stat`) belong to their
+# phase.
 # `s.chunk` is run_loop's own.
 PHASE_FUNCTIONS = {
     P_FAULT: ("_fault",),
@@ -442,20 +446,24 @@ class DirOutcome(NamedTuple):
 
 
 def _count(acc: dict, name: str, amount) -> None:
-    """Add `amount` [C] to counter `name` of this step. The deltas
-    collect in `acc`, a dict of [C] lanes that lives for one trace of
-    `step`, and fold into the [n_counters, C] array in ONE stacked add at
-    the end of the step (`_counter_deltas`): each `.at[row].add` is its
+    """Add `amount` [C] to counter or stat row `name` of this step. The
+    deltas collect in `acc`, a dict of [C] lanes that lives for one trace
+    of `step`, and fold into the [N_BLOCK_ROWS, C] array in ONE stacked add
+    at the end of the step (`_counter_deltas`): each `.at[row].add` is its
     own dynamic-update-slice kernel, while the dict adds fuse into the
-    surrounding elementwise work for free."""
+    surrounding elementwise work for free. A stat row (STAT_NAMES) is
+    written here and read by nothing in the step."""
     a = amount.astype(jnp.int32)
     acc[name] = a if name not in acc else acc[name] + a
 
 
-def _counter_deltas(acc: dict, C: int):
+def _counter_deltas(acc: dict, C: int, n_rows: int):
+    # the rows the state's block has: all of BLOCK_NAMES, or the counters
+    # alone (a mesh: `init_state(stat_rows=False)`; what was counted into
+    # `acc` for a row the block lacks is dead code to the compiler)
     rows = [
         acc[k] if k in acc else jnp.zeros(C, jnp.int32)
-        for k in COUNTER_NAMES
+        for k in BLOCK_NAMES[:n_rows]
     ]
     return jnp.stack(rows)
 
@@ -732,7 +740,9 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
             cycles_c = cycles_c + jnp.sum(
                 jnp.where(retire_k, cost_k, 0), axis=1
             )
-            ptr_c = ptr_c + jnp.sum(retire_k, axis=1).astype(jnp.int32)
+            n_retired = jnp.sum(retire_k, axis=1).astype(jnp.int32)
+            ptr_c = ptr_c + n_retired
+            _count(acc, "run_events", n_retired)
             _count(acc, "l1_read_hits", jnp.sum(r_hit_k & retire_k, axis=1))
             _count(acc, "l1_write_hits", jnp.sum(w_hit_k & retire_k, axis=1))
             _count(
@@ -761,10 +771,13 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
 
 
 def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
-           ptr_c, quantum_end, pev, run_patch, deadb, mesh=None) -> Request:
+           ptr_c, quantum_end, pev, run_patch, deadb, acc,
+           mesh=None) -> Request:
     """Phases 0.9 and 1: the event each core arbitrates with (the
     candidate after its local run), its L1 probe, the parse of its home
-    set's directory row, and its classification -> the `Request`.
+    set's directory row, and its classification -> the `Request`; and
+    into `acc` the three stat rows that say where this step's core-steps
+    went (the fourth share, cores at END, is what they leave of C).
     Under `step_impl="pallas"` the probe, the parse and the victim choice
     are ONE kernel (`probe_classify`) and the record carries its lanes."""
     C, B = cfg.n_cores, cfg.n_banks
@@ -847,6 +860,15 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
         active = not_done & ~frozen & (cycles_c < quantum_end)
         if cfg.faults_enabled:
             active = active & ~deadb
+        with jax.named_scope(_STAT):
+            _count(acc, "slot_active", active)
+            # a core-step that presents no event: frozen at a barrier, or
+            # ahead of the quantum window and waiting for the laggards;
+            # what is left of C is at END (or fail-stopped)
+            idle = not_done if deadb is None else not_done & ~deadb
+            _count(acc, "slot_frozen", idle & frozen)
+            _count(acc, "slot_quantum",
+                   idle & ~frozen & ~(cycles_c < quantum_end))
 
         is_ins = active & (et == EV_INS)
         is_st_ev = et == EV_ST
@@ -1625,6 +1647,28 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
             jnp.where(home_txn, extra_home, 0)
             + (jnp.where(is_barrier, extra_bar, 0) if has_sync else 0),
         )
+        with jax.named_scope(_STAT):
+            # the real entries of the sort above, lane by lane (a path
+            # holds as many links as it has hops: `ok_all.sum(1)`, without
+            # the reduction), and the power of two that would have held
+            # this step's, all lanes together: their number is where the
+            # masked entries' run starts in the sorted order, so it costs
+            # no reduction, and on a mesh no collective
+            entries = jnp.where(home_txn, req_hops + rep_hops, 0)
+            if has_sync:
+                entries = entries + jnp.where(is_barrier, arr_hops, 0)
+            _count(acc, "noc_entries", entries)
+            # lane b holds (2^(b-1), 2^b], lane 0 none or one, the last
+            # lane the rest: two constant vectors and one compare of each
+            # with the count, no scalar arithmetic
+            lane = np.arange(cfg.n_cores, dtype=np.int64)
+            above = np.where(lane == 0, -1, 1 << np.clip(lane - 1, 0, 31))
+            upto = 1 << np.minimum(lane, 31)
+            upto[-1] = INT32_MAX
+            above, upto = (np.minimum(x, INT32_MAX) for x in (above, upto))
+            n = runs.n_real
+            _count(acc, "noc_sort_log2",
+                   (n > above.astype(np.int32)) & (n <= upto.astype(np.int32)))
     return raw_rt, raw_arr, extra_home, extra_bar, link_free_n
 
 
@@ -2259,12 +2303,13 @@ def _commit_end(cfg: MachineConfig, st: MachineState, arange_c, rq: Request,
             l1_n, delta_row, counters = commit_step(
                 cfg, st.l1, rq.meta_rows, rq.tag_rows, rq.shw, commit_lanes,
                 arange_c, st.step, st.counters,
-                _counter_deltas(acc, cfg.n_cores),
+                _counter_deltas(acc, cfg.n_cores, st.counters.shape[0]),
                 *(run_patch or ()),
             )
             dirm_n = st.dirm.at[upd_slot].add(delta_row, mode="drop")
         else:
-            counters = st.counters + _counter_deltas(acc, cfg.n_cores)
+            counters = st.counters + _counter_deltas(
+                acc, cfg.n_cores, st.counters.shape[0])
     return l1_n, dirm_n, counters
 
 
@@ -2298,7 +2343,7 @@ def step(
     quantum_end, cycles_c, ptr_c, pev, run_patch = _local(
         cfg, events, st, arange_c, deadb, acc, mesh)
     rq = _probe(cfg, events, st, arange_c, cycles_c, ptr_c, quantum_end, pev,
-                run_patch, deadb, mesh)
+                run_patch, deadb, acc, mesh)
     winner, join, key = _arb(cfg, kn, arange_c, rq, cycles_c, quantum_end, acc)
     (ctile, btile, htile, bid, home_txn, req_lat, req_hops, rep_lat, rep_hops,
      flt) = _dir_legs(cfg, kn, st.faults, arange_c, rq, winner, join, has_sync)

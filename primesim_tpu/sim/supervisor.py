@@ -449,6 +449,7 @@ class RunSupervisor:
                 else eng.cycle_base
             ),
             "host_counters": {k: v.copy() for k, v in eng.host_counters.items()},
+            "host_stats": {k: v.copy() for k, v in eng.host_stats.items()},
         }
         if self.kind == "stream":
             snap["cursor"] = eng.cursor.copy()
@@ -464,6 +465,7 @@ class RunSupervisor:
         eng.steps_run = snap["steps_run"]
         eng.cycle_base = snap["cycle_base"]
         eng.host_counters = snap["host_counters"]
+        eng.host_stats = snap["host_stats"]
         if self.kind == "stream":
             eng.cursor = snap["cursor"]
         if "attest" in snap and getattr(eng, "attest", None) is not None:

@@ -49,7 +49,8 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
     cfg, chunk_steps, conf_devices = load_config(conf_path)
     devices = devices or conf_devices
     topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
-    st = jax.eval_shape(lambda: init_state(cfg))
+    # as `build_state` lays the block out: no stat rows on a mesh
+    st = jax.eval_shape(lambda: init_state(cfg, stat_rows=devices == 1))
     if devices > 1:
         mesh = Mesh(np.asarray(topo.devices[:devices]), (sharding.AXIS,))
         place = lambda spec: NamedSharding(mesh, spec)  # noqa: E731

@@ -401,7 +401,7 @@ def test_fsck_pool_unit_key_consistency(tmp_path):
 
 def _solo_npz(path, rows=None):
     from primesim_tpu.sim.checkpoint import _FORMAT, atomic_save_npz
-    from primesim_tpu.stats.counters import COUNTER_NAMES
+    from primesim_tpu.stats.counters import N_BLOCK_ROWS
 
     atomic_save_npz(
         str(path),
@@ -411,7 +411,7 @@ def _solo_npz(path, rows=None):
         config_json=np.frombuffer(b"{}", dtype=np.uint8),
         trace_sha=np.frombuffer(b"ab" * 32, dtype=np.uint8),
         state_counters=np.zeros(
-            (rows if rows is not None else len(COUNTER_NAMES), 4),
+            (rows if rows is not None else N_BLOCK_ROWS, 4),
             np.int32,
         ),
     )
@@ -437,7 +437,7 @@ def test_fsck_checkpoint_counter_rows(tmp_path):
 
 def test_fsck_warm_entry_and_sidecar(tmp_path):
     from primesim_tpu.sim.checkpoint import _FORMAT, atomic_save_npz
-    from primesim_tpu.stats.counters import COUNTER_NAMES
+    from primesim_tpu.stats.counters import N_BLOCK_ROWS
 
     key = "ab" * 32
     atomic_save_npz(
@@ -446,8 +446,8 @@ def test_fsck_warm_entry_and_sidecar(tmp_path):
         steps=np.int64(512), cycle_base=np.int64(0),
         steps_run=np.int64(512),
         trace_sha=np.frombuffer(b"cd" * 32, dtype=np.uint8),
-        state_counters=np.zeros((len(COUNTER_NAMES), 4), np.int32),
-        host_counters=np.zeros((len(COUNTER_NAMES), 4), np.int64),
+        state_counters=np.zeros((N_BLOCK_ROWS, 4), np.int32),
+        host_counters=np.zeros((N_BLOCK_ROWS, 4), np.int64),
     )
     meta = {"cfg_key": "ef" * 32, "key": key, "trace_sha": "cd" * 32,
             "steps": 512}

@@ -30,7 +30,8 @@ _MESH = {"mesh_x": 4, "mesh_y": 4}
 
 # machine -> (config, has_sync, the optional phases it enables); every
 # machine runs local, probe, arb, dir, commit and chunk
-ALWAYS = {"s.local", "s.probe", "s.arb", "s.dir", "s.commit", "s.chunk"}
+ALWAYS = {"s.local", "s.probe", "s.probe/stat", "s.arb", "s.dir", "s.commit",
+          "s.chunk"}
 MACHINES = {
     # no contention model, no DRAM queue, a trace without locks or barriers
     "plain": (dict(n_cores=N, n_banks=N, noc=_MESH, local_run_len=8), False,
@@ -41,8 +42,8 @@ MACHINES = {
              core={"cpi": 1, "o3_overlap_256": 128},
              noc=dict(_MESH, contention=True, contention_model="router",
                       contention_lat=1)),
-        True, {"s.noc", "s.noc/rank", "s.dram", "s.dram/rank", "s.sync",
-               "s.sync/lock", "s.sync/barrier"},
+        True, {"s.noc", "s.noc/rank", "s.noc/stat", "s.dram", "s.dram/rank",
+               "s.sync", "s.sync/lock", "s.sync/barrier"},
     ),
     # the tile-count contention model (no ranking) on a faulty machine
     "faulty": (

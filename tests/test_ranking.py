@@ -389,3 +389,22 @@ def test_fleet_vmapped_link_values_match_solo():
         np.testing.assert_array_equal(
             np.asarray(batched[1][b])[valid], want_floor[valid])
         np.testing.assert_array_equal(np.asarray(batched[2][b]), want_raised)
+
+
+@pytest.mark.parametrize("method", ["packed", "lex"])
+@pytest.mark.parametrize("mask_p", [0.0, 0.4, 1.0])
+def test_sorted_runs_count_their_real_entries(method, mask_p):
+    """`SortedRuns.n_real`, read off where the masked entries' run starts
+    in the sorted order, is the number of entries that are not masked:
+    also where none is masked (the last run is a real segment's) and where
+    all are (the masked run starts right after the table entries)."""
+    rng = np.random.default_rng(11)
+    C, S, n_seg = 16, 6, 24
+    seg = _unique_segs(rng, C, S, n_seg, mask_p=mask_p)
+    key = rng.integers(0, 50, C).astype(np.int32)
+    _, _, runs = segmented_rank_floor(
+        jnp.asarray(seg), jnp.asarray(_clocks(rng, seg.shape)),
+        jnp.asarray(_clocks(rng, n_seg)), order=lane_order(jnp.asarray(key)),
+        method=method)
+    assert int(runs.n_real) == int((seg < n_seg).sum())
+    assert int(runs.n_real) == {0.0: C * S, 1.0: 0}.get(mask_p, int(runs.n_real))
