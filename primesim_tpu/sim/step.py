@@ -223,6 +223,42 @@ def _l1_set_read(cfg: MachineConfig, l1, sets, planes):
     return jnp.stack(words, axis=2).reshape(*sets.shape, len(planes), W1)
 
 
+def _l1_row_write(cfg: MachineConfig, l1, writes):
+    """`l1` with `writes` applied, each core editing its own row: the
+    write-side twin of `_l1_set_read`. `writes` is a list of
+    `(plane, mask, col, val)`: a static plane number of the fused L1
+    array, the cores that write [C] (or [C, K]: K writes a core), the
+    column within the plane and the word (both broadcast to the mask).
+
+    Every write of core `c` lands in row `c`, so this is no scatter across
+    rows: each plane `l1[:, p * FS : (p + 1) * FS]` (a static, tile-aligned
+    view of the row; a reshape that put the plane or S1 on an axis of its
+    own would re-tile the array) is compared against the columns of its
+    own writes alone, a masked lane comparing against -1, and written back
+    where it lay by a static `dynamic_update_slice`, which XLA does in
+    place: one fusion a plane, the loop's carry edited and never copied
+    (a `concatenate` of the planes compiles to a 168 MB copy a step on
+    rung 5; one select over the full width compares every column against
+    every plane's writes). Dense vector work at the array's bandwidth:
+    19.7 us at 1024 cores x 2560 columns, 531 us at 16384, where the one
+    element scatter it replaced took 135 and 3777 (`prof_gather.py
+    writes`, PERF.md section 6, PR 38). Later writes of a plane win over
+    earlier ones; `_l1_writes` hands none that differ on one word."""
+    FS = cfg.l1.ways * cfg.l1.sets
+    iota = jnp.arange(FS, dtype=jnp.int32)
+    for p in sorted({plane for plane, _, _, _ in writes}):
+        new = jax.lax.slice_in_dim(l1, p * FS, (p + 1) * FS, axis=1)
+        for plane, mask, col, val in writes:
+            if plane != p:
+                continue
+            at = jnp.where(mask, col, -1).reshape(mask.shape[0], -1)  # [C, K]
+            val = jnp.broadcast_to(val, mask.shape).reshape(at.shape)
+            for k in range(at.shape[1]):
+                new = jnp.where(iota == at[:, k, None], val[:, k, None], new)
+        l1 = jax.lax.dynamic_update_slice(l1, new, (0, p * FS))
+    return l1
+
+
 def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
               run_patch=None, step_no=None, mesh=None):
     """Gather the accessed L1 set and derive each way's EFFECTIVE MESI state.
@@ -269,7 +305,7 @@ def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
     eph_rows = rows[:, 4] if cfg.sharer_group > 1 else None
     if run_patch is not None:
         # the local run's deferred L1 writes (applied only in phase 4.A's
-        # fused scatter) patched in-register: silent E->M at wm columns,
+        # one row write) patched in-register: silent E->M at wm columns,
         # LRU stamps at hm columns (tag/ptr/epoch planes never change
         # during a run)
         hm, wm, cm = run_patch
@@ -761,10 +797,10 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
             wm = w_hit_k & retire_k
             cm = phitcol[:, :rl]
             # The run's L1 writes (LRU refreshes, silent E->M) are DEFERRED
-            # all the way into phase 4.A's single fused scatter: a second
-            # scatter chained on the same array cannot alias its operand and
-            # re-materializes it (the 5 ms/step join-lru lesson). Phase 1
-            # patches the prefetched planes in-register instead.
+            # all the way into phase 4.A's one write of the L1 array
+            # (`_l1_row_write`): every plane is read and written once a
+            # step. Phase 1 patches the prefetched planes in-register
+            # instead.
     if not rl:
         return quantum_end, cycles_c, ptr_c, None, None
     return quantum_end, cycles_c, ptr_c, pev, (hm, wm, cm)
@@ -1786,31 +1822,113 @@ def _commit_retire(cfg: MachineConfig, kn, rq: Request, dr: DirOutcome,
     return cycles, ptr, grant, hit, req_hops, rep_hops
 
 
+def _l1_writes(cfg: MachineConfig, step_no, arange_c, rq: Request,
+               dr: DirOutcome, winner, join, grant, hit, run_patch, new_eph,
+               acc):
+    """Every L1 write of the step as `(plane, mask, col, val)` for
+    `_l1_row_write`: the seven of phase 4 (hit refresh, grant or silent
+    E->M, the fill's tag, way pointer and post-bump entry epoch `new_eph`,
+    the clear of a stale duplicate's tag and state) and, with local runs,
+    the runs' deferred LRU stamps and E->M writes (`run_patch`), [C, rl]
+    each: 7 + 2*rl words a core, at most. Counts the victim's writeback.
+
+    A core writes at most TWO ways of its accessed set — the retired way,
+    and (for fills) a stale duplicate of the filled tag — and the ways its
+    run hit; columns are way*S1 + set within a plane."""
+    S1, W2 = cfg.l1.sets, cfg.llc.ways
+    line, l1s, slot, hit_way = rq.line, rq.l1s, rq.slot, rq.hit_way
+    tag_rows, lru_rows, weff = rq.tag_rows, rq.lru_rows, rq.weff
+    write_hit, upg, llc_hway = rq.write_hit, rq.upg, rq.llc_hway
+    llc_hit, llc_vway = dr.llc_hit, dr.llc_vway
+    TAG, STATE, LRU, PTR, EPOCH = range(5)  # planes of the fused L1 array
+
+    # winner L1 update: UPG-in-place vs fill. Victim preference counts
+    # directory-invalidated (stale) ways as free, matching eager-MESI's
+    # invalid-first rule; the victim writeback fires only on EFFECTIVE M.
+    upg_in_place = upg & winner  # upg requires an L1 hit: always in-place
+    fill = (winner & ~upg_in_place) | join
+    l1_vkey = jnp.where(weff == I, -1, lru_rows)  # lru_rows from the probe
+    l1_vway = jnp.argmin(l1_vkey, axis=1).astype(jnp.int32)
+    _count(acc, "l1_writebacks", fill & (weff[arange_c, l1_vway] == M))
+    upd_way = jnp.where(upg_in_place, hit_way, l1_vway)
+    hit_col = hit_way * S1 + l1s
+    upd_col = upd_way * S1 + l1s
+
+    # a fill may duplicate a stale way's tag: clear the stale copy so tags
+    # stay unique per set (else the refill could "resurrect" it, since the
+    # directory once again records this core for the line); uniqueness also
+    # means at most one duplicate way exists
+    tagm = tag_rows == line[:, None]  # [C, W1], any state
+    t_way = jnp.argmax(tagm, axis=1).astype(jnp.int32)
+    dup = fill & jnp.any(tagm, axis=1) & (t_way != upd_way)
+    dup_col = t_way * S1 + l1s
+
+    wj = winner | join
+    lru_col = jnp.where(hit, hit_col, upd_col)
+    st_own = write_hit | wj  # silent E->M + grants
+    st_col = jnp.where(write_hit, hit_col, upd_col)
+    st_val = jnp.where(write_hit, M, grant)
+    # the filled line's directory entry position (way pointer); joins and
+    # LLC hits fill at the line's hit way, misses at the victim
+    fill_ptr = slot * W2 + jnp.where(join | llc_hit, llc_hway, llc_vway)
+    # Targets are pairwise distinct up to benign identical-value
+    # duplicates, so the order of the writes is free: dup_col != upd_col (a
+    # duplicate is a different way than the fill target), hit refresh and
+    # grant rows are disjoint lane classes, each write addresses its own
+    # plane, run-LRU duplicates of phase-4 LRU writes carry the identical
+    # step stamp, and a run E->M colliding with a phase-4 state write at the
+    # same way is SUPPRESSED (phase 4 wrote after the run in the serialized
+    # order, so its value wins).
+    writes = [
+        (TAG, dup, dup_col, -1),  # stale duplicate tag clear
+        (STATE, dup, dup_col, I),  # stale duplicate state clear
+        (LRU, hit | wj, lru_col, step_no),  # hit refresh / fill LRU stamp
+        (STATE, st_own, st_col, st_val),  # silent E->M + grant state
+        (TAG, wj, upd_col, line),  # fill tag
+        (PTR, wj, upd_col, fill_ptr),  # fill way pointer
+        (EPOCH, wj, upd_col, new_eph),  # fill-time entry epoch (post-bump)
+    ]
+    if cfg.local_run_len:
+        hm, wm, cm = run_patch
+        run_m_sup = wm & ~(st_own[:, None] & (st_col[:, None] == cm))
+        writes += [(LRU, hm, cm, step_no), (STATE, run_m_sup, cm, M)]
+    return writes
+
+
 def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
                    rq: Request, dr: DirOutcome, winner, join, key, grant, hit,
                    run_patch, acc):
     """Phase 4.A's array writes: every L1 write of the step (hit
     refreshes, grants and fills, stale-duplicate clears, the local runs'
-    deferred writes) in one scatter, and the directory's in one row
-    scatter-add -> (`l1_n`, `dirm_n`, None). Under `step_impl="pallas"`
-    nothing is written here: -> (None, None, (`commit_lanes`,
-    `upd_slot`)), the per-core lane block [C, CL_*] and the target rows
-    that `_commit_end`'s one kernel call writes from.
+    deferred writes: `_l1_writes`) as each core's edit of its own row
+    (`_l1_row_write`, a select), and the directory's, a true cross-row
+    write, in one row scatter-add -> (`l1_n`, `dirm_n`, None). Under
+    `step_impl="pallas"` nothing is written here: -> (None, None,
+    (`commit_lanes`, `upd_slot`)), the per-core lane block [C, CL_*] and
+    the target rows that `_commit_end`'s one kernel call writes from.
+
+    The L1 write has ONE form. Until PR 38 it was one element scatter of
+    all C x (7 + 2*rl) words, which on the v5e costs 5.7-10 ns a WORD and
+    draws a flat relayout of the whole array (and from 4096 cores an
+    index sort) round it; the select costs what the array's bytes cost.
+    `scripts/prof/prof_gather.py writes`, us a write of 23 words a core,
+    five planes, select against scatter: FS = W1*S1 512 (every BASELINE
+    rung) 19.7 / 135 at 1024 cores, 531 / 3777 at 16384; FS 2048 34.5 /
+    190 and 2067 / 8388; FS 8192 (the zoo's IPU tile, 1472 cores) 744 /
+    4366, 8236 / 50449 at 16384. The scatter wins nowhere, so no size
+    branch (scripts/prof/README.md has the table).
 
     No phase 4.B: under pull-based coherence the directory update IS the
     invalidations/downgrades — remote L1s re-derive their state on their
     next access (phase 1 validation)."""
     C, B = cfg.n_cores, cfg.n_banks
-    S1, W1 = cfg.l1.sets, cfg.l1.ways
     S2, W2 = cfg.llc.sets, cfg.llc.ways
     NW = cfg.n_sharer_words
     MW = llc_meta_width(cfg)
-    FS = W1 * S1  # plane stride in the fused L1 array
     logG = cfg.sharer_group.bit_length() - 1
-    rl = cfg.local_run_len
-    l1_c, step_no = st.l1, st.step
-    line, l1s, slot, hit_way = rq.line, rq.l1s, rq.slot, rq.hit_way
-    tag_rows, lru_rows, weff = rq.tag_rows, rq.lru_rows, rq.weff
+    step_no = st.step
+    line, slot, hit_way = rq.line, rq.slot, rq.hit_way
+    lru_rows, weff = rq.lru_rows, rq.weff
     meta_rows, llc_hway, shw = rq.meta_rows, rq.llc_hway, rq.shw
     word_idx, bit_idx = rq.word_idx, rq.bit_idx
     write_hit, upg = rq.write_hit, rq.upg
@@ -1819,8 +1937,6 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
     gets_probe, gets_shared, gets_excl_hit = (
         dr.gets_probe, dr.gets_shared, dr.gets_excl_hit)
     oclamp, llc_vway, llc_lru_rows = dr.oclamp, dr.llc_vway, dr.llc_lru_rows
-    if rl:
-        hm, wm, cm = run_patch
     with jax.named_scope(P_COMMIT):
         if cfg.step_impl == "pallas":
             # [PALLAS] fused commit (DESIGN.md §11): victim choice and the
@@ -1872,45 +1988,6 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
             )  # column order = kernels.step_kernels CL_* indices
             return None, None, (commit_lanes, upd_slot)
         else:
-            # L1-side updates touch at most TWO (row, column) slots per core — the
-            # retired way, and (for fills) a stale duplicate of the filled tag —
-            # so each is a [C]-element scatter into the [C, W1*S1] arrays, not a
-            # full-array one-hot select (which rewrites 4x8MB per step at 1024
-            # cores). Rows are the core's own, columns flat way*S1 + set; masked
-            # lanes scatter to dropped row C.
-
-            # winner L1 update: UPG-in-place vs fill. Victim preference counts
-            # directory-invalidated (stale) ways as free, matching eager-MESI's
-            # invalid-first rule; the victim writeback fires only on EFFECTIVE M.
-            upg_in_place = upg & winner  # upg requires an L1 hit: always in-place
-            fill = (winner & ~upg_in_place) | join
-            l1_vkey = jnp.where(weff == I, -1, lru_rows)  # lru_rows from the probe
-            l1_vway = jnp.argmin(l1_vkey, axis=1).astype(jnp.int32)
-            _count(acc, "l1_writebacks", fill & (weff[arange_c, l1_vway] == M))
-            upd_way = jnp.where(upg_in_place, hit_way, l1_vway)
-            hit_col = hit_way * S1 + l1s
-            upd_col = upd_way * S1 + l1s
-
-            # a fill may duplicate a stale way's tag: clear the stale copy so tags
-            # stay unique per set (else the refill could "resurrect" it, since the
-            # directory once again records this core for the line); uniqueness also
-            # means at most one duplicate way exists
-            tagm = tag_rows == line[:, None]  # [C, W1], any state
-            t_way = jnp.argmax(tagm, axis=1).astype(jnp.int32)
-            dup = fill & jnp.any(tagm, axis=1) & (t_way != upd_way)
-            dup_row = jnp.where(dup, arange_c, C)
-            dup_col = t_way * S1 + l1s
-
-            wj = winner | join
-            lru_row = jnp.where(hit | wj, arange_c, C)
-            lru_col = jnp.where(hit, hit_col, upd_col)
-            st_row = jnp.where(write_hit | wj, arange_c, C)  # silent E->M + grants
-            st_col = jnp.where(write_hit, hit_col, upd_col)
-            st_val = jnp.where(write_hit, M, grant)
-            wj_row = jnp.where(wj, arange_c, C)
-            # the filled line's directory entry position (way pointer); joins and
-            # LLC hits fill at the line's hit way, misses at the victim
-            fill_ptr = slot * W2 + jnp.where(join | llc_hit, llc_hway, llc_vway)
             # invalidation epoch: every sharer-CLEARING transition (M grants,
             # exclusive grants, fills — exactly the owner-taking ones) bumps the
             # entry's epoch so coarse-vector validation can reject pre-clearing
@@ -1921,62 +1998,12 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
             eph_rows2 = meta_rows[:, 3 * W2 : 4 * W2]  # [C, W2]
             eph_way = jnp.where(join, llc_hway, llc_uway)
             new_eph = eph_rows2[arange_c, eph_way] + takes_own.astype(jnp.int32)
-            # ALL of this step's L1 writes — the seven phase-4 columns AND the
-            # local run's deferred LRU/E->M writes — in ONE scatter on the fused
-            # plane array (per-kernel overhead dominates, and a second scatter
-            # chained on the same array cannot alias its operand). Targets are
-            # pairwise distinct up to benign identical-value duplicates:
-            # dup_col != upd_col (a duplicate is a different way than the fill
-            # target), hit refresh and grant rows are disjoint lane classes, each
-            # write addresses its own plane, run-LRU duplicates of phase-4 LRU
-            # writes carry the identical step stamp, and a run E->M colliding
-            # with a phase-4 state write at the same way is SUPPRESSED (phase 4
-            # wrote after the run in the serialized order, so its value wins).
-            l1_rows = [dup_row, dup_row, lru_row, st_row, wj_row, wj_row, wj_row]
-            l1_cols = [
-                dup_col,  # stale duplicate tag clear
-                dup_col + FS,  # stale duplicate state clear
-                lru_col + 2 * FS,  # hit refresh / fill LRU stamp
-                st_col + FS,  # silent E->M + grant state
-                upd_col,  # fill tag
-                upd_col + 3 * FS,  # fill way pointer
-                upd_col + 4 * FS,  # fill-time entry epoch (post-bump)
-            ]
-            l1_vals = [
-                jnp.full(C, -1, jnp.int32),
-                jnp.full(C, I, jnp.int32),
-                jnp.broadcast_to(step_no, (C,)),
-                st_val,
-                line,
-                fill_ptr,
-                new_eph,
-            ]
-            rows_mat = jnp.stack(l1_rows, axis=1)
-            cols_mat = jnp.stack(l1_cols, axis=1)
-            vals_mat = jnp.stack(l1_vals, axis=1)
-            if rl:
-                own_state_write = (st_row == arange_c)
-                run_m_sup = wm & ~(own_state_write[:, None] & (st_col[:, None] == cm))
-                rows_mat = jnp.concatenate(
-                    [
-                        rows_mat,
-                        jnp.where(hm, arange_c[:, None], C),
-                        jnp.where(run_m_sup, arange_c[:, None], C),
-                    ],
-                    axis=1,
-                )
-                cols_mat = jnp.concatenate(
-                    [cols_mat, cm + 2 * FS, cm + FS], axis=1
-                )
-                vals_mat = jnp.concatenate(
-                    [
-                        vals_mat,
-                        jnp.broadcast_to(step_no, (C, rl)),
-                        jnp.full((C, rl), M, jnp.int32),
-                    ],
-                    axis=1,
-                )
-            l1_n = l1_c.at[rows_mat, cols_mat].set(vals_mat, mode="drop")
+            l1_n = _l1_row_write(
+                cfg,
+                st.l1,
+                _l1_writes(cfg, step_no, arange_c, rq, dr, winner, join, grant,
+                           hit, run_patch, new_eph, acc),
+            )
 
             # Directory update: ONE full-row scatter-ADD covers the winner's
             # whole row — tags, owner, LRU, epoch, AND sharer words — plus every

@@ -3,6 +3,7 @@ array-layout choices and behind `sim/step.py::_l1_set_read` having one form.
 
     python scripts/prof/prof_gather.py          # the L1 set read's two forms
     python scripts/prof/prof_gather.py rows     # the probe's way read: rows / elements
+    python scripts/prof/prof_gather.py writes   # phase 4.A's L1 write: select / scatter
     python scripts/prof/prof_gather.py raw      # row / element gather, row scatter
 
 Default: the L1 set read's two forms alone (select: `_l1_set_read`, the core's
@@ -24,6 +25,17 @@ widest), inside one `fori_loop` on pointers that change every iteration. The
 evidence that `_validate_ways` needs no second form, and where the two would
 cross (PERF.md section 6, PR 36).
 
+`writes`: phase 4.A's write of the fused L1 array (`sim/step.py::
+_commit_writes`), 7 + 2 * 8 = 23 words a core, in the form the step has (each
+core edits its own row: `_l1_row_write`, a compare-select over each plane of
+the row, written back in place) against the ONE element scatter of all C x 23
+(row, column) pairs that it replaced in PR 38, kept here as `scatter_write`; at
+FS = W1 * S1 in {512, 2048, 8192} x C in {1024, 1472, 4096, 16384}, an array of
+four planes and of five, inside one `fori_loop` on columns that change every
+iteration; us a write, ns a scattered word, and whether the scatter's compiled
+text relays the array flat (`relay`) or sorts its indices (`sort`). The
+evidence for which form `_commit_writes` takes (PERF.md section 6, PR 38).
+
 `raw`: cost against index count, row width and operand size. Hypothesis from
 single-op ablations of the step: cost ~= per-INDEX overhead, mostly independent
 of row width and operand bytes; windowed (dynamic column) forms are
@@ -41,8 +53,8 @@ ITER = 50
 W1 = 4
 
 
-def timeit(fn, *args, n=20):
-    f = jax.jit(fn)
+def timeit(fn, *args, n=20, jit=True):
+    f = jax.jit(fn) if jit else fn
     out = f(*args)
     jax.block_until_ready(out)
     t0 = time.perf_counter()
@@ -175,6 +187,82 @@ def way_read_forms(widths=(768, 1536, 4608, 8704, 16896),
         del dirm
 
 
+def scatter_write(cfg, l1, writes):
+    """`_l1_row_write` as `_commit_writes` wrote until PR 38: ONE element
+    scatter of every (row, column, word), a masked lane's to the dropped
+    row C."""
+    C, FS = l1.shape[0], cfg.l1.ways * cfg.l1.sets
+    own = jnp.arange(C, dtype=jnp.int32)[:, None]
+    rows, cols, vals = [], [], []
+    for plane, mask, col, val in writes:
+        rows.append(jnp.where(mask.reshape(C, -1), own, C))
+        cols.append(jnp.broadcast_to(col, mask.shape).reshape(C, -1) + plane * FS)
+        vals.append(jnp.broadcast_to(val, mask.shape).reshape(C, -1))
+    return l1.at[jnp.concatenate(rows, 1), jnp.concatenate(cols, 1)].set(
+        jnp.concatenate(vals, 1), mode="drop")
+
+
+def write_forms(strides=(512, 2048, 8192), cores=(1024, 1472, 4096, 16384),
+                rl=8):
+    import re
+
+    from primesim_tpu.config.machine import CacheConfig, MachineConfig
+    from primesim_tpu.sim.step import _l1_row_write
+
+    rng = np.random.default_rng(0)
+    K = 7 + 2 * rl
+    print(f"device {jax.devices()[0].device_kind}; us an iteration, {ITER} in "
+          f"a loop; W1 {W1}, {K} words a core")
+    print("    FS      C planes  select_us  scatter_us  scatter_ns_word  "
+          "scatter's text")
+    for FS in strides:
+        S1 = FS // W1
+        # only `l1.sets` and `l1.ways` are read
+        cfg = MachineConfig(l1=CacheConfig(FS * 64, W1, 64, 2))
+        for C in cores:
+            for NP in (4, 5):
+                l1 = jax.lax.bitcast_convert_type(
+                    jax.random.bits(jax.random.key(FS + C), (C, NP * FS),
+                                    jnp.uint32), jnp.int32)
+                seeds = jnp.asarray(
+                    rng.integers(0, 1 << 20, (3, C, K), dtype=np.int32))
+
+                def writes_of(i, seeds):
+                    """The step's writes: seven of phase 4 in one set (two
+                    ways), a run's `rl` stamps and `rl` E->M; half masked."""
+                    col, val, live = seeds[0] + i * 7, seeds[1] ^ i, seeds[2] + i
+                    sets = col[:, 0] & (S1 - 1)
+                    way = lambda k: ((col[:, k] >> 12) % W1) * S1 + sets  # noqa: E731
+                    on = lambda k: (live[:, k] & 1) == 1  # noqa: E731
+                    run = slice(7, 7 + rl), slice(7 + rl, K)
+                    phase4 = [(0, 1), (1, 1), (2, 0), (1, 0), (0, 0), (3, 0),
+                              (4, 0)]  # (plane, which of the two ways)
+                    return [(p, on(k), way(w), val[:, k])
+                            for k, (p, w) in enumerate(phase4) if p < NP] + [
+                        (2, (live[:, run[0]] & 1) == 1, col[:, run[0]] % FS, i),
+                        (1, (live[:, run[1]] & 1) == 1, col[:, run[0]] % FS, 3)]
+
+                us, text = {}, ""
+                for name, form in (("select", _l1_row_write),
+                                   ("scatter", scatter_write)):
+                    def loop(l1, seeds, form=form):
+                        return jax.lax.fori_loop(
+                            0, ITER,
+                            lambda i, l1: form(cfg, l1, writes_of(i, seeds)), l1)
+                    compiled = jax.jit(loop).lower(l1, seeds).compile()
+                    us[name] = timeit(compiled, l1, seeds, n=3, jit=False) / ITER * 1e6
+                    if name == "scatter":
+                        text = compiled.as_text()
+                words = C * (K - (NP < 5))
+                found = [w for w, pat in (
+                    ("relay", rf"s32\[{C * NP * FS}\]\S* (?:reshape|bitcast|copy|fusion)\("),
+                    ("sort", r" sort\(")) if re.search(pat, text)]
+                print(f"{FS:6d} {C:6d} {NP:6d} {us['select']:10.1f} "
+                      f"{us['scatter']:11.1f} {us['scatter'] * 1e3 / words:16.2f}  "
+                      f"{' '.join(found) or '-'}", flush=True)
+                del l1
+
+
 def raw():
     rng = np.random.default_rng(0)
     R = 524288
@@ -200,5 +288,5 @@ def raw():
 
 
 if __name__ == "__main__":
-    {("rows",): way_read_forms, ("raw",): raw}.get(
+    {("rows",): way_read_forms, ("writes",): write_forms, ("raw",): raw}.get(
         tuple(sys.argv[1:]), set_read_forms)()

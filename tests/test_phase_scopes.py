@@ -249,6 +249,25 @@ def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
         cfg.n_cores, cfg.l1.ways * cfg.n_cores]
 
 
+@pytest.mark.parametrize("machine", ["plain", "coarse", "rung3"])
+def test_step_edits_the_l1_row_without_a_scatter(machine):
+    """The write-side twin: each core edits its own L1 row by a select over
+    the row's planes (`_l1_row_write`), with four planes read or, under the
+    coarse vector, five, with and without sync events: no `scatter*` of
+    `step` has the L1 array as its operand. What phase 4.A still scatters
+    is the directory, a true cross-row write: exactly one row `scatter-add`
+    with C indices."""
+    cfg, eng = build(machine)
+    shapes = {"l1": eng.state.l1.shape, "dirm": eng.state.dirm.shape}
+    assert shapes["l1"] != shapes["dirm"]
+    scatters = [op for op in indexed_ops(machine) if op[0].startswith("scatter")]
+    assert any("s.commit" in p for _, p, _, _, _ in scatters)  # the walk sees scopes
+    assert not [(name, p) for name, p, shape, _, _ in scatters
+                if shape == shapes["l1"]]
+    assert [(name, n) for name, p, shape, n, _ in scatters
+            if shape == shapes["dirm"]] == [("scatter-add", cfg.n_cores)]
+
+
 def test_rung3_walk_indexes_no_table_entry_by_entry():
     """The router walk's per-link state rides the rank's sorted order
     (`segmented_rank_floor`, `segmented_table_max`): under `s.noc` no
@@ -264,7 +283,9 @@ def test_rung3_walk_indexes_no_table_entry_by_entry():
     assert n_links(cfg) < n_slots
     indexed = indexed_ops("rung3")
     assert any("s.noc" in p for _, p, _, _, _ in indexed)  # the walk sees scopes
-    assert any(n >= n_slots for _, _, _, n, _ in indexed)  # and counts indices
+    # and counts indices: the probe's way rows, W1 a core
+    assert any(n == cfg.l1.ways * cfg.n_cores for _, p, _, n, _ in indexed
+               if "s.probe" in p)
     assert not [(name, p, n) for name, p, _, n, _ in indexed
                 if "s.noc" in p and n > n_links(cfg)]
 
