@@ -21,6 +21,9 @@ expected statistics are analyzable:
 - ``ocean_like``      — SPLASH-2 OCEAN's multigrid solver: square subgrids,
                         border exchange with four neighbours, red-black
                         relaxations on a V-cycle, a barrier after every phase
+- ``ycsb_like``       — YCSB core workload A on a shared in-memory hash
+                        table: zipfian keys, whole-record reads, one-field
+                        updates in place (hot records serialise at their homes)
 
 All generators are deterministic given ``seed``.
 """
@@ -408,6 +411,124 @@ def ocean_like(
     return from_event_lists(per_core)
 
 
+_FNV_OFFSET_64, _FNV_PRIME_64 = 0xCBF29CE484222325, 0x100000001B3
+
+
+def _fnv1a_64(x: int) -> int:
+    """FNV-1a over the eight bytes of `x`, low byte first (YCSB's
+    `Utils.fnvhash64`, kept unsigned)."""
+    h = _FNV_OFFSET_64
+    for _ in range(8):
+        h = ((h ^ (x & 0xFF)) * _FNV_PRIME_64) & 0xFFFFFFFFFFFFFFFF
+        x >>= 8
+    return h
+
+
+_YCSB_ITEMS = 10_000_000_000  # `ScrambledZipfianGenerator.ITEM_COUNT`
+
+
+def _zeta(n: int, theta: float) -> float:
+    """The sum of i^-theta over 1 .. n: the first 2^20 terms added up, the
+    others by Euler-Maclaurin (to 1e-14). `_zeta(10**10, 0.99)` is YCSB's
+    `ScrambledZipfianGenerator.ZETAN`, 26.46902820178302."""
+    m = min(n, 1 << 20)
+    head = float(np.sum(np.arange(1, m + 1, dtype=np.float64) ** -theta))
+    if n == m:
+        return head
+    n, m = float(n), float(m)
+    return (head + (n ** (1.0 - theta) - m ** (1.0 - theta)) / (1.0 - theta)
+            + (n ** -theta - m ** -theta) / 2.0
+            - theta * (n ** (-theta - 1.0) - m ** (-theta - 1.0)) / 12.0)
+
+
+def _zipfian_ranks(u: np.ndarray, n: int, theta: float) -> np.ndarray:
+    """Ranks in [0, n) for uniform draws `u`, rank i with probability
+    near (i+1)^-theta / zetan: Gray et al.'s method (SIGMOD 1994) as
+    YCSB's `ZipfianGenerator.nextLong` computes it."""
+    zetan = _zeta(n, theta)
+    zeta2 = 1.0 + 0.5 ** theta
+    alpha = 1.0 / (1.0 - theta)
+    eta = (1.0 - (2.0 / n) ** (1.0 - theta)) / (1.0 - zeta2 / zetan)
+    tail = np.floor(n * (eta * u - eta + 1.0) ** alpha).astype(np.int64)
+    uz = u * zetan
+    return np.where(uz < 1.0, 0, np.where(uz < zeta2, 1, np.minimum(tail, n - 1)))
+
+
+def ycsb_like(
+    n_cores: int,
+    seed: int = 0,
+    ops_per_core: int = 16,
+    recordcount: int = 1000,
+    theta: float = 0.99,
+    read_frac: float = 0.5,
+    fieldcount: int = 10,
+    fieldlength: int = 100,
+    ins_per_mem: int = 3,
+    op_ins: int = 30,
+    line: int = 64,
+) -> Trace:
+    """YCSB core workload A ("update heavy": Cooper et al., SoCC 2010;
+    `workloads/workloada`, `CoreWorkload`) against a shared in-memory hash
+    table with in-place updates, every core a worker thread that serves
+    `ops_per_core` operations in a closed loop.
+
+    The key of an operation is drawn as `ScrambledZipfianGenerator` draws
+    it: a zipfian rank (`_zipfian_ranks`, constant `theta`) over that
+    class's fixed 10^10 items, whatever the table's size, scattered over
+    the records by record = FNV-1a-64(rank) mod `recordcount`; its bucket
+    is FNV-1a-64(record) mod 2 * `recordcount`. The store: an index of
+    2 * `recordcount` 8-byte bucket heads from address 0, then, from the
+    next 4 KB boundary, the records at a stride of 1 + ceil(`fieldcount` *
+    `fieldlength` / line) lines: line 0 the header (the key, a
+    sequence-lock version), then the fields packed. A READ (a draw below
+    `read_frac`; `readallfields`) is LD the bucket head, LD the header, LD
+    every field line in rising order; an UPDATE (`writeallfields` false)
+    is LD the bucket head, LD the header, ST the header (the version), ST
+    every line that one field, uniform over `fieldcount`, lies on. No
+    record lock is taken: the trace holds LD and ST only. Before every
+    reference a batch of 1 .. 2 * `ins_per_mem` instructions, and `op_ins`
+    more before an operation's first (the hash and the dispatch).
+    """
+    n, fl = recordcount, fieldlength
+    if ops_per_core < 1 or n < 3 or not 0.0 < theta < 1.0 or not 0.0 <= read_frac <= 1.0:
+        raise ValueError("ops_per_core >= 1, recordcount >= 3, 0 < theta < 1, 0 <= read_frac <= 1")
+    if fieldcount < 1 or fl < 1 or ins_per_mem < 1 or op_ins < 0:
+        raise ValueError("fieldcount, fieldlength, ins_per_mem >= 1; op_ins >= 0")
+    field_lines = -(-fieldcount * fl // line)
+    stride = (1 + field_lines) * line
+    records = -(-2 * n * 8 // 4096) * 4096
+    if records + n * stride > 2**31:
+        raise ValueError(f"{n} records of {stride} bytes do not fit under 2^31")
+    spans = [(f * fl + fl - 1) // line - f * fl // line + 1 for f in range(fieldcount)]
+    slots = 2 + max(field_lines, 1 + max(spans))  # the references of the longest operation
+    rng = _rng(seed)
+    shape = (n_cores, ops_per_core)
+    ranks = _zipfian_ranks(rng.random(shape), _YCSB_ITEMS, theta)
+    is_read = rng.random(shape) < read_frac
+    field = rng.integers(0, fieldcount, shape)
+    batch = rng.integers(1, 2 * ins_per_mem + 1, shape + (slots,))
+
+    per_core = []
+    for c in range(n_cores):
+        evs: list[tuple] = []
+        for o in range(ops_per_core):
+            record = _fnv1a_64(int(ranks[c, o])) % n
+            base = records + record * stride
+            refs = [(EV_LD, 8 * (_fnv1a_64(record) % (2 * n))), (EV_LD, base)]
+            if is_read[c, o]:
+                refs += [(EV_LD, base + l * line) for l in range(1, field_lines + 1)]
+            else:
+                lo = int(field[c, o]) * fl
+                refs.append((EV_ST, base))
+                refs += [(EV_ST, base + line + max(lo, l * line) // 4 * 4)
+                         for l in range(lo // line, (lo + fl - 1) // line + 1)]
+            for j, (t, addr) in enumerate(refs):
+                evs.append((EV_INS, int(batch[c, o, j]) + (0 if j else op_ins), 0))
+                evs.append((t, 4, addr))
+        per_core.append(evs)
+    return from_event_lists(per_core)
+
+
 GENERATORS = {
     "uniform_random": uniform_random,
     "stream": stream,
@@ -418,4 +539,5 @@ GENERATORS = {
     "lock_contention": lock_contention,
     "barrier_phases": barrier_phases,
     "ocean_like": ocean_like,
+    "ycsb_like": ycsb_like,
 }

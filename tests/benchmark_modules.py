@@ -41,11 +41,12 @@ def load_benchmark_tests():
             sys.modules["conftest"] = mine
 
 
-def assert_reference_equals_golden(reference, machine: dict, ev):
+def assert_reference_equals_golden(reference, machine: dict, ev, gold=None):
     """A plain reference (a module with `RefSim` and `COUNTERS`) against
     the golden model on one machine and one folded trace: the step count,
     every core's cycles, every counter it models, and zero in every
-    counter it does not. Returns the reference's finished simulation."""
+    counter it does not. `gold`: the golden model already run on them.
+    Returns the reference's finished simulation."""
     import numpy as np
 
     import trafficgen
@@ -53,9 +54,10 @@ def assert_reference_equals_golden(reference, machine: dict, ev):
     from primesim_tpu.golden.sim import GoldenSim
     from primesim_tpu.trace.format import Trace
 
-    lengths = (ev[:, :, 0] != trafficgen.EV_END).sum(1) + 1
-    gold = GoldenSim(MachineConfig.from_dict(machine), Trace(ev, lengths))
-    gold.run()
+    if gold is None:
+        lengths = (ev[:, :, 0] != trafficgen.EV_END).sum(1) + 1
+        gold = GoldenSim(MachineConfig.from_dict(machine), Trace(ev, lengths))
+        gold.run()
     ref = reference.RefSim(machine, ev)
     ref.run()
     assert ref.step_count == gold.step_count

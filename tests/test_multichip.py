@@ -110,6 +110,25 @@ def test_sharded_parity_with_barriers():
     assert int(g.counters["lock_acquires"].sum()) == int((t == EV_LOCK).sum()) == 16
 
 
+def test_sharded_parity_in_the_retry_regime():
+    """`ycsb_like` on rung 3's machine cut to 64 cores, sharded over four
+    devices, in the regime `retry_regime.py` asserts (over a fifth of the
+    requests retried, join-eligible reads demoted and beaten by writers):
+    the arbitration table's minima and the demotion's view of the step's
+    arbitrating (bank, set)s are reduced over the chips. The one-chip cell
+    `rung3.ycsb-a` runs this load unsharded; this test is the sharded guard."""
+    from retry_regime import golden_in_the_regime
+
+    machine, trace, _ = golden_in_the_regime(64, True)
+    g, e1, e4 = _run_all(MachineConfig.from_dict(machine), trace, tile_mesh(4))
+    assert not e4.has_sync and len(e4.state.cycles.devices()) == 4
+    np.testing.assert_array_equal(e4.cycles, g.cycles)
+    np.testing.assert_array_equal(e4.cycles, e1.cycles)
+    for k in g.counters:
+        np.testing.assert_array_equal(e4.counters[k], g.counters[k], err_msg=k)
+        np.testing.assert_array_equal(e4.counters[k], e1.counters[k], err_msg=k)
+
+
 def _run_record_oracle(cfg, dirm, pslot, pline):
     """numpy: `dirm[pslot]` and what the local run reads of those rows
     (`sim/step.py::_run_record`), an element gather a field."""

@@ -461,7 +461,8 @@ def test_new_metrics_are_appended_to_the_benchmark_and_have_readers():
     import cells
 
     bench = json.load(open(os.path.join(benchmark_modules.ROOT, "BENCHMARK.json")))
-    new = bench["per_layer"][-10:]
+    first = [m["name"] for m in bench["per_layer"]].index(list(READERS)[0])
+    new = bench["per_layer"][first:first + 10]  # PR 37's ten, in the order it appended them
     assert [m["name"] for m in new] == list(READERS) + ["stat_ms_step"]
     # no stat rows on a mesh: the rows' readers list the one-chip cells
     router = ["rung3.fft-m16", "rung3.rand-ws1m", "rung3.ocean-n258"]
