@@ -32,6 +32,7 @@ from ..config.machine import MachineConfig
 from ..sim.engine import _ACC_BITS, stream_loop
 from ..sim.state import init_state
 from ..stats.counters import zero_counters, zero_stats
+from ..trace.device import DeviceTrace
 from ..trace.format import EV_BARRIER, EV_END
 from .stream import absorb_stream_outputs
 
@@ -301,7 +302,7 @@ class OnlineEngine:
         buf[:, :, 0] = EV_END
         out = stream_loop(
             self.cfg,
-            jnp.asarray(buf),
+            self._window(buf),
             self.state._replace(ptr=jnp.zeros(C, jnp.int32)),
             jnp.zeros(C, bool),
             jnp.zeros(C, jnp.int32),
@@ -309,6 +310,13 @@ class OnlineEngine:
             has_sync=True,
         )
         np.asarray(out[0].cycles)  # block until compiled
+
+    def _window(self, buf) -> DeviceTrace:
+        """A filled window on the device, as `StreamEngine` hands its
+        own over."""
+        import jax
+
+        return jax.device_put(DeviceTrace.of(buf, self.cfg.local_run_len))
 
     def run(self, max_steps: int | None = None) -> None:
         import jax.numpy as jnp
@@ -330,7 +338,7 @@ class OnlineEngine:
                 st = self.state._replace(ptr=jnp.zeros(C, jnp.int32))
                 out = stream_loop(
                     cfg,
-                    jnp.asarray(buf),
+                    self._window(buf),
                     st,
                     jnp.asarray(exhausted),
                     jnp.asarray(filled),

@@ -24,6 +24,7 @@ from __future__ import annotations
 
 import time
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -32,6 +33,7 @@ from ..stats.counters import fold_block, zero_counters, zero_stats
 from ..sim import exec_cache
 from ..sim.engine import _ACC_BITS, stream_loop
 from ..sim.state import init_state
+from ..trace.device import DeviceTrace
 from ..trace.format import (
     EV_BARRIER,
     EV_END,
@@ -175,18 +177,17 @@ class StreamEngine:
         return buf, exhausted, filled
 
     def _place_core_axis(self, x):
-        """Upload a host array whose leading axis is the core axis,
-        sharded over the mesh when one is set (fresh uploads carry no
-        sharding of their own to propagate from)."""
-        a = jnp.asarray(x)
+        """Upload a host array (or the window, a `DeviceTrace`) whose
+        leading axis is the core axis, sharded over the mesh when one is
+        set (fresh uploads carry no sharding of their own to propagate
+        from)."""
         if self.mesh is None:
-            return a
-        import jax
+            return jax.device_put(x)
         from jax.sharding import NamedSharding, PartitionSpec as P
 
         from ..parallel.sharding import AXIS
 
-        return jax.device_put(a, NamedSharding(self.mesh, P(AXIS)))
+        return jax.device_put(x, NamedSharding(self.mesh, P(AXIS)))
 
     def _zero_ptr(self):
         """The per-window ptr reset, placed like state.ptr so the reset
@@ -208,7 +209,8 @@ class StreamEngine:
             stream_loop, "stream.loop",
             (cfg,),
             (
-                self._place_core_axis(buf),
+                self._place_core_axis(
+                    DeviceTrace.of(buf, cfg.local_run_len)),
                 self.state._replace(ptr=self._zero_ptr()),
                 self._place_core_axis(exhausted),
                 self._place_core_axis(filled),
@@ -237,7 +239,8 @@ class StreamEngine:
             stream_loop, "stream.loop",
             (cfg,),
             (
-                self._place_core_axis(buf),
+                self._place_core_axis(
+                    DeviceTrace.of(buf, cfg.local_run_len)),
                 st,
                 self._place_core_axis(exhausted),
                 self._place_core_axis(filled),

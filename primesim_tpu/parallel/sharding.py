@@ -235,7 +235,7 @@ def state_pspecs() -> MachineState:
 
 
 def events_pspec() -> P:
-    return P(AXIS)  # events[C, T, 3] sharded by core
+    return P(AXIS)  # a `trace/device.py::DeviceTrace`, sharded by core
 
 
 def state_shardings(mesh: Mesh) -> MachineState:
@@ -397,7 +397,7 @@ def fleet_state_pspecs() -> MachineState:
 
 
 def fleet_events_pspec() -> P:
-    return P(None, AXIS)  # events[Batch, C, T, 4]: batch whole, core-sharded
+    return P(None, AXIS)  # a fleet's `DeviceTrace`: batch whole, core-sharded
 
 
 def shard_fleet_state(mesh: Mesh, st: MachineState) -> MachineState:

@@ -42,6 +42,7 @@ from ..stats.counters import (
     zero_counters,
     zero_stats,
 )
+from ..trace.device import DeviceTrace
 from ..trace.format import (
     EV_BARRIER,
     EV_END,
@@ -67,6 +68,8 @@ def run_chunk(
     and in the loops below: the tile mesh of `events` and `st`, which
     `mesh_jit` reads off them where the caller names none."""
 
+    events = DeviceTrace.of(events, cfg.local_run_len)
+
     def body(carry, _):
         return step(cfg, events, carry, has_sync=has_sync, mesh=mesh), None
 
@@ -86,10 +89,8 @@ def _np(x) -> np.ndarray:
     return np.asarray(x)
 
 
-def _device_done(events, st, arange_c, faults_enabled=False):
-    T = events.shape[1]
-    p = jnp.minimum(st.ptr, T - 1)
-    done = events[arange_c, p, 0] == EV_END
+def _device_done(events, st, faults_enabled=False):
+    done = events.at(st.ptr)[:, 0] == EV_END
     if faults_enabled:
         # a fail-stopped core never reaches its END marker; it is done by
         # decree, so a run with injected fail-stops still terminates
@@ -155,15 +156,13 @@ def run_loop(cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
     This replaces the reference's per-quantum MPI barrier + host polling
     (SURVEY.md §3.4) with zero host round-trips until the run completes.
     """
-    C = cfg.n_cores
-    T = events.shape[1]
-    arange_c = jnp.arange(C, dtype=jnp.int32)
+    events = DeviceTrace.of(events, cfg.local_run_len)
 
     def cond(carry):
         st, acc_lo, acc_hi, base_lo, base_hi, k = carry
         with jax.named_scope(P_CHUNK):
             return (k < max_chunks) & ~_device_done(
-                events, st, arange_c, cfg.faults_enabled
+                events, st, cfg.faults_enabled
             )
 
     def body(carry):
@@ -174,8 +173,7 @@ def run_loop(cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
 
         st, _ = jax.lax.scan(sbody, st, None, length=chunk_steps)
         with jax.named_scope(P_CHUNK):
-            p = jnp.minimum(st.ptr, T - 1)
-            nd = events[arange_c, p, 0] != EV_END
+            nd = events.at(st.ptr)[:, 0] != EV_END
             if cfg.faults_enabled:
                 # dead cores must not bound the rebase minimum: their frozen
                 # clocks would pin delta at 0 forever (int32 overflow risk on
@@ -216,14 +214,11 @@ def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
     and clocks rebase on-device every 64 steps, same arithmetic as
     run_loop.
     """
-    C = cfg.n_cores
-    T = events.shape[1]
+    events = DeviceTrace.of(events, cfg.local_run_len)
     need = cfg.local_run_len + 1
-    arange_c = jnp.arange(C, dtype=jnp.int32)
 
     def at_end(s):
-        p = jnp.minimum(s.ptr, T - 1)
-        done = events[arange_c, p, 0] == EV_END
+        done = events.at(s.ptr)[:, 0] == EV_END
         if cfg.faults_enabled:
             # defensive only — the CLI rejects streaming + faults (the
             # window prefetcher cannot know a core died mid-window), but
@@ -301,9 +296,11 @@ class Engine:
             # multi-chip: cores/banks laid out over the tile axis
             # (parallel/); events and state go into that layout from their
             # first byte, never whole onto one device
-            events = trace.line_events(cfg.line_bits)
+            events = DeviceTrace.of(
+                trace.line_events(cfg.line_bits), cfg.local_run_len)
             self.events = (
-                jnp.asarray(events) if mesh is None else shard_events(mesh, events)
+                jax.device_put(events) if mesh is None
+                else shard_events(mesh, events)
             )
             self.state = build_state(cfg, mesh)
         self._init_s = init.seconds  # reported with the first job's sample

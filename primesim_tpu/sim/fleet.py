@@ -46,6 +46,7 @@ from ..chaos import sites as chaos
 from ..config.machine import MachineConfig
 from ..parallel.sharding import mesh_jit
 from ..stats.counters import COUNTER_NAMES, STAT_NAMES, fold_block
+from ..trace.device import DeviceTrace
 from ..trace.format import EV_BARRIER, EV_END, EV_LOCK, EV_UNLOCK, Trace
 from . import exec_cache
 from .engine import _ACC_BITS, _np, run_chunk, run_loop
@@ -264,9 +265,11 @@ class FleetEngine:
         self.has_sync = has_sync or force_sync
         # events: per-element line-event arrays END-padded to a common T
         # and stacked [B, C, T, 4] (END padding is the format's own
-        # convention — engines clamp ptr to T-1). `min_events_capacity`
-        # reserves slack so traces up to that length can be SPLICED in
-        # later (replace_element) without changing the compiled shape.
+        # convention — `DeviceTrace` clamps ptr to T-1), on the device as
+        # `DeviceTrace` lays them out, the batch its leading axis.
+        # `min_events_capacity` reserves slack so traces up to that length
+        # can be SPLICED in later (replace_element) without changing the
+        # compiled shape.
         T = max(max(t.max_len for t in traces), int(min_events_capacity))
         evs = []
         for t in traces:
@@ -277,7 +280,8 @@ class FleetEngine:
                 e = np.concatenate([e, pad], axis=1)
             evs.append(e)
         self._events_np = np.stack(evs)
-        self.events = jnp.asarray(self._events_np)
+        self.mesh = mesh  # `upload_events` places the events on it
+        self.upload_events()
         # state: stack the elements' solo init states — init_state(elem
         # cfg) already seeds knobs and quantum_end from the element's
         # effective timing
@@ -325,7 +329,6 @@ class FleetEngine:
         # solo Engine, only the INPUTS are placed — the compiled loops'
         # output shardings follow by propagation, which the multichip
         # parity/HLO suites prove is both bit-exact and all-gather-free.
-        self.mesh = mesh
         if mesh is not None:
             self._reshard()
         # overlapped chunk dispatch (§23), mirroring Engine: speculate
@@ -799,11 +802,13 @@ class FleetEngine:
     def upload_events(self) -> None:
         """Push the host event array (mutated by splices) to the device.
         One call covers any number of `upload=False` splices."""
-        self.events = jnp.asarray(self._events_np)
-        if self.mesh is not None:
+        events = DeviceTrace.of(self._events_np, self.cfg.local_run_len)
+        if self.mesh is None:
+            self.events = jax.device_put(events)
+        else:
             from ..parallel.sharding import shard_fleet_events
 
-            self.events = shard_fleet_events(self.mesh, self.events)
+            self.events = shard_fleet_events(self.mesh, events)
 
     def step_chunk(self) -> None:
         """Advance the whole batch by exactly ONE committed chunk (the

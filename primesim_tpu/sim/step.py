@@ -52,6 +52,7 @@ from ..ops.ranking import (
 )
 from ..parallel.sharding import read_rows
 from ..stats.counters import BLOCK_NAMES
+from ..trace.device import DeviceTrace
 from ..trace.format import (
     EV_BARRIER,
     EV_END,
@@ -530,7 +531,6 @@ def _fault(cfg: MachineConfig, events, st: MachineState, arange_c, acc):
     (schedule arrays, counter-based PRNG on (seed, step, site)) so one
     compiled program serves every seed and schedule of a geometry, and
     the fleet vmaps straight through it."""
-    T = events.shape[1]
     with jax.named_scope(P_FAULT):
         from ..faults.inject import ecc_step, fire_events, scrub_dead_cond
 
@@ -540,10 +540,7 @@ def _fault(cfg: MachineConfig, events, st: MachineState, arange_c, acc):
         # determinism contract — a fleet element keeps stepping after it
         # completes (until the whole batch drains), so any fault counted
         # on an ended core would diverge from the same element run solo
-        p_end = jnp.minimum(st.ptr, T - 1)
-        alive0 = (events[arange_c, p_end, 0] != EV_END) & (
-            fsf.core_dead == 0
-        )
+        alive0 = (events.at(st.ptr)[:, 0] != EV_END) & (fsf.core_dead == 0)
         kill_sched, link_dead_n, link_extra_n = fire_events(
             cfg, fsf, st.step
         )
@@ -620,7 +617,6 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
     C, B = cfg.n_cores, cfg.n_banks
     S1 = cfg.l1.sets
     S2 = cfg.llc.sets
-    T = events.shape[1]
     kn = st.knobs
     Q, cpi_vec, l1_lat = kn.quantum, kn.cpi, kn.l1_lat
     with jax.named_scope(P_LOCAL):
@@ -628,16 +624,14 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
         # Barrier-frozen cores (arrived, waiting for release) neither bump nor
         # bound the quantum (DESIGN.md §3): they rejoin at release. With local
         # runs enabled the event at ptr is slot 0 of the phase-0.5 prefetch —
-        # reuse it instead of a separate gather kernel.
+        # reuse it instead of a separate gather kernel. The window is the
+        # two whole blocks of the core's trace that hold it, read as rows,
+        # and moved down to their lane 0 (`DeviceTrace.window`).
         if cfg.local_run_len:
-            _rl0 = cfg.local_run_len
-            _ioff0 = jnp.arange(_rl0 + 1, dtype=jnp.int32)
-            _pidx0 = jnp.minimum(st.ptr[:, None] + _ioff0[None, :], T - 1)
-            _pev0 = events[arange_c[:, None], _pidx0]  # [C, rl+1, 4]
+            _pev0 = events.window(st.ptr, cfg.local_run_len + 1)  # [C, rl+1, 4]
             et0 = _pev0[:, 0, 0]
         else:
-            p0 = jnp.minimum(st.ptr, T - 1)
-            et0 = events[arange_c, p0, 0]
+            et0 = events.at(st.ptr)[:, 0]
         countable0 = (et0 != EV_END) & ~((et0 == EV_BARRIER) & (st.sync_flag != 0))
         if cfg.faults_enabled:
             # a fail-stopped core neither bumps nor bounds the quantum — it
@@ -822,7 +816,6 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
     NW = cfg.n_sharer_words
     MW = llc_meta_width(cfg)
     FS = W1 * S1  # plane stride in the fused L1 array
-    T = events.shape[1]
     logB = B.bit_length() - 1
     rl = cfg.local_run_len
     l1_c, step_no = st.l1, st.step
@@ -833,16 +826,15 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
         # lines = 128 GiB at 64B lines, 64x the byte-addressed range
         if rl:
             # a lane that retired k local events arbitrates candidate k
-            # (clamped pidx repeats the final END row, so over-running lanes
-            # read END here exactly as a direct gather would). Reusing MORE
+            # (the window repeats the final END row, so over-running lanes
+            # read END here exactly as a direct read would). Reusing MORE
             # of the prefetch here (classification, L1 planes, home metadata
             # row) was tried and measured slower: the select/patch kernels
             # cost more than the gathers they replaced.
             consumed = (ptr_c - st.ptr)[:, None]
             ev = _pick(jnp.swapaxes(pev, 1, 2), consumed)  # [C, 4]
         else:
-            p = jnp.minimum(ptr_c, T - 1)
-            ev = events[arange_c, p]  # [C, 4]
+            ev = events.at(ptr_c)  # [C, 4]
         et, earg, eaddr, epre = ev[:, 0], ev[:, 1], ev[:, 2], ev[:, 3]
         line = eaddr
         l1s = line & (S1 - 1)
@@ -2342,7 +2334,7 @@ def _commit_end(cfg: MachineConfig, st: MachineState, arange_c, rq: Request,
 
 def step(
     cfg: MachineConfig,
-    events: jnp.ndarray,
+    events: DeviceTrace,
     st: MachineState,
     has_sync: bool = True,
     mesh=None,
@@ -2352,7 +2344,9 @@ def step(
     compiles; everything a phase reads or hands on is in its call. `mesh`
     is the tile mesh the state is sharded over, None on one device: the
     two phases that read it are `_local` and `_probe`, for their reads of
-    whole `dirm` rows (`sharding.read_rows`)."""
+    whole `dirm` rows (`sharding.read_rows`). `events` is the trace as
+    the device holds it (`trace/device.py`); the loops that call `step`
+    lay a caller's raw `[C, T, 4]` array out once, outside their scan."""
     C = cfg.n_cores
     arange_c = jnp.arange(C, dtype=jnp.int32)
     # TIMING comes from the TRACED knob pytree carried in state, never

@@ -6,7 +6,8 @@ before the call.
         <benchmark/configs/*.json or configs/*.json> <out.txt> [devices] [--sync]
 
 `devices` defaults to the configuration's `run.devices` (1 where it has
-none): above 1 the state and the events get their `NamedSharding` over a
+none): above 1 the state and the events (a `DeviceTrace` of `trace_len`
+records a core) get their `NamedSharding` over a
 1-D tile mesh of the first `devices` chips of a v5e 2x2, as `Engine`
 lays them out; `--sync` compiles the step for a trace with locks and
 barriers (`has_sync` true), as `hlo_same.py dump --sync` does. Writes the compiled module's text (for
@@ -42,6 +43,7 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
     from primesim_tpu.parallel import sharding
     from primesim_tpu.sim.engine import run_loop
     from primesim_tpu.sim.state import init_state
+    from primesim_tpu.trace.device import DeviceTrace
 
     # a compile for a described device is written to the persistent cache
     # and cannot be read back without the chip
@@ -62,7 +64,11 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
         events = scalar = SingleDeviceSharding(topo.devices[0])
         st = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=events), st)
-    ev = jax.ShapeDtypeStruct((cfg.n_cores, trace_len, 4), jnp.int32, sharding=events)
+    # the trace as `Engine` hands it over: `DeviceTrace`'s blocks
+    ev = jax.eval_shape(lambda: DeviceTrace.of(
+        jnp.zeros((cfg.n_cores, trace_len, 4), jnp.int32), cfg.local_run_len))
+    ev = jax.tree.map(
+        lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=events), ev)
     t0 = time.perf_counter()
     compiled = run_loop.lower(
         cfg, chunk_steps, ev, st,

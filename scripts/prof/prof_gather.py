@@ -4,6 +4,7 @@ array-layout choices and behind `sim/step.py::_l1_set_read` having one form.
     python scripts/prof/prof_gather.py          # the L1 set read's two forms
     python scripts/prof/prof_gather.py rows     # the probe's way read: rows / elements
     python scripts/prof/prof_gather.py writes   # phase 4.A's L1 write: select / scatter
+    python scripts/prof/prof_gather.py events   # the local run's candidates: blocks / elements
     python scripts/prof/prof_gather.py raw      # row / element gather, row scatter
 
 Default: the L1 set read's two forms alone (select: `_l1_set_read`, the core's
@@ -35,6 +36,23 @@ four planes and of five, inside one `fori_loop` on columns that change every
 iteration; us a write, ns a scattered word, and whether the scatter's compiled
 text relays the array flat (`relay`) or sorts its indices (`sort`). The
 evidence for which form `_commit_writes` takes (PERF.md section 6, PR 38).
+
+`events`: the local run's read of its `rl + 1` candidate event records
+(`sim/step.py::_local`, phase 0), in the form the step has (`trace/device.py::
+DeviceTrace.window`: the trace as `[C, Tb, 128]` blocks of 32 records, ONE
+gather, batched over the cores, of the two whole blocks that hold the window,
+which a lane shifter then moves down to lane 0 of the rows in hand; `pairs`)
+against the element read of `[C, T, 4]` at `min(ptr + i, T - 1)` that it
+replaced in PR 40, kept here as `events_elements`, and against the forms it did
+not take: `picked` (the same gather, the records picked by a compare and a
+masked sum a word), `b1st` (the pairs as two index arrays, blocks first, the
+same shifter), `flat` (two row reads of a flat `[C * Tb, 128]`, picked) and
+`slice2` (one slice of two blocks a core, picked); at C in {1024, 4096, 16384}
+x T in {150, 546, 8192} x `rl` in {0, 8}, inside one `fori_loop` on pointers
+that change every iteration; us a read, ns an index of the element read, ns a
+row of the block read, and whether a form's compiled text holds an op that
+writes the whole array anew (`*`). The evidence that the read needs no second
+form (scripts/prof/README.md; PERF.md section 6, PR 40).
 
 `raw`: cost against index count, row width and operand size. Hypothesis from
 single-op ablations of the step: cost ~= per-INDEX overhead, mostly independent
@@ -263,6 +281,132 @@ def write_forms(strides=(512, 2048, 8192), cores=(1024, 1472, 4096, 16384),
                 del l1
 
 
+def events_elements(events, ptr, n):
+    """`DeviceTrace.window` as `_local` read it until PR 40: `n` slices of
+    one record a core out of `[C, T, 4]`."""
+    C, T, _ = events.shape
+    idx = jnp.minimum(ptr[:, None] + jnp.arange(n, dtype=jnp.int32), T - 1)
+    return events[jnp.arange(C, dtype=jnp.int32)[:, None], idx]
+
+
+def _window_rows(tr, ptr, n):
+    """The blocks of a window and where it starts in them: `(first, off,
+    k)`, the first block, the record of it the window starts at, and the
+    blocks it may span."""
+    from primesim_tpu.trace.device import RECORDS, _span
+
+    p = jnp.minimum(ptr, tr.length - 1)
+    first = p // RECORDS
+    return first, p - first * RECORDS, _span(n)
+
+
+def _picked(rows, off, n):
+    """Records `off + i`, `i < n`, of `rows` `[C, k, 128]` laid end to end."""
+    lane = off[:, None, None] * 4 + jnp.arange(n * 4, dtype=jnp.int32).reshape(n, 4)
+    hit = jnp.arange(128, dtype=jnp.int32) == (lane % 128)[..., None]
+    src = rows[:, 0][:, None, None]
+    for j in range(1, rows.shape[1]):
+        src = jnp.where((lane // 128 == j)[..., None], rows[:, j][:, None, None], src)
+    return jnp.sum(jnp.where(hit, src, 0), axis=-1)
+
+
+def window_b1st(tr, ptr, n):
+    """(core, block) pairs as two index arrays, blocks first (`[k, C, 128]`:
+    whole tiles), where the type's gather is batched over the cores (`[C, k,
+    128]`); the same shifter."""
+    from primesim_tpu.trace.device import _shifted
+
+    first, off, k = _window_rows(tr, ptr, n)
+    C = tr.blocks.shape[0]
+    rows = tr.blocks[jnp.arange(C, dtype=jnp.int32)[None, :],
+                     first[None, :] + jnp.arange(k, dtype=jnp.int32)[:, None]]
+    return _shifted(jnp.concatenate(list(rows), axis=1), off, n)
+
+
+def window_picked(tr, ptr, n):
+    """`DeviceTrace.window`'s gather, the records PICKED out of the rows (a
+    compare against an iota and a masked sum a word, `step.py::_pick`'s
+    idiom) where the type moves the window down by a lane shifter."""
+    first, off, k = _window_rows(tr, ptr, n)
+    rows = jnp.take_along_axis(
+        tr.blocks, (first[:, None] + jnp.arange(k, dtype=jnp.int32))[:, :, None],
+        axis=1, mode="promise_in_bounds")
+    return _picked(rows, off, n)
+
+
+def window_flat(tr, ptr, n):
+    """Row reads of the blocks as one flat `[C * Tb, 128]` table."""
+    first, off, k = _window_rows(tr, ptr, n)
+    C, Tb, _ = tr.blocks.shape
+    slot = (jnp.arange(C, dtype=jnp.int32) * Tb + first)[:, None] + jnp.arange(
+        k, dtype=jnp.int32)
+    return _picked(tr.blocks.reshape(C * Tb, 128)[slot], off, n)
+
+
+def window_slice2(tr, ptr, n):
+    """ONE slice of `k` blocks a core."""
+    first, off, k = _window_rows(tr, ptr, n)
+    rows = jax.vmap(lambda blk, b: jax.lax.dynamic_slice_in_dim(blk, b, k, 0))(
+        tr.blocks, first)
+    return _picked(rows, off, n)
+
+
+def event_read_forms(cores=(1024, 4096, 16384), lengths=(150, 546, 8192),
+                     run_lens=(0, 8)):
+    import re
+
+    from primesim_tpu.trace.device import DeviceTrace, _span
+
+    forms = (("elements", events_elements), ("pairs", DeviceTrace.window),
+             ("picked", window_picked), ("b1st", window_b1st),
+             ("flat", window_flat), ("slice2", window_slice2))
+    rng = np.random.default_rng(0)
+    print(f"device {jax.devices()[0].device_kind}; us an iteration, {ITER} in a "
+          "loop; `*`: the compiled loop writes the whole array anew")
+    print("     C     T rl  " + "  ".join(f"{n:>11s}" for n, _ in forms)
+          + "  elements_ns_index  pairs_ns_row")
+    for C in cores:
+        for T in lengths:
+            events = jax.jit(lambda C=C, T=T: (
+                jax.lax.broadcasted_iota(jnp.int32, (C, T, 4), 0) * 40503
+                + jax.lax.broadcasted_iota(jnp.int32, (C, T, 4), 1) * 4
+                + jax.lax.broadcasted_iota(jnp.int32, (C, T, 4), 2)))()
+            ptr0 = jnp.asarray(rng.integers(0, T + 4, C, dtype=np.int32))
+            for rl in run_lens:
+                n = rl + 1
+                tr = jax.jit(lambda ev, rl=rl: DeviceTrace.of(ev, rl))(events)
+                us, copies, want = {}, {}, None
+                for name, form in forms:
+                    arg = events if name == "elements" else tr
+
+                    def loop(arg, ptr0, form=form):
+                        def body(i, acc):
+                            return acc ^ form(arg, (ptr0 + i * 7) % (T + 4), n)
+                        return jax.lax.fori_loop(
+                            0, ITER, body, jnp.zeros((C, n, 4), jnp.int32))
+                    compiled = jax.jit(loop).lower(arg, ptr0).compile()
+                    us[name] = timeit(compiled, arg, ptr0, n=3, jit=False) / ITER * 1e6
+                    # an op inside the loop whose result is the whole array
+                    # (as it is, or flat): a copy or a relayout a call
+                    shape = jax.tree.leaves(arg)[0].shape
+                    dims = "|".join(",".join(map(str, d)) for d in (
+                        shape, (shape[0] * shape[1], shape[2])))
+                    copies[name] = bool(re.search(
+                        rf"= s32\[(?:{dims})\]\S* (?:copy|fusion|transpose)\(",
+                        compiled.as_text()))
+                    got = np.asarray(compiled(arg, ptr0))
+                    want = got if want is None else want  # `elements` runs first
+                    np.testing.assert_array_equal(got, want, err_msg=name)
+                print(f"{C:6d} {T:5d} {rl:2d}  " + "  ".join(
+                    f"{us[name]:10.1f}{'*' if copies[name] else ' '}"
+                    for name, _ in forms)
+                      + f"  {us['elements'] * 1e3 / (C * n):17.2f}"
+                      f"  {us['pairs'] * 1e3 / (C * _span(n)):12.2f}",
+                      flush=True)
+                del tr
+            del events
+
+
 def raw():
     rng = np.random.default_rng(0)
     R = 524288
@@ -288,5 +432,6 @@ def raw():
 
 
 if __name__ == "__main__":
-    {("rows",): way_read_forms, ("writes",): write_forms, ("raw",): raw}.get(
+    {("rows",): way_read_forms, ("writes",): write_forms,
+     ("events",): event_read_forms, ("raw",): raw}.get(
         tuple(sys.argv[1:]), set_read_forms)()

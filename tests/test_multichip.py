@@ -619,7 +619,8 @@ def test_sharded_local_run_sends_records_not_rows():
             if shape and shape[-1] == dirm_width(cfg):
                 wide.append(line.strip()[:200])
     assert not wide, "whole directory rows cross chips:\n" + "\n".join(wide)
-    # slots and lines out, records back; on the CPU the partitioner also
-    # sums the candidates' event records (4 words) across devices
+    # slots and lines out (2 words a candidate), records back (3); the
+    # candidates' event records cross no chip: `DeviceTrace.window`'s
+    # gather is batched over the cores, which the trace is sharded by
     candidates = cfg.n_cores * (cfg.local_run_len + 1)
-    assert 0 < words <= 16 * candidates, (words, candidates)
+    assert 0 < words <= 6 * candidates, (words, candidates)

@@ -226,13 +226,15 @@ def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
     (`_l1_set_read`), and the local run's way and word picks are selects
     (`_pick`): no `gather` of `step` has the L1 array as its operand, with
     four planes or, under the coarse vector, five; and `s.local` holds two
-    gathers, of rows the core does not hold: its events and the home
-    sets' directory rows. Every gather of the directory takes whole rows
+    gathers: of the two whole blocks of the core's trace that hold its
+    run's candidates (`DeviceTrace.window`: 2 C rows of 128 words, no
+    slice of one record), and of the home sets' directory rows, which the
+    core does not hold. Every gather of the directory takes whole rows
     (`_validate_ways` reads the rows its way pointers name and selects:
     `_way_record`), and `s.probe` holds exactly two of them: the home
     rows, C of them, and the way rows, W1 * C."""
     cfg, eng = build(machine)
-    shapes = {"l1": eng.state.l1.shape, "events": eng.events.shape,
+    shapes = {"l1": eng.state.l1.shape, "events": eng.events.blocks.shape,
               "dirm": eng.state.dirm.shape}
     assert len(set(shapes.values())) == 3
     indexed = [op for op in indexed_ops(machine) if op[0] == "gather"]
@@ -241,6 +243,8 @@ def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
     assert not [p for p, shape in gathers if shape == shapes["l1"]]
     assert sorted(shape for p, shape in gathers if "s.local" in p) == sorted(
         [shapes["events"], shapes["dirm"]])
+    assert [(n, sizes) for _, _, shape, n, sizes in indexed
+            if shape == shapes["events"]] == [(2 * cfg.n_cores, (1, 1, 128))]
     of_dirm = [(path, n, sizes) for _, path, shape, n, sizes in indexed
                if shape == shapes["dirm"]]
     assert shapes["dirm"][1] == dirm_width(cfg)
