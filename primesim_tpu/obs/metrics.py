@@ -11,6 +11,12 @@ import json
 from collections import deque
 
 
+def _ints(values: dict) -> dict:
+    """A sample's counts as plain ints, a list-valued one item by item."""
+    return {k: [int(x) for x in v] if isinstance(v, list) else int(v)
+            for k, v in values.items()}
+
+
 class MetricStore:
     """Bounded ring buffer of per-chunk (or, from the fused `Engine.run`,
     per-job) samples.
@@ -24,7 +30,11 @@ class MetricStore:
 
     A value of ``deltas`` is a total over the cores, but for a row that
     is not per core (``noc_sort_log2``: a histogram, kept as a list).
-    ``caps`` holds the static sizes a job's stat deltas divide by.
+    ``caps`` holds the static sizes a job's stat deltas divide by; of a
+    fleet's job (B machines in one dispatch) they are the sizes of all
+    its machines together (``n_cores`` B x a machine's, ``sort_entries``
+    likewise), with ``elements`` B and ``element_steps`` the B step
+    counts (a list), so that no share of core-steps is off by a factor B.
 
     ``seq`` is a global monotonically increasing chunk index (it keeps
     counting even after the ring starts dropping, so the slowest-chunk
@@ -48,13 +58,12 @@ class MetricStore:
             "label": str(label),
             "steps": int(steps),
             "wall_s": float(wall_s),
-            "deltas": {k: [int(x) for x in v] if isinstance(v, list) else int(v)
-                       for k, v in deltas.items()},
+            "deltas": _ints(deltas),
         }
         if phases:
             sample["phases"] = {k: float(v) for k, v in phases.items()}
         if caps:
-            sample["caps"] = {k: int(v) for k, v in caps.items()}
+            sample["caps"] = _ints(caps)
         self._ring.append(sample)
         self.seq += 1
         return sample

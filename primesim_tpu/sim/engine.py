@@ -261,6 +261,43 @@ def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
     )
 
 
+def commit_job(eng, total, steps, phases, element_steps=None) -> None:
+    """The one sample of a fused run (DESIGN.md §15), committed once its
+    results are on the host: the job's totals row by row of the block
+    `total` [rows, C] (the histogram row as its lanes), its host spans'
+    seconds, and the static sizes the stat ratios divide by. `eng` is the
+    `Engine`, or a `FleetEngine` with `total` summed over its elements,
+    `steps` the longest element's and `element_steps` each element's own:
+    the sizes are then those of all its machines together, so that a
+    share of `n_cores` x `steps` counts a frozen element's lanes as not
+    active. To the attached `Recorder`, else to the process's store;
+    nothing reads it back."""
+    cfg = eng.cfg
+    machines = 1 if element_steps is None else len(element_steps)
+    rows = dict(zip(BLOCK_NAMES, total))  # the rows the block carries
+    deltas = {k: int(rows[k].sum()) for k in COUNTER_NAMES}
+    if len(rows) > len(COUNTER_NAMES):  # a sharded `Engine`'s block has no stat rows
+        deltas.update(stat_totals({k: rows[k] for k in STAT_NAMES}))
+    router = cfg.noc.contention and cfg.noc.contention_model == "router"
+    caps = {
+        "n_cores": machines * cfg.n_cores,
+        "local_run_len": cfg.local_run_len,
+        # the slots of the router walk's sort: a lane's legs, each
+        # padded to the longest path
+        "sort_entries": machines * cfg.n_cores * (3 if eng.has_sync else 2)
+        * path_width(cfg) if router else 0,
+    }
+    if element_steps is not None:
+        caps.update(elements=machines, element_steps=list(element_steps))
+    wall_s = sum(phases.values()) - phases["init"]
+    if eng.obs is not None:
+        eng.obs.job_committed(eng.obs_label, steps, wall_s, deltas,
+                              phases, caps)
+    else:
+        process_store().record(time.time(), eng.obs_label, steps, wall_s,
+                               deltas, phases=phases, caps=caps)
+
+
 class Engine:
     """Host runner (SURVEY.md §2 #8 UncoreManager equivalent).
 
@@ -461,40 +498,12 @@ class Engine:
             self.state = st
             steps = int(np.asarray(k)) * self.chunk_steps
             self.steps_run += steps
-        self._commit_job(total, steps, {
+        commit_job(self, total, steps, {
             "init": self._init_s, "dispatch": dispatch.seconds,
             "wait": wait.seconds, "readback": readback.seconds})
         self._init_s = 0.0  # the engine's build belongs to its first job
         if not self.done():
             raise RuntimeError("engine: max_steps exceeded (deadlock?)")
-
-    def _commit_job(self, total, steps, phases) -> None:
-        """The one sample of a fused run (DESIGN.md §15), committed once
-        its results are on the host: the job's totals row by row of the
-        block (the histogram row as its lanes), its host spans' seconds,
-        and the static sizes the stat ratios divide by. To the attached
-        `Recorder`, else to the process's store; nothing reads it back."""
-        cfg = self.cfg
-        rows = dict(zip(BLOCK_NAMES, total))  # the rows the block carries
-        deltas = {k: int(rows[k].sum()) for k in COUNTER_NAMES}
-        if len(rows) > len(COUNTER_NAMES):  # not on a mesh: no stat rows there
-            deltas.update(stat_totals({k: rows[k] for k in STAT_NAMES}))
-        router = cfg.noc.contention and cfg.noc.contention_model == "router"
-        caps = {
-            "n_cores": cfg.n_cores,
-            "local_run_len": cfg.local_run_len,
-            # the slots of the router walk's sort: a lane's legs, each
-            # padded to the longest path
-            "sort_entries": cfg.n_cores * (3 if self.has_sync else 2)
-            * path_width(cfg) if router else 0,
-        }
-        wall_s = sum(phases.values()) - phases["init"]
-        if self.obs is not None:
-            self.obs.job_committed(self.obs_label, steps, wall_s, deltas,
-                                   phases, caps)
-        else:
-            process_store().record(time.time(), self.obs_label, steps, wall_s,
-                                   deltas, phases=phases, caps=caps)
 
     def run_chunked(
         self, max_steps: int = 10_000_000, debug_invariants: bool = False

@@ -8,8 +8,23 @@ runs (the ISPASS'14 multi-host aggregate), and
 a parameter sweep is the common shape of that traffic. So: `jax.vmap` the
 existing `run_chunk`/`run_loop` over a leading batch axis of B independent
 simulations sharing one GEOMETRY (core count, cache shapes, mesh), and one
-scan step retires one event per core *per simulation* at nearly the B=1
-kernel-chain cost.
+scan step retires one event per core *per simulation*.
+
+What that costs was measured on a TPU v5e in PR 42 (PERF.md section 6;
+rung 2's 256-core machine, one FFT trace an element), and it is NOT the
+B=1 kernel-chain cost the round-5 reckoning hoped for: a fleet's step is
+B steps and more, 0.291 ms a step an element at B = 1 and 4, 0.405 at 16
+and 0.439 at 32, where a solo `Engine` on the same machine and trace
+takes 0.150. Most of it lies outside every phase of the step: what `vmap`
+makes of `run_loop`'s `lax.while_loop` (below) copies the whole carry,
+`dirm` included, where the solo loop updates it in place
+(`dynamic-update-slice`, `broadcast_select_fusion` and `copy` ops, 3.9 of
+6.9 s of a B = 16 job). So B solo jobs back to back are today the faster
+way to run B simulations on one chip. Open: donation of the carry
+(ROADMAP S11) and a loop whose freeze does not copy it; the benchmark
+cell `rung2.sweep-b16` (PR 43) is where either is judged. One compile for
+the whole sweep, and the served buckets' elastic slots, are what the fleet
+gives today.
 
 Two design points make a whole sweep ONE compilation:
 
@@ -24,6 +39,12 @@ Two design points make a whole sweep ONE compilation:
   with the same `chunk_steps` stops. Fleet element i is therefore
   bit-exact with a solo `Engine` run of the same (config, trace),
   including the step counter (tests/test_fleet.py).
+
+Observability: `FleetEngine.run` opens the host spans `fleet.init`,
+`fleet.dispatch`, `fleet.wait` and `fleet.readback` (`obs/span.py`) and
+commits ONE sample a run, as `Engine.run` does (`engine.commit_job`,
+DESIGN.md §15): the totals of all its machines, the longest element's
+steps, and `caps` that say B.
 
 Scope: preloaded traces only. Streamed (windowed) ingest stays solo — the
 host-side window refill rate is per-element state, and batching it buys
@@ -44,12 +65,13 @@ import numpy as np
 
 from ..chaos import sites as chaos
 from ..config.machine import MachineConfig
+from ..obs.span import span
 from ..parallel.sharding import mesh_jit
 from ..stats.counters import COUNTER_NAMES, STAT_NAMES, fold_block
 from ..trace.device import DeviceTrace
 from ..trace.format import EV_BARRIER, EV_END, EV_LOCK, EV_UNLOCK, Trace
 from . import exec_cache
-from .engine import _ACC_BITS, _np, run_chunk, run_loop
+from .engine import _ACC_BITS, _np, commit_job, run_chunk, run_loop
 from .state import MachineState, init_state
 
 
@@ -271,22 +293,24 @@ class FleetEngine:
         # can be SPLICED in later (replace_element) without changing the
         # compiled shape.
         T = max(max(t.max_len for t in traces), int(min_events_capacity))
-        evs = []
-        for t in traces:
-            e = np.asarray(t.line_events(cfg.line_bits))
-            if e.shape[1] < T:
-                pad = np.zeros((C, T - e.shape[1], 4), e.dtype)
-                pad[:, :, 0] = EV_END
-                e = np.concatenate([e, pad], axis=1)
-            evs.append(e)
-        self._events_np = np.stack(evs)
         self.mesh = mesh  # `upload_events` places the events on it
-        self.upload_events()
-        # state: stack the elements' solo init states — init_state(elem
-        # cfg) already seeds knobs and quantum_end from the element's
-        # effective timing
-        states = [init_state(c) for c in self.elem_cfgs]
-        self.state = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+        with span("fleet.init") as init:
+            evs = []
+            for t in traces:
+                e = np.asarray(t.line_events(cfg.line_bits))
+                if e.shape[1] < T:
+                    pad = np.zeros((C, T - e.shape[1], 4), e.dtype)
+                    pad[:, :, 0] = EV_END
+                    e = np.concatenate([e, pad], axis=1)
+                evs.append(e)
+            self._events_np = np.stack(evs)
+            self.upload_events()
+            # state: stack the elements' solo init states — init_state(elem
+            # cfg) already seeds knobs and quantum_end from the element's
+            # effective timing
+            states = [init_state(c) for c in self.elem_cfgs]
+            self.state = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+        self._init_s = init.seconds  # reported with the first job's sample
         self.chunk_steps = chunk_steps
         # same per-chunk counter-accumulator bound as Engine, over the
         # worst event of ANY element
@@ -438,22 +462,36 @@ class FleetEngine:
     def run(self, max_steps: int = 10_000_000) -> None:
         """Run every element to completion in ONE device dispatch."""
         max_chunks = -(-max_steps // self.chunk_steps)
-        st, acc_lo, acc_hi, base_lo, base_hi, k = exec_cache.call(
-            fleet_run_loop, "fleet.run_loop",
-            (self.geom_cfg, self.chunk_steps),
-            (self.events, self.state, jnp.asarray(max_chunks, jnp.int32)),
-            {"has_sync": self.has_sync},
-        )
-        acc_lo = _np(acc_lo).astype(np.int64)  # [B, N_BLOCK_ROWS, C]
-        acc_hi = _np(acc_hi).astype(np.int64)
-        total = (acc_hi << _ACC_BITS) + acc_lo
-        fold_block(self.host_counters, self.host_stats,
-                   np.swapaxes(total, 0, 1))
-        self.cycle_base += (
-            _np(base_hi).astype(np.int64) << _ACC_BITS
-        ) + _np(base_lo).astype(np.int64)
-        self.state = st
-        self.steps_run += _np(k).astype(np.int64) * self.chunk_steps
+        # the host spans of a fused run and its one sample, as
+        # `Engine.run` has them (DESIGN.md §15)
+        with span("fleet.dispatch") as dispatch:
+            st, acc_lo, acc_hi, base_lo, base_hi, k = exec_cache.call(
+                fleet_run_loop, "fleet.run_loop",
+                (self.geom_cfg, self.chunk_steps),
+                (self.events, self.state,
+                 jnp.asarray(max_chunks, jnp.int32)),
+                {"has_sync": self.has_sync},
+            )
+        with span("fleet.wait") as wait:
+            jax.block_until_ready(k)
+        with span("fleet.readback") as readback:
+            acc_lo = _np(acc_lo).astype(np.int64)  # [B, N_BLOCK_ROWS, C]
+            acc_hi = _np(acc_hi).astype(np.int64)
+            total = (acc_hi << _ACC_BITS) + acc_lo
+            fold_block(self.host_counters, self.host_stats,
+                       np.swapaxes(total, 0, 1))
+            self.cycle_base += (
+                _np(base_hi).astype(np.int64) << _ACC_BITS
+            ) + _np(base_lo).astype(np.int64)
+            self.state = st
+            steps = _np(k).astype(np.int64) * self.chunk_steps  # [B]
+            self.steps_run += steps
+        # the longest element's steps are what the device ran
+        commit_job(self, total.sum(axis=0), int(steps.max()), {
+            "init": self._init_s, "dispatch": dispatch.seconds,
+            "wait": wait.seconds, "readback": readback.seconds},
+            element_steps=steps.tolist())
+        self._init_s = 0.0  # the fleet's build belongs to its first job
         if not self.done():
             bad = np.flatnonzero(~self.done_mask()).tolist()
             raise RuntimeError(

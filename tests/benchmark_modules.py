@@ -20,19 +20,18 @@ def _load(name: str, path: str):
     return mod
 
 
-def load_benchmark_tests():
-    """`benchmark/tests/test_benchmark.py` as a module. It says `from
-    conftest import BENCH, ROOT` and means the benchmark's conftest, while
-    under `pytest tests/` that name is this directory's: the benchmark's
-    stands in under it for as long as the module loads."""
+def load_benchmark_tests(name: str = "test_benchmark"):
+    """`benchmark/tests/<name>.py` as a module. It says `from conftest
+    import BENCH, ROOT` and means the benchmark's conftest, while under
+    `pytest tests/` that name is this directory's: the benchmark's stands
+    in under it for as long as the module loads."""
     tests = os.path.join(BENCH, "tests")
     mine = sys.modules.get("conftest")
     sys.modules["conftest"] = _load("benchmark_tests_conftest",
                                     os.path.join(tests, "conftest.py"))
-    sys.path.insert(0, tests)  # its helper `tinycell`
+    sys.path.insert(0, tests)  # its helpers `tinycell` and `scratchroot`
     try:
-        return _load("benchmark_tests_test_benchmark",
-                     os.path.join(tests, "test_benchmark.py"))
+        return _load(f"benchmark_tests_{name}", os.path.join(tests, f"{name}.py"))
     finally:
         sys.path.remove(tests)
         if mine is None:

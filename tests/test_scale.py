@@ -95,13 +95,19 @@ def test_captured_traces_are_line_addressed(tmp_path):
          os.path.join(frontend, "examples", "ocean_like.c"), "-lpthread"],
         check=True, capture_output=True,
     )
-    tr = capture_run([binary, "2", "1", "2"], line=64)
-    assert tr.line_addressed
-    assert tr.line_bits == 6  # capture line size travels in the v4 flags
     # heap line indices exceed 2^25 — BYTE addressing would have had to
-    # alias these into its 2 GiB window; line addressing holds them
-    mem = (tr.events[:, :, 0] == EV_LD) | (tr.events[:, :, 0] == EV_ST)
-    assert tr.events[:, :, 2][mem].max() > (1 << 25)
+    # alias these into its 2 GiB window; line addressing holds them. Where
+    # the heap lies is ASLR's draw, and one run in about sixty puts every
+    # line of the capture under 2^25 (seen in tier-1, PR 43): draw again
+    for _ in range(4):
+        tr = capture_run([binary, "2", "1", "2"], line=64)
+        assert tr.line_addressed
+        assert tr.line_bits == 6  # capture line size travels in the v4 flags
+        mem = (tr.events[:, :, 0] == EV_LD) | (tr.events[:, :, 0] == EV_ST)
+        if tr.events[:, :, 2][mem].max() > (1 << 25):
+            break
+    else:
+        pytest.fail("four captures in a row held no line index above 2^25")
     # line-size mismatch is rejected, not silently misinterpreted
     from primesim_tpu.config.machine import CacheConfig, MachineConfig
 

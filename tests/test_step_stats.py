@@ -93,7 +93,7 @@ def test_counters_hold_no_stat_row_and_their_digest_is_goldens(ran):
     assert tuple(eng.step_stats) == STAT_NAMES
     assert eng.state.counters.shape == (N_BLOCK_ROWS, 16)
 
-    def digest(cycles, counters):  # benchmark/measure.py::_digest
+    def digest(cycles, counters):  # benchmark/measure.py::digest
         h = hashlib.sha256(np.ascontiguousarray(cycles, np.int64).tobytes())
         for k in sorted(counters):
             h.update(k.encode())
@@ -352,6 +352,83 @@ def test_metric_store_keeps_a_list_row_and_caps():
     assert store.summary()["total_instructions"] == 4
 
 
+def _fleet(cfg, traces, overrides, rec=None, mesh=None):
+    from primesim_tpu.sim.fleet import FleetEngine
+
+    fleet = FleetEngine(cfg, traces, overrides, chunk_steps=8, mesh=mesh)
+    if rec is not None:
+        rec.attach(fleet)
+    fleet.run()
+    return fleet
+
+
+def _assert_fleet_sample(sample, fleet):
+    """The one sample of `FleetEngine.run` (DESIGN.md §15): B machines'
+    totals, the longest element's steps, sizes that say B."""
+    B = fleet.n_elements
+    assert sample["label"] == "fleet" and sample["steps"] == int(fleet.steps_run.max())
+    ph = sample["phases"]
+    assert set(ph) == {"init", "dispatch", "wait", "readback"} and min(ph.values()) > 0
+    assert sample["wall_s"] == pytest.approx(ph["dispatch"] + ph["wait"] + ph["readback"])
+    assert set(sample["deltas"]) == set(BLOCK_NAMES)
+    for k in COUNTER_NAMES:
+        assert sample["deltas"][k] == int(fleet.counters[k].sum()), k
+    assert {k: sample["deltas"][k] for k in STAT_NAMES} == stat_totals(
+        {k: v.sum(axis=0) for k, v in fleet.step_stats.items()})
+    assert sample["caps"] == {
+        "n_cores": B * 16, "local_run_len": 4, "sort_entries": B * 16 * 2 * 6,
+        "elements": B, "element_steps": fleet.steps_run.tolist()}
+    json.dumps(sample)
+
+
+def test_fleet_run_commits_one_sample_of_all_its_machines():
+    cfg, trace = MACHINES["rung3"]()
+    ovs = [{}, {"quantum": 100, "llc_lat": 14}, {"dram_lat": 150}]
+    before = process_store().seq
+    fleet = _fleet(cfg, [trace] * 3, ovs)
+    assert process_store().seq == before + 1  # exactly one, whatever B
+    _assert_fleet_sample(process_store().samples()[-1], fleet)
+    assert len(set(fleet.steps_run.tolist())) > 1  # an element froze before the longest ended
+    # to the recorder where one is attached, and no simulated bit depends on who listens
+    rec = Recorder("basic")
+    heard = _fleet(cfg, [trace] * 3, ovs, rec)
+    assert process_store().seq == before + 1 and len(rec.store) == 1
+    _assert_fleet_sample(rec.store.samples()[0], heard)
+    np.testing.assert_array_equal(heard.cycles, fleet.cycles)
+    for k in COUNTER_NAMES:
+        np.testing.assert_array_equal(heard.counters[k], fleet.counters[k], err_msg=k)
+    assert rec.timeline_summary()["total_instructions"] == int(fleet.counters["instructions"].sum())
+    # the build belongs to the first job
+    assert fleet._init_s == 0.0
+
+
+def test_a_fleet_of_one_commits_a_solo_runs_deltas():
+    cfg, trace = MACHINES["sync"]()
+    eng = Engine(cfg, trace, chunk_steps=8)
+    eng.run()
+    solo = process_store().samples()[-1]
+    fleet = _fleet(cfg, [trace], None)
+    mine = process_store().samples()[-1]
+    assert (solo["label"], mine["label"]) == ("engine", "fleet")
+    assert mine["deltas"] == solo["deltas"] and mine["steps"] == solo["steps"] == eng.steps_run
+    assert mine["caps"] == {**solo["caps"], "elements": 1, "element_steps": [eng.steps_run]}
+    assert fleet.has_sync and mine["caps"]["sort_entries"] == 16 * 3 * 6
+
+
+def test_a_sharded_fleets_sample_holds_the_rows_its_block_carries():
+    """A fleet stacks solo `init_state`s and places the stack on its mesh,
+    so, unlike a sharded `Engine` (`build_state`), its block keeps the stat
+    rows there; the sample holds whatever rows were drained."""
+    cfg, trace = MACHINES["plain"]()
+    fleet = _fleet(cfg, [trace] * 2, [{}, {"dram_lat": 150}], mesh=tile_mesh(4))
+    mine = process_store().samples()[-1]
+    assert len(fleet.state.cycles.devices()) == 4
+    assert set(mine["deltas"]) == set(BLOCK_NAMES[:fleet.state.counters.shape[1]])
+    assert mine["caps"]["n_cores"] == 32 and mine["caps"]["elements"] == 2
+    for k in COUNTER_NAMES:
+        assert mine["deltas"][k] == int(fleet.counters[k].sum()), k
+
+
 # ---- the benchmark's readers of the job samples ---------------------------
 
 READERS = ("slot_active_pct", "slot_quantum_pct", "slot_frozen_pct", "arb_win_pct",
@@ -464,14 +541,20 @@ def test_new_metrics_are_appended_to_the_benchmark_and_have_readers():
     first = [m["name"] for m in bench["per_layer"]].index(list(READERS)[0])
     new = bench["per_layer"][first:first + 10]  # PR 37's ten, in the order it appended them
     assert [m["name"] for m in new] == list(READERS) + ["stat_ms_step"]
-    # no stat rows on a mesh: the rows' readers list the one-chip cells
+    # no stat rows on a mesh: the rows' readers list one-chip cells, these
+    # at least (a later `benchmark` PR may append a one-chip cell to a list)
     router = ["rung3.fft-m16", "rung3.rand-ws1m", "rung3.ocean-n258"]
     one_chip = ["mesh1024.fft-m16", "rung3.fft-m16", "rung3.rand-ws1m", "rung5.fft-m18-16k",
                 "rung3.ocean-n258"]
+    four_chips = {w["name"] for w in bench["workloads"] if w["chips"] != 1}
+    pinned = {"slot_frozen_pct": ["rung3.ocean-n258"],
+              "slot_active_pct": one_chip, "slot_quantum_pct": one_chip,
+              "run_slot_pct": one_chip, "stat_ms_step": one_chip,
+              "noc_active_pct": router, "noc_sort_log2_max": router}
     for m in new:
         assert callable(cells.load_metric(m["name"])) and m["moves"] == "sim_mips"
-        assert m.get("workloads") == {"slot_frozen_pct": ["rung3.ocean-n258"],
-                                      "slot_active_pct": one_chip, "slot_quantum_pct": one_chip,
-                                      "run_slot_pct": one_chip, "stat_ms_step": one_chip,
-                                      "noc_active_pct": router,
-                                      "noc_sort_log2_max": router}.get(m["name"])
+        if m["name"] in pinned:
+            assert set(m["workloads"]) >= set(pinned[m["name"]]), m["name"]
+            assert not set(m["workloads"]) & four_chips, m["name"]
+        else:  # the counters' and the host spans' readers: every cell
+            assert "workloads" not in m, m["name"]
