@@ -10,21 +10,27 @@ existing `run_chunk`/`run_loop` over a leading batch axis of B independent
 simulations sharing one GEOMETRY (core count, cache shapes, mesh), and one
 scan step retires one event per core *per simulation*.
 
-What that costs was measured on a TPU v5e in PR 42 (PERF.md section 6;
-rung 2's 256-core machine, one FFT trace an element), and it is NOT the
-B=1 kernel-chain cost the round-5 reckoning hoped for: a fleet's step is
-B steps and more, 0.291 ms a step an element at B = 1 and 4, 0.405 at 16
-and 0.439 at 32, where a solo `Engine` on the same machine and trace
-takes 0.150. Most of it lies outside every phase of the step: what `vmap`
-makes of `run_loop`'s `lax.while_loop` (below) copies the whole carry,
-`dirm` included, where the solo loop updates it in place
-(`dynamic-update-slice`, `broadcast_select_fusion` and `copy` ops, 3.9 of
-6.9 s of a B = 16 job). So B solo jobs back to back are today the faster
-way to run B simulations on one chip. Open: donation of the carry
-(ROADMAP S11) and a loop whose freeze does not copy it; the benchmark
-cell `rung2.sweep-b16` (PR 43) is where either is judged. One compile for
-the whole sweep, and the served buckets' elastic slots, are what the fleet
-gives today.
+What that costs was measured on a TPU v5e in PR 42 and 43 (PERF.md
+section 6; rung 2's 256-core machine, one FFT trace an element), and it
+is NOT the B=1 kernel-chain cost the round-5 reckoning hoped for: a
+fleet's step is B steps and more, 0.291 ms a step an element at B = 1 and
+4, 0.405 at 16 and 0.439 at 32, where a solo `Engine` on the same machine
+and trace takes 0.150, so B solo jobs back to back were the faster way to
+run B simulations on one chip. Of the 4.05 ms a step that lay outside
+every phase at B = 16, the compiled text (`scripts/prof/compile_v5e.py
+--fleet 16`, PR 44) says 1.85 was not the loop's carry at all: phase
+4.A's join table, relaid whole under the batch axis one row a loop trip,
+every step, which `step.py::_join_representative` cured by reading the
+table as rows of a tile (0.280 ms a step an element since, 17.7 Minstr/s
+for 12.3). What is left there is the freeze: what `vmap`
+makes of `run_loop`'s `lax.while_loop` (below) selects and copies the
+whole carry, `dirm` included, ONCE A CHUNK (`broadcast_select_fusion` and
+`copy`, 15.8 ms a chunk at B = 16), so 1.98 ms a step at the benchmark
+cell's `chunk_steps` 8 and 0.06 at the CLI's default 256. Open: a loop
+whose freeze does not copy the carry (ROADMAP S5) and donation of it
+(S11: memory, at any chunk size); the benchmark cell `rung2.sweep-b16`
+(PR 43) is where either is judged, and one compile for the whole sweep
+and the served buckets' elastic slots are what the fleet gives today.
 
 Two design points make a whole sweep ONE compilation:
 

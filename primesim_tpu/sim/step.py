@@ -1887,6 +1887,30 @@ def _l1_writes(cfg: MachineConfig, step_no, arange_c, rq: Request,
     return writes
 
 
+def _join_representative(join, entry, key, n):
+    """[C] bool: of the lanes with `join` set, the one a directory entry
+    (`entry` = slot * W2 + way, `n` entries in all) whose `key` is least:
+    a scatter-min of the keys into a table of one word an entry, read back
+    at the lane's own entry. Lanes without `join` scatter to the drop
+    index `n` and read a clamped entry that `join` masks.
+
+    The table has ONE form: scattered into flat, read as rows of 128
+    lanes (the device's tile) at `(i >> 7, i & 127)`. Read flat,
+    `jax.vmap` of the step (the fleet) relaid all B x n words from the
+    scatter's flat tile into `[1, B, n]` one row a loop trip before the
+    gather, every step (rung 2 at B = 16: 64 MB, 1.85 of a 6.48 ms step;
+    PERF.md section 6, PR 44). As rows of a tile the scatter's result
+    reaches the gather through a bitcast, solo and under a batch axis
+    alike. Where `n` is no multiple of 128 the last row is padded: the
+    drop index then lands in the padding (else past the table: dropped),
+    and no read reaches it."""
+    rows = -(-n // 128)
+    tab = jnp.full(rows * 128, INT32_MAX, jnp.int32).at[
+        jnp.where(join, entry, n)].min(key, mode="drop").reshape(rows, 128)
+    rd = jnp.minimum(entry, n - 1)
+    return join & (tab[rd >> 7, rd & 127] == key)
+
+
 def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
                    rq: Request, dr: DirOutcome, winner, join, key, grant, hit,
                    run_patch, acc):
@@ -1947,13 +1971,8 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
             )
             takes_own = write_w | gets_excl_hit | llc_miss
             st_val_m = jnp.where(write_hit, M, grant)
-            jsw = jnp.where(join, slot * W2 + llc_hway, B * S2 * W2)
-            jtab = jnp.full(B * S2 * W2, INT32_MAX, jnp.int32).at[jsw].min(
-                key, mode="drop"
-            )
-            jrep = join & (
-                jtab[jnp.minimum(slot * W2 + llc_hway, B * S2 * W2 - 1)] == key
-            )
+            jrep = _join_representative(
+                join, slot * W2 + llc_hway, key, B * S2 * W2)
             upd_slot = jnp.where(winner | join, slot, B * S2)
             commit_lanes = jnp.stack(
                 [
@@ -2088,13 +2107,8 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
             # after the row-add was measured at ~5 ms/step (round-5 ablation: any
             # read-modify-write scatter that cannot alias re-materializes the
             # 800 MB operand), so everything must go through the ONE add.
-            jsw = jnp.where(join, slot * W2 + llc_hway, B * S2 * W2)
-            jtab = jnp.full(B * S2 * W2, INT32_MAX, jnp.int32).at[jsw].min(
-                key, mode="drop"
-            )
-            jrep = join & (
-                jtab[jnp.minimum(slot * W2 + llc_hway, B * S2 * W2 - 1)] == key
-            )
+            jrep = _join_representative(
+                join, slot * W2 + llc_hway, key, B * S2 * W2)
             old_lru_h = meta_rows[arange_c, 2 * W2 + llc_hway]
             lru_oh = (
                 jnp.arange(MW, dtype=jnp.int32)[None, :]

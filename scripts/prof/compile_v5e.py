@@ -3,17 +3,24 @@ DESCRIBED v5e, no chip attached: what a four-chip call would compile,
 before the call.
 
     JAX_PLATFORMS=cpu PYTHONPATH=<checkout> python scripts/prof/compile_v5e.py \\
-        <benchmark/configs/*.json or configs/*.json> <out.txt> [devices] [--sync]
+        <benchmark/configs/*.json or configs/*.json> <out.txt> [devices] [--sync] [--fleet B]
 
 `devices` defaults to the configuration's `run.devices` (1 where it has
 none): above 1 the state and the events (a `DeviceTrace` of `trace_len`
 records a core) get their `NamedSharding` over a
 1-D tile mesh of the first `devices` chips of a v5e 2x2, as `Engine`
 lays them out; `--sync` compiles the step for a trace with locks and
-barriers (`has_sync` true), as `hlo_same.py dump --sync` does. Writes the compiled module's text (for
+barriers (`has_sync` true), as `hlo_same.py dump --sync` does;
+`--fleet B` compiles `fleet_run_loop` for B machines of the configuration
+(a leading axis of B on the state and the `DeviceTrace`, the static key
+`cfg.timing_normalized()`, as `FleetEngine` hands them over). Writes the compiled module's text (for
 `hlo_same.py compare`), and prints the compiler's bytes a chip
-(arguments, outputs, temporaries) and every collective with its shape
-and the tail of its `op_name`, which holds the phase scope. Nothing
+(arguments, outputs, temporaries), every collective with its shape
+and the tail of its `op_name`, which holds the phase scope, and every
+`while` nested inside the step (the chunk loop's scan body) with the
+arrays it carries: a loop there that no phase of `step` wrote is a
+relayout the compiler made (PERF.md section 6, PR 44: the fleet's join
+table, one element a trip). Nothing
 runs: a time never comes from here (PERF.md section 6: PR 21, 31, 33,
 34 each read their collectives off this before a call).
 """
@@ -29,10 +36,60 @@ from hlo_same import load_config  # beside this file: the script's directory
 _COLLECTIVE = re.compile(
     r"^\s*(?:ROOT )?%?(\S+) = (.*?) (all-reduce|all-gather|reduce-scatter"
     r"|all-to-all|collective-permute)(-start)?\(")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\) -> .* \{$")
+_WHILE = re.compile(r"^\s*(?:ROOT )?%?(\S+) = (.*) while\(.*body=%?([\w.\-]+)")
+_CALLED = re.compile(
+    r"(?:calls|to_apply|true_computation|false_computation)"
+    r"=%?([\w.\-]+)|branch_computations=\{([^}]*)\}")
+_SHAPE = re.compile(r"\w+\[[\d,]*\](?:\{[^}]*\})?")
+_OP_NAME = re.compile(r'op_name="([^"]*)"')
+
+
+def op_name_of(line: str) -> str:
+    found = _OP_NAME.search(line)
+    return found.group(1) if found else ""
+
+
+def nested_whiles(text: str) -> list:
+    """(depth, computation, instruction, carried shapes, `op_name`) of
+    every `while` of a compiled module that lies more than two loops
+    deep: `run_loop` is the loop over chunks and, in its body, the scan
+    over a chunk's steps, so a third loop runs inside every step."""
+    bodies: dict = {}
+    entry = at = None
+    for line in text.splitlines():
+        head = _COMPUTATION.match(line)
+        if head:
+            at = head.group(1)
+            bodies[at] = []
+            if line.startswith("ENTRY"):
+                entry = at
+        elif at is not None:
+            bodies[at].append(line)
+    found, seen = [], set()
+
+    def walk(name: str, depth: int) -> None:
+        if (name, depth) in seen or name not in bodies:
+            return
+        seen.add((name, depth))
+        for line in bodies[name]:
+            loop = _WHILE.match(line)
+            if loop:  # its condition holds no loop
+                if depth >= 2:
+                    found.append((depth + 1, name, loop.group(1),
+                                  _SHAPE.findall(loop.group(2)), op_name_of(line)))
+                walk(loop.group(3), depth + 1)
+                continue
+            for called in _CALLED.finditer(line):
+                for callee in (called.group(1) or called.group(2)).split(","):
+                    walk(callee.strip().lstrip("%"), depth)
+
+    walk(entry, 0)
+    return found
 
 
 def main(conf_path: str, out_path: str, devices: int | None = None,
-         trace_len: int = 546, has_sync: bool = False) -> None:
+         trace_len: int = 546, has_sync: bool = False, fleet: int = 0) -> None:
     import jax
     import jax.numpy as jnp
     import numpy as np
@@ -42,6 +99,7 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
 
     from primesim_tpu.parallel import sharding
     from primesim_tpu.sim.engine import run_loop
+    from primesim_tpu.sim.fleet import fleet_run_loop
     from primesim_tpu.sim.state import init_state
     from primesim_tpu.trace.device import DeviceTrace
 
@@ -60,6 +118,8 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
             lambda s, spec: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=place(spec)),
             st, sharding.state_pspecs())
         events, scalar = place(sharding.events_pspec()), place(P())
+        if fleet:
+            raise SystemExit("--fleet on a mesh is not laid out here: one device")
     else:
         events = scalar = SingleDeviceSharding(topo.devices[0])
         st = jax.tree.map(
@@ -69,14 +129,21 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
         jnp.zeros((cfg.n_cores, trace_len, 4), jnp.int32), cfg.local_run_len))
     ev = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=events), ev)
+    loop = run_loop
+    if fleet:  # the batch is the leading axis of every leaf, as `FleetEngine` stacks them
+        loop, cfg = fleet_run_loop, cfg.timing_normalized()
+        st, ev = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((fleet, *s.shape), s.dtype, sharding=events),
+            (st, ev))
     t0 = time.perf_counter()
-    compiled = run_loop.lower(
+    compiled = loop.lower(
         cfg, chunk_steps, ev, st,
         jax.ShapeDtypeStruct((), jnp.int32, sharding=scalar), has_sync=has_sync).compile()
     text = compiled.as_text()
     with open(out_path, "w") as f:
         f.write(text)
     print(f"{out_path}: {len(text)} bytes, {devices} described device(s), "
+          f"{f'a fleet of {fleet}' if fleet else 'one machine'}, "
           f"compiled in {time.perf_counter() - t0:.1f} s")
     mem = compiled.memory_analysis()
     print(f"a chip: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, outputs "
@@ -85,15 +152,24 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
     for line in text.splitlines():
         found = _COLLECTIVE.match(line)
         if found:
-            op_name = re.search(r'op_name="([^"]*)"', line)
             print(f"  {found.group(1)} {found.group(2)[:80]} | "
-                  f"{(op_name.group(1) if op_name else '')[-60:]}")
+                  f"{op_name_of(line)[-60:]}")
+    loops = nested_whiles(text)
+    print(f"loops inside the step: {len(loops)}")
+    for depth, inside, name, shapes, op_name in loops:
+        print(f"  {name} (depth {depth}, in {inside}) carries {' '.join(shapes)} | "
+              f"{op_name[-60:]}")
 
 
 if __name__ == "__main__":
     args = [a for a in sys.argv[1:] if a != "--sync"]
+    fleet = 0
+    if "--fleet" in args[:-1]:
+        at = args.index("--fleet")
+        fleet = int(args[at + 1])
+        del args[at:at + 2]
     if len(args) not in (2, 3):
         print(__doc__, file=sys.stderr)
         raise SystemExit(2)
     main(args[0], args[1], int(args[2]) if len(args) == 3 else None,
-         has_sync="--sync" in sys.argv[1:])
+         has_sync="--sync" in sys.argv[1:], fleet=fleet)

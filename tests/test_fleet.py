@@ -14,14 +14,16 @@ import numpy as np
 import pytest
 
 from primesim_tpu.analysis.recompile import recompile_sentinel
-from primesim_tpu.config.machine import small_test_config
+from primesim_tpu.config.machine import CacheConfig, small_test_config
 from primesim_tpu.sim.engine import Engine
 from primesim_tpu.sim.fleet import (
     FleetEngine,
     apply_overrides,
     fleet_run_loop,
 )
+from primesim_tpu.sim.validate import llc_views
 from primesim_tpu.trace import synth
+from primesim_tpu.trace.format import EV_INS, EV_LD, EV_ST, from_event_lists
 
 
 def assert_element_matches_solo(fleet, i, cfg_eff, trace, chunk_steps):
@@ -175,6 +177,87 @@ def test_fleet_one_compilation_per_geometry():
     )
     f3.run()
     assert fleet_run_loop._cache_size() == n0 + 1  # new geometry compiles
+
+
+def _joining_trace(n_cores: int, first_line: int):
+    """Rounds of read sharing that end in read-joins: cores 0 and 1 read
+    two lines (an E grant, then the probe that leaves the line ownerless
+    with two sharers); every other core pads with instructions and then
+    reads both, the even cores one line first and the odd cores the other,
+    all on one clock: several joiners of one directory entry in one step,
+    two entries a step. A store to the round's first line ends the round,
+    so joins are demoted and the entry's LRU stamp is read again."""
+    per_core = [[] for _ in range(n_cores)]
+    for r in range(4):
+        a = (first_line + 2 * r) * 64
+        b = a + 64
+        for c in range(n_cores):
+            if c < 2:
+                per_core[c] += [(EV_LD, 4, a), (EV_LD, 4, b)]
+            else:
+                x, y = (a, b) if c % 2 == 0 else (b, a)
+                per_core[c] += [(EV_INS, 60, 0), (EV_LD, 4, x), (EV_LD, 4, y)]
+        per_core[n_cores - 1] += [(EV_INS, 90, 0), (EV_ST, 4, a)]
+    return from_event_lists(per_core)
+
+
+def _joins_by_step(gold) -> dict:
+    """{(step, line): lanes that joined}, filled as the oracle runs."""
+    joined: dict = {}
+    do_join = gold._do_join
+
+    def counted(c, line, pre, step):
+        joined[step, line] = joined.get((step, line), 0) + 1
+        do_join(c, line, pre, step)
+
+    gold._do_join = counted
+    return joined
+
+
+# n_banks * sets * ways, the entries of the join table: under one row of
+# 128 lanes, over one row and no multiple of it (five ways), a multiple
+_JOIN_TABLE_MACHINES = {
+    "32_entries": dict(n_banks=2, llc=CacheConfig(size=1024, ways=4, line=64, latency=10)),
+    "160_entries": dict(n_banks=4, llc=CacheConfig(size=2560, ways=5, line=64, latency=10)),
+    "256_entries": dict(n_banks=4),
+}
+
+
+@pytest.mark.parametrize("n_elements", [2, 3])
+@pytest.mark.parametrize("machine", sorted(_JOIN_TABLE_MACHINES))
+def test_fleet_join_table_at_awkward_sizes(machine, n_elements):
+    # the join-LRU representative table has one form, rows of 128 lanes
+    # (`step.py::_join_representative`): whatever the table's size pads to,
+    # every element equals its solo Engine and the solo Engine the oracle
+    from primesim_tpu.golden.sim import GoldenSim
+
+    cfg = small_test_config(8, **_JOIN_TABLE_MACHINES[machine])
+    n = cfg.n_banks * cfg.llc.sets * cfg.llc.ways
+    assert n == int(machine.split("_")[0])
+    traces = [_joining_trace(8, first_line=3 * i) for i in range(n_elements)]
+    overrides = [{}, {"llc_lat": 25, "dram_lat": 140}, {"quantum": 150}][:n_elements]
+    fleet = FleetEngine(cfg, traces, overrides, chunk_steps=8)
+    fleet.run()
+    assert fleet.done()
+    for i, (t, ov) in enumerate(zip(traces, overrides)):
+        cfg_eff = apply_overrides(cfg, ov)
+        assert_element_matches_solo(fleet, i, cfg_eff, t, chunk_steps=8)
+        gold = GoldenSim(cfg_eff, t)
+        joined = _joins_by_step(gold)
+        gold.run()
+        # the trace does what it is for: one entry joined twice in a step
+        assert max(joined.values()) >= 2, joined
+        # the element equals its solo Engine in every field (above), so
+        # holding it to the oracle holds the solo Engine to it too
+        np.testing.assert_array_equal(fleet.cycles[i], gold.cycles)
+        counters = fleet.element_counters(i)
+        for k, v in gold.counters.items():
+            np.testing.assert_array_equal(counters[k], v, err_msg=f"elem {i} counter {k}")
+        # what the representative is for: a joined entry's LRU stamp is
+        # refreshed once, however many lanes joined it
+        llc_tag, _, llc_lru = llc_views(cfg_eff, fleet.element_state(i))
+        np.testing.assert_array_equal(llc_tag, gold.llc_tag, err_msg=f"elem {i} llc_tag")
+        np.testing.assert_array_equal(llc_lru, gold.llc_lru, err_msg=f"elem {i} llc_lru")
 
 
 def test_fleet_rejections():
