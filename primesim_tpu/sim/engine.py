@@ -141,6 +141,38 @@ def _drain_and_rebase(cfg, st, acc_lo, acc_hi, base_lo, base_hi, nd):
     return st, acc_lo, acc_hi, base_lo, base_hi
 
 
+def loop_live(cfg, events, carry, max_chunks):
+    """`run_loop`'s predicate on one machine's carry: chunks left, and a
+    core not yet at END. (`fleet_run_loop` maps it over its machines.)"""
+    st, k = carry[0], carry[-1]
+    with jax.named_scope(P_CHUNK):
+        return (k < max_chunks) & ~_device_done(
+            events, st, cfg.faults_enabled
+        )
+
+
+def loop_chunk(cfg, chunk_steps, events, carry, has_sync, mesh):
+    """`run_loop`'s body on one machine's carry: a scan of `chunk_steps`
+    steps, then the drain and the rebase, and one chunk counted."""
+    st, acc_lo, acc_hi, base_lo, base_hi, k = carry
+
+    def sbody(c, _):
+        return step(cfg, events, c, has_sync=has_sync, mesh=mesh), None
+
+    st, _ = jax.lax.scan(sbody, st, None, length=chunk_steps)
+    with jax.named_scope(P_CHUNK):
+        nd = events.at(st.ptr)[:, 0] != EV_END
+        if cfg.faults_enabled:
+            # dead cores must not bound the rebase minimum: their frozen
+            # clocks would pin delta at 0 forever (int32 overflow risk on
+            # long post-fault runs)
+            nd = nd & (st.faults.core_dead == 0)
+        st, acc_lo, acc_hi, base_lo, base_hi = _drain_and_rebase(
+            cfg, st, acc_lo, acc_hi, base_lo, base_hi, nd
+        )
+    return st, acc_lo, acc_hi, base_lo, base_hi, k + 1
+
+
 @functools.partial(
     mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
 )
@@ -157,40 +189,16 @@ def run_loop(cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
     (SURVEY.md §3.4) with zero host round-trips until the run completes.
     """
     events = DeviceTrace.of(events, cfg.local_run_len)
-
-    def cond(carry):
-        st, acc_lo, acc_hi, base_lo, base_hi, k = carry
-        with jax.named_scope(P_CHUNK):
-            return (k < max_chunks) & ~_device_done(
-                events, st, cfg.faults_enabled
-            )
-
-    def body(carry):
-        st, acc_lo, acc_hi, base_lo, base_hi, k = carry
-
-        def sbody(c, _):
-            return step(cfg, events, c, has_sync=has_sync, mesh=mesh), None
-
-        st, _ = jax.lax.scan(sbody, st, None, length=chunk_steps)
-        with jax.named_scope(P_CHUNK):
-            nd = events.at(st.ptr)[:, 0] != EV_END
-            if cfg.faults_enabled:
-                # dead cores must not bound the rebase minimum: their frozen
-                # clocks would pin delta at 0 forever (int32 overflow risk on
-                # long post-fault runs)
-                nd = nd & (st.faults.core_dead == 0)
-            st, acc_lo, acc_hi, base_lo, base_hi = _drain_and_rebase(
-                cfg, st, acc_lo, acc_hi, base_lo, base_hi, nd
-            )
-        return st, acc_lo, acc_hi, base_lo, base_hi, k + 1
-
     acc_lo = jnp.zeros_like(st.counters)
     acc_hi = jnp.zeros_like(st.counters)
     base_lo = jnp.asarray(0, jnp.int32)
     base_hi = jnp.asarray(0, jnp.int32)
     k = jnp.asarray(0, jnp.int32)
     return jax.lax.while_loop(
-        cond, body, (st, acc_lo, acc_hi, base_lo, base_hi, k)
+        lambda carry: loop_live(cfg, events, carry, max_chunks),
+        lambda carry: loop_chunk(
+            cfg, chunk_steps, events, carry, has_sync, mesh),
+        (st, acc_lo, acc_hi, base_lo, base_hi, k),
     )
 
 

@@ -6,8 +6,8 @@ any tested shape cost ~0.02 ms, so each kernel launch is mostly idle
 capacity. PriME's headline use case is throughput across many concurrent
 runs (the ISPASS'14 multi-host aggregate), and
 a parameter sweep is the common shape of that traffic. So: `jax.vmap` the
-existing `run_chunk`/`run_loop` over a leading batch axis of B independent
-simulations sharing one GEOMETRY (core count, cache shapes, mesh), and one
+existing `run_chunk`, and `run_loop`'s chunk, over a leading batch axis of
+B independent simulations sharing one GEOMETRY (core count, cache shapes, mesh), and one
 scan step retires one event per core *per simulation*.
 
 What that costs was measured on a TPU v5e in PR 42 and 43 (PERF.md
@@ -22,15 +22,21 @@ every phase at B = 16, the compiled text (`scripts/prof/compile_v5e.py
 4.A's join table, relaid whole under the batch axis one row a loop trip,
 every step, which `step.py::_join_representative` cured by reading the
 table as rows of a tile (0.280 ms a step an element since, 17.7 Minstr/s
-for 12.3). What is left there is the freeze: what `vmap`
-makes of `run_loop`'s `lax.while_loop` (below) selects and copies the
-whole carry, `dirm` included, ONCE A CHUNK (`broadcast_select_fusion` and
-`copy`, 15.8 ms a chunk at B = 16), so 1.98 ms a step at the benchmark
-cell's `chunk_steps` 8 and 0.06 at the CLI's default 256. Open: a loop
-whose freeze does not copy the carry (ROADMAP S5) and donation of it
-(S11: memory, at any chunk size); the benchmark cell `rung2.sweep-b16`
-(PR 43) is where either is judged, and one compile for the whole sweep
-and the served buckets' elastic slots are what the fleet gives today.
+for 12.3). The other half was the freeze, once a CHUNK: until PR 45
+`fleet_run_loop` was `jax.vmap(run_loop)`, and what `vmap` makes of a
+`lax.while_loop` with a batched predicate selects every leaf of the
+carry between old and new, `dirm` included (`broadcast_select_fusion`
+and `copy` of all B directories, 15.8 ms a chunk at B = 16: 1.98 ms a
+step at the benchmark cell's `chunk_steps` 8, 0.06 at the CLI's default
+256). Since PR 45 the loop over chunks is written out, outside the
+`vmap`, and freezes every leaf but the two a finished machine's step
+cannot change (`FREEZE_EXEMPT`, below): the fleet's step no longer
+depends on `chunk_steps` (PERF.md section 6, PR 45). Open: donation of
+the input state (ROADMAP S11: memory, at any chunk size) and the 13 %
+the phases cost more under the batch axis; the benchmark cell
+`rung2.sweep-b16` (PR 43) is where either is judged, and one compile for
+the whole sweep and the served buckets' elastic slots are what the fleet
+gives besides.
 
 Two design points make a whole sweep ONE compilation:
 
@@ -39,12 +45,18 @@ Two design points make a whole sweep ONE compilation:
   and stacked over the batch axis. The static jit key is
   `cfg.timing_normalized()`: every timing variant of one geometry hits the
   same cache entry.
-- Termination: `jax.vmap` of `lax.while_loop` runs the body while ANY
-  element's cond holds and SELECT-masks the carry, so finished elements
-  FREEZE at their own chunk boundary — exactly where a solo `run_loop`
-  with the same `chunk_steps` stops. Fleet element i is therefore
-  bit-exact with a solo `Engine` run of the same (config, trace),
-  including the step counter (tests/test_fleet.py).
+- Termination: `fleet_run_loop` runs the chunk for EVERY element while
+  ANY element's predicate holds (`engine.loop_live`, `run_loop`'s own)
+  and then puts back, leaf by leaf, what an element held before the
+  chunk if it was not live, so finished elements FREEZE at their own
+  chunk boundary — exactly where a solo `run_loop` with the same
+  `chunk_steps` stops. Fleet element i is therefore bit-exact with a solo
+  `Engine` run of the same (config, trace), including the step counter
+  (tests/test_fleet.py::test_fleet_freeze_elements_chunks_apart). The
+  two large leaves, `dirm` and `l1`, are handed on unselected: a step
+  over a machine whose cores all stand at END writes neither
+  (`test_finished_machine_keeps_exempt_leaves`), and selecting them
+  copied the directories once a chunk.
 
 Observability: `FleetEngine.run` opens the host spans `fleet.init`,
 `fleet.dispatch`, `fleet.wait` and `fleet.readback` (`obs/span.py`) and
@@ -77,7 +89,14 @@ from ..stats.counters import COUNTER_NAMES, STAT_NAMES, fold_block
 from ..trace.device import DeviceTrace
 from ..trace.format import EV_BARRIER, EV_END, EV_LOCK, EV_UNLOCK, Trace
 from . import exec_cache
-from .engine import _ACC_BITS, _np, commit_job, run_chunk, run_loop
+from .engine import (
+    _ACC_BITS,
+    _np,
+    commit_job,
+    loop_chunk,
+    loop_live,
+    run_chunk,
+)
 from .state import MachineState, init_state
 
 
@@ -204,6 +223,16 @@ def fleet_run_chunk(
     )(events, st)
 
 
+#: The state leaves `fleet_run_loop`'s freeze leaves out: a step writes
+#: them only for cores that present an event (`dirm` through a winner's
+#: or a joiner's row, `l1` through a hit, a grant, a fill or a local
+#: run), so a machine whose cores all stand at END hands them on as they
+#: were (tests/test_fleet.py::test_finished_machine_keeps_exempt_leaves).
+#: Every other leaf a finished machine's step can change (`step`, the
+#: counter block's stat rows) or is too small to be worth the proof.
+FREEZE_EXEMPT = ("dirm", "l1")
+
+
 @functools.partial(
     mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
 )
@@ -211,17 +240,45 @@ def fleet_run_loop(
     cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
     max_chunks, has_sync: bool = True, mesh=None,
 ):
-    """`run_loop` vmapped over the leading batch axis: one dispatched
-    device program for a whole FLEET run. Per-element drain/rebase and
-    termination come out of the vmap for free — the while_loop cond
-    batches to any(live) and the carry select-masks, so each element's
-    (state, counter accumulators, cycle base, chunk count) freezes the
-    moment it finishes."""
-    return jax.vmap(
-        lambda ev, s: run_loop(
-            cfg, chunk_steps, ev, s, max_chunks, has_sync=has_sync, mesh=mesh
-        )
-    )(events, st)
+    """`run_loop` for a whole FLEET, one dispatched device program: ONE
+    `lax.while_loop` over chunks, outside the `vmap`, whose predicate is
+    any(live) and whose body maps `run_loop`'s own body (`loop_chunk`)
+    over every machine, live or not, and then FREEZES the finished ones:
+    `where(live, new, old)` on every leaf of the carry but the state's
+    `FREEZE_EXEMPT`, which a finished machine's step cannot change. So
+    each element's (state, counter accumulators, cycle base, chunk count)
+    stops where a solo `run_loop` stops, `state.step` included, and the
+    directory is never copied. With `cfg.faults_enabled` phase -1
+    (`step.py::_fault`) rewrites `dirm` by the schedule's absolute step
+    index on finished machines too; what gates it is no part of that
+    proof, and every leaf is frozen.
+
+    `k` starts at 0 for every machine and counts with it while it lives,
+    so the live machines all hold the same `k`: when `max_chunks` stops
+    one that is not done it stops them all, and the body never runs over
+    a machine that still had work (whose exempt leaves would move)."""
+    events = DeviceTrace.of(events, cfg.local_run_len)
+    exempt = () if cfg.faults_enabled else FREEZE_EXEMPT
+    live_of = jax.vmap(lambda ev, c: loop_live(cfg, ev, c, max_chunks))
+    chunk_of = jax.vmap(
+        lambda ev, c: loop_chunk(cfg, chunk_steps, ev, c, has_sync, mesh))
+
+    def body(carry):
+        live = live_of(events, carry)
+        new = chunk_of(events, carry)
+        frozen = jax.tree.map(
+            lambda n, o: jnp.where(jnp.expand_dims(
+                live, tuple(range(1, n.ndim))), n, o),
+            new, carry)
+        st = frozen[0]._replace(**{f: getattr(new[0], f) for f in exempt})
+        return (st, *frozen[1:])
+
+    acc = jnp.zeros_like(st.counters)
+    zero = jnp.zeros_like(st.step)  # [B]: the bases and the chunk counts
+    return jax.lax.while_loop(
+        lambda carry: jnp.any(live_of(events, carry)), body,
+        (st, acc, acc, zero, zero, zero),
+    )
 
 
 class FleetEngine:
@@ -508,12 +565,15 @@ class FleetEngine:
         """Advance every LIVE element by `n_steps` (whole chunks) without
         the completion check — the checkpointed-run building block.
 
-        Unlike `run` (whose batched while_loop select-masks finished
-        elements), the plain vmapped scan steps EVERY element; a finished
-        element's steps are no-ops except the `step` counter (phase 0
-        proves quantum_end cannot bump once every core sits at END), so
-        its machine state stays bit-exact while `state.step` may run
-        ahead of a solo engine's."""
+        Unlike `run` (whose `fleet_run_loop` puts a finished element's
+        small leaves back after every chunk), the plain vmapped scan
+        steps EVERY element and freezes nothing; a finished element's
+        steps are no-ops except the `step` counter and, on a router
+        machine, the stat rows (phase 0 proves quantum_end cannot bump
+        once every core sits at END), so its machine state stays
+        bit-exact while `state.step` may run ahead of a solo engine's.
+        `fleet_run_loop` leans on the same invariant for `dirm` and `l1`
+        (`FREEZE_EXEMPT`)."""
         target = int(self.steps_run.max()) + n_steps
         while int(self.steps_run.max()) < target and not self.done():
             self._chunk_once()

@@ -32,7 +32,7 @@ An event scheduled at step S fires while executing step index S
 prefix fires exactly the events with step < P: any P at or below the
 divergence point is safe, and the planner additionally floors P to a
 chunk boundary so the solo prefix engine stops exactly where the fleet's
-select-masked chunks would.
+frozen chunks would.
 """
 
 from __future__ import annotations

@@ -20,7 +20,9 @@ and the tail of its `op_name`, which holds the phase scope, and every
 `while` nested inside the step (the chunk loop's scan body) with the
 arrays it carries: a loop there that no phase of `step` wrote is a
 relayout the compiler made (PERF.md section 6, PR 44: the fleet's join
-table, one element a trip). Nothing
+table, one element a trip); with `--fleet` also what the loop over
+chunks itself makes of the B directories' shape, once a chunk (PR 45:
+the freeze's select and copy, 2 ops, now none). Nothing
 runs: a time never comes from here (PERF.md section 6: PR 21, 31, 33,
 34 each read their collectives off this before a call).
 """
@@ -50,11 +52,9 @@ def op_name_of(line: str) -> str:
     return found.group(1) if found else ""
 
 
-def nested_whiles(text: str) -> list:
-    """(depth, computation, instruction, carried shapes, `op_name`) of
-    every `while` of a compiled module that lies more than two loops
-    deep: `run_loop` is the loop over chunks and, in its body, the scan
-    over a chunk's steps, so a third loop runs inside every step."""
+def computations(text: str) -> tuple:
+    """({computation: its lines}, the entry computation's name) of a
+    compiled module's text."""
     bodies: dict = {}
     entry = at = None
     for line in text.splitlines():
@@ -66,6 +66,30 @@ def nested_whiles(text: str) -> list:
                 entry = at
         elif at is not None:
             bodies[at].append(line)
+    return bodies, entry
+
+
+def chunk_loop_ops(text: str, shape: str) -> list:
+    """The instructions of `shape` (`s32[16,131072,192]`) that the body of
+    the loop over chunks runs itself, once a chunk: not what its scan over
+    the steps holds, and no reading of the carry (PERF.md section 6, PR
+    45: the fleet's freeze was a select and a copy of the B directories
+    there)."""
+    bodies, entry = computations(text)
+    outer = next(loop for loop in map(_WHILE.match, bodies[entry]) if loop)
+    made = re.compile(rf"^\s*(?:ROOT )?%?(\S+) = {re.escape(shape)}\S* ([\w\-]+)\(")
+    return [f"{found.group(1)} ({found.group(2)})"
+            for found in map(made.match, bodies[outer.group(3)])
+            if found and found.group(2) not in (
+                "while", "get-tuple-element", "parameter", "bitcast")]
+
+
+def nested_whiles(text: str) -> list:
+    """(depth, computation, instruction, carried shapes, `op_name`) of
+    every `while` of a compiled module that lies more than two loops
+    deep: `run_loop` is the loop over chunks and, in its body, the scan
+    over a chunk's steps, so a third loop runs inside every step."""
+    bodies, entry = computations(text)
     found, seen = [], set()
 
     def walk(name: str, depth: int) -> None:
@@ -154,6 +178,11 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
         if found:
             print(f"  {found.group(1)} {found.group(2)[:80]} | "
                   f"{op_name_of(line)[-60:]}")
+    if fleet:
+        dirm = f"s32[{','.join(str(n) for n in st.dirm.shape)}]"
+        ops = chunk_loop_ops(text, dirm)
+        print(f"ops of `dirm`'s shape {dirm} in the loop over chunks, outside "
+              f"its scan: {len(ops)} {' '.join(ops)}")
     loops = nested_whiles(text)
     print(f"loops inside the step: {len(loops)}")
     for depth, inside, name, shapes, op_name in loops:

@@ -3,22 +3,33 @@
 The contract is crisp: fleet element i must be BIT-EXACT with a solo
 `Engine` run of the same effective (config, trace) — final cycles, every
 stat counter, and the full machine state (L1/LLC/directory arrays, sync
-tables, LRU stamps, even the step counter: the batched while_loop
-select-masks finished elements at exactly the chunk boundary where a solo
-run_loop with the same chunk_steps stops). And a whole parameter sweep
+tables, LRU stamps, even the step counter: `fleet_run_loop` freezes a
+finished element at exactly the chunk boundary where a solo run_loop with
+the same chunk_steps stops, every leaf but the ones its step cannot
+change: `FREEZE_EXEMPT`, below). And a whole parameter sweep
 must be ONE compilation: the static jit key is the timing-normalized
 geometry, with every timing knob traced.
 """
 
+import dataclasses
+import re
+
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from primesim_tpu.analysis.recompile import recompile_sentinel
-from primesim_tpu.config.machine import CacheConfig, small_test_config
+from primesim_tpu.config.machine import (
+    FAULT_LINK_DEGRADE,
+    CacheConfig,
+    small_test_config,
+)
 from primesim_tpu.sim.engine import Engine
 from primesim_tpu.sim.fleet import (
+    FREEZE_EXEMPT,
     FleetEngine,
     apply_overrides,
+    fleet_run_chunk,
     fleet_run_loop,
 )
 from primesim_tpu.sim.validate import llc_views
@@ -32,10 +43,16 @@ def assert_element_matches_solo(fleet, i, cfg_eff, trace, chunk_steps):
     np.testing.assert_array_equal(
         fleet.cycles[i], solo.cycles, err_msg=f"elem {i} cycles"
     )
+    assert fleet.steps_run[i] == solo.steps_run, f"elem {i} steps_run"
+    assert fleet.cycle_base[i] == solo.cycle_base, f"elem {i} cycle_base"
     fc = fleet.element_counters(i)
     for k, v in solo.counters.items():
         np.testing.assert_array_equal(
             fc[k], v, err_msg=f"elem {i} counter {k}"
+        )
+    for k, v in solo.step_stats.items():
+        np.testing.assert_array_equal(
+            fleet.step_stats[k][i], v, err_msg=f"elem {i} stat row {k}"
         )
     es = fleet.element_state(i)
     for f in es._fields:
@@ -258,6 +275,104 @@ def test_fleet_join_table_at_awkward_sizes(machine, n_elements):
         llc_tag, _, llc_lru = llc_views(cfg_eff, fleet.element_state(i))
         np.testing.assert_array_equal(llc_tag, gold.llc_tag, err_msg=f"elem {i} llc_tag")
         np.testing.assert_array_equal(llc_lru, gold.llc_lru, err_msg=f"elem {i} llc_lru")
+
+
+def _router_dram(cfg):
+    return dataclasses.replace(
+        cfg, dram_queue=True, dram_service=20,
+        noc=dataclasses.replace(
+            cfg.noc, contention=True, contention_model="router"),
+    )
+
+
+def _freeze_case(name):
+    """(cfg, traces, overrides) of a fleet whose elements finish chunks
+    of 8 steps apart: what `fleet_run_loop`'s freeze has to hold."""
+    short, long_ = (synth.stream(4, n_mem_ops=4, seed=61),
+                    synth.uniform_random(4, n_mem_ops=60, seed=62))
+    cfg = small_test_config(4, n_banks=4, local_run_len=4)
+    overrides = [{}, {}]
+    if name == "router_dram":
+        cfg = _router_dram(cfg)
+    elif name == "sync":
+        short = synth.barrier_phases(4, n_phases=1, work_per_phase=3, seed=63)
+        long_ = synth.barrier_phases(4, n_phases=4, seed=64)
+    elif name == "faults":
+        # every step flips: what a finished machine's step would count or
+        # change shows at once
+        cfg = dataclasses.replace(
+            _router_dram(cfg), faults_enabled=True, max_fault_events=1,
+            fault_events=((30, FAULT_LINK_DEGRADE, 1, 3),),
+            fault_flip_l1=1.0, fault_flip_llc=1.0, fault_due_rate=0.25)
+        overrides = [{"fault_seed": 11}, {"fault_seed": 22}]
+    elif name == "quantum":
+        overrides = [{"quantum": 100}, {"quantum": 500, "llc_lat": 25}]
+    else:
+        assert name == "plain"
+    # the long element between two short ones: the freeze is by element
+    return cfg, [short, long_, short], [overrides[0], overrides[1], overrides[0]]
+
+
+_FREEZE_CASES = ("plain", "router_dram", "sync", "faults", "quantum")
+
+
+@pytest.mark.parametrize("case", _FREEZE_CASES)
+def test_fleet_freeze_elements_chunks_apart(case):
+    # the short elements stop chunks before the long one and the loop
+    # steps them on: each must come out as its solo Engine leaves it,
+    # `state.step`, steps, cycle base, counters and stat rows included
+    cfg, traces, overrides = _freeze_case(case)
+    fleet = FleetEngine(cfg, traces, overrides, chunk_steps=8)
+    fleet.run()
+    assert fleet.done()
+    steps = fleet.steps_run
+    assert steps[1] - steps[0] >= 16 and steps[2] == steps[0], steps
+    for i, (t, ov) in enumerate(zip(traces, overrides)):
+        assert_element_matches_solo(
+            fleet, i, apply_overrides(cfg, ov), t, chunk_steps=8)
+
+
+@pytest.mark.parametrize("case", _FREEZE_CASES)
+def test_finished_machine_keeps_exempt_leaves(case):
+    # the invariant `fleet_run_loop` leans on: a chunk of steps over a
+    # machine whose cores all stand at END leaves every FREEZE_EXEMPT
+    # leaf as it was (and `step` not: the steps did run)
+    cfg, traces, overrides = _freeze_case(case)
+    fleet = FleetEngine(cfg, traces[:2], overrides[:2], chunk_steps=8)
+    fleet.run()
+    assert fleet.done()
+    after = fleet_run_chunk(
+        fleet.geom_cfg, 8, fleet.events, fleet.state,
+        has_sync=fleet.has_sync)
+    np.testing.assert_array_equal(after.step, fleet.state.step + 8)
+    for f in FREEZE_EXEMPT:
+        np.testing.assert_array_equal(
+            getattr(after, f), getattr(fleet.state, f), err_msg=f)
+
+
+@pytest.mark.parametrize("faults", [False, True])
+def test_fleet_loop_selects_no_directory(faults):
+    # the compiled loop freezes by `select`: none has the shape of the
+    # batched `dirm` (nor of `l1`), unless the machine injects faults,
+    # whose loop exempts nothing
+    cfg = small_test_config(4, n_banks=4)
+    if faults:
+        cfg = dataclasses.replace(cfg, faults_enabled=True, max_fault_events=1)
+    fleet = FleetEngine(
+        cfg, [synth.stream(4, n_mem_ops=4, seed=s) for s in (1, 2, 3)],
+        chunk_steps=8)
+    text = fleet_run_loop.lower(
+        fleet.geom_cfg, 8, fleet.events, fleet.state,
+        jnp.asarray(4, jnp.int32), has_sync=fleet.has_sync,
+    ).compile().as_text()
+    for f in FREEZE_EXEMPT:
+        leaf = getattr(fleet.state, f)
+        shape = ",".join(str(n) for n in leaf.shape)
+        assert leaf.shape[0] == 3 and leaf.ndim == 3
+        selects = re.findall(rf"= s32\[{shape}\]\S* select\(", text)
+        assert bool(selects) == faults, (f, shape, len(selects))
+    # the text does say `select` where the freeze is: the clocks, [B, C]
+    assert re.search(r"= s32\[3,4\]\S* select\(", text)
 
 
 def test_fleet_rejections():
