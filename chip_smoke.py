@@ -10,16 +10,12 @@ the order bring-up proceeds, so the output shows how far it got:
                or exit, naming what was found.
 2. parity    — Engine vs the scalar oracle `golden.sim.GoldenSim`,
                per-core cycles and every counter equal, on the 64-core
-               rung-1 machine and the 8-core full-timing-stack machine,
-               under `step_impl` xla AND pallas.
+               rung-1 machine and the 8-core full-timing-stack machine.
 3. main path — `run configs/rung3_1024core_o3.json` on the 21.16 M-
                instruction bench trace (1024 cores, 32x32 router NoC,
                DRAM queue, O3; ~0.85 GB of state in HBM), the five
                backend-invariant counts pinned below.
-4. kernels   — the same run with `--step-impl pallas`: identical counts,
-               and the compiled `run_loop` holds Mosaic custom calls;
-               then `pallas_reduce=true` on the plain 1024-core machine.
-5. four chips — with >= 4 devices, phase 3 again with `--devices 4`.
+4. four chips — with >= 4 devices, phase 3 again with `--devices 4`.
 
 The last stdout line is exactly `{"ok": true, "device": {"platform",
 "kind", "count"}}`, the device as JAX reports it; nothing of the kind is
@@ -33,7 +29,6 @@ phase are JAX's own backend-compile events, so two runs against one
 from __future__ import annotations
 
 import contextlib
-import dataclasses
 import io
 import json
 import os
@@ -55,12 +50,6 @@ RUNG1_TRUTH = {"instructions": 191173, "max_core_cycles": 10693,
 RUNG3_TRUTH = {"instructions": 21163720, "max_core_cycles": 824798,
                "noc_msgs": 1077012, "noc_contention_cycles": 1454895220,
                "dram_queue_cycles": 100895397}
-PLAIN1024_TRUTH = {"instructions": 21163720, "max_core_cycles": 118141,
-                   "noc_msgs": 1093900}
-# tpu_custom_call sites in the compiled run_loop; update with the kernel
-# set (probe_classify + commit_step + sharer_reductions + router_cascade)
-RUNG3_MOSAIC_CALLS = 4
-PLAIN1024_MOSAIC_CALLS = 1  # pallas_reduce alone: sharer_reductions
 
 
 def fail(phase: str, reason: str):
@@ -142,14 +131,12 @@ def check_parity(cfg, trace, chunk_steps: int, platform: str) -> dict:
     if not np.array_equal(e.cycles, g.cycles):
         bad = np.flatnonzero(np.asarray(e.cycles) != np.asarray(g.cycles))
         fail("parity", f"per-core cycles differ from the oracle on "
-             f"{bad.size} core(s), first core {int(bad[0])} "
-             f"(step_impl={cfg.step_impl})")
+             f"{bad.size} core(s), first core {int(bad[0])}")
     ec = e.counters
     for name, gv in g.counters.items():
         if not np.array_equal(ec[name], gv):
             fail("parity", f"counter {name!r} differs from the oracle: "
-                 f"engine {int(ec[name].sum())} vs golden {int(gv.sum())} "
-                 f"(step_impl={cfg.step_impl})")
+                 f"engine {int(ec[name].sum())} vs golden {int(gv.sum())}")
     return {"instructions": int(ec["instructions"].sum()),
             "max_core_cycles": int(np.max(e.cycles)),
             "noc_msgs": int(ec["noc_msgs"].sum())}
@@ -187,20 +174,16 @@ def phase_parity(platform: str, meter: CompileMeter) -> dict:
     }
     out = {}
     for name, (cfg, trace, chunk) in cases.items():
-        for impl in ("xla", "pallas"):
-            t0 = time.perf_counter()
-            counts = check_parity(
-                dataclasses.replace(cfg, step_impl=impl), trace, chunk,
-                platform,
-            )
-            if name == "rung1_64core" and counts != RUNG1_TRUTH:
-                fail("parity", f"rung-1 counts {counts} != {RUNG1_TRUTH}")
-            out[f"{name}/{impl}"] = {
-                **counts, "wall_s": round(time.perf_counter() - t0, 2),
-                **meter.lap(),
-            }
-            print(f"[parity] {name} step_impl={impl} == oracle "
-                  f"{json.dumps(out[f'{name}/{impl}'])}", flush=True)
+        t0 = time.perf_counter()
+        counts = check_parity(cfg, trace, chunk, platform)
+        if name == "rung1_64core" and counts != RUNG1_TRUTH:
+            fail("parity", f"rung-1 counts {counts} != {RUNG1_TRUTH}")
+        out[name] = {
+            **counts, "wall_s": round(time.perf_counter() - t0, 2),
+            **meter.lap(),
+        }
+        print(f"[parity] {name} == oracle {json.dumps(out[name])}",
+              flush=True)
     return out
 
 
@@ -254,79 +237,12 @@ def run_and_check(phase: str, config: str, truth: dict, platform: str,
              f"{d['platform']!r} x{d['n_devices']}, expected "
              f"{platform!r} x{n_devices}")
     out = {
-        **{k: counts[k] for k in truth}, "step_impl": d["step_impl"],
+        **{k: counts[k] for k in truth},
         "platform": d["platform"], "device_kind": d["device_kind"],
         "n_devices": d["n_devices"], "wall_s": round(wall, 2),
         **meter.lap(), "run_s": d["wall_s"], "smoke_mips": rec["value"],
     }
     print(f"[{phase}] {json.dumps(out)}", flush=True)
-    return out
-
-
-def mosaic_calls(cfg) -> int:
-    """tpu_custom_call sites in the compiled `run_loop` of `cfg` on the
-    bench trace — lowered exactly as `primetpu run` dispatches it."""
-    import jax.numpy as jnp
-
-    from primesim_tpu.serve.scheduler import parse_synth_spec
-    from primesim_tpu.sim.engine import Engine, run_loop
-
-    eng = Engine(cfg, parse_synth_spec(BENCH_SYNTH, cfg.n_cores, True),
-                 chunk_steps=BENCH_CHUNK)
-    compiled = run_loop.lower(
-        cfg, BENCH_CHUNK, eng.events, eng.state, jnp.asarray(1, jnp.int32),
-        has_sync=eng.has_sync,
-    ).compile()
-    return compiled.as_text().count("tpu_custom_call")
-
-
-def phase_kernels(platform: str, meter: CompileMeter, rung3: str) -> dict:
-    from primesim_tpu.config.machine import (
-        CacheConfig,
-        MachineConfig,
-        NocConfig,
-    )
-    from primesim_tpu.kernels.layouts import interpret_mode
-
-    out = {"rung3_pallas": run_and_check(
-        "kernels", rung3, RUNG3_TRUTH, platform, meter,
-        extra=("--step-impl", "pallas"),
-    )}
-    if interpret_mode():
-        fail("kernels", "interpret_mode() is True: the Pallas kernels ran "
-             "interpreted, Mosaic compiled nothing")
-    with open(rung3) as f:
-        cfg3 = MachineConfig.from_json(f.read())
-    n = mosaic_calls(dataclasses.replace(cfg3, step_impl="pallas"))
-    if n != RUNG3_MOSAIC_CALLS:
-        fail("kernels", f"compiled rung-3 run_loop holds {n} "
-             f"tpu_custom_call(s), expected {RUNG3_MOSAIC_CALLS}")
-    out["rung3_pallas"]["mosaic_calls"] = n
-    print(f"[kernels] rung-3 run_loop: {n} Mosaic custom calls "
-          f"{json.dumps(meter.lap())}", flush=True)
-
-    # sharer_reductions alone: the plain 1024-core machine
-    # (benchmark/configs/mesh1024.json), pallas_reduce on, XLA step otherwise
-    plain = MachineConfig(
-        n_cores=1024, n_banks=1024,
-        l1=CacheConfig(size=32 * 1024, ways=4, line=64, latency=2),
-        llc=CacheConfig(size=256 * 1024, ways=8, line=64, latency=10),
-        noc=NocConfig(mesh_x=32, mesh_y=32, link_lat=1, router_lat=1),
-        dram_lat=100, quantum=1000, local_run_len=8, pallas_reduce=True,
-    )
-    with tempfile.TemporaryDirectory() as td:
-        path = os.path.join(td, "plain1024_pallas_reduce.json")
-        with open(path, "w") as f:
-            f.write(plain.to_json())
-        out["plain1024_pallas_reduce"] = run_and_check(
-            "kernels", path, PLAIN1024_TRUTH, platform, meter,
-        )
-    n = mosaic_calls(plain)
-    if n != PLAIN1024_MOSAIC_CALLS:
-        fail("kernels", f"compiled plain-1024 run_loop holds {n} "
-             f"tpu_custom_call(s), expected {PLAIN1024_MOSAIC_CALLS}")
-    out["plain1024_pallas_reduce"]["mosaic_calls"] = n
-    meter.lap()
     return out
 
 
@@ -376,7 +292,6 @@ def main(platform: str = "tpu") -> int:
     phases["main_path"] = run_and_check(
         "main_path", rung3, RUNG3_TRUTH, platform, meter
     )
-    phases["kernels"] = phase_kernels(platform, meter, rung3)
     phases["four_chips"] = phase_four_chips(platform, meter, rung3)
 
     import jax

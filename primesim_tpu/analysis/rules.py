@@ -11,9 +11,6 @@ repo has converged on over PRs 1-11:
   PT-JIT-KEY        every jax.jit site is review-gated (the jit key
                     must stay the timing-normalized geometry), and no
                     knob-derived name appears in static_argnames
-  PT-MOSAIC         kernels/ stays Mosaic-safe: core identity comes
-                    from data, never pl.program_id; no dynamic-shape
-                    ops outside the layouts.py idioms
   PT-DURABLE        no raw write-mode open() and no shared
                     deterministic "<path>.tmp" names on durability
                     paths — atomic_save_npz / journal append or bust
@@ -58,12 +55,10 @@ TRACED_FIELDS = KNOB_FIELDS | FAULT_FIELDS
 # key stops meaning anything.
 SELECTOR_FIELDS = frozenset({
     "topology", "coherence", "prefetcher", "contention_model",
-    "step_impl",
 })
 _TRACED_SELECTS = {"where", "select", "select_n", "cond", "switch"}
 
 _HOST_CASTS = {"bool", "float", "int"}
-_DYNSHAPE_OPS = {"nonzero", "flatnonzero", "unique", "argwhere"}
 
 
 def _traced_attrs(node: ast.AST):
@@ -81,7 +76,7 @@ def _traced_attrs(node: ast.AST):
 @rule(
     "PT-TRACED-BRANCH",
     "no Python control flow / host casts on traced knob or fault fields",
-    scope=("/sim/", "/kernels/", "/faults/"),
+    scope=("/sim/", "/faults/"),
 )
 def check_traced_branch(tree, ctx):
     hits: dict = {}
@@ -182,50 +177,6 @@ def check_jit_key(tree, ctx):
                                 "fault value in the jit key recompiles "
                                 "per knob variant",
                             )
-
-
-@rule(
-    "PT-MOSAIC",
-    "Mosaic safety: no pl.program_id core identity, no dynamic shapes",
-    scope=("/kernels/",),
-)
-def check_mosaic(tree, ctx):
-    in_layouts = ctx.relpath.endswith("layouts.py")
-    for node in ast.walk(tree):
-        if isinstance(node, ast.Attribute):
-            if node.attr == "program_id":
-                base = ast.unparse(node.value).lower()
-                if base == "pl" or "pallas" in base:
-                    yield (
-                        node.lineno, node.col_offset,
-                        "pl.program_id as core identity — Mosaic may "
-                        "re-tile the grid; core ids must arrive as "
-                        "data (iota/refs), never the grid index",
-                    )
-            elif node.attr in _DYNSHAPE_OPS and not in_layouts:
-                base = ast.unparse(node.value)
-                if base in ("jnp", "np", "jax.numpy", "numpy"):
-                    yield (
-                        node.lineno, node.col_offset,
-                        f"dynamic-shape op `{base}.{node.attr}` in a "
-                        "kernel file — data-dependent shapes cannot "
-                        "lower to Mosaic; keep these to layouts.py "
-                        "host-side planning",
-                    )
-        elif (
-            isinstance(node, ast.Call)
-            and isinstance(node.func, ast.Attribute)
-            and node.func.attr == "where"
-            and len(node.args) == 1
-            and not in_layouts
-        ):
-            base = ast.unparse(node.func.value)
-            if base in ("jnp", "np", "jax.numpy", "numpy"):
-                yield (
-                    node.lineno, node.col_offset,
-                    "single-argument where() is a dynamic-shape op — "
-                    "use the three-argument select form in kernels",
-                )
 
 
 def _open_write_mode(call: ast.Call) -> str | None:

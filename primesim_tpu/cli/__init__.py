@@ -104,7 +104,6 @@ def _emit_summary(
     tot_ins = int(counters["instructions"].sum())
     detail = {
         "engine": engine_name,
-        "step_impl": cfg.step_impl if engine_name != "golden" else None,
         "n_cores": cfg.n_cores,
         "instructions": tot_ins,
         "max_core_cycles": int(max(cycles)),
@@ -230,14 +229,6 @@ def _run_supervised(ns, cfg, eng, rec=None) -> int:
     )
     _finalize_obs(rec)
     return 0
-
-
-def _apply_step_impl(ns, cfg):
-    if getattr(ns, "step_impl", None) and ns.step_impl != cfg.step_impl:
-        import dataclasses
-
-        cfg = dataclasses.replace(cfg, step_impl=ns.step_impl)
-    return cfg
 
 
 def _apply_faults(ns, cfg):
@@ -366,7 +357,7 @@ def cmd_run(ns) -> int:
             "--exec-cache/--overlap require --engine jax (the golden "
             "oracle has no compiled program or device loop)"
         )
-    cfg = _apply_faults(ns, _apply_step_impl(ns, _load_config(ns.config)))
+    cfg = _apply_faults(ns, _load_config(ns.config))
     if cfg.faults_enabled and ns.engine == "golden":
         raise SystemExit(
             "fault injection requires --engine jax (the golden oracle "
@@ -692,7 +683,7 @@ def cmd_sweep(ns) -> int:
     t_start = time.perf_counter()
     cache = _activate_exec_cache(ns)
     overlap = getattr(ns, "overlap", "off") == "on"
-    cfg = _apply_faults(ns, _apply_step_impl(ns, _load_config(ns.config)))
+    cfg = _apply_faults(ns, _load_config(ns.config))
     _check_supervision_flags(ns)
     if ns.workers:
         # elastic pool path (DESIGN.md §17): coordinator in-process, N
@@ -1413,7 +1404,7 @@ def cmd_serve(ns) -> int:
     slots as elements retire, WAL-journaled so kill -9 loses nothing.
     SIGTERM drains (checkpoint + exit 75 when work remains); SIGHUP
     reloads --config's fault schedule (same geometry only)."""
-    cfg = _apply_faults(ns, _apply_step_impl(ns, _load_config(ns.config)))
+    cfg = _apply_faults(ns, _load_config(ns.config))
     from ..serve.quota import TenantQuota
     from ..serve.server import PrimeServer
 
@@ -1941,12 +1932,6 @@ def build_parser() -> argparse.ArgumentParser:
         "--fold", action="store_true", help="fold INS batches into pre fields"
     )
     r.add_argument("--engine", choices=("jax", "golden"), default="jax")
-    r.add_argument(
-        "--step-impl", choices=("xla", "pallas"), default=None,
-        help="step implementation (jax engine): 'pallas' routes phase "
-             "1/4 + the reductions through the fused VMEM step kernels "
-             "(kernels/, DESIGN.md §11); default: the config's step_impl",
-    )
     r.add_argument("--chunk-steps", type=int, default=256)
     r.add_argument(
         "--max-steps", type=int, default=None,
@@ -2032,12 +2017,6 @@ def build_parser() -> argparse.ArgumentParser:
     )
     w.add_argument(
         "--fold", action="store_true", help="fold INS batches into pre fields"
-    )
-    w.add_argument(
-        "--step-impl", choices=("xla", "pallas"), default=None,
-        help="step implementation for every fleet element (geometry-keyed "
-             "like the rest of the jit key: the whole sweep still "
-             "compiles once; timing knobs stay traced)",
     )
     w.add_argument("--chunk-steps", type=int, default=256)
     w.add_argument("--max-steps", type=int, default=None)
@@ -2269,10 +2248,6 @@ def build_parser() -> argparse.ArgumentParser:
         "--idle-exit", type=float, default=None, metavar="SEC",
         help="exit 0 after SEC seconds with nothing queued or running "
              "(one-shot/CI mode; default: serve forever)",
-    )
-    v.add_argument(
-        "--step-impl", choices=("xla", "pallas"), default=None,
-        help="step implementation for the serving fleets",
     )
     v.add_argument(
         "--report", metavar="PATH",

@@ -271,12 +271,6 @@ class MachineConfig:
     # `dram_queue_cycles`; golden and engine are bit-exact.
     dram_queue: bool = False
     dram_service: int = 0
-    # Route the dense sharer-expansion reductions through the Pallas TPU
-    # kernel (primesim_tpu/kernels/reductions.py) instead of the jnp path —
-    # bit-identical results; full-map vectors only (the coarse/chunked
-    # modes have their own reduction shapes). On the CPU the kernel runs
-    # interpreted, so tests exercise it; on a TPU Mosaic compiles it.
-    pallas_reduce: bool = False
     quantum: int = 1000  # relaxed-sync quantum, cycles (the fidelity/speed knob)
     # Local-run length: how many LOCAL events (INS batches, L1 hits) each
     # core may retire per step BEFORE the one arbitrated uncore event
@@ -311,17 +305,6 @@ class MachineConfig:
     # commute). Both engines implement the identical model; parity is
     # proven at small scale with G in {4, 32} (tests/test_coarse.py).
     sharer_group: int = 1
-    # Step-body implementation (DESIGN.md §11): "xla" keeps the original
-    # per-phase gather/scatter graph; "pallas" routes the step's dominant
-    # serial segments through the VMEM-resident fused kernels in
-    # primesim_tpu/kernels/ (probe_classify + commit, plus the sharer
-    # reduction) to beat the per-kernel-overhead floor on TPU. Bit-exact
-    # either way (tests/test_step_pallas.py proves golden/xla/pallas
-    # three-way parity); a GEOMETRY selector, so it is part of the jit
-    # key but timing knobs stay traced — fleet sweeps still compile once.
-    # On the CPU the kernels run in Pallas interpreter mode; on a TPU
-    # Mosaic compiles them (kernels/layouts.py::interpret_mode).
-    step_impl: str = "xla"
     # ---- machine zoo selectors (DESIGN.md §25) --------------------------
     # STATIC coherence selector: "mesi" (the default pull-based protocol)
     # or "moesi" — adds the Owned state: a GETS to a modified line leaves
@@ -438,15 +421,6 @@ class MachineConfig:
             raise ValueError("barrier_slots must be a power of two")
         if not _is_pow2(self.sharer_group):
             raise ValueError("sharer_group must be a power of two >= 1")
-        if self.pallas_reduce and (
-            self.sharer_group > 1 or self.sharer_chunk_words
-        ):
-            raise ValueError(
-                "pallas_reduce covers the dense full-map reduction only "
-                "(sharer_group == 1, sharer_chunk_words == 0)"
-            )
-        if self.step_impl not in ("xla", "pallas"):
-            raise ValueError("step_impl must be 'xla' or 'pallas'")
         if self.sharer_chunk_words < 0:
             raise ValueError("sharer_chunk_words must be >= 0")
         if self.sharer_chunk_words and (
@@ -605,6 +579,15 @@ class MachineConfig:
     def from_dict(d: dict) -> "MachineConfig":
         # keys starting with "_" are annotations ("_comment"), not fields
         d = {k: v for k, v in d.items() if not k.startswith("_")}
+        # the two options of the Pallas step, removed in PR 46: the
+        # benchmark's files and the parent's checkpoints still state their
+        # defaults (ROADMAP D15: the shim goes once `benchmark/` drops them)
+        for gone, default in (("step_impl", "xla"), ("pallas_reduce", False)):
+            said = d.pop(gone, default)
+            if said != default:
+                raise ConfigError(
+                    "the Pallas step was removed in PR 46; the one step "
+                    f"is what {default!r} ran", selector=gone, value=said)
         if "core" in d and isinstance(d["core"], dict):
             c = dict(d["core"])
             if c.get("cpi_per_core") is not None:

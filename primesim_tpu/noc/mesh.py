@@ -4,8 +4,8 @@ TPU-native replacement for the reference's hop-by-hop `Network` mesh router
 (SURVEY.md §2 #6). v1 is the analytic uncontended model shared verbatim by
 the golden simulator and the JAX engine (these helpers are written so they
 work on NumPy arrays AND traced jnp arrays alike). The congestion-aware
-Pallas router (per-link occupancy, ICI neighbor exchange under shard_map) is
-the planned v2 behind `NocConfig` gating.
+models (per-link occupancy counts; the router's per-link next-free clocks,
+`sim/step.py::_router_walk`) sit behind `NocConfig` gating.
 """
 
 from __future__ import annotations
@@ -103,9 +103,9 @@ def concat_legs(legs):
     (path_links result [C, H], lane mask [C]) pairs.  Both the "link"
     occupancy count and the hop-by-hop router block run every per-link
     operation ONCE over this concatenation (one scatter-add; or one
-    sorted pass for rank and link state, one for the departures) —
-    per-kernel overhead is the budget, so per-path loops become per-path
-    kernels (sim/step.py::_router_walk)."""
+    sorted pass for rank and link state, one for the departures) — the
+    overhead of each device op is the budget, and a loop over paths would
+    pay it once a path (sim/step.py::_router_walk)."""
     pths = [p for p, _ in legs]
     masks = [jnp.broadcast_to(m[:, None], p.shape) for p, m in legs]
     return jnp.concatenate(pths, axis=1), jnp.concatenate(masks, axis=1)

@@ -41,8 +41,7 @@ __all__ = [
 
 def coord_hops(topology: str, ax, ay, bx, by, mesh_x: int, mesh_y: int, xp=jnp):
     """Hop count between tile COORDINATES under `topology`; `xp` picks the
-    array module (np/jnp — also the form the Pallas reduction kernel
-    inlines, all elementwise min/abs/where arithmetic)."""
+    array module (np/jnp: all elementwise min/abs/where arithmetic)."""
     if topology == "torus":
         return _torus.ring_dist(xp, ax, bx, mesh_x) + _torus.ring_dist(
             xp, ay, by, mesh_y

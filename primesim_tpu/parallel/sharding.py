@@ -122,27 +122,6 @@ def validate_devices(cfg, n_devices: int) -> None:
             visible=visible,
         )
     validate_mesh_shape(cfg, n_devices, visible)
-    if n_devices > 1 and (cfg.step_impl == "pallas" or cfg.pallas_reduce):
-        from ..config.machine import ConfigError
-        from ..kernels.layouts import interpret_mode
-
-        if not interpret_mode():
-            # measured on the v5e toolchain (PR 21): lowering a sharded
-            # step refuses with "Mosaic kernels cannot be automatically
-            # partitioned. Please wrap the call in a shard_map." Say so
-            # up front, typed, instead of mid-lowering — and never as a
-            # silent all-gather around a replicated kernel.
-            selector = (
-                "step_impl" if cfg.step_impl == "pallas" else "pallas_reduce"
-            )
-            raise ConfigError(
-                f"the Pallas kernels cannot run sharded over {n_devices} "
-                "TPU devices: a Mosaic kernel has no partitioning rule; "
-                "use step_impl='xla' and pallas_reduce=false with "
-                "--devices, or run on one device",
-                selector=selector,
-                value=getattr(cfg, selector),
-            )
 
 
 def largest_valid_submesh(cfg, n_available: int) -> int:

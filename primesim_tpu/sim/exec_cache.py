@@ -20,8 +20,8 @@ Key derivation — the sha256 of a canonical-JSON payload over:
   - the entry-point name
   - `cfg.timing_normalized()` geometry hash — timing knobs are TRACED
     (they live in `state.knobs`), so one executable serves every
-    timing variant of a geometry; `step_impl` and the model selectors
-    ride inside the normalized config JSON
+    timing variant of a geometry; the model selectors ride inside the
+    normalized config JSON
   - the remaining static args (chunk_steps) and static kwargs
     (has_sync)
   - per-leaf avals of the dynamic args: shape, dtype, weak_type, and

@@ -67,8 +67,6 @@ steps, and `caps` that say B.
 Scope: preloaded traces only. Streamed (windowed) ingest stays solo — the
 host-side window refill rate is per-element state, and batching it buys
 nothing while any element's refill stalls the fleet (see DESIGN.md §6).
-`pallas_reduce` configs are rejected: the Pallas kernel bakes link/router
-latencies in as static kernel params.
 """
 
 from __future__ import annotations
@@ -302,13 +300,6 @@ class FleetEngine:
         force_sync: bool = False,
         mesh=None,
     ):
-        if cfg.pallas_reduce:
-            raise ValueError(
-                "FleetEngine does not support pallas_reduce configs: the "
-                "Pallas reduction kernel takes link/router latencies as "
-                "static kernel parameters, which defeats the fleet's "
-                "traced-knob compilation sharing"
-            )
         traces = list(traces)
         if not traces:
             raise ValueError("FleetEngine needs at least one trace")

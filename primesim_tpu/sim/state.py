@@ -24,7 +24,7 @@ I, S, E, M = 0, 1, 2, 3
 # never stored: the L1 plane still holds only I/S/E/M, and an access sees
 # O when the directory says this core owns the line while other sharers
 # are recorded (a GETS left the dirty copy in place). Keeping O out of
-# the stored encoding keeps every plane layout and Pallas kernel
+# the stored encoding keeps every plane layout
 # unchanged; O > M so `>= E`-style "exclusive" tests must be written as
 # the explicit (== E) | (== M) pair wherever a derived state can appear.
 O = 4
@@ -40,11 +40,7 @@ def llc_meta_width(cfg: MachineConfig) -> int:
 
 def dirm_width(cfg: MachineConfig) -> int:
     """Full `dirm` row width: metadata prefix + W2*NW packed sharer
-    words. These row/plane layouts are a PUBLIC contract: the Pallas
-    step kernels (kernels/layouts.py, DESIGN.md §11) stage `dirm` rows
-    and the five-plane L1 blocks into VMEM verbatim and hard-code the
-    same column maps — change a layout here and the kernels' index maps
-    must move with it (the three-way parity suite catches drift)."""
+    words."""
     return llc_meta_width(cfg) + cfg.llc.ways * cfg.n_sharer_words
 
 

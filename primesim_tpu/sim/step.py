@@ -441,14 +441,10 @@ class Request(NamedTuple):
     g_c: jax.Array  # this core's sharer bit: group, word and bit in word
     word_idx: jax.Array
     bit_idx: jax.Array
-    # step_impl "xla": the parsed home row, [C, W2], [C, W2], [C, W2, NW]
-    llc_tag_rows: jax.Array | None
-    owner_rows: jax.Array | None
-    sh_rows: jax.Array | None
-    # step_impl "pallas": probe_classify's lane block [C, PL_*] and the
-    # victim's sharer words (the kernel chose the victim already)
-    pc_lanes: jax.Array | None
-    vic_shw: jax.Array | None
+    # the parsed home row, [C, W2], [C, W2], [C, W2, NW]
+    llc_tag_rows: jax.Array
+    owner_rows: jax.Array
+    sh_rows: jax.Array
 
 
 class DirOutcome(NamedTuple):
@@ -472,7 +468,7 @@ class DirOutcome(NamedTuple):
     llc_vway: jax.Array  # the miss's victim way, its owner, whether valid
     vic_owner: jax.Array
     vic_valid: jax.Array
-    llc_lru_rows: jax.Array | None  # [C, W2] (step_impl "xla")
+    llc_lru_rows: jax.Array  # [C, W2]
     inv_lat: jax.Array  # invalidation fan-out of a write hit: slowest
     inv_count: jax.Array  # round trip, messages, hops
     inv_hops: jax.Array
@@ -807,15 +803,12 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
     candidate after its local run), its L1 probe, the parse of its home
     set's directory row, and its classification -> the `Request`; and
     into `acc` the three stat rows that say where this step's core-steps
-    went (the fourth share, cores at END, is what they leave of C).
-    Under `step_impl="pallas"` the probe, the parse and the victim choice
-    are ONE kernel (`probe_classify`) and the record carries its lanes."""
+    went (the fourth share, cores at END, is what they leave of C)."""
     C, B = cfg.n_cores, cfg.n_banks
-    S1, W1 = cfg.l1.sets, cfg.l1.ways
+    S1 = cfg.l1.sets
     S2, W2 = cfg.llc.sets, cfg.llc.ways
     NW = cfg.n_sharer_words
     MW = llc_meta_width(cfg)
-    FS = W1 * S1  # plane stride in the fused L1 array
     logB = B.bit_length() - 1
     rl = cfg.local_run_len
     l1_c, step_no = st.l1, st.step
@@ -838,50 +831,16 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
         et, earg, eaddr, epre = ev[:, 0], ev[:, 1], ev[:, 2], ev[:, 3]
         line = eaddr
         l1s = line & (S1 - 1)
-        pallas_step = cfg.step_impl == "pallas"
-        llc_tag_rows = owner_rows = sh_rows = pc_lanes = vic_shw = None
-        if pallas_step:
-            # [PALLAS] fused probe_classify (DESIGN.md §11): phase 1 AND the
-            # LLC home-row parse below run as ONE VMEM-blocked kernel. XLA
-            # keeps only the two row gathers that STAGE the directory rows
-            # into the kernel (data-dependent row gathers are the one access
-            # shape the block model cannot express); everything downstream of
-            # them — plane selects, pointer validation, classification,
-            # sharer predicates, victim selection — fuses.
-            from ..kernels.step_kernels import probe_classify
-
-            DWK = dirm_width(cfg)
-            bank = line & (B - 1)
-            bset = (line >> logB) & (S2 - 1)
-            slot = bank * S2 + bset
-            meta_rows = st.dirm[slot]  # [C, DW], reused by commit_step
-            w1cols = jnp.arange(W1, dtype=jnp.int32)[None, :] * S1 + l1s[:, None]
-            ptr_pre = jnp.take_along_axis(l1_c, w1cols + 3 * FS, axis=1)
-            vrows = st.dirm[ptr_pre // W2].reshape(C, W1 * DWK)
-            tag_rows, lru_rows, weff, shw, vic_shw, pc_lanes = probe_classify(
-                cfg, l1_c, vrows, meta_rows, line, arange_c, step_no,
-                *(run_patch or ()),
-            )
-            from ..kernels.step_kernels import (
-                PL_HIT_ANY,
-                PL_HIT_STATE,
-                PL_HIT_WAY,
-            )
-
-            hit_any = pc_lanes[:, PL_HIT_ANY] != 0
-            hit_way = pc_lanes[:, PL_HIT_WAY]
-            hit_state = pc_lanes[:, PL_HIT_STATE]
-        else:
-            w1cols, tag_rows, lru_rows, weff = _l1_probe(
-                cfg, arange_c, l1_c, st.dirm, line,
-                run_patch=run_patch,
-                step_no=step_no,
-                mesh=mesh,
-            )
-            l1_match = (tag_rows == line[:, None]) & (weff != I)
-            hit_any = jnp.any(l1_match, axis=1)
-            hit_way = jnp.argmax(l1_match, axis=1).astype(jnp.int32)
-            hit_state = weff[arange_c, hit_way]
+        w1cols, tag_rows, lru_rows, weff = _l1_probe(
+            cfg, arange_c, l1_c, st.dirm, line,
+            run_patch=run_patch,
+            step_no=step_no,
+            mesh=mesh,
+        )
+        l1_match = (tag_rows == line[:, None]) & (weff != I)
+        hit_any = jnp.any(l1_match, axis=1)
+        hit_way = jnp.argmax(l1_match, axis=1).astype(jnp.int32)
+        hit_state = weff[arange_c, hit_way]
 
         not_done = et != EV_END
         frozen = (et == EV_BARRIER) & (st.sync_flag != 0)
@@ -913,30 +872,22 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
         # ONE full-row gather returns the home set's tags, owners AND LRU
         # stamps; the owner, victim-owner and victim-LRU reads below become
         # in-register row indexing instead of separate element gathers.
-        if pallas_step:
-            # [PALLAS] parse already fused into probe_classify; unpack lanes
-            from ..kernels.step_kernels import PL_LLC_HAS, PL_LLC_HWAY, PL_OWNER
-
-            llc_has = pc_lanes[:, PL_LLC_HAS] != 0
-            llc_hway = pc_lanes[:, PL_LLC_HWAY]
-            owner = pc_lanes[:, PL_OWNER]
-        else:
-            bank = line & (B - 1)
-            bset = (line >> logB) & (S2 - 1)
-            slot = bank * S2 + bset  # [C], exact (bank,set) id
-            meta_rows = st.dirm[slot]  # [C, DW]: the set's metadata AND sharers
-            mr2 = meta_rows[:, : 2 * W2].reshape(C, W2, 2)
-            llc_tag_rows = mr2[..., 0]  # [C, W2]
-            owner_rows = mr2[..., 1]
-            llc_match = llc_tag_rows == line[:, None]
-            llc_has = jnp.any(llc_match, axis=1)
-            llc_hway = jnp.argmax(llc_match, axis=1).astype(jnp.int32)
-            owner = owner_rows[arange_c, llc_hway]  # [C]
-            # the sharer words came along in the same row gather
-            sh_rows = meta_rows[:, MW:].reshape(C, W2, NW)  # [C, W2, NW]
-            shw = jnp.take_along_axis(
-                sh_rows, llc_hway[:, None, None], axis=1
-            )[:, 0]
+        bank = line & (B - 1)
+        bset = (line >> logB) & (S2 - 1)
+        slot = bank * S2 + bset  # [C], exact (bank,set) id
+        meta_rows = st.dirm[slot]  # [C, DW]: the set's metadata AND sharers
+        mr2 = meta_rows[:, : 2 * W2].reshape(C, W2, 2)
+        llc_tag_rows = mr2[..., 0]  # [C, W2]
+        owner_rows = mr2[..., 1]
+        llc_match = llc_tag_rows == line[:, None]
+        llc_has = jnp.any(llc_match, axis=1)
+        llc_hway = jnp.argmax(llc_match, axis=1).astype(jnp.int32)
+        owner = owner_rows[arange_c, llc_hway]  # [C]
+        # the sharer words came along in the same row gather
+        sh_rows = meta_rows[:, MW:].reshape(C, W2, NW)  # [C, W2, NW]
+        shw = jnp.take_along_axis(
+            sh_rows, llc_hway[:, None, None], axis=1
+        )[:, 0]
 
         # sharer-set predicates from the PACKED words — popcount minus the
         # self bit needs no [C, C] expansion (the expansion, when needed for
@@ -948,25 +899,19 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
         word_idx = g_c // 32  # [C] self -> sharer word
         bit_idx = g_c % 32
 
-        if pallas_step:
-            from ..kernels.step_kernels import PL_OTHER_SH, PL_SELF_BIT
-
-            self_bit = pc_lanes[:, PL_SELF_BIT]
-            other_sharers = pc_lanes[:, PL_OTHER_SH] != 0
+        self_bit = (
+            (shw[arange_c, word_idx] >> bit_idx) & 1
+        ).astype(jnp.int32)
+        total_sharers = jnp.sum(
+            jax.lax.population_count(shw), axis=1
+        ).astype(jnp.int32)
+        if cfg.sharer_group > 1:
+            # coarse: the requester's own group bit may cover OTHER
+            # cores, so exclusivity (E grants) requires an empty vector
+            # (golden `shared_any`)
+            other_sharers = total_sharers > 0
         else:
-            self_bit = (
-                (shw[arange_c, word_idx] >> bit_idx) & 1
-            ).astype(jnp.int32)
-            total_sharers = jnp.sum(
-                jax.lax.population_count(shw), axis=1
-            ).astype(jnp.int32)
-            if cfg.sharer_group > 1:
-                # coarse: the requester's own group bit may cover OTHER
-                # cores, so exclusivity (E grants) requires an empty vector
-                # (golden `shared_any`)
-                other_sharers = total_sharers > 0
-            else:
-                other_sharers = (total_sharers - self_bit) > 0
+            other_sharers = (total_sharers - self_bit) > 0
 
         if cfg.coherence == "moesi":
             # derived Owned (DESIGN.md §25): a stored E/M hit while the home
@@ -1002,7 +947,6 @@ def _probe(cfg: MachineConfig, events, st: MachineState, arange_c, cycles_c,
         llc_hway=llc_hway, owner=owner, shw=shw, other_sharers=other_sharers,
         g_c=g_c, word_idx=word_idx, bit_idx=bit_idx,
         llc_tag_rows=llc_tag_rows, owner_rows=owner_rows, sh_rows=sh_rows,
-        pc_lanes=pc_lanes, vic_shw=vic_shw,
     )
 
 
@@ -1177,12 +1121,11 @@ def _dir_transition(cfg: MachineConfig, st: MachineState, arange_c,
     NW = cfg.n_sharer_words
     n_tiles = cfg.n_tiles
     logG = cfg.sharer_group.bit_length() - 1
-    pallas_step = cfg.step_impl == "pallas"
     kn = st.knobs
     line, llc_has, owner = rq.line, rq.llc_has, rq.owner
     gets, getm, upg, other_sharers = rq.gets, rq.getm, rq.upg, rq.other_sharers
-    shw, vic_shw, g_c = rq.shw, rq.vic_shw, rq.g_c
-    rr_po = llc_lru_rows = None
+    shw, g_c = rq.shw, rq.g_c
+    rr_po = None
     with jax.named_scope(P_DIR):
         llc_hit = llc_has & winner
         llc_miss = winner & ~llc_has
@@ -1216,29 +1159,15 @@ def _dir_transition(cfg: MachineConfig, st: MachineState, arange_c,
         write_probe = write_w & llc_hit & has_owner
 
         # --- LLC miss: victim + back-invalidation
-        if pallas_step:
-            # [PALLAS] victim chosen inside probe_classify (first-minimum
-            # LRU over valid ways, identical tie-breaking); vic_shw is a
-            # kernel output
-            from ..kernels.step_kernels import (
-                PL_LLC_VWAY,
-                PL_VIC_OWNER,
-                PL_VIC_TAG,
-            )
-
-            vic_tag = rq.pc_lanes[:, PL_VIC_TAG]
-            vic_owner = rq.pc_lanes[:, PL_VIC_OWNER]
-            llc_vway = rq.pc_lanes[:, PL_LLC_VWAY]
-        else:
-            llc_state_valid = rq.llc_tag_rows != -1
-            llc_lru_rows = rq.meta_rows[:, 2 * W2 : 3 * W2]  # [C, W2], row gather
-            vkey = jnp.where(llc_state_valid, llc_lru_rows, -1)
-            llc_vway = jnp.argmin(vkey, axis=1).astype(jnp.int32)
-            vic_tag = rq.llc_tag_rows[arange_c, llc_vway]
-            vic_owner = rq.owner_rows[arange_c, llc_vway]
-            vic_shw = jnp.take_along_axis(
-                rq.sh_rows, llc_vway[:, None, None], axis=1
-            )[:, 0]
+        llc_state_valid = rq.llc_tag_rows != -1
+        llc_lru_rows = rq.meta_rows[:, 2 * W2 : 3 * W2]  # [C, W2], row gather
+        vkey = jnp.where(llc_state_valid, llc_lru_rows, -1)
+        llc_vway = jnp.argmin(vkey, axis=1).astype(jnp.int32)
+        vic_tag = rq.llc_tag_rows[arange_c, llc_vway]
+        vic_owner = rq.owner_rows[arange_c, llc_vway]
+        vic_shw = jnp.take_along_axis(
+            rq.sh_rows, llc_vway[:, None, None], axis=1
+        )[:, 0]
         vic_valid = llc_miss & (vic_tag != -1)
 
         # --- invalidation + back-invalidation target reductions. Targets come
@@ -1369,20 +1298,6 @@ def _dir_transition(cfg: MachineConfig, st: MachineState, arange_c,
                 (inv_lat, inv_count, inv_hops, back_count, back_hops), _ = jax.lax.scan(
                     _blk, (z5, z5, z5, z5, z5), jnp.arange(nblk, dtype=jnp.int32)
                 )
-        elif cfg.pallas_reduce or pallas_step:
-            # same dense reduction as the branch below, as ONE Pallas kernel
-            # (SURVEY §2 #4's Pallas uncore piece; the step subsystem's third
-            # resident kernel — step_impl="pallas" routes it unconditionally);
-            # bit-identical. Latencies are the TRACED knobs, so fleet sweeps
-            # through this kernel compile once per geometry.
-            from ..kernels.reductions import sharer_reductions
-
-            (inv_lat, inv_count, inv_hops, back_count, back_hops) = (
-                sharer_reductions(
-                    cfg, shw, vic_shw, btile, vic_owner, inv_row, vic_valid,
-                    arange_c, kn.link_lat, kn.router_lat,
-                )
-            )
         else:
             ttile = arange_c % n_tiles  # target tiles
             pair_lat, pair_hops = _one_way(btile[:, None], ttile[None, :], cfg, kn)
@@ -1554,7 +1469,6 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
     entries, key order `ord_c`: ops/ranking.py, DESIGN.md §13, O(E log E)
     and no table indexed entry by entry. Bit-exact vs the golden scalar
     walk (tests/test_router.py)."""
-    pallas_step = cfg.step_impl == "pallas"
     cpi_vec, l1_lat, llc_lat = kn.cpi, kn.l1_lat, kn.llc_lat
     epre, is_lock, is_unlock, is_barrier = (
         rq.epre, rq.is_lock, rq.is_unlock, rq.is_barrier)
@@ -1622,43 +1536,29 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
                 tgt_all, a_all, link_free, order=ord_c
             )
         arr_lat_a, arr_hops = _one_way(ctile, htile, cfg, kn)
-        if pallas_step:
-            # [PALLAS] wait floors + per-leg cummax cascades + departure
-            # composition fused in one VMEM kernel (router_kernels.py);
-            # the sorted passes above and below stay XLA: a sort is not
-            # a block the kernel's model holds. The kernel keeps its
-            # signature and forms max(lf, bs) itself: the floor term,
-            # which is that maximum already, is handed in as both
-            from ..kernels.router_kernels import router_cascade
+        F_all = jnp.where(
+            ok_all, fl_all + r_all * L_lat, SENT
+        )  # [C, legs*H] wait floors
 
-            t_rep_end, t_arr_end, d_all = router_cascade(
-                fl_all, fl_all, r_all, ok_all, t0, service, req_hops,
-                rep_hops, arr_hops, L_lat, R_lat, has_sync=has_sync,
+        def _cascade(t_start, F, nh):
+            G = F - hidx * c_hop
+            cum = jax.lax.cummax(G, axis=1)
+            t1 = t_start + R_lat
+            t_end = jnp.maximum(t1, cum[:, -1]) + nh * c_hop
+            departs = (
+                jnp.maximum(t1[:, None], cum) + hidx * c_hop + L_lat
             )
-        else:
-            F_all = jnp.where(
-                ok_all, fl_all + r_all * L_lat, SENT
-            )  # [C, legs*H] wait floors
+            return t_end, departs
 
-            def _cascade(t_start, F, nh):
-                G = F - hidx * c_hop
-                cum = jax.lax.cummax(G, axis=1)
-                t1 = t_start + R_lat
-                t_end = jnp.maximum(t1, cum[:, -1]) + nh * c_hop
-                departs = (
-                    jnp.maximum(t1[:, None], cum) + hidx * c_hop + L_lat
-                )
-                return t_end, departs
-
-            t_req_end, d_req = _cascade(t0, F_all[:, :H], req_hops)
-            t_rep_end, d_rep = _cascade(
-                t_req_end + service, F_all[:, H : 2 * H], rep_hops
-            )
-            deps = [d_req, d_rep]
-            if has_sync:
-                t_arr_end, d_arr = _cascade(t0, F_all[:, 2 * H :], arr_hops)
-                deps.append(d_arr)
-            d_all = jnp.concatenate(deps, axis=1)
+        t_req_end, d_req = _cascade(t0, F_all[:, :H], req_hops)
+        t_rep_end, d_rep = _cascade(
+            t_req_end + service, F_all[:, H : 2 * H], rep_hops
+        )
+        deps = [d_req, d_rep]
+        if has_sync:
+            t_arr_end, d_arr = _cascade(t0, F_all[:, 2 * H :], arr_hops)
+            deps.append(d_arr)
+        d_all = jnp.concatenate(deps, axis=1)
         raw_rt = t_rep_end - t0  # valid on home_txn lanes
         extra_home = raw_rt - (req_lat + service + rep_lat)
         if has_sync:
@@ -1918,10 +1818,7 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
     refreshes, grants and fills, stale-duplicate clears, the local runs'
     deferred writes: `_l1_writes`) as each core's edit of its own row
     (`_l1_row_write`, a select), and the directory's, a true cross-row
-    write, in one row scatter-add -> (`l1_n`, `dirm_n`, None). Under
-    `step_impl="pallas"` nothing is written here: -> (None, None,
-    (`commit_lanes`, `upd_slot`)), the per-core lane block [C, CL_*] and
-    the target rows that `_commit_end`'s one kernel call writes from.
+    write, in one row scatter-add -> (`l1_n`, `dirm_n`).
 
     The L1 write has ONE form. Until PR 38 it was one element scatter of
     all C x (7 + 2*rl) words, which on the v5e costs 5.7-10 ns a WORD and
@@ -1943,189 +1840,142 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
     MW = llc_meta_width(cfg)
     logG = cfg.sharer_group.bit_length() - 1
     step_no = st.step
-    line, slot, hit_way = rq.line, rq.slot, rq.hit_way
-    lru_rows, weff = rq.lru_rows, rq.weff
+    line, slot = rq.line, rq.slot
     meta_rows, llc_hway, shw = rq.meta_rows, rq.llc_hway, rq.shw
     word_idx, bit_idx = rq.word_idx, rq.bit_idx
-    write_hit, upg = rq.write_hit, rq.upg
     llc_tag_rows, owner_rows, sh_rows = rq.llc_tag_rows, rq.owner_rows, rq.sh_rows
     llc_hit, llc_miss, write_w = dr.llc_hit, dr.llc_miss, dr.write_w
     gets_probe, gets_shared, gets_excl_hit = (
         dr.gets_probe, dr.gets_shared, dr.gets_excl_hit)
     oclamp, llc_vway, llc_lru_rows = dr.oclamp, dr.llc_vway, dr.llc_lru_rows
     with jax.named_scope(P_COMMIT):
-        if cfg.step_impl == "pallas":
-            # [PALLAS] fused commit (DESIGN.md §11): victim choice and the
-            # writeback counter stay in-register here (they feed _count), and
-            # the join-LRU representative scatter-min keeps its tiny XLA
-            # table, but EVERY array write of phase 4.A — the 7 + 2*rl L1
-            # plane writes, the directory row delta, and the stacked counter
-            # fold — is deferred into ONE commit_step kernel call at the end
-            # of the step (after phase 2.7 contributes its counter deltas).
-            upg_in_place = upg & winner  # upg requires an L1 hit: in-place
-            fill = (winner & ~upg_in_place) | join
-            l1_vkey = jnp.where(weff == I, -1, lru_rows)
-            l1_vway = jnp.argmin(l1_vkey, axis=1).astype(jnp.int32)
-            _count(
-                acc, "l1_writebacks", fill & (weff[arange_c, l1_vway] == M)
-            )
-            takes_own = write_w | gets_excl_hit | llc_miss
-            st_val_m = jnp.where(write_hit, M, grant)
-            jrep = _join_representative(
-                join, slot * W2 + llc_hway, key, B * S2 * W2)
-            upd_slot = jnp.where(winner | join, slot, B * S2)
-            commit_lanes = jnp.stack(
-                [
-                    line,
-                    hit_way,
-                    l1_vway,
-                    hit.astype(jnp.int32),
-                    write_hit.astype(jnp.int32),
-                    upg_in_place.astype(jnp.int32),
-                    winner.astype(jnp.int32),
-                    join.astype(jnp.int32),
-                    llc_hit.astype(jnp.int32),
-                    st_val_m,
-                    slot,
-                    llc_hway,
-                    llc_vway,
-                    jrep.astype(jnp.int32),
-                    takes_own.astype(jnp.int32),
-                    gets_probe.astype(jnp.int32),
-                    gets_shared.astype(jnp.int32),
-                    oclamp,
-                ],
-                axis=1,
-            )  # column order = kernels.step_kernels CL_* indices
-            return None, None, (commit_lanes, upd_slot)
-        else:
-            # invalidation epoch: every sharer-CLEARING transition (M grants,
-            # exclusive grants, fills — exactly the owner-taking ones) bumps the
-            # entry's epoch so coarse-vector validation can reject pre-clearing
-            # fill records (GETS probe/shared grants preserve sharers: no bump);
-            # fills record the POST-bump value
-            llc_uway = jnp.where(llc_hit, llc_hway, llc_vway)
-            takes_own = write_w | gets_excl_hit | llc_miss
-            eph_rows2 = meta_rows[:, 3 * W2 : 4 * W2]  # [C, W2]
-            eph_way = jnp.where(join, llc_hway, llc_uway)
-            new_eph = eph_rows2[arange_c, eph_way] + takes_own.astype(jnp.int32)
-            l1_n = _l1_row_write(
-                cfg,
-                st.l1,
-                _l1_writes(cfg, step_no, arange_c, rq, dr, winner, join, grant,
-                           hit, run_patch, new_eph, acc),
-            )
+        # invalidation epoch: every sharer-CLEARING transition (M grants,
+        # exclusive grants, fills — exactly the owner-taking ones) bumps the
+        # entry's epoch so coarse-vector validation can reject pre-clearing
+        # fill records (GETS probe/shared grants preserve sharers: no bump);
+        # fills record the POST-bump value
+        llc_uway = jnp.where(llc_hit, llc_hway, llc_vway)
+        takes_own = write_w | gets_excl_hit | llc_miss
+        eph_rows2 = meta_rows[:, 3 * W2 : 4 * W2]  # [C, W2]
+        eph_way = jnp.where(join, llc_hway, llc_uway)
+        new_eph = eph_rows2[arange_c, eph_way] + takes_own.astype(jnp.int32)
+        l1_n = _l1_row_write(
+            cfg,
+            st.l1,
+            _l1_writes(cfg, step_no, arange_c, rq, dr, winner, join, grant,
+                       hit, run_patch, new_eph, acc),
+        )
 
-            # Directory update: ONE full-row scatter-ADD covers the winner's
-            # whole row — tags, owner, LRU, epoch, AND sharer words — plus every
-            # join's sharer bit (winner and join slots are disjoint: join slots
-            # never have a winner). Winner rows carry the exact full-row delta
-            # (new - old; exactly one winner per slot, so old + delta == new,
-            # wrap-safe in int32); join rows contribute only the joiner's own
-            # bit, masked against the step-start word (self_word & ~shw) so a
-            # silently-evicted re-joiner's stale bit cannot carry into the
-            # adjacent bit — golden's _set_sharer is idempotent, the masked add
-            # matches it; multiple joiners per slot add distinct bits. Join LRU
-            # refreshes land in a second element scatter (same-slot joiners write
-            # the identical step stamp).
-            new_owner = jnp.where(takes_own, arange_c, -1)
-            if cfg.coherence == "moesi":
-                # dirty sharing: a GETS probe LEAVES the probed owner recorded
-                # (its line derives to Owned — DESIGN.md §25) instead of
-                # clearing it; every other non-owning transition still clears.
-                new_owner = jnp.where(gets_probe, oclamp, new_owner)
-            wayeq = jnp.arange(W2, dtype=jnp.int32)[None, :] == llc_uway[:, None]
-            new_meta = jnp.concatenate(
-                [
-                    jnp.stack(
-                        [
-                            jnp.where(wayeq, line[:, None], llc_tag_rows),
-                            jnp.where(wayeq, new_owner[:, None], owner_rows),
-                        ],
-                        axis=-1,
-                    ).reshape(C, 2 * W2),
-                    jnp.where(wayeq, step_no, llc_lru_rows),
-                    jnp.where(wayeq, new_eph[:, None], eph_rows2),
-                    jnp.zeros((C, MW - 4 * W2), jnp.int32),
-                ],
-                axis=1,
-            )
+        # Directory update: ONE full-row scatter-ADD covers the winner's
+        # whole row — tags, owner, LRU, epoch, AND sharer words — plus every
+        # join's sharer bit (winner and join slots are disjoint: join slots
+        # never have a winner). Winner rows carry the exact full-row delta
+        # (new - old; exactly one winner per slot, so old + delta == new,
+        # wrap-safe in int32); join rows contribute only the joiner's own
+        # bit, masked against the step-start word (self_word & ~shw) so a
+        # silently-evicted re-joiner's stale bit cannot carry into the
+        # adjacent bit — golden's _set_sharer is idempotent, the masked add
+        # matches it; multiple joiners per slot add distinct bits. Join LRU
+        # refreshes land in a second element scatter (same-slot joiners write
+        # the identical step stamp).
+        new_owner = jnp.where(takes_own, arange_c, -1)
+        if cfg.coherence == "moesi":
+            # dirty sharing: a GETS probe LEAVES the probed owner recorded
+            # (its line derives to Owned — DESIGN.md §25) instead of
+            # clearing it; every other non-owning transition still clears.
+            new_owner = jnp.where(gets_probe, oclamp, new_owner)
+        wayeq = jnp.arange(W2, dtype=jnp.int32)[None, :] == llc_uway[:, None]
+        new_meta = jnp.concatenate(
+            [
+                jnp.stack(
+                    [
+                        jnp.where(wayeq, line[:, None], llc_tag_rows),
+                        jnp.where(wayeq, new_owner[:, None], owner_rows),
+                    ],
+                    axis=-1,
+                ).reshape(C, 2 * W2),
+                jnp.where(wayeq, step_no, llc_lru_rows),
+                jnp.where(wayeq, new_eph[:, None], eph_rows2),
+                jnp.zeros((C, MW - 4 * W2), jnp.int32),
+            ],
+            axis=1,
+        )
 
-            # new sharer words [C, NW]
-            self_word = (
-                (jnp.arange(NW)[None, :] == word_idx[:, None]).astype(jnp.int32)
-                << bit_idx[:, None]
-            )  # bit(c) as packed words
-            # the probed owner is re-recorded as a sharer unconditionally: the home
-            # node cannot observe silent L1 evictions (golden does the same), and
-            # this keeps the transition free of cross-core L1 reads — which under
-            # core-axis sharding would all-gather the L1 arrays every step
-            og_bit = oclamp >> logG  # owner's sharer-GROUP bit (identity at G=1)
-            owner_word = jnp.where(
-                jnp.arange(NW)[None, :] == (og_bit // 32)[:, None],
-                jnp.int32(1) << (og_bit % 32)[:, None],
-                0,
-            )
-            probe_word = self_word | owner_word
-            if cfg.coherence == "moesi":
-                # dirty sharing accumulates: existing sharers stay recorded
-                # alongside requester + owner (shw == 0 here under mesi — any
-                # owner-setting transition cleared it)
-                probe_word = shw | probe_word
-            new_shw = jnp.where(
-                gets_probe[:, None],
-                probe_word,
-                jnp.where(
-                    gets_shared[:, None],
-                    shw | self_word,
-                    jnp.zeros_like(shw),  # M grants, E grants, misses: cleared
-                ),
-            )
-            way_seg = (
-                jnp.arange(W2 * NW, dtype=jnp.int32)[None, :] // NW == llc_uway[:, None]
-            )
-            old_flat = sh_rows.reshape(C, W2 * NW)
-            new_sh_row = jnp.where(
-                way_seg,
-                jnp.broadcast_to(new_shw[:, None, :], (C, W2, NW)).reshape(C, W2 * NW),
-                old_flat,
-            )
-            join_seg = (
-                jnp.arange(W2 * NW, dtype=jnp.int32)[None, :] // NW == llc_hway[:, None]
-            )
-            join_word = self_word & ~shw  # carry-free when the bit is already set
-            join_sh_row = jnp.where(
-                join_seg,
-                jnp.broadcast_to(join_word[:, None, :], (C, W2, NW)).reshape(C, W2 * NW),
-                0,
-            )
-            # Join LRU refreshes ride the SAME scatter-add: adds only commute for
-            # identical targets if exactly one lane carries the delta, so a
-            # per-(slot, way) scatter-min on the (small, 16 MB) representative
-            # table picks one joiner per joined way to add (step_no - old_lru);
-            # same-way co-joiners add zero. A second element scatter chained
-            # after the row-add was measured at ~5 ms/step (round-5 ablation: any
-            # read-modify-write scatter that cannot alias re-materializes the
-            # 800 MB operand), so everything must go through the ONE add.
-            jrep = _join_representative(
-                join, slot * W2 + llc_hway, key, B * S2 * W2)
-            old_lru_h = meta_rows[arange_c, 2 * W2 + llc_hway]
-            lru_oh = (
-                jnp.arange(MW, dtype=jnp.int32)[None, :]
-                == (2 * W2 + llc_hway)[:, None]
-            )
-            join_meta = jnp.where(
-                lru_oh, jnp.where(jrep, step_no - old_lru_h, 0)[:, None], 0
-            )
-            new_full = jnp.concatenate([new_meta, new_sh_row], axis=1)  # [C, DW]
-            delta_row = jnp.where(
-                winner[:, None],
-                new_full - meta_rows,
-                jnp.concatenate([join_meta, join_sh_row], axis=1),
-            )
-            upd_slot = jnp.where(winner | join, slot, B * S2)
-            dirm_n = st.dirm.at[upd_slot].add(delta_row, mode="drop")
-            return l1_n, dirm_n, None
+        # new sharer words [C, NW]
+        self_word = (
+            (jnp.arange(NW)[None, :] == word_idx[:, None]).astype(jnp.int32)
+            << bit_idx[:, None]
+        )  # bit(c) as packed words
+        # the probed owner is re-recorded as a sharer unconditionally: the home
+        # node cannot observe silent L1 evictions (golden does the same), and
+        # this keeps the transition free of cross-core L1 reads — which under
+        # core-axis sharding would all-gather the L1 arrays every step
+        og_bit = oclamp >> logG  # owner's sharer-GROUP bit (identity at G=1)
+        owner_word = jnp.where(
+            jnp.arange(NW)[None, :] == (og_bit // 32)[:, None],
+            jnp.int32(1) << (og_bit % 32)[:, None],
+            0,
+        )
+        probe_word = self_word | owner_word
+        if cfg.coherence == "moesi":
+            # dirty sharing accumulates: existing sharers stay recorded
+            # alongside requester + owner (shw == 0 here under mesi — any
+            # owner-setting transition cleared it)
+            probe_word = shw | probe_word
+        new_shw = jnp.where(
+            gets_probe[:, None],
+            probe_word,
+            jnp.where(
+                gets_shared[:, None],
+                shw | self_word,
+                jnp.zeros_like(shw),  # M grants, E grants, misses: cleared
+            ),
+        )
+        way_seg = (
+            jnp.arange(W2 * NW, dtype=jnp.int32)[None, :] // NW == llc_uway[:, None]
+        )
+        old_flat = sh_rows.reshape(C, W2 * NW)
+        new_sh_row = jnp.where(
+            way_seg,
+            jnp.broadcast_to(new_shw[:, None, :], (C, W2, NW)).reshape(C, W2 * NW),
+            old_flat,
+        )
+        join_seg = (
+            jnp.arange(W2 * NW, dtype=jnp.int32)[None, :] // NW == llc_hway[:, None]
+        )
+        join_word = self_word & ~shw  # carry-free when the bit is already set
+        join_sh_row = jnp.where(
+            join_seg,
+            jnp.broadcast_to(join_word[:, None, :], (C, W2, NW)).reshape(C, W2 * NW),
+            0,
+        )
+        # Join LRU refreshes ride the SAME scatter-add: adds only commute for
+        # identical targets if exactly one lane carries the delta, so a
+        # per-(slot, way) scatter-min on the (small, 16 MB) representative
+        # table picks one joiner per joined way to add (step_no - old_lru);
+        # same-way co-joiners add zero. A second element scatter chained
+        # after the row-add was measured at ~5 ms/step (round-5 ablation: any
+        # read-modify-write scatter that cannot alias re-materializes the
+        # 800 MB operand), so everything must go through the ONE add.
+        jrep = _join_representative(
+            join, slot * W2 + llc_hway, key, B * S2 * W2)
+        old_lru_h = meta_rows[arange_c, 2 * W2 + llc_hway]
+        lru_oh = (
+            jnp.arange(MW, dtype=jnp.int32)[None, :]
+            == (2 * W2 + llc_hway)[:, None]
+        )
+        join_meta = jnp.where(
+            lru_oh, jnp.where(jrep, step_no - old_lru_h, 0)[:, None], 0
+        )
+        new_full = jnp.concatenate([new_meta, new_sh_row], axis=1)  # [C, DW]
+        delta_row = jnp.where(
+            winner[:, None],
+            new_full - meta_rows,
+            jnp.concatenate([join_meta, join_sh_row], axis=1),
+        )
+        upd_slot = jnp.where(winner | join, slot, B * S2)
+        dirm_n = st.dirm.at[upd_slot].add(delta_row, mode="drop")
+        return l1_n, dirm_n
 
 
 def _sync(cfg: MachineConfig, st: MachineState, arange_c, rq: Request, cycles,
@@ -2317,33 +2167,14 @@ def _sync(cfg: MachineConfig, st: MachineState, arange_c, rq: Request, cycles,
     return cycles, ptr, lock_holder, barrier_count, barrier_time, sync_flag
 
 
-def _commit_end(cfg: MachineConfig, st: MachineState, arange_c, rq: Request,
-                run_patch, l1_n, dirm_n, deferred, acc):
+def _commit_end(cfg: MachineConfig, st: MachineState, acc):
     """The end-of-step commit, once phase 2.7 has added its counter
-    deltas -> `l1_n`, `dirm_n` and the step's counters: the ONE stacked
-    add of every delta in `acc` — and, under `step_impl="pallas"`, the
-    array writes `_commit_writes` deferred, in one kernel call."""
+    deltas -> the step's counters: the ONE stacked add of every delta in
+    `acc`."""
     with jax.named_scope(P_COMMIT):
-        if cfg.step_impl == "pallas":
-            # [PALLAS] end-of-step fused commit: ONE kernel call performs
-            # every deferred array write of the step — the 7 + 2*rl-column
-            # L1 plane scatter, the per-core directory row delta, and the
-            # full counter fold. The single data-dependent row scatter the
-            # block model cannot express stays in XLA.
-            from ..kernels.step_kernels import commit_step
-
-            commit_lanes, upd_slot = deferred
-            l1_n, delta_row, counters = commit_step(
-                cfg, st.l1, rq.meta_rows, rq.tag_rows, rq.shw, commit_lanes,
-                arange_c, st.step, st.counters,
-                _counter_deltas(acc, cfg.n_cores, st.counters.shape[0]),
-                *(run_patch or ()),
-            )
-            dirm_n = st.dirm.at[upd_slot].add(delta_row, mode="drop")
-        else:
-            counters = st.counters + _counter_deltas(
-                acc, cfg.n_cores, st.counters.shape[0])
-    return l1_n, dirm_n, counters
+        counters = st.counters + _counter_deltas(
+            acc, cfg.n_cores, st.counters.shape[0])
+    return counters
 
 
 def step(
@@ -2410,7 +2241,7 @@ def step(
     cycles, ptr, grant, hit, req_hops, rep_hops = _commit_retire(
         cfg, kn, rq, dr, winner, join, cycles_c, ptr_c, service, probe_any,
         extra_home, raw_rt, req_lat, req_hops, rep_lat, rep_hops, flt, acc)
-    l1_n, dirm_n, deferred = _commit_writes(
+    l1_n, dirm_n = _commit_writes(
         cfg, st, arange_c, rq, dr, winner, join, key, grant, hit, run_patch,
         acc)
     lock_holder, barrier_count = st.lock_holder, st.barrier_count
@@ -2421,8 +2252,7 @@ def step(
             cfg, st, arange_c, rq, cycles, ptr, cycles_c, quantum_end, ctile,
             htile, bid, req_lat, req_hops, rep_lat, rep_hops, flt, extra_home,
             extra_bar, raw_rt, raw_arr, deadb, acc)
-    l1_n, dirm_n, counters = _commit_end(
-        cfg, st, arange_c, rq, run_patch, l1_n, dirm_n, deferred, acc)
+    counters = _commit_end(cfg, st, acc)
 
     return MachineState(
         cycles=cycles,

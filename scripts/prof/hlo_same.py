@@ -6,17 +6,16 @@
 `dump` compiles the `run_loop` of the `primesim_tpu` it imports on the
 present default device (one device; shapes only, nothing runs) and writes
 the compiled module's text. The file is a benchmark configuration
-(`benchmark/configs/*.json`: its machine, `step_impl` and `chunk_steps`)
+(`benchmark/configs/*.json`: its machine and `chunk_steps`; its
+`run.step_impl`, which the files still state, is handed to
+`MachineConfig.from_dict` as `benchmark/measure.py` hands it: ROADMAP D15)
 or a plain machine file (`configs/*.json`: every static selector is a
 field of it; chunks of 8 steps); `--sync` compiles the step for a trace
 with locks and barriers (`has_sync` true). Run it once for each checkout, then `compare` the
 two files with everything that is only
 metadata removed: every instruction's `metadata={...}` (`op_name`, source
-line, stack frame), the file/function/stack-frame tables those point
-into, and the debug locations inside a Mosaic kernel (a Pallas
-`tpu_custom_call` carries its kernel as serialized MLIR, call-site lines
-and all: it is read back and stands in the comparison as the digest of
-its text without them). It says whether the rest is byte-identical and, where it is not,
+line, stack frame) and the file/function/stack-frame tables those point
+into. It says whether the rest is byte-identical and, where it is not,
 whether the two texts still agree in everything but instruction names
 (XLA numbers the instructions it creates late after the names the front
 end gave, and a `jax.named_scope` reaches a few of those), naming what
@@ -25,9 +24,7 @@ differs. Exit code 0: identical, or identical up to names; 1: not.
 
 from __future__ import annotations
 
-import base64
 import collections
-import hashlib
 import json
 import re
 import sys
@@ -38,22 +35,10 @@ _TABLES = re.compile(
 # a name where it is used (`%add.7`) and where a computation's header
 # declares it as a parameter (`(reduce_sum.108: s32[], ...)`)
 _NAME = re.compile(r"%[\w.\-]+|\b[\w.\-]+(?=: )")
-_MOSAIC = re.compile(r'("custom_call_config":\{"body":")([A-Za-z0-9+/=]+)"')
-
-
-def _kernel_digest(found: re.Match) -> str:
-    from jaxlib.mlir import ir
-
-    ctx = ir.Context()
-    ctx.allow_unregistered_dialects = True
-    kernel = ir.Module.parse(base64.b64decode(found.group(2)), context=ctx)
-    asm = kernel.operation.get_asm(enable_debug_info=False)
-    return f'{found.group(1)}sha256:{hashlib.sha256(asm.encode()).hexdigest()}"'
 
 
 def strip(text: str) -> str:
-    text = _TABLES.sub("\n", _METADATA.sub("", text))
-    return _MOSAIC.sub(_kernel_digest, text)
+    return _TABLES.sub("\n", _METADATA.sub("", text))
 
 
 def load_config(config_path: str):

@@ -10,10 +10,8 @@ costs of the router + DRAM-queue step tail (DESIGN.md §13):
   matmul formulation ([C,C] int8 kless x [C,NL] one-hot, O(C^2 * NL)
   MACs) it replaced, at identical shapes. This is the cut that moved
   rung 3 from ~1296 to ~67 ms/step on a 1-core CPU container.
-- `cascade`: the wait-floor + per-leg cummax cascade + departures, XLA
-  closed form vs the fused Pallas kernel (`kernels.router_kernels`,
-  interpreter mode off-TPU — so on CPU this row measures the interpreter,
-  not Mosaic; compare on TPU for the real kernel number).
+- `cascade`: the wait-floor + per-leg cummax cascade + departures, the
+  closed form `sim/step.py::_router_walk` computes.
 - `links`: the walk's per-link state. Shipped (PR 31): it rides the
   rank's sorted order, `ops.ranking.segmented_rank_floor` (rank, the
   link's earliest nominal arrival and its next-free clock out of one
@@ -24,9 +22,8 @@ costs of the router + DRAM-queue step tail (DESIGN.md §13):
   link_free/base gather pair and the departure scatter-max, each over
   all C * legs * H slots of a table of NL words.
 
-Plus whole-step ms/step on the full rung-3 machine for both
-`step_impl=xla` and `=pallas` (the end-to-end number the components
-should sum toward). No source surgery — everything here calls shipped
+Plus whole-step ms/step on the full rung-3 machine (the end-to-end
+number the components should sum toward). No source surgery — everything here calls shipped
 entry points, so this tool cannot rot silently.
 
 Usage: `python scripts/prof/prof_router.py` · env:
@@ -43,7 +40,6 @@ import jax.numpy as jnp
 import numpy as np
 
 from primesim_tpu.config.machine import MachineConfig
-from primesim_tpu.kernels.router_kernels import SENT, router_cascade
 from primesim_tpu.ops import ranking
 from primesim_tpu.ops.ranking import (
     lane_order,
@@ -56,6 +52,7 @@ from primesim_tpu.sim.state import init_state
 from primesim_tpu.trace import synth
 from primesim_tpu.trace.format import fold_ins
 
+SENT = -(1 << 30) - (1 << 21)  # `_router_walk`'s: under any real wait floor
 R3 = os.path.join(os.path.dirname(__file__), "..", "..", "configs",
                   "rung3_1024core_o3.json")
 
@@ -149,14 +146,8 @@ def cascade_cuts(s, cfg):
         te_arr, d_arr = leg(t0, F[:, 2 * H:], nh[2])
         return te_rep, te_arr, jnp.concatenate([d_req, d_rep, d_arr], axis=1)
 
-    def pallas_cascade(lf, bs, r, ok, t0, sv, nh):
-        return router_cascade(lf, bs, r, ok, t0, sv, nh[0], nh[1], nh[2],
-                              L_lat, R_lat, has_sync=True)
-
     a = (s["lf"], s["bs"], r, s["ok"], s["t0"], s["sv"], s["nh"])
     timed(xla_cascade, *a, tag="cascade: xla closed form")
-    kind = "mosaic" if jax.default_backend() == "tpu" else "interpreter"
-    timed(pallas_cascade, *a, tag=f"cascade: pallas kernel ({kind})")
 
 
 ITER = 50
@@ -239,9 +230,7 @@ def link_cuts(s):
                "links: retired departure scatter-max")
 
 
-def whole_step(cfg, step_impl, n_steps):
-    cfg = (cfg if cfg.step_impl == step_impl
-           else __import__("dataclasses").replace(cfg, step_impl=step_impl))
+def whole_step(cfg, n_steps):
     trace = fold_ins(synth.fft_like(
         cfg.n_cores, n_phases=2, points_per_core=16, ins_per_mem=8, seed=42))
     events = jnp.asarray(trace.line_events(cfg.line_bits))
@@ -253,7 +242,7 @@ def whole_step(cfg, step_impl, n_steps):
         st = run_chunk(cfg, n_steps, events, st, has_sync=True)
     np.asarray(st.step)
     ms = (time.perf_counter() - t0) / 2 / n_steps * 1e3
-    print(f"[whole rung-3 step: {step_impl}] {ms:.3f} ms/step", flush=True)
+    print(f"[whole rung-3 step] {ms:.3f} ms/step", flush=True)
 
 
 if __name__ == "__main__":
@@ -267,5 +256,4 @@ if __name__ == "__main__":
     link_cuts(s)
     if os.environ.get("PRIMETPU_PROF_WHOLE", "1") != "0":
         n = int(os.environ.get("PRIMETPU_PROF_STEPS", "16"))
-        whole_step(cfg, "xla", n)
-        whole_step(cfg, "pallas", n)
+        whole_step(cfg, n)

@@ -93,33 +93,6 @@ def test_jit_key_bad_and_good(tmp_path):
     assert res.clean
 
 
-def test_mosaic_bad_and_good(tmp_path):
-    bad = (
-        "import jax.numpy as jnp\n"
-        "def kern(pl, x):\n"
-        "    core = pl.program_id(0)\n"
-        "    idx = jnp.nonzero(x)\n"
-        "    return core, idx, jnp.where(x > 0)\n"
-    )
-    res = _lint(tmp_path, "primesim_tpu/kernels/k.py", bad,
-                select=["PT-MOSAIC"])
-    assert len(res.findings) == 3
-    good = (
-        "import jax.numpy as jnp\n"
-        "def kern(core_ids, x):\n"
-        "    return jnp.where(core_ids > 0, x, 0)\n"
-    )
-    res = _lint(tmp_path, "primesim_tpu/kernels/k.py", good,
-                select=["PT-MOSAIC"])
-    assert res.clean
-    # dynamic-shape ops ARE the layouts.py idiom (host-side planning)
-    res = _lint(tmp_path, "primesim_tpu/kernels/layouts.py",
-                "import numpy as np\ndef plan(x):\n"
-                "    return np.nonzero(x)\n",
-                select=["PT-MOSAIC"])
-    assert res.clean
-
-
 def test_durable_shared_tmp_regression_pr10(tmp_path):
     # the exact PR 10 bug shape: deterministic shared temp name + raw
     # write-mode open on a checkpoint path

@@ -1,11 +1,17 @@
 """Checkpoint/resume bit-exactness (SURVEY.md §5.4) + xml_compat loader."""
 
+import json
 import os
 
 import numpy as np
 import pytest
 
-from primesim_tpu.config.machine import MachineConfig, small_test_config
+from primesim_tpu.config.machine import (
+    ConfigError,
+    MachineConfig,
+    small_test_config,
+)
+from primesim_tpu.sim.checkpoint import atomic_save_npz, load_verified_npz
 from primesim_tpu.sim.engine import Engine
 from primesim_tpu.trace import synth
 
@@ -131,6 +137,53 @@ def test_checkpoint_rejects_mismatches(tmp_path):
     other_tr = synth.stream(4, n_mem_ops=10, seed=99)
     with pytest.raises(ValueError, match="trace does not match"):
         Engine(cfg, other_tr, chunk_steps=8).load_checkpoint(ckpt)
+
+
+def _as_the_parent_wrote(cfg) -> str:
+    """`cfg.to_json()` as the parent of PR 46 wrote it: the two options of
+    the removed Pallas step stated at their defaults."""
+    d = json.loads(cfg.to_json())
+    d.update(pallas_reduce=False, step_impl="xla")
+    return json.dumps(d, indent=2)
+
+
+@pytest.mark.parametrize("case", [
+    "step_impl-xla", "pallas_reduce-false", "step_impl-pallas",
+    "pallas_reduce-true", "parents-checkpoint"])
+def test_removed_step_options_at_the_edge(tmp_path, case):
+    # `MachineConfig.from_dict` drops the defaults of the two options PR 46
+    # removed (benchmark/ and the parent's checkpoints still state them)
+    # and refuses anything else (ROADMAP D15: the shim's removal)
+    cfg = small_test_config(4)
+    plain = json.loads(cfg.to_json())
+    assert "step_impl" not in plain and "pallas_reduce" not in plain
+    if case == "step_impl-xla":
+        assert MachineConfig.from_dict({**plain, "step_impl": "xla"}) == cfg
+    elif case == "pallas_reduce-false":
+        assert MachineConfig.from_dict({**plain, "pallas_reduce": False}) == cfg
+    elif case in ("step_impl-pallas", "pallas_reduce-true"):
+        key, said = (("step_impl", "pallas") if case == "step_impl-pallas"
+                     else ("pallas_reduce", True))
+        with pytest.raises(ConfigError, match="removed in PR 46") as e:
+            MachineConfig.from_dict({**plain, key: said})
+        assert (e.value.selector, e.value.value) == (key, said)
+    else:
+        stored = _as_the_parent_wrote(cfg)
+        assert MachineConfig.from_json(stored) == cfg
+        tr = synth.stream(4, n_mem_ops=10, seed=43)
+        ref = Engine(cfg, tr, chunk_steps=8)
+        ref.run()
+        e = Engine(cfg, tr, chunk_steps=8)
+        e.run_steps(8)
+        ckpt = str(tmp_path / "c.npz")
+        e.save_checkpoint(ckpt)
+        members = load_verified_npz(ckpt)
+        members["config_json"] = np.frombuffer(stored.encode(), dtype=np.uint8)
+        atomic_save_npz(ckpt, **members)
+        b = Engine(cfg, tr, chunk_steps=8)
+        b.load_checkpoint(ckpt)
+        b.run()
+        np.testing.assert_array_equal(b.cycles, ref.cycles)
 
 
 def test_fleet_checkpoint_resume_bit_exact(tmp_path):

@@ -1,7 +1,6 @@
 """The CPU-checkable half of the chip bring-up (ISSUE 21): chip_smoke.py's
-parity phase at test size, its refusal to run off the chip, the three-way
-kernel dispatch, the device fields of a run summary, the compile-cache
-helper and the per-worker chip plan. The other half — `python
+parity phase at test size, its refusal to run off the chip, the device
+fields of a run summary, the compile-cache helper and the per-worker chip plan. The other half — `python
 chip_smoke.py` on a TPU — is the driver's chip check."""
 
 import json
@@ -15,7 +14,6 @@ import pytest
 import chip_smoke
 from primesim_tpu.cli import main as cli_main
 from primesim_tpu.config.machine import small_test_config
-from primesim_tpu.kernels.layouts import interpret_mode
 from primesim_tpu.parallel.sharding import DeviceMeshError
 from primesim_tpu.trace import synth
 from primesim_tpu.util import device
@@ -25,13 +23,8 @@ REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
 def test_parity_phase_at_test_size():
     trace = synth.false_sharing(4, n_mem_ops=20, seed=3)
-    counts = [
-        chip_smoke.check_parity(
-            small_test_config(4, step_impl=impl), trace, 16, "cpu"
-        )
-        for impl in ("xla", "pallas")
-    ]
-    assert counts[0] == counts[1] and counts[0]["instructions"] > 0
+    counts = chip_smoke.check_parity(small_test_config(4), trace, 16, "cpu")
+    assert counts["instructions"] > 0
     # the same run held to the wrong platform is a failure, not a note
     with pytest.raises(SystemExit, match="lives on 'cpu', not 'tpu'"):
         chip_smoke.check_parity(small_test_config(4), trace, 16, "tpu")
@@ -49,8 +42,7 @@ def test_main_refuses_cpu_and_names_it(capsys):
 def test_last_line_is_the_drivers_contract(monkeypatch, capsys):
     # the driver refuses any last line but {"ok", "device": {platform,
     # kind, count}}; phases stubbed, the device phase and the ending real
-    for name in ("phase_parity", "run_and_check", "phase_kernels",
-                 "phase_four_chips"):
+    for name in ("phase_parity", "run_and_check", "phase_four_chips"):
         monkeypatch.setattr(chip_smoke, name, lambda *a, **k: {})
     monkeypatch.setenv(device.CACHE_ENV, "/nonexistent")  # set nothing
     assert chip_smoke.main("cpu") == 0
@@ -59,13 +51,6 @@ def test_last_line_is_the_drivers_contract(monkeypatch, capsys):
     assert json.loads(lines[-1]) == {"ok": True, "device": {
         "platform": "cpu", "kind": d[0].device_kind, "count": len(d)}}
     assert lines[-2].startswith("[summary] {")
-
-
-def test_interpret_mode_is_three_way():
-    assert interpret_mode() is True  # this suite runs on the CPU
-    assert interpret_mode("tpu") is False
-    with pytest.raises(RuntimeError, match="'rocm'"):
-        interpret_mode("rocm")
 
 
 def test_run_summary_names_the_device(tmp_path, capsys):

@@ -384,10 +384,6 @@ def test_fleet_rejections():
         FleetEngine(cfg, [tr], [{}, {}])
     with pytest.raises(ValueError, match="unknown timing override"):
         FleetEngine(cfg, [tr], [{"llc_latency": 3}])
-    with pytest.raises(ValueError, match="pallas"):
-        FleetEngine(
-            small_test_config(4, n_banks=4, pallas_reduce=True), [tr]
-        )
     with pytest.raises(ValueError, match="quantum"):
         apply_overrides(cfg, {"quantum": 2**30})
 

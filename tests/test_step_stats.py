@@ -217,17 +217,6 @@ def test_windowed_engine_counts_the_same_core_steps():
         np.testing.assert_array_equal(win.step_stats[k], solo.step_stats[k], err_msg=k)
 
 
-def test_pallas_step_folds_the_same_block():
-    import dataclasses
-
-    cfg, trace = MACHINES["rung3"]()
-    xla = _fused(cfg, trace)
-    pallas = _fused(dataclasses.replace(cfg, step_impl="pallas"), trace)
-    np.testing.assert_array_equal(pallas.cycles, xla.cycles)
-    for k in STAT_NAMES:
-        np.testing.assert_array_equal(pallas.step_stats[k], xla.step_stats[k], err_msg=k)
-
-
 def test_checkpoint_carries_the_stat_totals(tmp_path):
     cfg, trace = MACHINES["sync"]()
     whole = _fused(cfg, trace)

@@ -40,7 +40,7 @@ def ran(spec):
     """The parity job of a run of seed 43, as `measure.run_cell` builds it."""
     run, machine = spec["config"]["run"], spec["config"]["machine"]
     ev = trafficgen.make_trace(spec["traffic"], machine["n_cores"], 43, parity=True)
-    cfg = MachineConfig.from_dict({**machine, "step_impl": run["step_impl"]})
+    cfg = MachineConfig.from_dict(machine)
     trace = Trace(ev, measure._lengths(ev))
     ovs = run["fleet"]["overrides"]
     fleet = FleetEngine(cfg, [trace] * len(ovs), ovs, chunk_steps=run["chunk_steps"])

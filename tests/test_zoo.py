@@ -3,7 +3,7 @@
 Covers the pluggable-topology contract (vectorized `path_links` vs the
 memoized scalar `route_links` walk, link-for-link, on every topology),
 MOESI dirty-sharing semantics and its divergence from MESI, the stride
-prefetcher's counters, three-way golden/XLA/Pallas parity across zoo
+prefetcher's counters, golden/engine parity across zoo
 selector combinations, link faults on torus/ring solo-vs-fleet, the
 typed ConfigError/CalibError exit-2 contract, checkpoint round-trips of
 the prefetcher state (format v7), and the `primetpu calibrate` fit
@@ -413,28 +413,6 @@ def test_golden_engine_parity_zoo(topology, coherence, prefetcher, gen):
         prefetch_degree=4, prefetch_lat=3,
     )
     assert_parity(cfg, _zoo_trace(gen), chunk_steps=32)
-
-
-@pytest.mark.slow
-def test_pallas_step_parity_zoo():
-    # every zoo selector at once through the Pallas step kernel: the
-    # interpreter-mode kernel must match the XLA path bit-for-bit
-    from primesim_tpu.sim.engine import Engine
-
-    cfg = zoo_cfg(
-        topology="torus", coherence="moesi", prefetcher="stride",
-        prefetch_degree=4, prefetch_lat=3,
-    )
-    tr = _zoo_trace("fft_like")
-    xla = Engine(cfg, tr, chunk_steps=32)
-    xla.run()
-    pal = Engine(
-        dataclasses.replace(cfg, step_impl="pallas"), tr, chunk_steps=32
-    )
-    pal.run()
-    np.testing.assert_array_equal(pal.cycles, xla.cycles)
-    for k, v in xla.counters.items():
-        np.testing.assert_array_equal(pal.counters[k], v, err_msg=k)
 
 
 # ---- faults on torus/ring (slow) ------------------------------------------
