@@ -67,3 +67,36 @@ def assert_reference_equals_golden(reference, machine: dict, ev, gold=None):
         else:
             assert not v.any(), k
     return ref
+
+
+def vary_string(overrides: dict) -> str:
+    """One element's overrides as `primetpu sweep --vary` spells them."""
+    return ",".join(f"{k}={v}" for k, v in overrides.items())
+
+
+def handed_to_fleet_by_sweep(monkeypatch, machine_file: str, spec: dict) -> tuple:
+    """(cfg, traces, overrides, keywords) that `primetpu sweep
+    configs/<machine_file> --synth <the cell's parity trace> --vary ...`,
+    one `--vary` for each of the cell's elements after the first, hands
+    `FleetEngine`; nothing is built or run."""
+    import pytest
+
+    import primesim_tpu.sim.fleet as fleet_module
+    from primesim_tpu.cli import main
+
+    class Handed(Exception):
+        pass
+
+    def capture(cfg, traces, overrides=None, **kw):
+        raise Handed(cfg, traces, overrides, kw)
+
+    monkeypatch.setattr(fleet_module, "FleetEngine", capture)
+    args = spec["traffic"]["args"] | spec["traffic"]["parity_args"]
+    argv = ["sweep", os.path.join(ROOT, "configs", machine_file),
+            "--synth", "fft_like:" + ",".join(f"{k}={v}" for k, v in args.items()), "--fold",
+            "--chunk-steps", "8", "--strict"]
+    for ov in spec["config"]["run"]["fleet"]["overrides"][1:]:
+        argv += ["--vary", vary_string(ov)]
+    with pytest.raises(Handed) as handed:
+        main(argv)
+    return handed.value.args

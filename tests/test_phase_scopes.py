@@ -20,6 +20,7 @@ import pytest
 
 from primesim_tpu.config.machine import MachineConfig
 from primesim_tpu.sim.engine import Engine, run_loop
+from primesim_tpu.sim.fleet import FleetEngine, fleet_run_loop
 from primesim_tpu.sim.state import dirm_width
 from primesim_tpu.sim.step import PHASE_FUNCTIONS, PHASES, step
 from primesim_tpu.trace import synth
@@ -87,7 +88,35 @@ def compiled_text(machine: str) -> str:
 
 def scope_paths(machine: str) -> frozenset:
     """Every `op_name` of the machine's compiled `run_loop`."""
-    return frozenset(re.findall(r'op_name="([^"]*)"', compiled_text(machine)))
+    return _op_names(compiled_text(machine))
+
+
+def _op_names(text: str) -> frozenset:
+    return frozenset(re.findall(r'op_name="([^"]*)"', text))
+
+
+FLEET = "rung3 x2"  # two of rung 3's small machines in one `fleet_run_loop`
+_FLEET_OVERRIDES = [{}, {"link_lat": 2, "dram_service": 25}]
+
+
+def build_fleet():
+    """`build("rung3")`'s machine twice, the second with the NoC's and the
+    DRAM controller's knobs turned, as `primetpu sweep` stacks them."""
+    cfg, eng = build("rung3")
+    return FleetEngine(cfg, [eng.trace] * 2, _FLEET_OVERRIDES, chunk_steps=8,
+                       force_sync=MACHINES["rung3"][1])
+
+
+@functools.lru_cache(maxsize=None)
+def program_paths(program: str) -> frozenset:
+    """Every `op_name` of a compiled loop: a machine's `run_loop`, or
+    (`FLEET`) the `fleet_run_loop` of two small router machines."""
+    if program != FLEET:
+        return scope_paths(program)
+    fleet = build_fleet()
+    return _op_names(fleet_run_loop.lower(
+        fleet.geom_cfg, 8, fleet.events, fleet.state, jnp.asarray(1, jnp.int32),
+        has_sync=fleet.has_sync).compile().as_text())
 
 
 def _has(paths, name: str) -> bool:
@@ -175,8 +204,9 @@ def test_chunk_scope_holds_the_full_maps_blockwise_reductions_and_only_there():
     assert not any("/s.chunk/" in p and "/s.dir/" in p for p in paths)
 
 
-def test_rank_scopes_hold_the_ranking_under_their_own_phase():
-    paths = scope_paths("rung3")
+@pytest.mark.parametrize("program", ["rung3", FLEET])
+def test_rank_scopes_hold_the_ranking_under_their_own_phase(program):
+    paths = program_paths(program)
     for phase in ("s.noc", "s.dram"):
         assert any(f"/{phase}/rank/sort" in p for p in paths)
     # nothing of the ranking outside a rank scope, no scope inside another
@@ -184,11 +214,12 @@ def test_rank_scopes_hold_the_ranking_under_their_own_phase():
     assert not any(len(re.findall(r"/s\.\w+", p)) > 1 for p in paths)
 
 
-def test_rung3_loop_ranks_without_a_search():
+@pytest.mark.parametrize("program", ["rung3", FLEET])
+def test_rung3_loop_ranks_without_a_search(program):
     """Each entry's rank is read from the sort's own output: no
     `searchsorted` anywhere, and no loop (a bisection is a `while` where
-    it is not unrolled) under a rank scope."""
-    paths = scope_paths("rung3")
+    it is not unrolled) under a rank scope; under a batch axis too."""
+    paths = program_paths(program)
     ranked = [p.split("/rank/", 1)[1] for p in paths if "/rank/" in p]
     assert any(p.startswith("sort") for p in ranked)
     assert not [p for p in paths if "searchsorted" in p]
@@ -196,11 +227,11 @@ def test_rung3_loop_ranks_without_a_search():
 
 
 def indexed_ops(machine: str) -> list:
-    """Every `gather` and `scatter*` equation of the machine's `step`,
-    through every sub-jaxpr: (primitive, scope path, operand shape, number
-    of indices, a gather's slice sizes)."""
-    has_sync = MACHINES[machine][1]
-    cfg, eng = build(machine)
+    """Every `gather` and `scatter*` equation of the machine's `step`
+    (`FLEET`: of the two machines' `fleet_run_loop`, where the batching
+    rules have given each its batch axis), through every sub-jaxpr:
+    (primitive, scope path, operand shape, number of indices, a gather's
+    slice sizes)."""
     found = []
 
     def walk(jaxpr, prefix):
@@ -214,9 +245,17 @@ def indexed_ops(machine: str) -> list:
             for sub in jax.core.jaxprs_in_params(eqn.params):
                 walk(sub, path)
 
-    walk(jax.make_jaxpr(
-        lambda ev, st: step(cfg, ev, st, has_sync=has_sync))(
-            eng.events, eng.state).jaxpr, "")
+    if machine == FLEET:
+        fleet = build_fleet()
+        traced = jax.make_jaxpr(lambda ev, st: fleet_run_loop(
+            fleet.geom_cfg, 8, ev, st, jnp.asarray(1, jnp.int32),
+            has_sync=fleet.has_sync))(fleet.events, fleet.state)
+    else:
+        has_sync = MACHINES[machine][1]
+        cfg, eng = build(machine)
+        traced = jax.make_jaxpr(
+            lambda ev, st: step(cfg, ev, st, has_sync=has_sync))(eng.events, eng.state)
+    walk(traced.jaxpr, "")
     return found
 
 
@@ -272,26 +311,29 @@ def test_step_edits_the_l1_row_without_a_scatter(machine):
             if shape == shapes["dirm"]] == [("scatter-add", cfg.n_cores)]
 
 
-def test_rung3_walk_indexes_no_table_entry_by_entry():
+@pytest.mark.parametrize("program, machines", [("rung3", 1), (FLEET, len(_FLEET_OVERRIDES))])
+def test_rung3_walk_indexes_no_table_entry_by_entry(program, machines):
     """The router walk's per-link state rides the rank's sorted order
     (`segmented_rank_floor`, `segmented_table_max`): under `s.noc` no
     `gather` and no `scatter` of any kind has more indices than the
     machine has links. The element forms over all C * legs * H slots (a
     scatter-min for `base`, a gather pair, the departures' scatter-max)
     were three quarters of the rung-3 step on the chip (PERF.md section 6,
-    PR 31); what stays reads or writes NL words or fewer."""
+    PR 31); what stays reads or writes NL words or fewer. In a fleet's
+    loop the batching rules give each such op its batch axis and no more:
+    NL words or fewer a machine."""
     from primesim_tpu.noc.mesh import n_links
 
     cfg, _ = build("rung3")
     n_slots = cfg.n_cores * 2 * 6  # two legs of a 4x4 mesh's 6 hops
     assert n_links(cfg) < n_slots
-    indexed = indexed_ops("rung3")
+    indexed = indexed_ops(program)
     assert any("s.noc" in p for _, p, _, _, _ in indexed)  # the walk sees scopes
     # and counts indices: the probe's way rows, W1 a core
-    assert any(n == cfg.l1.ways * cfg.n_cores for _, p, _, n, _ in indexed
+    assert any(n == machines * cfg.l1.ways * cfg.n_cores for _, p, _, n, _ in indexed
                if "s.probe" in p)
     assert not [(name, p, n) for name, p, _, n, _ in indexed
-                if "s.noc" in p and n > n_links(cfg)]
+                if "s.noc" in p and n > machines * n_links(cfg)]
 
 
 def test_benchmark_needles_are_phase_names():

@@ -7,13 +7,12 @@ benchmark's stock reference and the golden model; the overrides spelt as
 on a hand-made record and trace."""
 
 import json
-import os
 
 import numpy as np
 import pytest
 
-import benchmark_modules
-from benchmark_modules import assert_reference_equals_golden
+from benchmark_modules import (assert_reference_equals_golden, handed_to_fleet_by_sweep,
+                               vary_string)
 
 import cells  # noqa: E402  (benchmark/ is on the path now)
 import measure  # noqa: E402
@@ -96,43 +95,22 @@ def test_an_element_whole_against_the_reference_and_golden(spec, ran, e):
 
 # ---- the cell is what `primetpu sweep` runs ----------------------------------
 
-def _vary(ov: dict) -> str:
-    return ",".join(f"{k}={v}" for k, v in ov.items())
-
-
 def test_each_override_is_what_its_vary_string_parses_to(spec):
     from primesim_tpu.cli import _parse_vary
 
     ovs = spec["config"]["run"]["fleet"]["overrides"]
-    assert _vary(ovs[1]) == "quantum=500,llc_lat=10,dram_lat=80"
+    assert vary_string(ovs[1]) == "quantum=500,llc_lat=10,dram_lat=80"
     for ov in ovs[1:]:  # element 0 is the machine as it stands: no string spells `{}`
-        assert _parse_vary(_vary(ov)) == ov
+        assert _parse_vary(vary_string(ov)) == ov
 
 
 def test_cmd_sweeps_fan_builds_the_files_machines(spec, monkeypatch):
     """`primetpu sweep configs/rung2_256core_parsec.json --synth ... --vary ...`
     with the fifteen strings hands `FleetEngine` the configurations
     `apply_overrides` builds from the file."""
-    import primesim_tpu.sim.fleet as fleet_module
-    from primesim_tpu.cli import main
-
-    class Handed(Exception):
-        pass
-
-    def capture(cfg, traces, overrides=None, **kw):
-        raise Handed(cfg, traces, overrides, kw)
-
-    monkeypatch.setattr(fleet_module, "FleetEngine", capture)
     ovs = spec["config"]["run"]["fleet"]["overrides"]
-    args = spec["traffic"]["args"] | spec["traffic"]["parity_args"]
-    argv = ["sweep", os.path.join(benchmark_modules.ROOT, "configs", "rung2_256core_parsec.json"),
-            "--synth", "fft_like:" + ",".join(f"{k}={v}" for k, v in args.items()), "--fold",
-            "--chunk-steps", "8", "--strict"]
-    for ov in ovs[1:]:
-        argv += ["--vary", _vary(ov)]
-    with pytest.raises(Handed) as handed:
-        main(argv)
-    cfg, traces, overrides, kw = handed.value.args
+    cfg, traces, overrides, kw = handed_to_fleet_by_sweep(
+        monkeypatch, "rung2_256core_parsec.json", spec)
     mine = MachineConfig.from_dict(spec["config"]["machine"])
     assert cfg == mine and kw["chunk_steps"] == 8 and kw["mesh"] is None
     assert overrides == ovs[1:] and len(traces) == 15  # the one trace fanned over the varies
