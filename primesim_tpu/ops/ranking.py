@@ -88,8 +88,26 @@ table form's, for every int32:
    Masked slots sit in the sentinel run, which no table entry reads.
 
 Everything here is plain int32 sort/scan/scatter, and one read of
-``n_seg`` words — vmap-safe, so the fleet engine batches it unchanged, and the
-jit key stays geometry-only (keys/segments are traced data).
+``n_seg`` words, and the jit key stays geometry-only (keys/segments are
+traced data).
+
+UNDER A BATCH AXIS (the fleet engine's `vmap`, DESIGN.md §22) the scans,
+maxima and reads batch as `vmap` batches them. The entry sorts do not:
+`vmap` of a sort of ``[N]`` is a sort of ``[B, N]`` along its last axis,
+which the TPU lays out with the B machines in the sublanes of a tile and
+runs at the price of WHOLE tiles of eight machines: one machine at 8.1
+times its solo sort, four at 2.0 times four solo sorts, eight and
+sixteen at 0.99 (PERF.md §6, PR 48). So every entry sort of this module
+goes through `_entry_sort`, a `custom_vmap` whose rule sorts the B
+machines' entries one machine after another, each in the solo layout,
+B plain sorts unrolled at trace time (B is static): no loop enters the
+step, and a value that goes from one entry sort to the next goes
+machine by machine without being stacked in between. The integers are
+`vmap`'s own: each machine's sort is the solo sort of its own operands.
+Unmapped, the helper lowers to the plain sort it wraps: a solo program
+is the same text with or without it. `lane_order`'s sort (C arbitration
+keys, 1024 on rung 3) is not an entry sort and batches as `vmap`
+batches it.
 """
 
 from __future__ import annotations
@@ -190,6 +208,26 @@ def _segmented_scan(x, edge, op, *, reverse=False):
     return jnp.where(inner, rows, op(rows, carry[:, None])).reshape(-1)[:n]
 
 
+def _entry_sort(operands, num_keys):
+    """The module's one sort of entries: 1-D `operands` by the first
+    `num_keys` of them, in no order among ties. Unmapped it is
+    ``jax.lax.sort(operands, num_keys=num_keys, is_stable=False)``; under
+    `vmap` it is that sort once a machine (the module's docstring, UNDER
+    A BATCH AXIS)."""
+
+    @jax.custom_batching.custom_vmap
+    def sort(*operands):
+        return tuple(jax.lax.sort(operands, num_keys=num_keys, is_stable=False))
+
+    @sort.def_vmap
+    def one_machine_at_a_time(batch, mapped, *operands):
+        rows = [sort(*(x[b] if m else x for x, m in zip(operands, mapped)))
+                for b in range(batch)]
+        return tuple(jnp.stack(x) for x in zip(*rows)), (True,) * len(operands)
+
+    return sort(*operands)
+
+
 def lane_order(key):
     """Dense first-occurrence rank of each lane's arbitration key:
     ``ord[i] = #{j : key[j] < key[i]}`` — [C] int32 in [0, C).
@@ -252,16 +290,14 @@ def segmented_rank(seg, key=None, n_seg=None, *, order=None, method="auto"):
     keys = _sort_keys(seg_flat, ord_flat, C, n_seg, method)
     pos = jnp.arange(E, dtype=jnp.int32)
     # no order is needed among ties: tied entries share their rank
-    *skeys, sidx = jax.lax.sort(
-        (*keys, pos), num_keys=len(keys), is_stable=False
-    )
+    *skeys, sidx = _entry_sort((*keys, pos), len(keys))
     # sorted position of the first entry of each entry's segment, and of
     # its (segment, ord) group: the later of the segment's start and the
     # start of the run of equal last keys
     seg0 = _run_starts(_sorted_seg(skeys, C))
     grp0 = jnp.maximum(seg0, _run_starts(skeys[-1]))
     # back to entry order: sidx is a permutation, sorting by it inverts it
-    _, rank = jax.lax.sort((sidx, grp0 - seg0), num_keys=1, is_stable=False)
+    _, rank = _entry_sort((sidx, grp0 - seg0), 1)
     return rank.reshape(C, S)
 
 
@@ -307,10 +343,8 @@ def segmented_rank_floor(seg, val, table, *, order, method="auto"):
         C + 1, n_seg, method,
     )
     pos = jnp.arange(E + n_seg, dtype=jnp.int32)
-    *skeys, sidx, sval = jax.lax.sort(
-        (*keys, pos, jnp.concatenate([val.reshape(E), table])),
-        num_keys=len(keys), is_stable=False,
-    )
+    *skeys, sidx, sval = _entry_sort(
+        (*keys, pos, jnp.concatenate([val.reshape(E), table])), len(keys))
     sseg = _sorted_seg(skeys, C + 1)
     starts = _is_start(sseg)
     ends = jnp.concatenate([starts[1:], jnp.ones((1,), jnp.bool_)])
@@ -329,8 +363,8 @@ def segmented_rank_floor(seg, val, table, *, order, method="auto"):
         jnp.where(is_table, jnp.maximum(sval, low), INT32_MIN),
         starts, jnp.maximum)
     # the table entry is one more element ahead of every lane of its run
-    _, rank, floor, spos = jax.lax.sort(
-        (sidx, grp0 - seg0 - 1, sfloor, pos), num_keys=1, is_stable=False)
+    _, rank, floor, spos = _entry_sort(
+        (sidx, grp0 - seg0 - 1, sfloor, pos), 1)
     return (rank[:E].reshape(C, S), floor[:E].reshape(C, S),
             SortedRuns(spos, ends, n_real))
 
@@ -340,8 +374,7 @@ def segmented_table_max(runs, val, table):
     entries -> [n_seg] int32; a segment no entry is in keeps its value.
     `val` [C, S] lies as the `seg` that `runs` was built from."""
     E = val.size
-    _, sval = jax.lax.sort(
-        (runs.spos, jnp.concatenate([val.reshape(E), table])),
-        num_keys=1, is_stable=False)
+    _, sval = _entry_sort(
+        (runs.spos, jnp.concatenate([val.reshape(E), table])), 1)
     top = _segmented_scan(sval, runs.ends, jnp.maximum, reverse=True)
     return top[runs.spos[E:]]  # n_seg reads, at the runs' first elements

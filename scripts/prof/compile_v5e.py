@@ -13,10 +13,14 @@ lays them out; `--sync` compiles the step for a trace with locks and
 barriers (`has_sync` true), as `hlo_same.py dump --sync` does;
 `--fleet B` compiles `fleet_run_loop` for B machines of the configuration
 (a leading axis of B on the state and the `DeviceTrace`, the static key
-`cfg.timing_normalized()`, as `FleetEngine` hands them over). Writes the compiled module's text (for
+`cfg.timing_normalized()`, as `FleetEngine` hands them over; with
+`devices` above 1 as `FleetEngine(mesh=...)` places them: the batch axis
+whole on every chip, each machine's cores and banks over the tiles). Writes the compiled module's text (for
 `hlo_same.py compare`), and prints the compiler's bytes a chip
 (arguments, outputs, temporaries), every collective with its shape
 and the tail of its `op_name`, which holds the phase scope, and every
+`sort` with its operands' shape and layout, the dimension it sorts, its
+scoped memory and what made each operand (PR 48), and every
 `while` nested inside the step (the chunk loop's scan body) with the
 arrays it carries: a loop there that no phase of `step` wrote is a
 relayout the compiler made (PERF.md section 6, PR 44: the fleet's join
@@ -84,6 +88,35 @@ def chunk_loop_ops(text: str, shape: str) -> list:
                 "while", "get-tuple-element", "parameter", "bitcast")]
 
 
+_SORT = re.compile(
+    r"^\s*(?:ROOT )?%?(\S+) = \(?(.*?)\)? sort\(([^)]*)\), dimensions=\{(\d+)\}")
+_DEFINED = re.compile(r"^\s*(?:ROOT )?%?(\S+) = .*? ([\w\-]+)\(")
+_SCOPED = re.compile(r'"used_scoped_memory_configs":\[\{[^}]*"size":"(\d+)"')
+
+
+def sorts(text: str) -> list:
+    """(instruction, operand shapes, sorted dimension, each operand's
+    name and the opcode that made it, scoped memory in bytes, `op_name`)
+    of every `sort` of a compiled module: the layout a sort got, and
+    whether a `copy` stands in front of it (PERF.md section 6, PR 48:
+    under `vmap` the router's sorts lay `[B, N]` with the machines in a
+    tile's sublanes, until the ranking sorted a machine at a time)."""
+    found = []
+    for lines in computations(text)[0].values():
+        made = {m.group(1): m.group(2) for m in map(_DEFINED.match, lines) if m}
+        for line in lines:
+            sort = _SORT.match(line)
+            if not sort:
+                continue
+            operands = [o.strip().lstrip("%") for o in sort.group(3).split(",")]
+            scoped = _SCOPED.search(line)
+            found.append((
+                sort.group(1), _SHAPE.findall(sort.group(2)), int(sort.group(4)),
+                [f"{o} ({made.get(o, '?')})" for o in operands],
+                int(scoped.group(1)) if scoped else 0, op_name_of(line)))
+    return found
+
+
 def nested_whiles(text: str) -> list:
     """(depth, computation, instruction, carried shapes, `op_name`) of
     every `while` of a compiled module that lies more than two loops
@@ -133,32 +166,32 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
     cfg, chunk_steps, conf_devices = load_config(conf_path)
     devices = devices or conf_devices
     topo = topologies.get_topology_desc(topology_name="v5e:2x2", platform="tpu")
-    # as `build_state` lays the block out: no stat rows on a mesh
-    st = jax.eval_shape(lambda: init_state(cfg, stat_rows=devices == 1))
+    # as `build_state` lays the block out: no stat rows on a mesh; a fleet
+    # stacks solo `init_state`s and places the stack, so its block has them
+    st = jax.eval_shape(lambda: init_state(cfg, stat_rows=bool(fleet) or devices == 1))
+    # the trace as `Engine` hands it over: `DeviceTrace`'s blocks
+    ev = jax.eval_shape(lambda: DeviceTrace.of(
+        jnp.zeros((cfg.n_cores, trace_len, 4), jnp.int32), cfg.local_run_len))
+    loop = run_loop
+    st_specs, ev_spec = sharding.state_pspecs(), sharding.events_pspec()
+    if fleet:  # the batch is the leading axis of every leaf, as `FleetEngine` stacks them
+        loop, cfg = fleet_run_loop, cfg.timing_normalized()
+        st, ev = jax.tree.map(
+            lambda s: jax.ShapeDtypeStruct((fleet, *s.shape), s.dtype), (st, ev))
+        st_specs, ev_spec = sharding.fleet_state_pspecs(), sharding.fleet_events_pspec()
     if devices > 1:
         mesh = Mesh(np.asarray(topo.devices[:devices]), (sharding.AXIS,))
         place = lambda spec: NamedSharding(mesh, spec)  # noqa: E731
         st = jax.tree.map(
             lambda s, spec: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=place(spec)),
-            st, sharding.state_pspecs())
-        events, scalar = place(sharding.events_pspec()), place(P())
-        if fleet:
-            raise SystemExit("--fleet on a mesh is not laid out here: one device")
+            st, st_specs)
+        events, scalar = place(ev_spec), place(P())
     else:
         events = scalar = SingleDeviceSharding(topo.devices[0])
         st = jax.tree.map(
             lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=events), st)
-    # the trace as `Engine` hands it over: `DeviceTrace`'s blocks
-    ev = jax.eval_shape(lambda: DeviceTrace.of(
-        jnp.zeros((cfg.n_cores, trace_len, 4), jnp.int32), cfg.local_run_len))
     ev = jax.tree.map(
         lambda s: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=events), ev)
-    loop = run_loop
-    if fleet:  # the batch is the leading axis of every leaf, as `FleetEngine` stacks them
-        loop, cfg = fleet_run_loop, cfg.timing_normalized()
-        st, ev = jax.tree.map(
-            lambda s: jax.ShapeDtypeStruct((fleet, *s.shape), s.dtype, sharding=events),
-            (st, ev))
     t0 = time.perf_counter()
     compiled = loop.lower(
         cfg, chunk_steps, ev, st,
@@ -179,10 +212,17 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
             print(f"  {found.group(1)} {found.group(2)[:80]} | "
                   f"{op_name_of(line)[-60:]}")
     if fleet:
-        dirm = f"s32[{','.join(str(n) for n in st.dirm.shape)}]"
+        rows = list(st.dirm.shape)
+        rows[1] //= devices  # a chip's own rows of each machine's directory
+        dirm = f"s32[{','.join(str(n) for n in rows)}]"
         ops = chunk_loop_ops(text, dirm)
         print(f"ops of `dirm`'s shape {dirm} in the loop over chunks, outside "
               f"its scan: {len(ops)} {' '.join(ops)}")
+    found = sorts(text)
+    print(f"sorts: {len(found)}")
+    for name, shapes, dim, operands, scoped, op_name in found:
+        print(f"  {name} {len(shapes)} x {shapes[0]} dimensions={{{dim}}} scoped "
+              f"{scoped / 1e6:.1f} MB of {' '.join(operands)} | {op_name[-48:]}")
     loops = nested_whiles(text)
     print(f"loops inside the step: {len(loops)}")
     for depth, inside, name, shapes, op_name in loops:

@@ -26,6 +26,12 @@ Plus whole-step ms/step on the full rung-3 machine (the end-to-end
 number the components should sum toward). No source surgery — everything here calls shipped
 entry points, so this tool cannot rot silently.
 
+`python scripts/prof/prof_router.py fleet [entries [B,B,... [form,...]]]`
+(PR 48) times the walk's three sorts alone under a batch axis, in the
+four forms `vmap` can be given (`fleet_cuts`; 131072 entries a machine, B
+of 4 and 16 and every form unless told), and prints a table for
+`scripts/prof/README.md`.
+
 Usage: `python scripts/prof/prof_router.py` · env:
 `PRIMETPU_PROF_MATMUL=0` skips the retired-matmul reference row (it is
 deliberately the slow one), `PRIMETPU_PROF_WHOLE=0` the whole-step rows, `PRIMETPU_PROF_STEPS` (default 16) sizes
@@ -33,6 +39,7 @@ the whole-step chunks.
 """
 import functools
 import os
+import sys
 import time
 
 import jax
@@ -230,6 +237,83 @@ def link_cuts(s):
                "links: retired departure scatter-max")
 
 
+FLEET_SORTS = ((3, "rank+floor"), (4, "back"), (2, "table max"))
+
+
+def fleet_cuts(n=131072, batches=(4, 16), only=None):
+    """The router walk's three entry sorts under a batch axis (PERF.md
+    section 6, PR 48): `n` entries a machine (rung 3's E + NL = 131072;
+    194560 with a `has_sync` trace's third leg), 3, 4 and 2 operands, one
+    key; B machines as `vmap` batches a sort (`[B, n]` along its last
+    axis: the form until PR 48), as ONE flat sort of B·n with the
+    machine's number in the key, as B solo sorts in a `lax.map`
+    (`jax.custom_batching.sequential_vmap`; both tried in PR 48, not
+    shipped) and as B solo sorts unrolled, each on its machine's rows
+    (what `ranking._entry_sort` is); `only` names the forms to time.
+    Beside them ONE solo sort of n, 2n, 4n and 16n entries, what a flat
+    sort of B·n can cost at best. The keys are permutations of the
+    entries, as the walk's second and third sorts have them, and each
+    iteration sorts what the last one returned. ms an iteration, all B
+    machines."""
+
+    def plain(*ops):
+        return tuple(jax.lax.sort(ops, num_keys=1, is_stable=False))
+
+    def flat(*ops):
+        shape = ops[0].shape
+        off = jnp.arange(shape[0], dtype=jnp.int32)[:, None] * jnp.int32(n)
+        out = plain(*(x.reshape(-1) for x in (ops[0] + off, *ops[1:])))
+        out = [x.reshape(shape) for x in out]
+        return (out[0] - off, *out[1:])
+
+    def unrolled(*ops):
+        rows = [plain(*(x[b] for x in ops)) for b in range(ops[0].shape[0])]
+        return tuple(jnp.stack(x) for x in zip(*rows))
+
+    forms = {
+        "batched": jax.vmap(plain),
+        "flat": flat,
+        "sequential": jax.vmap(jax.custom_batching.sequential_vmap(plain)),
+        "unrolled": unrolled,
+    }
+    forms = {k: v for k, v in forms.items() if only is None or k in only}
+
+    def body(sort):
+        def step(i, ops):  # two permutations go in, two come out
+            out = sort(*ops)
+            return (out[1], out[0], *(x ^ i for x in out[2:]))
+        return step
+
+    def operands(rng, shape, k):
+        perm = rng.permuted(
+            np.broadcast_to(np.arange(shape[-1], dtype=np.int32), shape), axis=-1)
+        rest = [rng.integers(-(1 << 30), 1 << 30, shape).astype(np.int32)
+                for _ in range(k - 2)]
+        return tuple(jnp.asarray(x) for x in (
+            perm, np.broadcast_to(np.arange(shape[-1], dtype=np.int32), shape),
+            *rest))
+
+    rng = np.random.default_rng(2)
+    rows = []  # (what, operands, form, machines' worth of entries, ms)
+    for k, what in FLEET_SORTS:
+        for m in (1, 2, 4, 16):
+            ms = timed_loop(body(plain), operands(rng, (m * n,), k),
+                            f"fleet: solo sort of {m * n}, {k} operands")
+            rows.append((what, k, "solo", m, ms))
+        for B in batches:
+            for form, sort in forms.items():
+                ms = timed_loop(body(sort), operands(rng, (B, n), k),
+                                f"fleet: {what}, {k} operands, B={B}, {form}")
+                rows.append((what, k, form, B, ms))
+    solo = {k: ms for _, k, form, m, ms in rows if form == "solo" and m == 1}
+    print(f"\n| sort ({n} entries a machine) | operands | form | size | "
+          f"ms, all machines | x B solo sorts of {n} |\n|---|---|---|---|---|---|")
+    for what, k, form, m, ms in rows:
+        size = f"{m * n}" if form == "solo" else f"B={m}"
+        print(f"| {what} | {k} | {form} | {size} | {ms:.4f} | "
+              f"{ms / (m * solo[k]):.2f} |")
+
+
 def whole_step(cfg, n_steps):
     trace = fold_ins(synth.fft_like(
         cfg.n_cores, n_phases=2, points_per_core=16, ins_per_mem=8, seed=42))
@@ -247,6 +331,11 @@ def whole_step(cfg, n_steps):
 
 if __name__ == "__main__":
     print("devices:", jax.devices(), flush=True)
+    if sys.argv[1:2] == ["fleet"]:  # fleet [entries a machine [B,B,... [form,...]]]
+        n, batches, only = (sys.argv[2:] + ["131072", "4,16", ""][len(sys.argv) - 2:])[:3]
+        fleet_cuts(int(n), tuple(int(b) for b in batches.split(",")),
+                   only.split(",") if only else None)
+        raise SystemExit(0)
     with open(R3) as f:
         cfg = MachineConfig.from_json(f.read())
     s = router_shapes(cfg)

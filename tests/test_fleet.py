@@ -14,6 +14,7 @@ geometry, with every timing knob traced.
 import dataclasses
 import re
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -373,6 +374,43 @@ def test_fleet_loop_selects_no_directory(faults):
         assert bool(selects) == faults, (f, shape, len(selects))
     # the text does say `select` where the freeze is: the clocks, [B, C]
     assert re.search(r"= s32\[3,4\]\S* select\(", text)
+
+
+def _router_sort_shapes(fleet):
+    """The operand shapes of every `sort` under `s.noc/rank` in the
+    fleet's `fleet_run_loop`, through every sub-jaxpr."""
+    found = []
+
+    def walk(jaxpr, prefix):
+        for eqn in jaxpr.eqns:
+            path = f"{prefix}/{eqn.source_info.name_stack}"
+            if eqn.primitive.name == "sort" and "/s.noc/rank" in path:
+                found.append(eqn.invars[0].aval.shape)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub, path)
+
+    walk(jax.make_jaxpr(lambda ev, st: fleet_run_loop(
+        fleet.geom_cfg, 8, ev, st, jnp.asarray(1, jnp.int32),
+        has_sync=fleet.has_sync))(fleet.events, fleet.state).jaxpr, "")
+    return found
+
+
+@pytest.mark.parametrize("B", [2, 8])
+def test_fleet_sorts_the_routers_entries_a_machine_at_a_time(B):
+    """Under the fleet's `vmap` the router walk's three entry sorts are
+    each machine's solo sort, 1-D of C x legs x H + NL entries, B times
+    each (`ranking._entry_sort`), never one sort of `[B, N]`; every
+    element is still its solo engine's."""
+    cfg = _router_dram(small_test_config(4, n_banks=4, local_run_len=4))
+    traces = [synth.uniform_random(4, n_mem_ops=30, seed=71 + s) for s in range(B)]
+    overrides = [{}, {"link_lat": 3}] + [{"link_lat": 2}] * (B - 2)
+    fleet = FleetEngine(cfg, traces, overrides, chunk_steps=8)
+    fleet.run()
+    entries = 4 * 2 * 2 + 16
+    assert _router_sort_shapes(fleet) == [(entries,)] * (3 * B)
+    for i, (t, ov) in enumerate(zip(traces[:3], overrides)):
+        assert_element_matches_solo(
+            fleet, i, apply_overrides(cfg, ov), t, chunk_steps=8)
 
 
 def test_fleet_rejections():

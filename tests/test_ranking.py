@@ -20,6 +20,7 @@ from primesim_tpu.config.machine import (
     small_test_config,
 )
 from primesim_tpu.noc.mesh import n_links, path_links
+from primesim_tpu.ops import ranking
 from primesim_tpu.ops.ranking import (
     lane_order,
     segmented_rank,
@@ -408,3 +409,145 @@ def test_sorted_runs_count_their_real_entries(method, mask_p):
         method=method)
     assert int(runs.n_real) == int((seg < n_seg).sum())
     assert int(runs.n_real) == {0.0: C * S, 1.0: 0}.get(mask_p, int(runs.n_real))
+
+
+# --- the entry sorts under a batch axis (`_entry_sort`, PR 48) -------------
+
+INT32_MIN = np.iinfo(np.int32).min
+
+
+def _sorts_of(fn, *args):
+    """(operand shapes, `dimension`) of every `sort` equation of `fn`'s
+    jaxpr, through every sub-jaxpr (a `custom_vmap_call`'s too)."""
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "sort":
+                found.append(([v.aval.shape for v in eqn.invars],
+                              eqn.params["dimension"], eqn.params["num_keys"]))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(fn)(*args).jaxpr)
+    return found
+
+
+def _entry_operands(rng, B, N, form, payloads, n_seg=11, width=7):
+    """B machines' operands for one entry sort, `[B, N]` each, and the
+    number of keys: tied keys (N entries over fewer values), a third
+    of the entries in the sentinel segment, payloads at the ends of
+    int32 and at the rebase clamp. The last payload numbers the entries,
+    so no two of a machine's rows are equal."""
+    seg = np.where(rng.random((B, N)) < 0.33, n_seg,
+                   rng.integers(0, n_seg, (B, N))).astype(np.int32)
+    ordr = rng.integers(0, width, (B, N)).astype(np.int32)
+    if form == "packed":
+        keys = [seg * width + ordr]
+    else:
+        keys = [seg, ordr]
+    ends = np.array([INT32_MIN, INT32_MAX, CLOCK_LO, 0, -1], np.int32)
+    pays = [np.where(rng.random((B, N)) < 0.5, rng.choice(ends, (B, N)),
+                     _clocks(rng, (B, N))).astype(np.int32)
+            for _ in range(payloads - 1)]
+    pays.append(np.broadcast_to(np.arange(N, dtype=np.int32), (B, N)))
+    return keys + pays, len(keys)
+
+
+def _rows(operands):
+    """A sort's result as a set of its rows: what is left to compare of
+    an unstable sort once the keys are seen to be equal."""
+    return sorted(zip(*(np.asarray(x).tolist() for x in operands)))
+
+
+@pytest.mark.parametrize("payloads", [1, 3])
+@pytest.mark.parametrize("form", ["packed", "lex"])
+@pytest.mark.parametrize("B", [1, 3, 4])
+def test_mapped_entry_sort_equals_a_loop_of_the_plain_one(B, form, payloads):
+    rng = np.random.default_rng(100 * B + payloads)
+    ops, num_keys = _entry_operands(rng, B, 300, form, payloads)
+    # the entries' numbers arrive unbatched, as the callers' `arange`
+    in_axes = (0,) * (len(ops) - 1) + (None,)
+    mapped = jax.vmap(
+        lambda *o: ranking._entry_sort(o, num_keys), in_axes=in_axes)(
+        *(jnp.asarray(x) for x in ops[:-1]), jnp.asarray(ops[-1][0]))
+    for b in range(B):
+        solo = jax.lax.sort(
+            tuple(jnp.asarray(x[b]) for x in ops), num_keys=num_keys,
+            is_stable=False)
+        for k in range(num_keys):  # the keys: equal place by place
+            np.testing.assert_array_equal(mapped[k][b], solo[k], err_msg=f"elem {b}")
+        # the payloads: equal but for the order among ties
+        assert _rows(x[b] for x in mapped) == _rows(solo), f"elem {b}"
+        assert _rows(solo) == _rows(x[b] for x in ops), f"elem {b}"
+
+
+@pytest.mark.parametrize("B", [3, 8, 16])
+@pytest.mark.parametrize("form", ["packed", "lex"])
+def test_mapped_entry_sort_asks_nothing_of_its_keys(form, B):
+    """The rule is the plain sort once a machine, so it holds for keys of
+    any sign up to the ends of int32 and for any B (no room is needed
+    for a machine's number in the key), and its program holds B sorts
+    of 1-D operands and none of `[B, N]`."""
+    rng = np.random.default_rng(5)
+    N = 200
+    ops, num_keys = _entry_operands(rng, B, N, form, 2)
+    ends = np.array([INT32_MIN, INT32_MAX, -1, (1 << 30) + 7], np.int32)
+    ops[0] = np.where(
+        rng.random((B, N)) < 0.3, rng.choice(ends, (B, N)), ops[0]).astype(np.int32)
+    fn = jax.vmap(lambda *o: ranking._entry_sort(o, num_keys))
+    args = [jnp.asarray(x) for x in ops]
+    assert [s for s, _, _ in _sorts_of(fn, *args)] == [[(N,)] * len(ops)] * B
+    mapped = fn(*args)
+    for b in range(B):
+        solo = jax.lax.sort(
+            tuple(a[b] for a in args), num_keys=num_keys, is_stable=False)
+        for k in range(num_keys):
+            np.testing.assert_array_equal(mapped[k][b], solo[k])
+        assert _rows(x[b] for x in mapped) == _rows(solo)
+
+
+def test_two_batch_axes_unroll_twice():
+    """The rule sorts each machine with the helper again: a second `vmap`
+    round the first still sorts 1-D, a machine at a time."""
+    rng = np.random.default_rng(6)
+    ops, num_keys = _entry_operands(rng, 6, 150, "packed", 2)
+    args = [jnp.asarray(x.reshape(2, 3, 150)) for x in ops]
+    fn = jax.vmap(jax.vmap(lambda *o: ranking._entry_sort(o, num_keys)))
+    assert [s for s, _, _ in _sorts_of(fn, *args)] == [[(150,)] * len(ops)] * 6
+    mapped = fn(*args)
+    for a in range(2):
+        for b in range(3):
+            solo = ranking._entry_sort(tuple(x[a, b] for x in args), num_keys)
+            np.testing.assert_array_equal(mapped[0][a, b], solo[0])
+            assert _rows(x[a, b] for x in mapped) == _rows(solo)
+
+
+@pytest.mark.parametrize("method", ["packed", "lex"])
+def test_vmapped_link_passes_sort_a_machine_at_a_time(method):
+    """`vmap` of `segmented_rank_floor` and `segmented_table_max` at B = 4
+    holds the three sorts of the unmapped passes four times each, 1-D of
+    `E + n_seg` entries with the same operands and `num_keys`, and none
+    of `[4, N]`; unmapped, they are those of before the helper."""
+    rng = np.random.default_rng(31)
+    B, C, S, n_seg = 4, 24, 5, 40
+    N = C * S + n_seg
+    segs = np.stack([_unique_segs(rng, C, S, n_seg) for _ in range(B)])
+    order = np.stack([rng.permutation(C) for _ in range(B)]).astype(np.int32)
+    vals, deps = _clocks(rng, segs.shape), _clocks(rng, segs.shape)
+    tables = _clocks(rng, (B, n_seg))
+
+    def passes(seg, order, val, dep, table):
+        rank, floor, runs = segmented_rank_floor(
+            seg, val, table, order=order, method=method)
+        return rank, floor, segmented_table_max(runs, dep, table)
+
+    args = [jnp.asarray(a) for a in (segs, order, vals, deps, tables)]
+    keys = 1 if method == "packed" else 2
+    want = [(keys + 2, keys), (4, 1), (2, 1)]  # operands, num_keys
+    for fn, fn_args, times in ((jax.vmap(passes), args, B),
+                               (passes, [a[0] for a in args], 1)):
+        sorts = _sorts_of(fn, *fn_args)
+        assert [(len(s), k) for s, _, k in sorts] == [
+            w for w in want for _ in range(times)]
+        assert all(s == [(N,)] * len(s) and d == 0 for s, d, _ in sorts)
