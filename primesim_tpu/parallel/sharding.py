@@ -17,12 +17,15 @@ invalidations back to remote cores) is NOT hand-written message passing:
 the step function stays pure and global, and XLA's SPMD partitioner inserts
 the all-gathers/reduce-scatters that realize it over ICI (multi-host: DCN).
 The per-step `lax.scan` boundary doubles as the quantum barrier collective
-(SURVEY.md §2 #10 [DRIVER]). One seam is written by hand, `read_rows`: a
+(SURVEY.md §2 #10 [DRIVER]). Two seams are written by hand. `read_rows`: a
 read of whole directory rows by slot, of which the reader wants a few
 words. The partitioner sends the rows; `read_rows` reduces each row on the
-chip that holds it and sends the words. The device loops learn their mesh
-from their arguments (`mesh_jit`), as `build_state` learns it from
-`Engine`: no configuration field says it.
+chip that holds it and sends the words. `least_of_entry`: a scratch table
+of one word a directory entry into which C lanes scatter. The partitioner
+all-reduces the table; `least_of_entry` cuts it by bank and sends the
+lanes' words to the chip that holds their entry. The device loops learn
+their mesh from their arguments (`mesh_jit`), as `build_state` learns it
+from `Engine`: no configuration field says it.
 
 Works identically on real TPU meshes and on virtual CPU meshes
 (``--xla_force_host_platform_device_count``), which is how tests and the
@@ -361,6 +364,46 @@ def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
     if core_axis == 0:
         record = jnp.swapaxes(record, 0, 1)
     return tuple(record[..., i].astype(dt) for i, dt in enumerate(dtypes))
+
+
+def least_of_entry(mesh: Mesh | None, table_least, join, entry, key, n):
+    """`table_least(join, entry, key, n)`: `[C]` bool, of the lanes with
+    `join` set the one an `entry` (of `n`, bank-major) whose `key` is
+    least, by a scatter-min into a table of one word an entry, read back
+    at each lane's own entry (`step.py::_join_representative`).
+
+    Without a mesh it is exactly that expression. On a mesh the lanes are
+    sharded by core and the table by nothing, and left to the partitioner
+    every chip fills and scatters into a whole table and the TABLE is
+    all-reduced, every step (rung 4: 64 MB at 55 GB/s, a fifth of the
+    step, to settle 4096 lanes; PERF.md section 6, PR 49). Here the table
+    is cut by bank like `dirm`, a contiguous `n // devices` entries a
+    chip: each chip takes every lane's `(join, entry, key)` (one
+    all-gather of 3 x C words), runs the SAME `table_least` on the lanes
+    whose entry it holds, the index made local (clamped for the others,
+    whose `join` it clears), and the 0/1 answers are summed (one
+    all-reduce of C words; a `psum_scatter` compiles to the same
+    all-reduce and a slice, and loses its phase scope on the way). One
+    chip holds each entry, so the sum is that chip's answer to the bit.
+    The table keeps its one form; only its indices and its length are
+    the chip's."""
+    if mesh is None:
+        return table_least(join, entry, key, n)
+    held = n // mesh.shape[AXIS]
+
+    def on_chip(packed):
+        packed = jax.lax.all_gather(packed, AXIS, axis=1, tiled=True)
+        join, entry, key = packed[0] != 0, packed[1], packed[2]
+        local = entry - jax.lax.axis_index(AXIS) * held
+        mine = join & (local >= 0) & (local < held)
+        least = mine & table_least(
+            mine, jnp.clip(local, 0, held - 1), key, held)
+        return jax.lax.psum(least.astype(jnp.int32), AXIS)
+
+    packed = jnp.stack((join.astype(jnp.int32), entry, key))
+    return jax.shard_map(
+        on_chip, mesh=mesh, in_specs=P(None, AXIS), out_specs=P(),
+    )(packed) != 0
 
 
 def fleet_state_pspecs() -> MachineState:

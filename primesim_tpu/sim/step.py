@@ -50,7 +50,7 @@ from ..ops.ranking import (
     segmented_rank_floor,
     segmented_table_max,
 )
-from ..parallel.sharding import read_rows
+from ..parallel.sharding import least_of_entry, read_rows
 from ..stats.counters import BLOCK_NAMES
 from ..trace.device import DeviceTrace
 from ..trace.format import (
@@ -1813,7 +1813,7 @@ def _join_representative(join, entry, key, n):
 
 def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
                    rq: Request, dr: DirOutcome, winner, join, key, grant, hit,
-                   run_patch, acc):
+                   run_patch, acc, mesh=None):
     """Phase 4.A's array writes: every L1 write of the step (hit
     refreshes, grants and fills, stale-duplicate clears, the local runs'
     deferred writes: `_l1_writes`) as each core's edit of its own row
@@ -1957,8 +1957,9 @@ def _commit_writes(cfg: MachineConfig, st: MachineState, arange_c,
         # after the row-add was measured at ~5 ms/step (round-5 ablation: any
         # read-modify-write scatter that cannot alias re-materializes the
         # 800 MB operand), so everything must go through the ONE add.
-        jrep = _join_representative(
-            join, slot * W2 + llc_hway, key, B * S2 * W2)
+        jrep = least_of_entry(
+            mesh, _join_representative, join, slot * W2 + llc_hway, key,
+            B * S2 * W2)
         old_lru_h = meta_rows[arange_c, 2 * W2 + llc_hway]
         lru_oh = (
             jnp.arange(MW, dtype=jnp.int32)[None, :]
@@ -2188,8 +2189,9 @@ def step(
     selectors of `cfg` (and `has_sync`) decide which phases a machine
     compiles; everything a phase reads or hands on is in its call. `mesh`
     is the tile mesh the state is sharded over, None on one device: the
-    two phases that read it are `_local` and `_probe`, for their reads of
-    whole `dirm` rows (`sharding.read_rows`). `events` is the trace as
+    three phases that read it are `_local` and `_probe`, for their reads of
+    whole `dirm` rows (`sharding.read_rows`), and `_commit_writes`, for
+    its join table (`sharding.least_of_entry`). `events` is the trace as
     the device holds it (`trace/device.py`); the loops that call `step`
     lay a caller's raw `[C, T, 4]` array out once, outside their scan."""
     C = cfg.n_cores
@@ -2243,7 +2245,7 @@ def step(
         extra_home, raw_rt, req_lat, req_hops, rep_lat, rep_hops, flt, acc)
     l1_n, dirm_n = _commit_writes(
         cfg, st, arange_c, rq, dr, winner, join, key, grant, hit, run_patch,
-        acc)
+        acc, mesh)
     lock_holder, barrier_count = st.lock_holder, st.barrier_count
     barrier_time, sync_flag = st.barrier_time, st.sync_flag
     if has_sync:

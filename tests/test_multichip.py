@@ -7,6 +7,9 @@ the single-device run and the golden scalar model. This is the
 single-host stand-in for PriME's multi-node MPI runs (SURVEY.md §4d).
 """
 
+import functools
+import re
+
 import jax
 import numpy as np
 import pytest
@@ -354,6 +357,113 @@ def test_validate_ways_reads_rows_like_elements(machine, case, devices):
             np.testing.assert_array_equal(np.asarray(g_), w_)
 
 
+def _join_cases():
+    """(join, entry, key, n) of each case of the join table's seam, C = 64
+    lanes, four chips' quarters of `n` entries. Keys are distinct, as the
+    step's are ((clock, core) packed), and wherever lanes join an entry a
+    lane that does not join it holds a lesser key there."""
+    C = 64
+    lane = np.arange(C)
+
+    def keys(seed):
+        return np.random.default_rng(seed).permutation(C * 8)[:C].astype(np.int32)
+
+    n = 2048
+    per = n // 4
+    cases = {}
+    cases["no_lane_joins"] = (
+        np.zeros(C, bool), (lane * 37 % n).astype(np.int32), keys(1), n)
+    cases["every_lane_joins_one_entry"] = (
+        np.ones(C, bool), np.full(C, 2 * per + 3, np.int32), keys(2), n)
+    # every chip holds four entries, of two, three, four and five lanes
+    entry = np.concatenate([
+        np.repeat(q * per + np.array([5, 130, 131, per - 7]), [2, 3, 4, 5])
+        for q in range(4)
+    ] + [np.array([5, per + 130, 2 * per + 131, 4 * per - 7] * 2)])
+    key = keys(3)
+    key[-8:] = -1 - np.arange(8)  # the lanes that do not join: least of all
+    cases["two_to_five_lanes_an_entry_on_every_chip"] = (
+        lane < 56, entry.astype(np.int32), key, n)
+    # each quarter's first and last entry: two joiners and a lane that is none
+    edge = np.array([[q * per, (q + 1) * per - 1] for q in range(4)]).ravel()
+    key = keys(4)
+    key[16:24] = -1 - np.arange(8)
+    cases["each_quarters_first_and_last_entry"] = (
+        lane < 16, np.tile(edge, 8).astype(np.int32), key, n)
+    rng = np.random.default_rng(5)
+    ragged = 4 * 200  # a quarter of 200 entries: its last row of 128 is padded
+    entry = rng.integers(0, ragged, C)
+    entry[:8] = [0, 199, 200, 399, 400, 599, 600, 799]
+    entry[8:16] = entry[:8]
+    cases["a_quarter_no_multiple_of_128"] = (
+        rng.random(C) < 0.8, entry.astype(np.int32), keys(6), ragged)
+    rng = np.random.default_rng(7)
+    cases["under_vmap"] = (
+        rng.random((2, C)) < 0.7, rng.integers(0, 96, (2, C)).astype(np.int32) * 21,
+        np.stack([keys(8), keys(9)]), n)
+    return cases
+
+
+JOIN_CASES = _join_cases()
+
+
+def _least_of_entry_oracle(join, entry, key):
+    """Lane by lane: a joiner whose key no other joiner of its entry beats."""
+    return np.array([
+        bool(join[c]) and key[c] == min(
+            key[d] for d in range(len(key)) if join[d] and entry[d] == entry[c])
+        for c in range(len(key))
+    ])
+
+
+@pytest.mark.parametrize("devices", [1, 4])
+@pytest.mark.parametrize("case", list(JOIN_CASES))
+def test_least_of_entry_is_the_join_table_on_the_entrys_own_chip(case, devices):
+    """`sharding.least_of_entry` against the plain expression it is without
+    a mesh, `_join_representative` on the whole arrays, and against the
+    lanes counted one by one: the `[C]` answer to the bit. On four devices
+    the lanes arrive sharded by core and each chip holds a quarter of the
+    table."""
+    import jax.numpy as jnp
+    from jax.sharding import NamedSharding, PartitionSpec as P
+
+    from primesim_tpu.parallel.sharding import least_of_entry
+    from primesim_tpu.sim.step import _join_representative
+
+    join, entry, key, n = JOIN_CASES[case]
+    batched = join.ndim == 2
+    mesh = tile_mesh(devices) if devices > 1 else None
+    assert (n // 4) % 128 == (72 if case == "a_quarter_no_multiple_of_128" else 0)
+
+    def seam(join, entry, key):
+        return least_of_entry(mesh, _join_representative, join, entry, key, n)
+
+    def plain(join, entry, key):
+        return _join_representative(join, entry, key, n)
+
+    args = [jnp.asarray(a) for a in (join, entry, key)]
+    want = (jax.vmap(plain) if batched else plain)(*args)
+    if mesh is not None:
+        lanes = NamedSharding(mesh, P(None, AXIS) if batched else P(AXIS))
+        args = [jax.device_put(a, lanes) for a in args]
+    got = jax.jit(jax.vmap(seam) if batched else seam)(*args)
+    assert got.dtype == jnp.bool_ and got.shape == join.shape
+    np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
+    rows = list(zip(join, entry, key)) if batched else [(join, entry, key)]
+    oracle = np.array([_least_of_entry_oracle(*row) for row in rows])
+    np.testing.assert_array_equal(np.asarray(got), oracle.reshape(join.shape))
+    # one lane an entry that is joined; but for the first two cases, some
+    # such entries on every chip
+    joined = [np.unique(e[j]) for j, e, _ in rows]
+    assert oracle.sum() == sum(map(len, joined))
+    if case == "no_lane_joins":
+        assert not oracle.any()
+    elif case == "every_lane_joins_one_entry":
+        assert oracle.sum() == 1
+    else:
+        assert all(set(e // (n // 4)) == {0, 1, 2, 3} for e in joined)
+
+
 @pytest.mark.parametrize("engine", ["fleet", "stream"])
 def test_sharded_local_runs_under_vmap_and_windows(engine):
     """The run's row read is a `shard_map`: it has to compose with the
@@ -516,10 +626,29 @@ def test_sharded_parity_256core():
         np.testing.assert_array_equal(ec[k], v, err_msg=k)
 
 
+_COLLECTIVE = re.compile(
+    r" = (.*?) (?:all-reduce|all-gather|reduce-scatter|all-to-all"
+    r"|collective-permute)(?:-start)?\("
+)
+
+
+def _collectives(txt, scope=""):
+    """(line, its operands' shapes) of every collective of a compiled text
+    whose line names `scope`."""
+    for line in txt.splitlines():
+        found = _COLLECTIVE.search(line)
+        if found and scope in line:
+            yield line, [
+                [int(d) for d in dims.split(",") if d]
+                for dims in re.findall(r"\w+\[([\d,]*)\]", found.group(1))
+            ]
+
+
+@functools.lru_cache(maxsize=None)
 def _sharded_chunk_text(**machine):
     """A 256-core machine and the compiled text of its `run_chunk` sharded
     over the eight devices: what the partitioner (and `read_rows`) made of
-    the step."""
+    the step. Compiled once a machine: two tests read each text."""
     import jax.numpy as jnp
 
     from primesim_tpu.parallel.sharding import shard_events, shard_state
@@ -547,8 +676,6 @@ def test_sharded_step_never_allgathers_directory():
     # makes XLA materialize the FULL sharers/llc_meta array on every
     # device each step. Compile the sharded chunk and assert no
     # all-gather/all-reduce touches a directory-shaped operand.
-    import re
-
     cfg, txt = _sharded_chunk_text()
     B_S2 = cfg.n_banks * cfg.llc.sets  # full (unsharded) leading dim
     bad = [
@@ -566,23 +693,13 @@ def test_sharded_probe_sends_way_records_not_way_rows():
     under `s.probe` carries more than C rows of `dirm` width (the home
     rows may cross; nothing of `[W1*C, DW]`), and the way read's two
     collectives are there, slots out and records back."""
-    import re
-
     from primesim_tpu.sim.state import dirm_width
 
     cfg, txt = _sharded_chunk_text(local_run_len=4)
-    collective = re.compile(
-        r" = (.*?) (?:all-reduce|all-gather|reduce-scatter|all-to-all"
-        r"|collective-permute)(?:-start)?\("
-    )
     C, W1, DW = cfg.n_cores, cfg.l1.ways, dirm_width(cfg)
     wide, shapes = [], []
-    for line in txt.splitlines():
-        found = collective.search(line)
-        if not found or "s.probe" not in line:
-            continue
-        for dims in re.findall(r"\w+\[([\d,]*)\]", found.group(1)):
-            shape = [int(d) for d in dims.split(",") if d]
+    for line, operands in _collectives(txt, "s.probe"):
+        for shape in operands:
             shapes.append(shape)
             if shape and shape[-1] == DW and np.prod(shape[:-1]) > C:
                 wide.append(line.strip()[:200])
@@ -599,22 +716,12 @@ def test_sharded_local_run_sends_records_not_rows():
     candidate cross chips. Held on the compiled sharded chunk: no
     collective under `s.local` has an operand as wide as a `dirm` row, and
     all of them together carry O(C*(rl+1)) words."""
-    import re
-
     from primesim_tpu.sim.state import dirm_width
 
     cfg, txt = _sharded_chunk_text(local_run_len=4)
-    collective = re.compile(
-        r" = (.*?) (?:all-reduce|all-gather|reduce-scatter|all-to-all"
-        r"|collective-permute)(?:-start)?\("
-    )
     words, wide = 0, []
-    for line in txt.splitlines():
-        found = collective.search(line)
-        if not found or "s.local" not in line:
-            continue
-        for dims in re.findall(r"\w+\[([\d,]*)\]", found.group(1)):
-            shape = [int(d) for d in dims.split(",") if d]
+    for line, operands in _collectives(txt, "s.local"):
+        for shape in operands:
             words += int(np.prod(shape))
             if shape and shape[-1] == dirm_width(cfg):
                 wide.append(line.strip()[:200])
@@ -624,3 +731,27 @@ def test_sharded_local_run_sends_records_not_rows():
     # gather is batched over the cores, which the trace is sharded by
     candidates = cfg.n_cores * (cfg.local_run_len + 1)
     assert 0 < words <= 6 * candidates, (words, candidates)
+
+
+def test_sharded_commit_sends_join_keys_not_the_table():
+    """Phase 4.A's join table has one word a directory entry (B*S2*W2) and
+    C lanes scatter into it. Left to the partitioner every chip fills a
+    whole table and the table is all-reduced, every step (PERF.md section
+    6, PR 49: 64 MB of rung 4's step); through `sharding.least_of_entry`
+    each chip holds the entries of its own banks and the lanes' words
+    cross. Held on the compiled sharded chunk: no collective anywhere has
+    an operand of B*S2*W2 words, in any shape, and the seam's collectives
+    under `s.commit` carry at most 4 x C words together (3 a lane out, 1
+    back)."""
+    cfg, txt = _sharded_chunk_text()
+    table = cfg.n_banks * cfg.llc.sets * cfg.llc.ways
+    assert table > 4 * cfg.n_cores
+    whole, words = [], 0
+    for line, operands in _collectives(txt):
+        for shape in operands:
+            if np.prod(shape) == table:
+                whole.append(line.strip()[:200])
+            if "s.commit" in line and "shard_map" in line:
+                words += int(np.prod(shape))
+    assert not whole, "the join table crosses chips whole:\n" + "\n".join(whole)
+    assert 0 < words <= 4 * cfg.n_cores, (words, cfg.n_cores)
