@@ -797,6 +797,11 @@ def cmd_sweep(ns) -> int:
                 file=sys.stderr,
             )
             kept_ids = [ids[j] for j in keep]
+            if mesh is not None:
+                # what is left lies on the most devices that divide it
+                from ..parallel.sharding import fleet_submesh
+
+                mesh = fleet_submesh(mesh, len(keep))
             fleet = FleetEngine(
                 cfg,
                 [fleet.traces[j] for j in keep],
@@ -812,17 +817,17 @@ def cmd_sweep(ns) -> int:
     # fused path fleet_run_loop — warm what will run.
     warm = FleetEngine(
         cfg, fleet.traces, fleet.element_overrides,
-        chunk_steps=ns.chunk_steps, mesh=mesh,
+        chunk_steps=ns.chunk_steps, mesh=fleet.mesh,
     )
     from ..sim import exec_cache
 
     if supervised or rec is not None:
-        out_st = exec_cache.call(
+        out = exec_cache.call(
             fleet_run_chunk, "fleet.run_chunk",
             (warm.geom_cfg, warm.chunk_steps), (warm.events, warm.state),
             {"has_sync": warm.has_sync},
         )
-        np.asarray(out_st.cycles)
+        np.asarray(out.cycles)
     else:
         out = exec_cache.call(
             fleet_run_loop, "fleet.run_loop",
@@ -832,6 +837,10 @@ def cmd_sweep(ns) -> int:
         )
         np.asarray(out[0].cycles)
     _emit_ttfs_line(cache, t_start)
+    # the warm-up's fleet and its result are two more copies of every
+    # machine in HBM (as in `cmd_run`): at four rung-3 machines a chip the
+    # difference between the timed run fitting and not
+    del warm, out
     fleet.overlap = overlap
     fleet.block_until_ready()
     if rec is not None:
@@ -2045,11 +2054,13 @@ def build_parser() -> argparse.ArgumentParser:
     )
     w.add_argument(
         "--devices", type=int, default=0, metavar="N",
-        help="shard EVERY fleet element over the first N jax devices "
-             "(shard x vmap, DESIGN.md §22: cores/L1s by core, LLC/"
-             "directory by bank, under the element batch; still one "
-             "compiled program per geometry); with --workers each worker "
-             "owns a sharded fleet on its own mesh",
+        help="lay the fleet over the first N jax devices, every machine "
+             "whole on one device and B / N machines a device, in the "
+             "order the elements are written (DESIGN.md §22: each device "
+             "builds and runs its own machines, nothing crosses devices; "
+             "still one compiled program per geometry). N must divide "
+             "the number of elements; with --workers each worker's unit "
+             "is ONE machine, cut by core and bank over its N devices",
     )
     w.add_argument(
         "--workers", type=int, default=0, metavar="N",
@@ -2220,9 +2231,9 @@ def build_parser() -> argparse.ArgumentParser:
     )
     v.add_argument(
         "--devices", type=int, default=0, metavar="N",
-        help="dispatch mode: every leased unit runs on a fleet sharded "
-             "over N jax devices (shard x vmap; the mesh shape joins the "
-             "unit's geometry bucket)",
+        help="dispatch mode: every leased unit, one machine, runs cut "
+             "by core and bank over N jax devices (the mesh shape joins "
+             "the unit's geometry bucket)",
     )
     v.add_argument(
         "--quota", default=None, metavar="RATE[:BURST]",

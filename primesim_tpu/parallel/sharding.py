@@ -41,8 +41,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from ..faults.schedule import FaultState
-from ..sim.state import MachineState, TimingKnobs, init_state
+from ..faults.schedule import FaultState, fault_state_from_config
+from ..sim.state import MachineState, TimingKnobs, init_state, knobs_from_config
 
 AXIS = "tiles"
 
@@ -220,13 +220,16 @@ def events_pspec() -> P:
     return P(AXIS)  # a `trace/device.py::DeviceTrace`, sharded by core
 
 
-def state_shardings(mesh: Mesh) -> MachineState:
-    """`state_pspecs()` on `mesh`: a NamedSharding per MachineState field."""
+def _on(mesh: Mesh, specs: MachineState) -> MachineState:
     return jax.tree.map(
-        lambda spec: NamedSharding(mesh, spec),
-        state_pspecs(),
+        lambda spec: NamedSharding(mesh, spec), specs,
         is_leaf=lambda x: isinstance(x, P),
     )
+
+
+def state_shardings(mesh: Mesh) -> MachineState:
+    """`state_pspecs()` on `mesh`: a NamedSharding per MachineState field."""
+    return _on(mesh, state_pspecs())
 
 
 def shard_state(mesh: Mesh, st: MachineState) -> MachineState:
@@ -406,28 +409,131 @@ def least_of_entry(mesh: Mesh | None, table_least, join, entry, key, n):
     )(packed) != 0
 
 
-def fleet_state_pspecs() -> MachineState:
-    """state_pspecs() lifted under the fleet's leading batch axis: every
-    leaf gains an UNSHARDED leading dim (elements replicate across the
-    mesh; cores/banks shard within each element, shard x vmap)."""
-    solo = state_pspecs()
+def fleet_is_cut(n_elements: int, n_devices: int) -> bool:
+    """Whether a fleet of `n_elements` machines on `n_devices` chips lies
+    as `Engine`'s one machine does, cut by core and bank over the chips,
+    and not with its machines whole: a fleet of ONE machine on several
+    chips, which is what a pool worker, the auditor and a served bucket
+    build for a unit that asks for devices (`pool/worker.py`,
+    `attest/audit.py`: a unit is one element, and no code of theirs can
+    give more). A rule on the two counts alone; ROADMAP D13 books it."""
+    return n_elements == 1 and n_devices > 1
+
+
+def check_fleet_mesh(n_elements: int, n_devices: int) -> None:
+    """A fleet on a mesh lies B / D whole machines a chip (or is one
+    machine, `fleet_is_cut`): any other B is refused, typed, before
+    anything is built."""
+    if n_elements % n_devices and not fleet_is_cut(n_elements, n_devices):
+        raise DeviceMeshError(
+            f"a fleet of {n_elements} machines does not lie on {n_devices} "
+            f"devices: every machine is whole on one device, "
+            f"{n_elements} / {n_devices} a device, so the devices must "
+            f"divide the machines",
+            devices=n_devices,
+        )
+
+
+def fleet_devices(n_elements: int, n_devices: int) -> int:
+    """The most of `n_devices` a fleet of `n_elements` machines can lie
+    on (`check_fleet_mesh`): for a caller whose fleet is what is left of
+    the one that was asked for (a sweep's quarantined or deduplicated
+    elements, a mesh that lost a chip)."""
+    if fleet_is_cut(n_elements, n_devices):
+        return n_devices
+    return max(d for d in range(1, n_devices + 1) if n_elements % d == 0)
+
+
+def fleet_submesh(mesh: Mesh, n_elements: int) -> Mesh:
+    """`mesh`, or its first `fleet_devices` devices where a fleet of
+    `n_elements` does not lie on all of it."""
+    n = fleet_devices(n_elements, mesh.shape[AXIS])
+    if n == mesh.shape[AXIS]:
+        return mesh
+    return tile_mesh(devices=list(mesh.devices.flat)[:n])
+
+
+def fleet_state_pspecs(cut: bool = False) -> MachineState:
+    """PartitionSpec per leaf of a fleet's state: the leading (batch) axis
+    over the chips, every machine whole on one chip, element `e` of B on
+    chip `e // (B // D)`. Nothing of a machine crosses chips then, and a
+    chip's program is the one-chip fleet's (`sim/fleet.py::fleet_run_loop`).
+    Until PR 51 the batch axis was replicated and every machine cut, and
+    the chip gave four chips for the speed of one (5.894 ms a step on four
+    chips, 5.828 on one: ROADMAP S15 (iii)); `cut` is that layout, `state_pspecs()`
+    under an unsharded leading axis, for the fleet of one (`fleet_is_cut`)."""
     return jax.tree.map(
-        lambda spec: P(None, *spec),
-        solo,
+        (lambda spec: P(None, *spec)) if cut else (lambda spec: P(AXIS)),
+        state_pspecs(),
         is_leaf=lambda x: isinstance(x, P),
     )
 
 
-def fleet_events_pspec() -> P:
-    return P(None, AXIS)  # a fleet's `DeviceTrace`: batch whole, core-sharded
+def fleet_events_pspec(cut: bool = False) -> P:
+    """Of a fleet's `DeviceTrace`: with its machines, as the state."""
+    return P(None, AXIS) if cut else P(AXIS)
+
+
+def _cut(mesh: Mesh, batched) -> bool:
+    return fleet_is_cut(batched.shape[0], mesh.shape[AXIS])
+
+
+def fleet_state_shardings(mesh: Mesh, cut: bool = False) -> MachineState:
+    return _on(mesh, fleet_state_pspecs(cut))
 
 
 def shard_fleet_state(mesh: Mesh, st: MachineState) -> MachineState:
-    specs = fleet_state_pspecs()
     return jax.tree.map(
-        lambda x, spec: jax.device_put(x, NamedSharding(mesh, spec)), st, specs
+        jax.device_put, st, fleet_state_shardings(mesh, _cut(mesh, st.step))
     )
 
 
 def shard_fleet_events(mesh: Mesh, events) -> jax.Array:
-    return jax.device_put(events, NamedSharding(mesh, fleet_events_pspec()))
+    """A host array goes to each chip as that chip's shard: no chip holds
+    another's machines' events on the way."""
+    return jax.device_put(
+        events, NamedSharding(mesh, fleet_events_pspec(_cut(mesh, events)))
+    )
+
+
+@functools.lru_cache(maxsize=None)
+def _fleet_state_builder(mesh: Mesh, geom_cfg, n_elements: int):
+    def build(knobs, faults, quantum_end):
+        one = init_state(geom_cfg)
+        st = jax.tree.map(
+            lambda x: jnp.broadcast_to(x, (n_elements, *x.shape)), one
+        )
+        return st._replace(knobs=knobs, faults=faults, quantum_end=quantum_end)
+
+    return jax.jit(build, out_shardings=fleet_state_shardings(mesh))
+
+
+def build_fleet_state(elem_cfgs, mesh: Mesh | None = None) -> MachineState:
+    """A fleet's initial state, `init_state` of every element's effective
+    config stacked. Without a mesh exactly that, on the default device
+    (the one-chip fleets' build, left as it was: ROADMAP N11). On a mesh,
+    machines whole: one compiled program a geometry and B whose outputs
+    are laid out by `fleet_state_pspecs()`, so every chip fills its own
+    machines and none ever holds another's (sixteen rung-3 machines are
+    13.2 GB: stacked on one chip they fail on 16 GB before they are laid
+    out). The leaves that an element's timing reaches (`knobs`, `faults`,
+    `quantum_end`: a few words a machine) are made element by element as
+    `init_state` makes them and handed in; every other leaf is the
+    geometry's, the same for every element
+    (tests/test_fleet_on_chips.py holds the two builds equal)."""
+    if mesh is None or fleet_is_cut(len(elem_cfgs), mesh.shape[AXIS]):
+        st = jax.tree.map(
+            lambda *xs: jnp.stack(xs), *[init_state(c) for c in elem_cfgs]
+        )
+        return st if mesh is None else shard_fleet_state(mesh, st)
+
+    def stacked(make):
+        return jax.tree.map(lambda *xs: jnp.stack(xs), *map(make, elem_cfgs))
+
+    return _fleet_state_builder(
+        mesh, elem_cfgs[0].timing_normalized(), len(elem_cfgs)
+    )(
+        stacked(knobs_from_config),
+        stacked(fault_state_from_config),
+        stacked(lambda c: jnp.asarray(c.quantum, jnp.int32)),
+    )

@@ -115,7 +115,7 @@ def build_units(
         if devices:
             # mesh shape is part of the leased workload's identity: an
             # acked result must have been produced on the geometry bucket
-            # the campaign asked for (shard x vmap, DESIGN.md §22)
+            # the campaign asked for (DESIGN.md §22)
             unit["devices"] = int(devices)
         unit["key"] = unit_key(unit)
         units.append(unit)

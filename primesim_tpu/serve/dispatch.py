@@ -219,7 +219,7 @@ class DispatchScheduler:
         }
         if self.devices:
             # geometry bucket with a mesh shape: the leasing worker owns
-            # a sharded fleet over this many devices (shard x vmap)
+            # a fleet of one machine cut over this many devices (§22)
             spec["devices"] = self.devices
         spec["key"] = unit_key(spec)
         return spec

@@ -620,7 +620,7 @@ def load_fleet_checkpoint(path: str, fleet) -> None:
         )
     st = _state_from(z)
     if getattr(fleet, "mesh", None) is not None:
-        # restore the shard x vmap layout FleetEngine.__init__ applies
+        # the layout FleetEngine.__init__ builds (whole machines a chip)
         from ..parallel.sharding import shard_fleet_state
 
         st = shard_fleet_state(fleet.mesh, st)

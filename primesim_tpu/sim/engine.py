@@ -269,13 +269,16 @@ def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
     )
 
 
-def commit_job(eng, total, steps, phases, element_steps=None) -> None:
+def commit_job(eng, total, steps, phases, element_steps=None,
+               chip_steps=None) -> None:
     """The one sample of a fused run (DESIGN.md §15), committed once its
     results are on the host: the job's totals row by row of the block
     `total` [rows, C] (the histogram row as its lanes), its host spans'
     seconds, and the static sizes the stat ratios divide by. `eng` is the
     `Engine`, or a `FleetEngine` with `total` summed over its elements,
-    `steps` the longest element's and `element_steps` each element's own:
+    `steps` the longest element's, `element_steps` each element's own and
+    `chip_steps` each chip's loop's (one without a mesh; on a mesh every
+    chip runs its own machines to their end, DESIGN.md §22):
     the sizes are then those of all its machines together, so that a
     share of `n_cores` x `steps` counts a frozen element's lanes as not
     active. To the attached `Recorder`, else to the process's store;
@@ -296,7 +299,8 @@ def commit_job(eng, total, steps, phases, element_steps=None) -> None:
         * path_width(cfg) if router else 0,
     }
     if element_steps is not None:
-        caps.update(elements=machines, element_steps=list(element_steps))
+        caps.update(elements=machines, element_steps=list(element_steps),
+                    chips=len(chip_steps), chip_steps=list(chip_steps))
     wall_s = sum(phases.values()) - phases["init"]
     if eng.obs is not None:
         eng.obs.job_committed(eng.obs_label, steps, wall_s, deltas,

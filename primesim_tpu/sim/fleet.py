@@ -78,11 +78,20 @@ import time
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from ..chaos import sites as chaos
 from ..config.machine import MachineConfig
 from ..obs.span import span
-from ..parallel.sharding import mesh_jit
+from ..parallel.sharding import (
+    AXIS,
+    build_fleet_state,
+    check_fleet_mesh,
+    fleet_is_cut,
+    mesh_jit,
+    shard_fleet_events,
+    shard_fleet_state,
+)
 from ..stats.counters import COUNTER_NAMES, STAT_NAMES, fold_block
 from ..trace.device import DeviceTrace
 from ..trace.format import EV_BARRIER, EV_END, EV_LOCK, EV_UNLOCK, Trace
@@ -203,6 +212,30 @@ def apply_overrides(cfg: MachineConfig, ov: dict | None) -> MachineConfig:
     return out
 
 
+def _each_chip(mesh, st: MachineState, fn, n_whole: int = 0):
+    """`fn(events, st, *whole, mesh)` for a fleet that lies on `mesh` (None:
+    on no mesh, and `fn` is called as it stands). A fleet on a mesh lies
+    with its machines whole, B / D a chip (`sharding.fleet_state_pspecs`),
+    and `fn` then runs under `jax.shard_map` over the chips: every chip
+    the one-chip program (`mesh` None inside, so the step's two seams for
+    a machine that is cut, `read_rows` and `least_of_entry`, are not in
+    its text) on its own machines, the `n_whole` last arguments whole on
+    every chip, every output with the batch as its leading axis again.
+    Nothing crosses chips: no collective, no barrier. The fleet of ONE
+    machine on several chips (`fleet_is_cut`: the pool's unit) is cut as
+    `Engine`'s machine is and takes `fn` with the mesh, as until PR 51."""
+    if mesh is None or fleet_is_cut(st.step.shape[0], mesh.shape[AXIS]):
+        return functools.partial(fn, mesh=mesh)
+    # `check_vma` off: every input and output is a chip's own and no
+    # collective is inside, so there is nothing for it to hold, and the
+    # step's `lax.cond`s (fault injection) need not type their branches
+    return jax.shard_map(
+        functools.partial(fn, mesh=None), mesh=mesh,
+        in_specs=(P(AXIS), P(AXIS)) + (P(),) * n_whole, out_specs=P(AXIS),
+        check_vma=False,
+    )
+
+
 @functools.partial(
     mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
 )
@@ -211,14 +244,18 @@ def fleet_run_chunk(
     has_sync: bool = True, mesh=None,
 ):
     """`run_chunk` vmapped over the leading batch axis. `cfg` must be the
-    TIMING-NORMALIZED geometry config — timing comes from st.knobs. Under
-    the `vmap` the elements are tracers, so the fleet's mesh (read off the
-    batched arguments by `mesh_jit`) is handed on by name."""
-    return jax.vmap(
-        lambda ev, s: run_chunk(
-            cfg, n_steps, ev, s, has_sync=has_sync, mesh=mesh
-        )
-    )(events, st)
+    TIMING-NORMALIZED geometry config — timing comes from st.knobs. The
+    fleet's mesh is read off the batched arguments by `mesh_jit`; on it
+    every chip maps the chunk over its own machines (`_each_chip`)."""
+
+    def chunk(events, st, mesh):
+        return jax.vmap(
+            lambda ev, s: run_chunk(
+                cfg, n_steps, ev, s, has_sync=has_sync, mesh=mesh
+            )
+        )(events, st)
+
+    return _each_chip(mesh, st, chunk)(events, st)
 
 
 #: The state leaves `fleet_run_loop`'s freeze leaves out: a step writes
@@ -254,29 +291,38 @@ def fleet_run_loop(
     `k` starts at 0 for every machine and counts with it while it lives,
     so the live machines all hold the same `k`: when `max_chunks` stops
     one that is not done it stops them all, and the body never runs over
-    a machine that still had work (whose exempt leaves would move)."""
-    events = DeviceTrace.of(events, cfg.local_run_len)
-    exempt = () if cfg.faults_enabled else FREEZE_EXEMPT
-    live_of = jax.vmap(lambda ev, c: loop_live(cfg, ev, c, max_chunks))
-    chunk_of = jax.vmap(
-        lambda ev, c: loop_chunk(cfg, chunk_steps, ev, c, has_sync, mesh))
+    a machine that still had work (whose exempt leaves would move).
 
-    def body(carry):
-        live = live_of(events, carry)
-        new = chunk_of(events, carry)
-        frozen = jax.tree.map(
-            lambda n, o: jnp.where(jnp.expand_dims(
-                live, tuple(range(1, n.ndim))), n, o),
-            new, carry)
-        st = frozen[0]._replace(**{f: getattr(new[0], f) for f in exempt})
-        return (st, *frozen[1:])
+    On a mesh (`_each_chip`) every chip runs this loop over its own B / D
+    machines to THEIR end: `any(live)` is a chip's own, so a chip whose
+    machines finish early stops early and waits for nobody, and the six
+    outputs come back with all B machines on their leading axis."""
 
-    acc = jnp.zeros_like(st.counters)
-    zero = jnp.zeros_like(st.step)  # [B]: the bases and the chunk counts
-    return jax.lax.while_loop(
-        lambda carry: jnp.any(live_of(events, carry)), body,
-        (st, acc, acc, zero, zero, zero),
-    )
+    def loop(events, st, max_chunks, mesh):
+        events = DeviceTrace.of(events, cfg.local_run_len)
+        exempt = () if cfg.faults_enabled else FREEZE_EXEMPT
+        live_of = jax.vmap(lambda ev, c: loop_live(cfg, ev, c, max_chunks))
+        chunk_of = jax.vmap(
+            lambda ev, c: loop_chunk(cfg, chunk_steps, ev, c, has_sync, mesh))
+
+        def body(carry):
+            live = live_of(events, carry)
+            new = chunk_of(events, carry)
+            frozen = jax.tree.map(
+                lambda n, o: jnp.where(jnp.expand_dims(
+                    live, tuple(range(1, n.ndim))), n, o),
+                new, carry)
+            st = frozen[0]._replace(**{f: getattr(new[0], f) for f in exempt})
+            return (st, *frozen[1:])
+
+        acc = jnp.zeros_like(st.counters)
+        zero = jnp.zeros_like(st.step)  # [B]: the bases and the chunk counts
+        return jax.lax.while_loop(
+            lambda carry: jnp.any(live_of(events, carry)), body,
+            (st, acc, acc, zero, zero, zero),
+        )
+
+    return _each_chip(mesh, st, loop, n_whole=1)(events, st, max_chunks)
 
 
 class FleetEngine:
@@ -347,7 +393,13 @@ class FleetEngine:
         # can be SPLICED in later (replace_element) without changing the
         # compiled shape.
         T = max(max(t.max_len for t in traces), int(min_events_capacity))
-        self.mesh = mesh  # `upload_events` places the events on it
+        # a fleet on a mesh lies with its machines whole, B / D a chip
+        # (DESIGN.md §22): a B the devices do not divide is refused here,
+        # before anything is built. `upload_events` and `build_fleet_state`
+        # make each chip's machines on that chip.
+        if mesh is not None:
+            check_fleet_mesh(B, mesh.shape[AXIS])
+        self.mesh = mesh
         with span("fleet.init") as init:
             evs = []
             for t in traces:
@@ -359,11 +411,10 @@ class FleetEngine:
                 evs.append(e)
             self._events_np = np.stack(evs)
             self.upload_events()
-            # state: stack the elements' solo init states — init_state(elem
+            # state: the elements' solo init states stacked — init_state(elem
             # cfg) already seeds knobs and quantum_end from the element's
             # effective timing
-            states = [init_state(c) for c in self.elem_cfgs]
-            self.state = jax.tree.map(lambda *xs: jnp.stack(xs), *states)
+            self.state = build_fleet_state(self.elem_cfgs, mesh)
         self._init_s = init.seconds  # reported with the first job's sample
         self.chunk_steps = chunk_steps
         # same per-chunk counter-accumulator bound as Engine, over the
@@ -401,14 +452,6 @@ class FleetEngine:
         # prefix was saved/loaded under (None = element ran from step 0)
         self.prefix_steps = np.zeros(B, np.int64)
         self.prefix_cache_keys: list = [None] * B
-        # shard x vmap (DESIGN.md §22): each element's cores/banks lay out
-        # over the mesh's "tiles" axis UNDER the batch vmap (batch dim
-        # replicated, per-element layout = the solo state_pspecs). Like the
-        # solo Engine, only the INPUTS are placed — the compiled loops'
-        # output shardings follow by propagation, which the multichip
-        # parity/HLO suites prove is both bit-exact and all-gather-free.
-        if mesh is not None:
-            self._reshard()
         # overlapped chunk dispatch (§23), mirroring Engine: speculate
         # chunk k+1 from the committed state before the caller's host-side
         # durability work; identity of the source state object validates
@@ -418,11 +461,10 @@ class FleetEngine:
         self._pending = None
 
     def _reshard(self) -> None:
-        """Re-place events and state on the fleet mesh layout. Called at
-        init and after any host-side state surgery (splice/restore/fork)
-        whose `.at[i].set` output sharding is not guaranteed to match."""
-        from ..parallel.sharding import shard_fleet_events, shard_fleet_state
-
+        """Re-place events and state on the fleet mesh layout. Called
+        after any host-side state surgery (splice/restore/fork) whose
+        `.at[i].set` output sharding is not guaranteed to match, and by
+        the supervisor once it has given the fleet another mesh."""
         self.events = shard_fleet_events(self.mesh, self.events)
         self.state = shard_fleet_state(self.mesh, self.state)
 
@@ -544,13 +586,23 @@ class FleetEngine:
         commit_job(self, total.sum(axis=0), int(steps.max()), {
             "init": self._init_s, "dispatch": dispatch.seconds,
             "wait": wait.seconds, "readback": readback.seconds},
-            element_steps=steps.tolist())
+            element_steps=steps.tolist(), chip_steps=self._chip_steps(steps))
         self._init_s = 0.0  # the fleet's build belongs to its first job
         if not self.done():
             bad = np.flatnonzero(~self.done_mask()).tolist()
             raise RuntimeError(
                 f"fleet: max_steps exceeded on element(s) {bad} (deadlock?)"
             )
+
+    def _chip_steps(self, steps: np.ndarray) -> list:
+        """The steps each chip's loop ran in a fused run whose elements
+        ran `steps` [B]: the most of that chip's own machines (all of
+        them where the fleet is one chip's, or one machine cut over its
+        chips)."""
+        chips = 1 if self.mesh is None else self.mesh.shape[AXIS]
+        if fleet_is_cut(len(steps), chips):
+            return [int(steps.max())] * chips
+        return [int(block.max()) for block in np.split(steps, chips)]
 
     def run_steps(self, n_steps: int) -> None:
         """Advance every LIVE element by `n_steps` (whole chunks) without
@@ -901,8 +953,6 @@ class FleetEngine:
         if self.mesh is None:
             self.events = jax.device_put(events)
         else:
-            from ..parallel.sharding import shard_fleet_events
-
             self.events = shard_fleet_events(self.mesh, events)
 
     def step_chunk(self) -> None:

@@ -608,6 +608,8 @@ class RunSupervisor:
         except sharding.DeviceMeshError as e:
             self._log("degrade", f"device loss: no landing mesh ({e})")
             return False
+        if self.kind == "fleet":  # whole machines, B / n a device
+            n = sharding.fleet_devices(self.engine.n_elements, n)
         if n >= len(cur) and not lost:
             return False
         new_mesh = sharding.tile_mesh(devices=healthy[:n])
@@ -982,6 +984,13 @@ def build_fleet_isolated(
         ids.append(i)
     if not kept:
         return None, quarantined
+    if mesh is not None and quarantined:
+        # what is left of the fleet that was asked for may not lie on the
+        # whole mesh (B / D whole machines a chip): it takes the most
+        # devices that divide it (ROADMAP D13), never a stand-in machine
+        from ..parallel.sharding import fleet_submesh
+
+        mesh = fleet_submesh(mesh, len(kept))
     fleet = FleetEngine(cfg, kept, kept_ovs, chunk_steps=chunk_steps,
                         mesh=mesh)
     fleet.element_ids = ids

@@ -15,7 +15,9 @@ barriers (`has_sync` true), as `hlo_same.py dump --sync` does;
 (a leading axis of B on the state and the `DeviceTrace`, the static key
 `cfg.timing_normalized()`, as `FleetEngine` hands them over; with
 `devices` above 1 as `FleetEngine(mesh=...)` places them: the batch axis
-whole on every chip, each machine's cores and banks over the tiles). Writes the compiled module's text (for
+over the chips, B / devices whole machines a chip, and the loop the
+one-chip program a chip under `shard_map`: no collective is to be
+printed). Writes the compiled module's text (for
 `hlo_same.py compare`), and prints the compiler's bytes a chip
 (arguments, outputs, temporaries), every collective with its shape
 and the tail of its `op_name`, which holds the phase scope, and every
@@ -213,7 +215,7 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
                   f"{op_name_of(line)[-60:]}")
     if fleet:
         rows = list(st.dirm.shape)
-        rows[1] //= devices  # a chip's own rows of each machine's directory
+        rows[0] //= devices  # a chip's own machines' directories, whole
         dirm = f"s32[{','.join(str(n) for n in rows)}]"
         ops = chunk_loop_ops(text, dirm)
         print(f"ops of `dirm`'s shape {dirm} in the loop over chunks, outside "

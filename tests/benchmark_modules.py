@@ -74,11 +74,13 @@ def vary_string(overrides: dict) -> str:
     return ",".join(f"{k}={v}" for k, v in overrides.items())
 
 
-def handed_to_fleet_by_sweep(monkeypatch, machine_file: str, spec: dict) -> tuple:
+def handed_to_fleet_by_sweep(monkeypatch, machine_file: str, spec: dict,
+                             extra: tuple = (), first: str | None = None) -> tuple:
     """(cfg, traces, overrides, keywords) that `primetpu sweep
     configs/<machine_file> --synth <the cell's parity trace> --vary ...`,
-    one `--vary` for each of the cell's elements after the first, hands
-    `FleetEngine`; nothing is built or run."""
+    one `--vary` for each of the cell's elements after the first (and one,
+    `first`, for the first where a caller spells `{}` as a knob at its own
+    value), then `extra`, hands `FleetEngine`; nothing is built or run."""
     import pytest
 
     import primesim_tpu.sim.fleet as fleet_module
@@ -95,8 +97,11 @@ def handed_to_fleet_by_sweep(monkeypatch, machine_file: str, spec: dict) -> tupl
     argv = ["sweep", os.path.join(ROOT, "configs", machine_file),
             "--synth", "fft_like:" + ",".join(f"{k}={v}" for k, v in args.items()), "--fold",
             "--chunk-steps", "8", "--strict"]
+    if first is not None:
+        argv += ["--vary", first]
     for ov in spec["config"]["run"]["fleet"]["overrides"][1:]:
         argv += ["--vary", vary_string(ov)]
+    argv += list(extra)
     with pytest.raises(Handed) as handed:
         main(argv)
     return handed.value.args

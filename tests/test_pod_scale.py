@@ -1,13 +1,14 @@
-"""Pod-scale composition: shard x vmap fleets + the pipelined rung-5
-path (ISSUE 16 tentpole, DESIGN.md §22).
+"""Pod-scale composition: fleets on a mesh + the pipelined rung-5
+path (ISSUE 16 tentpole, DESIGN.md §22; the fleet's layout since PR 51).
 
 The contracts under test:
 
-- `FleetEngine(..., mesh=...)` lays every element's MachineState out with
-  the solo `state_pspecs()` under the batch vmap, and per-element results
-  are BIT-EXACT vs the unsharded fleet (and, transitively, vs a solo
-  Engine) — across knob sweeps, fault injection, prefix forking, and
-  checkpoint kill -> resume.
+- `FleetEngine(..., mesh=...)` lays its batch axis over the chips, every
+  machine whole on one chip (`fleet_state_pspecs()`), and per-element
+  results are BIT-EXACT vs the unsharded fleet (and, transitively, vs a
+  solo Engine) — across knob sweeps, fault injection, prefix forking, and
+  checkpoint kill -> resume. tests/test_fleet_on_chips.py holds the
+  layout, the build and the loop themselves.
 - `state_pspecs()` is a TRIPWIRE for MachineState: adding a state field
   without deciding its partitioning fails here, not as a silent
   replication regression on a real pod.
@@ -113,9 +114,15 @@ def test_state_pspecs_cover_machine_state_exactly():
     fspecs = fleet_state_pspecs()
     assert jax.tree.structure(fspecs, is_leaf=is_p) == jax.tree.structure(st)
     for spec in jax.tree.leaves(fspecs, is_leaf=is_p):
-        assert isinstance(spec, P) and len(spec) >= 1, spec
-        assert spec[0] is None, f"{spec!r}: batch axis must stay unsharded"
-    assert tuple(fleet_events_pspec()) == (None, AXIS)
+        assert tuple(spec) == (AXIS,), f"{spec!r}: machines whole, the batch over the chips"
+    assert tuple(fleet_events_pspec()) == (AXIS,)
+    # the fleet of one machine on several chips (the pool's unit) is cut as `Engine`'s is
+    cut = fleet_state_pspecs(cut=True)
+    assert jax.tree.structure(cut, is_leaf=is_p) == jax.tree.structure(st)
+    for spec, solo in zip(jax.tree.leaves(cut, is_leaf=is_p),
+                          jax.tree.leaves(specs, is_leaf=is_p)):
+        assert tuple(spec) == (None, *solo), f"{spec!r}: batch axis must stay unsharded"
+    assert tuple(fleet_events_pspec(cut=True)) == (None, AXIS)
 
 
 def test_state_pspecs_shard_the_core_and_bank_axes():
@@ -163,13 +170,13 @@ def test_cli_devices_errors_exit_2_with_structured_json(capsys):
         assert obj["error"]["location"]["devices"] in (5, 48)
 
 
-# ---- sharded fleet parity (shard x vmap) ----------------------------------
+# ---- fleet-on-a-mesh parity (machines whole, B / D a chip) ----------------
 
 
 # heavy GSPMD compiles on the 8-device virtual mesh: slow-marked so the
 # tier-1 budget stays seed-level; the multichip-fleet CI job runs these
 @pytest.mark.slow
-@pytest.mark.parametrize("devices", [4, 8])
+@pytest.mark.parametrize("devices", [2, 4])
 def test_sharded_fleet_bit_exact_vs_unsharded_and_solo(devices):
     cfg = _cfg()
     traces = _traces()
@@ -192,15 +199,15 @@ def test_sharded_fleet_state_is_actually_sharded():
 
     cfg = _cfg()
     fleet = FleetEngine(
-        cfg, _traces(), OVS, chunk_steps=CHUNK, mesh=tile_mesh(8)
+        cfg, _traces(), OVS, chunk_steps=CHUNK, mesh=tile_mesh(4)
     )
     spec = fleet.state.cycles.sharding.spec
-    assert tuple(spec) == (None, AXIS), spec
-    assert tuple(fleet.events.sharding.spec)[:2] == (None, AXIS)
-    assert len(fleet.state.cycles.sharding.mesh.devices.flat) == 8
+    assert tuple(spec) == (AXIS,), spec
+    assert tuple(fleet.events.sharding.spec) == (AXIS,)
+    assert len(fleet.state.cycles.sharding.mesh.devices.flat) == 4
     fleet.run()
-    # outputs keep the layout (GSPMD propagation, no host gather mid-run)
-    assert tuple(fleet.state.cycles.sharding.spec) == (None, AXIS)
+    # outputs keep the layout (`shard_map`'s out_specs, no host gather mid-run)
+    assert tuple(fleet.state.cycles.sharding.spec) == (AXIS,)
     del jax
 
 
@@ -214,12 +221,12 @@ def test_sharded_fleet_fault_injection_parity():
         max_fault_events=1,
         fault_events=((30, FAULT_CORE_FAILSTOP, 3, 0),),
     )
-    traces = [_traces()[1]] * 3
-    ovs = [{"fault_seed": 100 + i} for i in range(3)]
+    traces = [_traces()[1]] * 4
+    ovs = [{"fault_seed": 100 + i} for i in range(4)]
     plain = FleetEngine(cfg, traces, ovs, chunk_steps=CHUNK)
     plain.run()
     sharded = FleetEngine(
-        cfg, traces, ovs, chunk_steps=CHUNK, mesh=tile_mesh(8)
+        cfg, traces, ovs, chunk_steps=CHUNK, mesh=tile_mesh(4)
     )
     sharded.run()
     _assert_fleets_equal(sharded, plain)
@@ -247,13 +254,13 @@ def test_sharded_fleet_prefix_fork_parity():
     plain.run()
 
     forked = FleetEngine(
-        cfg, [tr] * 4, ovs, chunk_steps=CHUNK, mesh=tile_mesh(8)
+        cfg, [tr] * 4, ovs, chunk_steps=CHUNK, mesh=tile_mesh(4)
     )
     groups = plan_prefix(forked.elem_cfgs, forked.traces, chunk_steps=CHUNK)
     assert groups and groups[0].prefix_steps > 0
     st = execute_prefix_plan(forked, groups)
     assert st["forked_elements"] == 4
-    assert tuple(forked.state.cycles.sharding.spec) == (None, AXIS)
+    assert tuple(forked.state.cycles.sharding.spec) == (AXIS,)
     forked.run()
     _assert_fleets_equal(forked, plain)
 
@@ -273,7 +280,7 @@ def test_sharded_fleet_checkpoint_kill_resume_parity(tmp_path):
     plain.run()
 
     first = FleetEngine(
-        cfg, traces, OVS, chunk_steps=CHUNK, mesh=tile_mesh(8)
+        cfg, traces, OVS, chunk_steps=CHUNK, mesh=tile_mesh(4)
     )
     first.run_steps(2 * CHUNK)  # mid-run cut, then the "crash"
     path = str(tmp_path / "fleet.npz")
@@ -281,10 +288,11 @@ def test_sharded_fleet_checkpoint_kill_resume_parity(tmp_path):
     del first
 
     resumed = FleetEngine(
-        cfg, traces, OVS, chunk_steps=CHUNK, mesh=tile_mesh(8)
+        cfg, traces, OVS, chunk_steps=CHUNK, mesh=tile_mesh(2)
     )
-    load_fleet_checkpoint(path, resumed)
-    assert tuple(resumed.state.cycles.sharding.spec) == (None, AXIS)
+    load_fleet_checkpoint(path, resumed)  # a resume crosses meshes
+    assert tuple(resumed.state.cycles.sharding.spec) == (AXIS,)
+    assert len(resumed.state.cycles.sharding.device_set) == 2
     resumed.run()
     _assert_fleets_equal(resumed, plain)
 
@@ -333,7 +341,7 @@ def test_cli_sweep_devices_bit_exact_vs_unsharded(capsys):
             d["value"] = None  # MIPS embeds wall clock
         return lines
 
-    assert run(["--devices", "8"]) == run([])
+    assert run(["--devices", "2"]) == run([])
 
 
 # ---- ingest pipeline (rung-5 stages) --------------------------------------

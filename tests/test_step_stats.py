@@ -366,7 +366,8 @@ def _assert_fleet_sample(sample, fleet):
         {k: v.sum(axis=0) for k, v in fleet.step_stats.items()})
     assert sample["caps"] == {
         "n_cores": B * 16, "local_run_len": 4, "sort_entries": B * 16 * 2 * 6,
-        "elements": B, "element_steps": fleet.steps_run.tolist()}
+        "elements": B, "element_steps": fleet.steps_run.tolist(),
+        "chips": 1, "chip_steps": [int(fleet.steps_run.max())]}
     json.dumps(sample)
 
 
@@ -400,18 +401,22 @@ def test_a_fleet_of_one_commits_a_solo_runs_deltas():
     mine = process_store().samples()[-1]
     assert (solo["label"], mine["label"]) == ("engine", "fleet")
     assert mine["deltas"] == solo["deltas"] and mine["steps"] == solo["steps"] == eng.steps_run
-    assert mine["caps"] == {**solo["caps"], "elements": 1, "element_steps": [eng.steps_run]}
+    assert mine["caps"] == {**solo["caps"], "elements": 1, "element_steps": [eng.steps_run],
+                            "chips": 1, "chip_steps": [eng.steps_run]}
     assert fleet.has_sync and mine["caps"]["sort_entries"] == 16 * 3 * 6
 
 
 def test_a_sharded_fleets_sample_holds_the_rows_its_block_carries():
-    """A fleet stacks solo `init_state`s and places the stack on its mesh,
-    so, unlike a sharded `Engine` (`build_state`), its block keeps the stat
-    rows there; the sample holds whatever rows were drained."""
+    """A fleet on a mesh is whole machines a chip, each chip running the
+    one-chip fleet's program, so, unlike a sharded `Engine` (`build_state`),
+    its block keeps the stat rows there; the sample holds whatever rows were
+    drained, and says how many chips ran and how long each."""
     cfg, trace = MACHINES["plain"]()
-    fleet = _fleet(cfg, [trace] * 2, [{}, {"dram_lat": 150}], mesh=tile_mesh(4))
+    fleet = _fleet(cfg, [trace] * 2, [{}, {"dram_lat": 150}], mesh=tile_mesh(2))
     mine = process_store().samples()[-1]
-    assert len(fleet.state.cycles.devices()) == 4
+    assert len(fleet.state.cycles.devices()) == 2
+    assert mine["caps"]["chips"] == 2
+    assert mine["caps"]["chip_steps"] == mine["caps"]["element_steps"]  # one machine a chip
     assert set(mine["deltas"]) == set(BLOCK_NAMES[:fleet.state.counters.shape[1]])
     assert mine["caps"]["n_cores"] == 32 and mine["caps"]["elements"] == 2
     for k in COUNTER_NAMES:
