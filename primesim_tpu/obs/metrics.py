@@ -32,9 +32,11 @@ class MetricStore:
     is not per core (``noc_sort_log2``: a histogram, kept as a list).
     ``caps`` holds the static sizes a job's stat deltas divide by; of a
     fleet's job (B machines in one dispatch) they are the sizes of all
-    its machines together (``n_cores`` B x a machine's, ``sort_entries``
-    likewise), with ``elements`` B and ``element_steps`` the B step
-    counts (a list), so that no share of core-steps is off by a factor B.
+    its machines together (``n_cores`` B x a machine's, ``sort_entries``,
+    the router walk's two legs a lane at the longest path's width, with
+    or without sync events, likewise), with ``elements`` B and
+    ``element_steps`` the B step counts (a list), so that no share of
+    core-steps is off by a factor B.
 
     ``seq`` is a global monotonically increasing chunk index (it keeps
     counting even after the ring starts dropping, so the slowest-chunk

@@ -41,9 +41,11 @@ matmul's, including duplicate keys):
 CONTRACT: one entry per (lane, segment) — a lane may not enter the same
 segment's FIFO twice in one step, or the sort counts it twice while the
 matmul's one-hot `.set(1)` collapses it.  The engine guarantees this by
-construction: request and reply legs traverse *reversed directed* links
-(distinct ids), and the barrier-arrival leg is masked to barrier lanes,
-disjoint from home-transaction lanes.  Masked entries use ``seg ==
+construction: a lane's first leg is its request OR, on a barrier lane,
+its arrival (one XY path, which crosses a directed link once); first leg
+and reply traverse *reversed directed* links (distinct ids); and a
+barrier lane has no reply (the reply is masked to home-transaction
+lanes, disjoint from barrier lanes).  Masked entries use ``seg ==
 n_seg`` (one past the last real segment); their ranks are garbage the
 caller must mask, same as the matmul path's out-of-range gathers.
 

@@ -293,10 +293,11 @@ def commit_job(eng, total, steps, phases, element_steps=None,
     caps = {
         "n_cores": machines * cfg.n_cores,
         "local_run_len": cfg.local_run_len,
-        # the slots of the router walk's sort: a lane's legs, each
-        # padded to the longest path
-        "sort_entries": machines * cfg.n_cores * (3 if eng.has_sync else 2)
-        * path_width(cfg) if router else 0,
+        # the slots of the router walk's sort: a lane's two legs (the
+        # request, or on a barrier lane the arrival, and the reply), each
+        # padded to the longest path, with or without sync events
+        "sort_entries": machines * cfg.n_cores * 2 * path_width(cfg)
+        if router else 0,
     }
     if element_steps is not None:
         caps.update(elements=machines, element_steps=list(element_steps),

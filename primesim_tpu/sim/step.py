@@ -1479,9 +1479,18 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
         R_lat = kn.router_lat
         c_hop = kn.link_lat + kn.router_lat
         SENT = jnp.int32(-(1 << 30) - (1 << 21))  # < any real wait floor
-        req_p = _path_links(cfg, ctile, btile)  # [C, H]
+        # the first leg: a home transaction's request or, on a barrier
+        # lane (which makes none), the arrival ctile -> htile: both start
+        # at `t0` with the nominal clocks `a_req`, so a walk with sync
+        # events sorts the slots of one without
+        leg1_dst, leg1_mask, leg1_hops = btile, home_txn, req_hops
+        if has_sync:
+            arr_lat_a, arr_hops = _one_way(ctile, htile, cfg, kn)
+            leg1_dst = jnp.where(is_barrier, htile, btile)
+            leg1_mask = home_txn | is_barrier
+            leg1_hops = jnp.where(is_barrier, arr_hops, req_hops)
+        req_p = _path_links(cfg, ctile, leg1_dst)  # [C, H]
         rep_p = _path_links(cfg, btile, ctile)
-        arr_p = _path_links(cfg, ctile, htile)
         H = req_p.shape[1]
         hidx = jnp.arange(H, dtype=jnp.int32)[None, :]
         first_lock = is_lock & (sync_flag == 0)
@@ -1504,26 +1513,22 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
             + hidx * c_hop
         )
         # EVERY per-link operation runs once over the concatenated paths
-        # ([C, 2H] legs, or [C, 3H] with the barrier-arrival leg), and in
-        # the order ONE sort gives them: sorted by (link, key) a link's
-        # entries are one contiguous run, so its rank, its earliest
-        # nominal arrival and its next-free clock are scans over that
-        # run, and a sort back by the carried index returns them to
-        # [C, legs*H] (ops/ranking.py). A table of NL words indexed entry
-        # by entry is the slow form on the chip: an element gather or
-        # scatter costs ten times a sort of the same entries (PERF.md §6,
-        # PR 31). The per-(lane, segment) uniqueness contract of the
-        # rank holds by construction: request and reply legs traverse
-        # reversed DIRECTED links (distinct ids), and the barrier-arrival
-        # leg is masked to barrier lanes, disjoint from home-transaction
-        # lanes.
+        # ([C, 2H]: the first leg and the reply), and in the order ONE
+        # sort gives them: sorted by (link, key) a link's entries are one
+        # contiguous run, so its rank, its earliest nominal arrival and
+        # its next-free clock are scans over that run, and a sort back by
+        # the carried index returns them to [C, 2H] (ops/ranking.py). A
+        # table of NL words indexed entry by entry is the slow form on
+        # the chip: an element gather or scatter costs ten times a sort
+        # of the same entries (PERF.md §6, PR 31). The per-(lane, segment)
+        # uniqueness contract of the rank holds by construction: an XY
+        # path crosses a directed link once, first leg and reply traverse
+        # reversed DIRECTED links (distinct ids), and a barrier lane has
+        # no reply (masked to home-transaction lanes, which are disjoint).
         pth_all, mask_all = _concat_legs(
-            [(req_p, home_txn), (rep_p, home_txn)]
-            + ([(arr_p, is_barrier)] if has_sync else [])
+            [(req_p, leg1_mask), (rep_p, home_txn)]
         )
-        a_all = jnp.concatenate(
-            [a_req, a_rep] + ([a_req] if has_sync else []), axis=1
-        )
+        a_all = jnp.concatenate([a_req, a_rep], axis=1)
         ok_all = mask_all & (pth_all >= 0)
         tgt_all = jnp.where(ok_all, pth_all, NL)
         # r_all: packets ahead of lane i in each hop's same-step FIFO,
@@ -1535,10 +1540,9 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
             r_all, fl_all, runs = segmented_rank_floor(
                 tgt_all, a_all, link_free, order=ord_c
             )
-        arr_lat_a, arr_hops = _one_way(ctile, htile, cfg, kn)
         F_all = jnp.where(
             ok_all, fl_all + r_all * L_lat, SENT
-        )  # [C, legs*H] wait floors
+        )  # [C, 2H] wait floors
 
         def _cascade(t_start, F, nh):
             G = F - hidx * c_hop
@@ -1550,19 +1554,15 @@ def _router_walk(cfg: MachineConfig, kn, link_free, sync_flag, rq: Request,
             )
             return t_end, departs
 
-        t_req_end, d_req = _cascade(t0, F_all[:, :H], req_hops)
+        t_req_end, d_req = _cascade(t0, F_all[:, :H], leg1_hops)
         t_rep_end, d_rep = _cascade(
-            t_req_end + service, F_all[:, H : 2 * H], rep_hops
+            t_req_end + service, F_all[:, H:], rep_hops
         )
-        deps = [d_req, d_rep]
-        if has_sync:
-            t_arr_end, d_arr = _cascade(t0, F_all[:, 2 * H :], arr_hops)
-            deps.append(d_arr)
-        d_all = jnp.concatenate(deps, axis=1)
+        d_all = jnp.concatenate([d_req, d_rep], axis=1)
         raw_rt = t_rep_end - t0  # valid on home_txn lanes
         extra_home = raw_rt - (req_lat + service + rep_lat)
         if has_sync:
-            raw_arr = t_arr_end - t0  # valid on barrier lanes
+            raw_arr = t_req_end - t0  # valid on barrier lanes
             extra_bar = raw_arr - arr_lat_a
         # every link's clock raised to its latest departure, in the
         # sorted order the rank built (masked slots sit in the sentinel

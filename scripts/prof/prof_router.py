@@ -89,7 +89,7 @@ def router_shapes(cfg, seed=0):
     C = cfg.n_cores
     NL = cfg.n_tiles * 4
     H = max(1, (cfg.noc.mesh_x - 1) + (cfg.noc.mesh_y - 1))
-    LT = 3 * H  # req + rep + barrier-arrival legs
+    LT = 2 * H  # the first leg (request, or barrier arrival) + the reply
     key = jnp.asarray(
         rng.integers(0, 1 << 20, C).astype(np.int32) * C
         + np.arange(C, dtype=np.int32)
@@ -102,7 +102,7 @@ def router_shapes(cfg, seed=0):
     bs = jnp.asarray(rng.integers(0, 1000, (C, LT)).astype(np.int32))
     t0 = jnp.asarray(rng.integers(0, 500, C).astype(np.int32))
     sv = jnp.asarray(rng.integers(1, 80, C).astype(np.int32))
-    nh = jnp.asarray(rng.integers(0, H + 1, (3, C)).astype(np.int32))
+    nh = jnp.asarray(rng.integers(0, H + 1, (2, C)).astype(np.int32))
     return dict(C=C, NL=NL, H=H, LT=LT, key=key, tgt=tgt, ok=ok,
                 lf=lf, bs=bs, t0=t0, sv=sv, nh=nh)
 
@@ -149,9 +149,8 @@ def cascade_cuts(s, cfg):
             return t_end, jnp.maximum(t1[:, None], cum) + hidx * c_hop + L_lat
 
         te_req, d_req = leg(t0, F[:, :H], nh[0])
-        te_rep, d_rep = leg(te_req + sv, F[:, H:2 * H], nh[1])
-        te_arr, d_arr = leg(t0, F[:, 2 * H:], nh[2])
-        return te_rep, te_arr, jnp.concatenate([d_req, d_rep, d_arr], axis=1)
+        te_rep, d_rep = leg(te_req + sv, F[:, H:], nh[1])
+        return te_rep, te_req, jnp.concatenate([d_req, d_rep], axis=1)
 
     a = (s["lf"], s["bs"], r, s["ok"], s["t0"], s["sv"], s["nh"])
     timed(xla_cascade, *a, tag="cascade: xla closed form")
@@ -178,13 +177,13 @@ def timed_loop(body, init, tag):
 
 
 def link_cuts(s):
-    # request and reply legs alone, as a trace without locks or barriers
-    # has them (both benchmark traffic mixes): E = 126976 at rung 3
-    NL, S = s["NL"], 2 * s["H"]
-    E = s["C"] * S
+    # the walk's two legs (a barrier arrival rides the first: PR 52):
+    # E = 126976 at rung 3
+    NL = s["NL"]
+    E = s["C"] * s["LT"]
     rng = np.random.default_rng(1)
     lf0 = jnp.asarray(rng.integers(-(1 << 30), 1000, NL).astype(np.int32))
-    tgt, a0, d0 = s["tgt"][:, :S], s["bs"][:, :S], s["lf"][:, :S] + 7
+    tgt, a0, d0 = s["tgt"], s["bs"], s["lf"] + 7
     ordr = lane_order(s["key"])
     edge = jnp.asarray(rng.random(E) < 0.03)
 
@@ -242,8 +241,8 @@ FLEET_SORTS = ((3, "rank+floor"), (4, "back"), (2, "table max"))
 
 def fleet_cuts(n=131072, batches=(4, 16), only=None):
     """The router walk's three entry sorts under a batch axis (PERF.md
-    section 6, PR 48): `n` entries a machine (rung 3's E + NL = 131072;
-    194560 with a `has_sync` trace's third leg), 3, 4 and 2 operands, one
+    section 6, PR 48): `n` entries a machine (rung 3's E + NL = 131072,
+    with or without sync events since PR 52), 3, 4 and 2 operands, one
     key; B machines as `vmap` batches a sort (`[B, n]` along its last
     axis: the form until PR 48), as ONE flat sort of B·n with the
     machine's number in the key, as B solo sorts in a `lax.map`

@@ -89,7 +89,7 @@ def test_sharded_parity_with_barriers():
     DRAM queue, O3 window, local runs) at 16 cores, sharded over four
     devices, on `ocean_like`: the lock table and the barrier slots are
     replicated, the lanes that read and write them are sharded by core,
-    and every arrival is a third leg in the link walk. No cell of the
+    and every arrival rides the first leg of the link walk. No cell of the
     benchmark shards a program with sync events: this test is the guard."""
     from primesim_tpu.trace.format import EV_BARRIER, EV_LOCK, fold_ins
 

@@ -184,3 +184,132 @@ def test_engine_link_free_matches_golden():
     np.testing.assert_array_equal(
         np.asarray(e.state.link_free) + int(e.cycle_base), g.link_free
     )
+
+
+# --- a barrier arrival rides the walk's first leg (PR 52) -----------------
+
+
+def _sync_router_cfg():
+    # rung 3's selectors at 16 cores on a 4 x 4 mesh. Four banks: a
+    # barrier's home is tile `id % 16`, the tile of "its" bank `id % 4`,
+    # so an arrival laid as a request to that bank walks the wrong links
+    from primesim_tpu.config.machine import CoreConfig
+
+    return small_test_config(
+        16, n_banks=4, quantum=500, local_run_len=4, dram_queue=True,
+        core=CoreConfig(o3_overlap_256=64),
+        noc=NocConfig(mesh_x=4, mesh_y=4, contention=True,
+                      contention_model="router"),
+    )
+
+
+def _barriers_among_shared_lines():
+    """The even cores: a few loads, then a barrier of their eight, six
+    times over; the odd cores stream loads and stores over sixteen shared
+    lines all the while. The barriers live on tiles 5 and 10, the lines
+    on tiles 0 to 3: arrivals and other lanes' home transactions cross in
+    the mesh's columns and rows."""
+    from primesim_tpu.trace.format import EV_BARRIER
+
+    rng = np.random.default_rng(52)
+    per_core = []
+    for c in range(16):
+        evs = []
+        if c % 2 == 0:
+            for p in range(6):
+                for i in range(int(rng.integers(1, 4))):
+                    evs.append((EV_LD, 2, int(rng.integers(0, 16)) * 64))
+                evs.append((EV_BARRIER, 8, (5, 10)[p % 2]))
+        else:
+            for i in range(40):
+                t = EV_ST if rng.random() < 0.3 else EV_LD
+                evs.append((t, 2, int(rng.integers(0, 16)) * 64))
+        per_core.append(evs)
+    return from_event_lists(per_core)
+
+
+SYNC_WALKS = {
+    "barriers_among_shared_lines": _barriers_among_shared_lines,
+    "ocean_like": lambda: synth.ocean_like(
+        16, seed=52, grid_n=18, levels=2, visits=3, lock_reductions=1),
+}
+
+
+def _links_shared_by_arrivals_and_home_txns(cfg, trace):
+    """The golden model step by step -> (model, the number of (step, link)
+    pairs at which a barrier arrival and ANOTHER lane's home transaction
+    stood in one link's FIFO)."""
+    g = GoldenSim(cfg, trace)
+    shared = 0
+    while not g.done():
+        before = g.counters["barrier_waits"].copy()
+        g.step()
+        arrived = set(np.flatnonzero(g.counters["barrier_waits"] - before))
+        for users in g._rtr_users.values():
+            cores = {c for _, c in users}
+            shared += bool(cores & arrived) and bool(cores - arrived)
+    return g, shared
+
+
+@pytest.mark.parametrize("name", sorted(SYNC_WALKS))
+def test_parity_arrivals_and_home_txns_share_links(name):
+    """A `has_sync` walk lays a barrier lane's arrival in its FIRST leg's
+    slots (no third leg): where arrivals and other lanes' home
+    transactions queue at the same directed links in the same step, every
+    counter, every core's clock, the stat rows and the links' clocks are
+    the golden model's."""
+    from primesim_tpu.sim.engine import Engine
+
+    cfg, trace = _sync_router_cfg(), SYNC_WALKS[name]()
+    g, shared = _links_shared_by_arrivals_and_home_txns(cfg, trace)
+    assert shared > 20, shared
+    assert g.counters["noc_contention_cycles"].sum() > 0
+    assert_parity(cfg, trace, chunk_steps=16)  # clocks, counters, caches
+    e = Engine(cfg, trace, chunk_steps=16)
+    e.run()
+    assert e.has_sync
+    np.testing.assert_array_equal(
+        np.asarray(e.state.link_free).astype(np.int64) + int(e.cycle_base),
+        g.link_free)
+    np.testing.assert_array_equal(
+        e.step_stats["noc_entries"], g.stats["noc_entries"])
+
+
+def _step_sorts(cfg, trace, has_sync):
+    """The operand length of every `sort` equation of one `step`, through
+    every sub-jaxpr."""
+    import jax
+
+    from primesim_tpu.sim.engine import Engine
+    from primesim_tpu.sim.step import step
+
+    eng = Engine(cfg, trace, chunk_steps=8)
+    found = []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            if eqn.primitive.name == "sort":
+                found.append(int(np.prod(eqn.invars[0].aval.shape)))
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(jax.make_jaxpr(
+        lambda ev, st: step(cfg, ev, st, has_sync=has_sync))(
+            eng.events, eng.state).jaxpr)
+    return sorted(found)
+
+
+def test_has_sync_step_sorts_the_entries_of_a_step_without():
+    """The step of a trace with locks and barriers holds the sorts of the
+    step without, to the length: none longer than two legs a lane at the
+    longest path's width plus the links' table entries (a third leg made
+    it C x 3 x H + NL, and a sort pays for its length padded to a power
+    of two: 2^18 for rung 3's 2^17)."""
+    from primesim_tpu.noc.mesh import n_links
+    from primesim_tpu.noc.topology import path_width
+
+    cfg = _sync_router_cfg()
+    trace = _barriers_among_shared_lines()
+    with_sync = _step_sorts(cfg, trace, True)
+    assert with_sync == _step_sorts(cfg, trace, False)
+    assert max(with_sync) == cfg.n_cores * 2 * path_width(cfg) + n_links(cfg)

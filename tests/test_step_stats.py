@@ -284,7 +284,7 @@ def _assert_job_sample(sample, eng, label="engine"):
     assert isinstance(sample["deltas"]["noc_sort_log2"], list)
     assert sample["caps"] == {
         "n_cores": 16, "local_run_len": 4,
-        "sort_entries": 16 * (3 if eng.has_sync else 2) * 6}
+        "sort_entries": 16 * 2 * 6}  # two legs, with or without sync events
     json.dumps(sample)  # plain data, as `dump_jsonl` writes it
 
 
@@ -403,7 +403,7 @@ def test_a_fleet_of_one_commits_a_solo_runs_deltas():
     assert mine["deltas"] == solo["deltas"] and mine["steps"] == solo["steps"] == eng.steps_run
     assert mine["caps"] == {**solo["caps"], "elements": 1, "element_steps": [eng.steps_run],
                             "chips": 1, "chip_steps": [eng.steps_run]}
-    assert fleet.has_sync and mine["caps"]["sort_entries"] == 16 * 3 * 6
+    assert fleet.has_sync and mine["caps"]["sort_entries"] == 16 * 2 * 6
 
 
 def test_a_sharded_fleets_sample_holds_the_rows_its_block_carries():
@@ -457,7 +457,7 @@ def test_reader_on_stored_samples(window, name):
         "slot_frozen_pct": lambda: 100 * st["slot_frozen"] / (16 * steps),
         "arb_win_pct": lambda: 100 * served / (served + int(eng.counters["retries"].sum())),
         "run_slot_pct": lambda: 100 * st["run_events"] / (16 * steps * 4),
-        "noc_active_pct": lambda: 100 * st["noc_entries"] / (steps * 16 * 3 * 6),
+        "noc_active_pct": lambda: 100 * st["noc_entries"] / (steps * 16 * 2 * 6),
         "noc_sort_log2_max": lambda: max(b for b, n in enumerate(st["noc_sort_log2"]) if n),
     }
     if name in want:

@@ -301,9 +301,9 @@ def test_parity_ocean_like_on_rung3_cut_to_8x8(lock_reductions):
     """The benchmark's `rung3.ocean-n258` at 64 cores: rung 3's machine
     file (router walk, DRAM queue, O3 window, local runs) cut to an 8 x 8
     mesh, on the cell's shape of trace (8 x 8 points a core, four levels,
-    the descent of one V-cycle, 23 global barriers): every arrival is a
-    third leg in the step's link walk, and a frozen core sits out the
-    quantum barrier. With one global lock reduction too."""
+    the descent of one V-cycle, 23 global barriers): every arrival is the
+    first leg of its lane in the step's link walk, and a frozen core sits
+    out the quantum barrier. With one global lock reduction too."""
     import json
     import os
 
