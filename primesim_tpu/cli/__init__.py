@@ -89,6 +89,22 @@ def _load_config(path: str):
         return MachineConfig.from_json(f.read())
 
 
+def _job_detail(eng) -> dict | None:
+    """`detail.job` of a run's summary line: of the sample the engine's
+    last fused run committed (`engine.commit_job`, DESIGN.md §15), its
+    host spans in ms (where the wall went: `init` the engine's build,
+    outside the timed wall) and `place`, the chips the machine lies on and
+    what their allocators held as it was laid.
+    None for an engine that ran no fused job (the chunked paths)."""
+    sample = getattr(eng, "last_job", None)
+    if sample is None:
+        return None
+    return {
+        "phases_ms": {k: round(v * 1e3, 3) for k, v in sample["phases"].items()},
+        "place": sample.get("place"),
+    }
+
+
 def _emit_summary(
     ns, cfg, engine_name, counters, cycles, wall, extra=None,
     resilience=None, timeline=None, eng=None,
@@ -116,6 +132,9 @@ def _emit_summary(
 
         # where the step's own lane-slots went (DESIGN.md §15, STAT_NAMES)
         detail["step_stats"] = stat_totals(eng.step_stats)
+    job = _job_detail(eng)
+    if job:
+        detail["job"] = job
     if extra:
         detail.update(extra)
     if timeline:
@@ -1009,6 +1028,9 @@ def cmd_sweep(ns) -> int:
         "instructions": total_ins,
         "wall_s": round(wall, 3),
     }
+    job = _job_detail(fleet)
+    if job:
+        agg_detail["job"] = job
     if dup_of_caller:
         agg_detail["deduplicated"] = sorted(dup_of_caller)
     if quarantined:
@@ -1927,7 +1949,9 @@ def build_parser() -> argparse.ArgumentParser:
         epilog="The JSON line's detail.step_stats says where the step's own "
                "lane-slots went (core-steps active / ahead of the quantum / "
                "frozen at a barrier, local-run events, "
-               "the router's real sorted entries; all zero under --devices): "
+               "the router's real sorted entries; all zero under --devices); "
+               "detail.job the fused run's host spans in ms, the chips the "
+               "machine lay on and what their allocators held under it: "
                "DESIGN.md section 15.",
     )
     r.add_argument("config", help="machine config (.json or reference-schema .xml)")

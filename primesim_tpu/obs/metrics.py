@@ -26,7 +26,8 @@ class MetricStore:
         {"seq": int, "t": float, "label": str, "steps": int,
          "wall_s": float, "deltas": {counter: int, ...},
          "phases": {phase: float, ...},   # optional
-         "caps": {size: int, ...}}        # optional, a job's sample only
+         "caps": {size: int, ...},        # optional, a job's sample only
+         "place": {...}}                  # optional, a job's sample only
 
     A value of ``deltas`` is a total over the cores, but for a row that
     is not per core (``noc_sort_log2``: a histogram, kept as a list).
@@ -36,7 +37,11 @@ class MetricStore:
     the router walk's two legs a lane at the longest path's width, with
     or without sync events, likewise), with ``elements`` B and
     ``element_steps`` the B step counts (a list), so that no share of
-    core-steps is off by a factor B.
+    core-steps is off by a factor B. ``place`` says where the engine's
+    bytes lie (``sim/engine.py::job_place``): ``devices`` (chip ids, mesh
+    order) and ``alloc`` (the allocator's ``bytes_in_use`` and
+    ``largest_free_block_bytes`` a device as the engine's arrays were
+    laid); plain ints, kept as given.
 
     ``seq`` is a global monotonically increasing chunk index (it keeps
     counting even after the ring starts dropping, so the slowest-chunk
@@ -51,7 +56,8 @@ class MetricStore:
         self.seq = 0
         self.dropped = 0
 
-    def record(self, t, label, steps, wall_s, deltas, phases=None, caps=None):
+    def record(self, t, label, steps, wall_s, deltas, phases=None, caps=None,
+               place=None):
         if len(self._ring) == self._ring.maxlen:
             self.dropped += 1
         sample = {
@@ -66,6 +72,8 @@ class MetricStore:
             sample["phases"] = {k: float(v) for k, v in phases.items()}
         if caps:
             sample["caps"] = _ints(caps)
+        if place:
+            sample["place"] = place
         self._ring.append(sample)
         self.seq += 1
         return sample

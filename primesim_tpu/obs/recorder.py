@@ -87,18 +87,21 @@ class Recorder:
                          for k, v in phases.items()})
         self.trace.complete(label, name, wall_s, args)
 
-    def job_committed(self, label, steps, wall_s, deltas, phases, caps) -> None:
+    def job_committed(self, label, steps, wall_s, deltas, phases, caps,
+                      place=None) -> dict:
         """One fused run (`Engine.run`): a single sample for the whole
-        job. `deltas` are the job's own totals, not cumulative ones; the
-        label's cumulative totals move on by them, so that chunks of the
-        same engine committed before or after keep their deltas whole."""
+        job, which is returned. `deltas` are the job's own totals, not
+        cumulative ones; the label's cumulative totals move on by them, so
+        that chunks of the same engine committed before or after keep
+        their deltas whole. `place`: where the job's bytes lay."""
         prev = self._prev_totals.get(label)
         if prev is not None:
             for k in prev:
                 prev[k] += deltas.get(k, 0)
-        self.store.record(time.time(), label, steps, wall_s, deltas,
-                          phases=phases, caps=caps)
+        sample = self.store.record(time.time(), label, steps, wall_s, deltas,
+                                   phases=phases, caps=caps, place=place)
         self._trace_span(label, "job", steps, wall_s, deltas, phases)
+        return sample
 
     # ---- supervisor / serve side ----------------------------------------
 

@@ -254,7 +254,12 @@ def build_state(cfg, mesh: Mesh | None = None) -> MachineState:
     Without a mesh: `init_state` itself, array by array on the default
     device. The compiled builder gives the same bytes there, but lays
     them elsewhere in HBM, and the step's speed follows that placement:
-    one one-chip cell lost 5 % to it (PERF.md section 6, PR 33). On a mesh
+    one one-chip cell lost 5 % to it (PERF.md section 6, PR 33). What the
+    allocator held and had free as an engine's arrays were laid is on
+    record since PR 53: a fused job's sample holds `place`
+    (`sim/engine.py::job_place`, DESIGN.md §15), and 512 bytes more or
+    less there are a fifth of the way rows' gather (PERF.md section 7
+    (n)). On a mesh
     the counter block carries no stat rows (`init_state(stat_rows=False)`):
     rung 4's row gathers on four chips lost 4 % to the taller block alone
     and 10 % with the rows counted (PERF.md section 6, PR 37), so a sharded

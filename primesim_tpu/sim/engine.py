@@ -269,20 +269,60 @@ def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
     )
 
 
+# ---- where a job's bytes lay: the sample's `place` (DESIGN.md §15) ---------
+
+# what is kept of `Device.memory_stats()`: the bytes held, which parent
+# against change move by the loaded programs' own length, and the largest
+# free block, whose last bytes differ from process to process of one program
+# and with them the speed of the step's gathers (PERF.md section 7 (n))
+ALLOC_KEYS = ("bytes_in_use", "largest_free_block_bytes")
+
+
+def alloc_now() -> dict:
+    """`ALLOC_KEYS` of every local device's allocator, by device id. An
+    engine reads it as its `init` span opens: what lies in HBM under the
+    arrays the span is about to lay (the loaded programs, an earlier job's
+    buffers not yet freed) and the room they are laid into. A platform that
+    keeps no such count (the CPU) has no entry."""
+    held = {}
+    for d in jax.local_devices():
+        stats = d.memory_stats()
+        if stats and all(k in stats for k in ALLOC_KEYS):
+            held[d.id] = {k: int(stats[k]) for k in ALLOC_KEYS}
+    return held
+
+
+def job_place(held: dict, state) -> dict:
+    """Where an engine's bytes lie, for its jobs' samples (`place`,
+    DESIGN.md §15): `devices`, the ids of the chips `state` lies on, in mesh
+    order, and `alloc`, each of `ALLOC_KEYS` -> the value a device of
+    `devices` in `held`, which the engine read (`alloc_now`) as its `init`
+    span opened (`{}` where the platform counts none). A buffer's own
+    device address is not to be had: `unsafe_buffer_pointer()` answers with
+    a host address on the TPU (PERF.md section 7 (n))."""
+    devices = [s.device.id for s in state.cycles.addressable_shards]
+    counted = all(d in held for d in devices)
+    return {
+        "devices": devices,
+        "alloc": {k: [held[d][k] for d in devices] for k in ALLOC_KEYS} if counted else {},
+    }
+
+
 def commit_job(eng, total, steps, phases, element_steps=None,
                chip_steps=None) -> None:
     """The one sample of a fused run (DESIGN.md §15), committed once its
     results are on the host: the job's totals row by row of the block
     `total` [rows, C] (the histogram row as its lanes), its host spans'
-    seconds, and the static sizes the stat ratios divide by. `eng` is the
+    seconds, the static sizes the stat ratios divide by, and `eng.place`,
+    where the engine's bytes lie (`job_place`). `eng` is the
     `Engine`, or a `FleetEngine` with `total` summed over its elements,
     `steps` the longest element's, `element_steps` each element's own and
     `chip_steps` each chip's loop's (one without a mesh; on a mesh every
     chip runs its own machines to their end, DESIGN.md §22):
     the sizes are then those of all its machines together, so that a
     share of `n_cores` x `steps` counts a frozen element's lanes as not
-    active. To the attached `Recorder`, else to the process's store;
-    nothing reads it back."""
+    active. To the attached `Recorder`, else to the process's store, and
+    kept as `eng.last_job` for the run's summary line (`cli`)."""
     cfg = eng.cfg
     machines = 1 if element_steps is None else len(element_steps)
     rows = dict(zip(BLOCK_NAMES, total))  # the rows the block carries
@@ -304,11 +344,12 @@ def commit_job(eng, total, steps, phases, element_steps=None,
                     chips=len(chip_steps), chip_steps=list(chip_steps))
     wall_s = sum(phases.values()) - phases["init"]
     if eng.obs is not None:
-        eng.obs.job_committed(eng.obs_label, steps, wall_s, deltas,
-                              phases, caps)
+        eng.last_job = eng.obs.job_committed(
+            eng.obs_label, steps, wall_s, deltas, phases, caps, eng.place)
     else:
-        process_store().record(time.time(), eng.obs_label, steps, wall_s,
-                               deltas, phases=phases, caps=caps)
+        eng.last_job = process_store().record(
+            time.time(), eng.obs_label, steps, wall_s, deltas,
+            phases=phases, caps=caps, place=eng.place)
 
 
 class Engine:
@@ -343,6 +384,7 @@ class Engine:
         )
         self.mesh = mesh
         with span("engine.init") as init:
+            held = alloc_now()  # before this engine's arrays are laid
             # multi-chip: cores/banks laid out over the tile axis
             # (parallel/); events and state go into that layout from their
             # first byte, never whole onto one device
@@ -354,6 +396,7 @@ class Engine:
             )
             self.state = build_state(cfg, mesh)
         self._init_s = init.seconds  # reported with the first job's sample
+        self.place = job_place(held, self.state)  # in every job's sample
         self.chunk_steps = chunk_steps
         # Counter-accumulator guard (run_loop drains int32 step counters
         # into (lo, hi) pairs whose hi carries above 2^30): any per-core
@@ -387,6 +430,7 @@ class Engine:
         # store (DESIGN.md §15 overhead contract)
         self.obs = None
         self.obs_label = "engine"
+        self.last_job = None  # the sample of the last fused run (`commit_job`)
         # attestation chain (attest.SoloAttest) — None means the chunked
         # loop never fingerprints; like obs, the fused run() never
         # consults it (DESIGN.md §24: --attest off is bit-exact by

@@ -99,7 +99,9 @@ from . import exec_cache
 from .engine import (
     _ACC_BITS,
     _np,
+    alloc_now,
     commit_job,
+    job_place,
     loop_chunk,
     loop_live,
     run_chunk,
@@ -401,6 +403,7 @@ class FleetEngine:
             check_fleet_mesh(B, mesh.shape[AXIS])
         self.mesh = mesh
         with span("fleet.init") as init:
+            held = alloc_now()  # before this fleet's arrays are laid
             evs = []
             for t in traces:
                 e = np.asarray(t.line_events(cfg.line_bits))
@@ -416,6 +419,7 @@ class FleetEngine:
             # effective timing
             self.state = build_fleet_state(self.elem_cfgs, mesh)
         self._init_s = init.seconds  # reported with the first job's sample
+        self.place = job_place(held, self.state)  # in every job's sample
         self.chunk_steps = chunk_steps
         # same per-chunk counter-accumulator bound as Engine, over the
         # worst event of ANY element
@@ -443,6 +447,7 @@ class FleetEngine:
         # branch in the chunked loops; fleet_run_loop never consults it
         self.obs = None
         self.obs_label = "fleet"
+        self.last_job = None  # the sample of the last fused run (`commit_job`)
         # attestation chains (attest.FleetAttest) — None means chunks are
         # never fingerprinted (DESIGN.md §24); per-element chains advance
         # only for elements live at chunk start, matching the solo loop

@@ -423,11 +423,122 @@ def test_a_sharded_fleets_sample_holds_the_rows_its_block_carries():
         assert mine["deltas"][k] == int(fleet.counters[k].sum()), k
 
 
+# ---- where the job's bytes lay: the sample's `place` ----------------------
+
+def _counting(monkeypatch, held):
+    """An allocator that counts, which the CPU's does not."""
+    from primesim_tpu.sim import engine, fleet
+
+    monkeypatch.setattr(engine, "alloc_now", lambda: held)
+    monkeypatch.setattr(fleet, "alloc_now", lambda: held)
+
+
+def _place_job(kind):
+    cfg, trace = MACHINES["sync"]()
+    if kind == "solo":
+        return _fused(cfg, trace), None
+    if kind == "four":
+        mesh = tile_mesh(4)
+        return _fused(cfg, trace, mesh=mesh), mesh
+    if kind == "fleet":
+        return _fleet(cfg, [trace] * 2, [{}, {"dram_lat": 150}]), None
+    mesh = tile_mesh(4)
+    return _fleet(cfg, [trace] * 4, None, mesh=mesh), mesh
+
+
+@pytest.mark.parametrize("kind", ["solo", "four", "fleet", "fleet_four"])
+@pytest.mark.parametrize("counts", [False, True])
+def test_a_fused_job_commits_where_its_bytes_lay(monkeypatch, kind, counts):
+    """`place`, the fifth key of a job's sample (DESIGN.md §15): the chips
+    in mesh order and what each one's allocator said as the engine's build
+    began (nothing to count on the CPU)."""
+    from primesim_tpu.sim.engine import ALLOC_KEYS
+
+    if counts:
+        _counting(monkeypatch, {i: {k: 1000 * j + i for j, k in enumerate(ALLOC_KEYS)}
+                                for i in range(8)})
+    eng, mesh = _place_job(kind)
+    sample = process_store().samples()[-1]
+    assert sample is eng.last_job and sample["place"] is eng.place
+    chips = [0] if mesh is None else [d.id for d in mesh.devices.flat]
+    assert sample["place"] == {"devices": chips, "alloc": {
+        k: [1000 * j + i for i in chips] for j, k in enumerate(ALLOC_KEYS)} if counts else {}}
+    json.dumps(sample)
+
+
+def test_an_allocator_that_counts_for_some_chips_alone_counts_for_none(monkeypatch):
+    _counting(monkeypatch, {0: {"bytes_in_use": 1, "largest_free_block_bytes": 2}})
+    assert _place_job("four")[0].place["alloc"] == {}
+    assert _place_job("solo")[0].place["alloc"] == {
+        "bytes_in_use": [1], "largest_free_block_bytes": [2]}
+
+
+def test_alloc_now_keeps_the_two_counts_of_a_device_that_has_both(monkeypatch):
+    import jax
+
+    from primesim_tpu.sim import engine
+
+    class Device:
+        def __init__(self, i, stats):
+            self.id, self._stats = i, stats
+
+        def memory_stats(self):
+            return self._stats
+
+    monkeypatch.setattr(jax, "local_devices", lambda: [
+        Device(0, {"bytes_in_use": 5, "largest_free_block_bytes": 7, "num_allocs": 1}),
+        Device(1, {"bytes_in_use": 5}), Device(2, None)])
+    assert engine.alloc_now() == {0: {"bytes_in_use": 5, "largest_free_block_bytes": 7}}
+
+
+def test_a_second_fused_run_carries_the_builds_place(monkeypatch):
+    _counting(monkeypatch, {0: {"bytes_in_use": 3 << 20, "largest_free_block_bytes": 1 << 33}})
+    cfg, trace = MACHINES["rung3"]()
+    eng = Engine(cfg, trace, chunk_steps=8)
+    with pytest.raises(RuntimeError, match="max_steps exceeded"):
+        eng.run(max_steps=8)
+    first = eng.last_job
+    with pytest.raises(RuntimeError, match="max_steps exceeded"):
+        eng.run(max_steps=8)
+    assert eng.last_job is not first and eng.last_job["place"] == first["place"] == {
+        "devices": [0],
+        "alloc": {"bytes_in_use": [3 << 20], "largest_free_block_bytes": [1 << 33]}}
+
+
+def test_a_recorder_writes_place_through_dump_jsonl(monkeypatch, tmp_path):
+    _counting(monkeypatch, {0: {"bytes_in_use": 9, "largest_free_block_bytes": 11}})
+    cfg, trace = MACHINES["rung3"]()
+    rec = Recorder("basic", metrics_path=str(tmp_path / "m.jsonl"))
+    eng = Engine(cfg, trace, chunk_steps=32)
+    rec.attach(eng)
+    eng.run()
+    assert eng.last_job is rec.store.samples()[0]
+    rec.finalize()
+    line = json.loads(open(tmp_path / "m.jsonl").read().splitlines()[0])
+    assert line["place"] == eng.place and line["place"]["alloc"]["bytes_in_use"] == [9]
+    fleet = _fleet(cfg, [trace] * 2, None, rec=Recorder("basic"))
+    assert fleet.last_job["place"] == fleet.place and fleet.last_job["label"] == "fleet"
+
+
+def test_the_run_summary_says_where_the_wall_went_and_where_the_machine_lay():
+    from primesim_tpu import cli
+
+    eng = _fused(*MACHINES["rung3"]())
+    job = cli._job_detail(eng)
+    assert set(job) == {"phases_ms", "place"} and job["place"] is eng.place
+    assert job["phases_ms"] == {k: round(1e3 * v, 3) for k, v in eng.last_job["phases"].items()}
+    cfg, trace = MACHINES["rung3"]()
+    chunked = Engine(cfg, trace, chunk_steps=32)
+    chunked.run_chunked()
+    assert cli._job_detail(chunked) is None and cli._job_detail(None) is None
+
+
 # ---- the benchmark's readers of the job samples ---------------------------
 
 READERS = ("slot_active_pct", "slot_quantum_pct", "slot_frozen_pct", "arb_win_pct",
            "run_slot_pct", "noc_active_pct", "noc_sort_log2_max", "host_readback_ms_job",
            "host_dispatch_ms_job")
+INIT_READER = ("engine_init_ms_job",)  # PR 53: the reader of `phases.init`
 
 
 @pytest.fixture(scope="module")
@@ -443,7 +554,7 @@ def window():
     return cells, run, engines
 
 
-@pytest.mark.parametrize("name", READERS)
+@pytest.mark.parametrize("name", READERS + INIT_READER)
 def test_reader_on_stored_samples(window, name):
     cells, run, engines = window
     got = cells.load_metric(name)(run, None)
@@ -468,12 +579,24 @@ def test_reader_on_stored_samples(window, name):
         assert got == pytest.approx(1e3 * sum(s["phases"][key] for s in samples) / 2) and got > 0
 
 
-@pytest.mark.parametrize("name", READERS)
+@pytest.mark.parametrize("name", READERS + INIT_READER)
 def test_reader_finds_nothing_where_the_samples_are_not_the_windows(window, name):
     cells, run, _ = window
     read = cells.load_metric(name)
     assert read({"jobs": [], "n_cores": 16}, None) is None
     assert read({"jobs": [{"steps": 1}] + run["jobs"], "n_cores": 16}, None) is None
+
+
+def test_the_init_reader_reads_the_parents_samples(window, monkeypatch):
+    """The parent's samples: everything but `place`."""
+    import primesim_tpu.obs
+
+    cells, run, engines = window
+    store = MetricStore()
+    store._ring.extend({k: v for k, v in e.last_job.items() if k != "place"} for e in engines)
+    monkeypatch.setattr(primesim_tpu.obs, "process_store", lambda: store)
+    assert cells.load_metric("engine_init_ms_job")(run, None) > 0
+    assert cells.load_metric("slot_active_pct")(run, None) > 0
 
 
 @pytest.mark.parametrize("passes, kept", [(1, 2), (0, 1)])
