@@ -38,10 +38,13 @@ class MetricStore:
     or without sync events, likewise), with ``elements`` B and
     ``element_steps`` the B step counts (a list), so that no share of
     core-steps is off by a factor B. ``place`` says where the engine's
-    bytes lie (``sim/engine.py::job_place``): ``devices`` (chip ids, mesh
-    order) and ``alloc`` (the allocator's ``bytes_in_use`` and
-    ``largest_free_block_bytes`` a device as the engine's arrays were
-    laid); plain ints, kept as given.
+    bytes lie (``sim/engine.py::job_place``, ``place_run``): ``devices``
+    (chip ids, mesh order) and three readings of the allocator a device
+    (``bytes_in_use``, ``largest_free_block_bytes``,
+    ``peak_bytes_in_use``): ``alloc`` as the engine's build began,
+    ``alloc_built`` as it ended, ``alloc_run`` as the job's wait ended,
+    while the engine still named the state it had handed the loop; plain
+    ints, kept as given.
 
     ``seq`` is a global monotonically increasing chunk index (it keeps
     counting even after the ring starts dropping, so the slowest-chunk

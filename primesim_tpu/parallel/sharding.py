@@ -252,12 +252,14 @@ def build_state(cfg, mesh: Mesh | None = None) -> MachineState:
     shard and none ever holds a whole `dirm` or `l1` (rung 4's directory
     is 9.66 GB: `init_state` then `shard_state` fails on a 16 GB chip).
     Without a mesh: `init_state` itself, array by array on the default
-    device. The compiled builder gives the same bytes there, but lays
+    device, each large leaf by one op that holds nothing but its result
+    (`sim/state.py::_rows`, PR 54: the build passes through the machine
+    once). The compiled builder gives the same bytes there, but lays
     them elsewhere in HBM, and the step's speed follows that placement:
     one one-chip cell lost 5 % to it (PERF.md section 6, PR 33). What the
-    allocator held and had free as an engine's arrays were laid is on
-    record since PR 53: a fused job's sample holds `place`
-    (`sim/engine.py::job_place`, DESIGN.md §15), and 512 bytes more or
+    allocator held and had free as an engine's arrays were laid, and its
+    high-water mark once they were, is on record: a fused job's sample
+    holds `place` (`sim/engine.py::job_place`, DESIGN.md §15), and 512 bytes more or
     less there are a fifth of the way rows' gather (PERF.md section 7
     (n)). On a mesh
     the counter block carries no stat rows (`init_state(stat_rows=False)`):

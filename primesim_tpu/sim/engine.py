@@ -66,7 +66,10 @@ def run_chunk(
 ):
     """lax.scan over `n_steps` steps — the jitted hot loop. `mesh`, here
     and in the loops below: the tile mesh of `events` and `st`, which
-    `mesh_jit` reads off them where the caller names none."""
+    `mesh_jit` reads off them where the caller names none. `st` is NOT
+    donated (`run_loop` alone, of all the loops, owns what it is given): the overlapped
+    prefetch, the supervisor's snapshots and a restore hold the source
+    across a chunk."""
 
     events = DeviceTrace.of(events, cfg.local_run_len)
 
@@ -174,11 +177,17 @@ def loop_chunk(cfg, chunk_steps, events, carry, has_sync, mesh):
 
 
 @functools.partial(
-    mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",)
+    mesh_jit, static_argnums=(0, 1), static_argnames=("has_sync",),
+    donate_argnums=(3,),
 )
 def run_loop(cfg: MachineConfig, chunk_steps: int, events, st: MachineState,
              max_chunks, has_sync: bool = True, mesh=None):
     """ONE dispatched device program for a whole simulation run.
+
+    The loop OWNS the state it is given: `st` is donated, its buffers
+    become the result's and the caller's arrays are deleted, so a job
+    holds its machine once in HBM (DESIGN.md §6; no flag, on every
+    platform). `events` is not: an engine runs again on it.
 
     `lax.while_loop` over scan chunks; after each chunk, ON DEVICE: drain
     int32 step counters into (lo, hi) int32 accumulator pairs (hi carries
@@ -220,7 +229,8 @@ def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
     simulation is therefore BIT-EXACT with the preloaded run, including
     LRU stamps (step_no advances only on executed steps). Counters drain
     and clocks rebase on-device every 64 steps, same arithmetic as
-    run_loop.
+    run_loop. `st` is NOT donated: no benchmark cell runs this loop (R5),
+    so nothing could judge it.
     """
     events = DeviceTrace.of(events, cfg.local_run_len)
     need = cfg.local_run_len + 1
@@ -272,18 +282,22 @@ def stream_loop(cfg: MachineConfig, events, st: MachineState, exhausted,
 # ---- where a job's bytes lay: the sample's `place` (DESIGN.md §15) ---------
 
 # what is kept of `Device.memory_stats()`: the bytes held, which parent
-# against change move by the loaded programs' own length, and the largest
+# against change move by the loaded programs' own length, the largest
 # free block, whose last bytes differ from process to process of one program
-# and with them the speed of the step's gathers (PERF.md section 7 (n))
-ALLOC_KEYS = ("bytes_in_use", "largest_free_block_bytes")
+# and with them the speed of the step's gathers (PERF.md section 7 (n)), and
+# the process's high-water mark, which between two readings of one engine
+# says what the span between them passed through
+ALLOC_KEYS = ("bytes_in_use", "largest_free_block_bytes", "peak_bytes_in_use")
 
 
 def alloc_now() -> dict:
     """`ALLOC_KEYS` of every local device's allocator, by device id. An
-    engine reads it as its `init` span opens: what lies in HBM under the
-    arrays the span is about to lay (the loaded programs, an earlier job's
-    buffers not yet freed) and the room they are laid into. A platform that
-    keeps no such count (the CPU) has no entry."""
+    engine reads it three times (`job_place`, `place_run`): as its `init`
+    span opens, what lies in HBM under the arrays the span is about to lay
+    (the loaded programs, an earlier job's buffers not yet freed) and the
+    room they are laid into; as that span closes; and as a fused job's
+    `wait` span closes, before the engine lets go of the state it handed
+    the loop. A platform that keeps no such count (the CPU) has no entry."""
     held = {}
     for d in jax.local_devices():
         stats = d.memory_stats()
@@ -292,20 +306,43 @@ def alloc_now() -> dict:
     return held
 
 
-def job_place(held: dict, state) -> dict:
+def _alloc_of(held: dict, devices: list) -> dict:
+    """Each of `ALLOC_KEYS` -> the value a device of `devices` in `held`
+    (an `alloc_now`); `{}` where the platform counts none."""
+    if not all(d in held for d in devices):
+        return {}
+    return {k: [held[d][k] for d in devices] for k in ALLOC_KEYS}
+
+
+def job_place(held: dict, built: dict, state) -> dict:
     """Where an engine's bytes lie, for its jobs' samples (`place`,
     DESIGN.md §15): `devices`, the ids of the chips `state` lies on, in mesh
-    order, and `alloc`, each of `ALLOC_KEYS` -> the value a device of
+    order; `alloc`, each of `ALLOC_KEYS` -> the value a device of
     `devices` in `held`, which the engine read (`alloc_now`) as its `init`
-    span opened (`{}` where the platform counts none). A buffer's own
-    device address is not to be had: `unsafe_buffer_pointer()` answers with
-    a host address on the TPU (PERF.md section 7 (n))."""
+    span opened; and `alloc_built`, the same of `built`, read as that span
+    closed: its `peak_bytes_in_use` less `alloc`'s `bytes_in_use` is what
+    the build passed through (one machine; two while `init_state`
+    concatenated `dirm` from its parts). Each `{}` where the platform
+    counts none. A buffer's own device address is not to be had:
+    `unsafe_buffer_pointer()` answers with a host address on the TPU
+    (PERF.md section 7 (n))."""
     devices = [s.device.id for s in state.cycles.addressable_shards]
-    counted = all(d in held for d in devices)
     return {
         "devices": devices,
-        "alloc": {k: [held[d][k] for d in devices] for k in ALLOC_KEYS} if counted else {},
+        "alloc": _alloc_of(held, devices),
+        "alloc_built": _alloc_of(built, devices),
     }
+
+
+def place_run(place: dict) -> dict:
+    """`place` of the job whose `wait` span has just closed: the engine's
+    own (`job_place`) plus `alloc_run`, the allocator read now, while the
+    engine still names the state it handed the loop. `alloc_run`'s
+    `bytes_in_use` less `alloc`'s is what the job held: one machine where
+    the loop took the state in place (`run_loop` donates it), two where
+    the result was laid beside it (a fleet's: `fleet_run_loop` does not). A new dict a job: an earlier job's
+    sample keeps its own."""
+    return {**place, "alloc_run": _alloc_of(alloc_now(), place["devices"])}
 
 
 def commit_job(eng, total, steps, phases, element_steps=None,
@@ -314,8 +351,8 @@ def commit_job(eng, total, steps, phases, element_steps=None,
     results are on the host: the job's totals row by row of the block
     `total` [rows, C] (the histogram row as its lanes), its host spans'
     seconds, the static sizes the stat ratios divide by, and `eng.place`,
-    where the engine's bytes lie (`job_place`). `eng` is the
-    `Engine`, or a `FleetEngine` with `total` summed over its elements,
+    where the engine's bytes lie and what the job held (`job_place`,
+    `place_run`). `eng` is the `Engine`, or a `FleetEngine` with `total` summed over its elements,
     `steps` the longest element's, `element_steps` each element's own and
     `chip_steps` each chip's loop's (one without a mesh; on a mesh every
     chip runs its own machines to their end, DESIGN.md §22):
@@ -395,8 +432,9 @@ class Engine:
                 else shard_events(mesh, events)
             )
             self.state = build_state(cfg, mesh)
+            built = alloc_now()  # what the build passed through: its peak
         self._init_s = init.seconds  # reported with the first job's sample
-        self.place = job_place(held, self.state)  # in every job's sample
+        self.place = job_place(held, built, self.state)  # in every job's sample
         self.chunk_steps = chunk_steps
         # Counter-accumulator guard (run_loop drains int32 step counters
         # into (lo, hi) pairs whose hi carries above 2^30): any per-core
@@ -530,6 +568,9 @@ class Engine:
         to chunk_steps-1 extra steps may execute before the guard trips.
         """
         max_chunks = -(-max_steps // self.chunk_steps)
+        # the loop takes `self.state` in place (`run_loop` donates it): a
+        # chunk speculated from it would be a second machine in HBM
+        self.discard_prefetch()
         # the three host spans of a fused run, on the profiler's own clock
         # beside the device ops and, in seconds, in the job's sample
         # (DESIGN.md §15): the enqueue, the wait for the device, and the
@@ -543,6 +584,7 @@ class Engine:
             )
         with span("engine.wait") as wait:
             jax.block_until_ready(k)
+        self.place = place_run(self.place)  # before the old state is let go
         with span("engine.readback") as readback:
             # everything the host needs of the finished run
             acc_lo = _np(acc_lo).astype(np.int64)

@@ -16,7 +16,9 @@ Key derivation — the sha256 of a canonical-JSON payload over:
     error)
   - backend platform and device count
   - the checkpoint `_FORMAT` (state pytree layout) and this module's
-    own `_FORMAT`
+    own `_FORMAT`, which also stands for what the entry points donate
+    (2 since `run_loop` takes its state in place: a blob of before
+    aliases nothing and is never loaded for it)
   - the entry-point name
   - `cfg.timing_normalized()` geometry hash — timing knobs are TRACED
     (they live in `state.knobs`), so one executable serves every
@@ -73,7 +75,10 @@ from ..chaos import sites as chaos
 log = logging.getLogger("primetpu.exec_cache")
 
 _MAGIC = b"PTEXEC01"
-_FORMAT = 1  # exec-entry layout; combined with checkpoint._FORMAT in the key
+# exec-entry layout; combined with checkpoint._FORMAT in the key. 2 (PR 54):
+# `run_loop` donates its state, and an executable serialized before that
+# aliases nothing: its entries must not be found under today's keys
+_FORMAT = 2
 
 
 class ExecCacheCorrupt(Exception):
@@ -164,7 +169,10 @@ class ExecCache:
              static_kwargs: dict):
         """Run `fn(*statics, *dynamics, **static_kwargs)` through the
         cache; any failure anywhere in the cache machinery falls back to
-        the plain jitted call with a structured warning."""
+        the plain jitted call with a structured warning. But for one: an
+        execute that failed AFTER it consumed a donated argument (`run_loop`
+        owns the state it is given) has nothing left to
+        run again on, and what happened is raised."""
         exe, key = self._lookup(fn, entry, statics, dynamics, static_kwargs)
         if exe is None:
             return fn(*statics, *dynamics, **static_kwargs)
@@ -172,6 +180,9 @@ class ExecCache:
             return exe(*dynamics)
         except Exception as e:  # wrong placement, stale artifact, ...
             self._fallback("execute", entry, key, e)
+            if any(isinstance(x, jax.Array) and x.is_deleted()
+                   for x in jax.tree_util.tree_leaves(dynamics)):
+                raise
             return fn(*statics, *dynamics, **static_kwargs)
 
     def ensure(self, fn, entry: str, statics: tuple, dynamics: tuple,

@@ -104,6 +104,7 @@ from .engine import (
     job_place,
     loop_chunk,
     loop_live,
+    place_run,
     run_chunk,
 )
 from .state import MachineState, init_state
@@ -248,7 +249,9 @@ def fleet_run_chunk(
     """`run_chunk` vmapped over the leading batch axis. `cfg` must be the
     TIMING-NORMALIZED geometry config — timing comes from st.knobs. The
     fleet's mesh is read off the batched arguments by `mesh_jit`; on it
-    every chip maps the chunk over its own machines (`_each_chip`)."""
+    every chip maps the chunk over its own machines (`_each_chip`). `st`
+    is not donated, as `run_chunk`'s is not: the prefetch, the
+    supervisor's snapshots and element surgery hold the source."""
 
     def chunk(events, st, mesh):
         return jax.vmap(
@@ -298,7 +301,14 @@ def fleet_run_loop(
     On a mesh (`_each_chip`) every chip runs this loop over its own B / D
     machines to THEIR end: `any(live)` is a chip's own, so a chip whose
     machines finish early stops early and waits for nobody, and the six
-    outputs come back with all B machines on their leading axis."""
+    outputs come back with all B machines on their leading axis.
+
+    Unlike `run_loop`, `st` is NOT donated (PR 54 tried it: DESIGN.md §6).
+    With the fleet's state aliased the TPU's compiler keeps fewer values in
+    fast memory: `rung2.sweep-b16` lost 2.5 % to a slice of `s.local` on the
+    chip, and four rung-3 machines' program gained a copy of the 64 MB join
+    table out of fast memory every step; and a one-chip fleet's peak is its
+    build's, which the donation does not touch (ROADMAP S11 (2))."""
 
     def loop(events, st, max_chunks, mesh):
         events = DeviceTrace.of(events, cfg.local_run_len)
@@ -418,8 +428,9 @@ class FleetEngine:
             # cfg) already seeds knobs and quantum_end from the element's
             # effective timing
             self.state = build_fleet_state(self.elem_cfgs, mesh)
+            built = alloc_now()  # what the build passed through: its peak
         self._init_s = init.seconds  # reported with the first job's sample
-        self.place = job_place(held, self.state)  # in every job's sample
+        self.place = job_place(held, built, self.state)  # in every job's sample
         self.chunk_steps = chunk_steps
         # same per-chunk counter-accumulator bound as Engine, over the
         # worst event of ANY element
@@ -575,6 +586,7 @@ class FleetEngine:
             )
         with span("fleet.wait") as wait:
             jax.block_until_ready(k)
+        self.place = place_run(self.place)  # while input and result are both held
         with span("fleet.readback") as readback:
             acc_lo = _np(acc_lo).astype(np.int64)  # [B, N_BLOCK_ROWS, C]
             acc_hi = _np(acc_hi).astype(np.int64)

@@ -13,6 +13,7 @@ from __future__ import annotations
 from typing import NamedTuple
 
 import jax.numpy as jnp
+import numpy as np
 
 from ..config.machine import MachineConfig
 from ..faults.schedule import FaultState, fault_state_from_config
@@ -176,9 +177,23 @@ class MachineState(NamedTuple):
     faults: FaultState
 
 
+def _rows(n_rows: int, *runs) -> jnp.ndarray:
+    """`[n_rows, W]` int32, every row the same: `runs` of (value, lanes)
+    side by side. ONE broadcast of the `[W]` row (made on the host), so
+    the only large buffer the build holds is the result: a `concatenate`
+    of the runs as `[n_rows, lanes]` arrays keeps its parts alive while
+    the result is laid, and an engine's build then passed through the
+    machine twice (PERF.md section 6, PR 54)."""
+    row = np.concatenate([np.full(lanes, v, np.int32) for v, lanes in runs])
+    return jnp.broadcast_to(row, (n_rows, row.size))
+
+
 def init_state(cfg: MachineConfig, stat_rows: bool = True) -> MachineState:
-    """`stat_rows` false: the counter block holds COUNTER_NAMES alone, the
-    program then counts no stat row (`step` folds the rows the block has).
+    """Every leaf by one op whose only large buffer is its result (`dirm`
+    and `l1` a row broadcast, `_rows`), eagerly or inside a compiled
+    builder (`parallel/sharding.py`). `stat_rows` false: the counter block
+    holds COUNTER_NAMES alone, the program then counts no stat row (`step`
+    folds the rows the block has).
     `build_state` asks for that on a mesh (DESIGN.md §15: rung 4's sharded
     row gathers lost 4 % to the taller block and 10 % to the counts)."""
     C, B = cfg.n_cores, cfg.n_banks
@@ -193,23 +208,10 @@ def init_state(cfg: MachineConfig, stat_rows: bool = True) -> MachineState:
     return MachineState(
         cycles=jnp.zeros(C, jnp.int32),
         ptr=jnp.zeros(C, jnp.int32),
-        l1=jnp.concatenate(
-            [
-                jnp.full((C, w1 * s1), -1, jnp.int32),  # tag plane
-                jnp.full((C, w1 * s1), I, jnp.int32),  # state plane
-                jnp.zeros((C, 3 * w1 * s1), jnp.int32),  # lru/ptr/epoch
-            ],
-            axis=1,
-        ),
-        dirm=jnp.concatenate(
-            [
-                jnp.full((B * s2, 2 * w2), -1, jnp.int32),  # tag/owner
-                jnp.zeros(
-                    (B * s2, dirm_width(cfg) - 2 * w2), jnp.int32
-                ),  # lru + epochs + pad + sharer words
-            ],
-            axis=1,
-        ),
+        # tag plane, state plane, then lru / way pointer / epoch
+        l1=_rows(C, (-1, w1 * s1), (I, w1 * s1), (0, 3 * w1 * s1)),
+        # tag/owner pairs, then lru + epochs + pad + sharer words
+        dirm=_rows(B * s2, (-1, 2 * w2), (0, dirm_width(cfg) - 2 * w2)),
         link_free=jnp.zeros(cfg.n_tiles * 4, jnp.int32),
         dram_free=jnp.zeros(B, jnp.int32),
         lock_holder=jnp.full(cfg.lock_slots, -1, jnp.int32),

@@ -19,7 +19,9 @@ over the chips, B / devices whole machines a chip, and the loop the
 one-chip program a chip under `shard_map`: no collective is to be
 printed). Writes the compiled module's text (for
 `hlo_same.py compare`), and prints the compiler's bytes a chip
-(arguments, outputs, temporaries), every collective with its shape
+(arguments, outputs, what of the outputs is laid in an argument's
+buffer (`alias`: the donated state, PR 54: a job then holds its machine
+once), temporaries), every collective with its shape
 and the tail of its `op_name`, which holds the phase scope, and every
 `sort` with its operands' shape and layout, the dimension it sorts, its
 scoped memory and what made each operand (PR 48), and every
@@ -206,7 +208,8 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
           f"compiled in {time.perf_counter() - t0:.1f} s")
     mem = compiled.memory_analysis()
     print(f"a chip: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, outputs "
-          f"{mem.output_size_in_bytes / 1e9:.3f} GB, temporaries "
+          f"{mem.output_size_in_bytes / 1e9:.3f} GB, of them aliased to an argument "
+          f"{mem.alias_size_in_bytes / 1e9:.3f} GB, temporaries "
           f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
     for line in text.splitlines():
         found = _COLLECTIVE.match(line)

@@ -3,7 +3,9 @@
 The result line of `benchmark/run.py --trace 1` carries one number a metric;
 the job samples behind them (`obs.process_store()`: `phases`, `caps`, and
 `place`, the chips and what their allocators held and had free as each job's
-arrays were laid: DESIGN.md section 15) stay in the process. This runs the benchmark of a checkout and dumps them after it, and
+arrays were laid, and since PR 54 once they were (`alloc_built`) and as the
+job's wait ended (`alloc_run`), each with the process's peak: DESIGN.md
+section 15) stay in the process. This runs the benchmark of a checkout and dumps them after it, and
 prints a directory of such runs as one row a run:
 
     python scripts/prof/job_samples.py run ROOT OUT.jsonl --workload W --seed N --seconds 10 --trace 1

@@ -18,6 +18,8 @@ import json
 import os
 import signal
 
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -231,6 +233,102 @@ def test_inactive_cache_is_a_tail_call():
                           ("EV", "ST"), {"has_sync": True})
     assert out == "out"
     assert seen["args"] == ("CFG", 16, "EV", "ST", True)
+
+
+# ---- the fused loops donate their state (PR 54) ----------------------------
+
+
+def _fused_args(eng):
+    """(entry, statics, dynamics, static kwargs) as `Engine.run` hands them."""
+    return ("engine.run_loop", (eng.cfg, eng.chunk_steps),
+            (eng.events, eng.state, jnp.asarray(1 << 20, jnp.int32)),
+            {"has_sync": eng.has_sync})
+
+
+def _deleted(state):
+    return [x.is_deleted() for x in jax.tree.leaves(state)]
+
+
+def test_a_blob_of_an_undonated_program_is_not_loaded_for_the_donating_one(
+        tmp_path, monkeypatch):
+    """A serialized executable of before PR 54 aliases nothing: loaded for
+    today's `run_loop` it would lay a second machine beside the one it is
+    given, every job, in silence. The payload's `exec_format` tells the
+    two apart: the parent's blob (format 1) lies under another key, and
+    nothing else of the payload differs."""
+    from primesim_tpu.parallel.sharding import mesh_jit
+    from primesim_tpu.sim.engine import run_loop
+
+    cfg, tr = _cfg(), _trace()
+    root = str(tmp_path / "exec")
+    undonated = mesh_jit(run_loop.__wrapped__, static_argnums=(0, 1),
+                         static_argnames=("has_sync",))
+    today = exec_cache._FORMAT
+    monkeypatch.setattr(exec_cache, "_FORMAT", 1)  # the parent writes its entry
+    parent = ExecCache(root)
+    held = Engine(cfg, tr, chunk_steps=CHUNK)
+    entry, statics, dynamics, kw = _fused_args(held)
+    parent.call(undonated, entry, statics, dynamics, kw)
+    assert parent.stats["misses"] == 1 and not any(_deleted(held.state))
+    (old_key,) = parent._memo
+    old_payload = exec_key_payload(entry, statics, dynamics, kw)[0]
+
+    monkeypatch.setattr(exec_cache, "_FORMAT", today)
+    assert today > 1
+    new_payload = exec_key_payload(entry, statics, dynamics, kw)[0]
+    assert {k for k in new_payload if new_payload[k] != old_payload[k]} == {"exec_format"}
+    cache = exec_cache.configure(True, root=root)
+    ref = Engine(cfg, tr, chunk_steps=CHUNK)
+    eng = Engine(cfg, tr, chunk_steps=CHUNK)
+    handed = eng.state
+    eng.run()
+    assert cache.stats["errors"] == 0
+    assert (cache.stats["hits"], cache.stats["misses"]) == (0, 1)
+    assert old_key not in cache._memo and exec_key(new_payload) != old_key
+    assert os.path.exists(os.path.join(root, old_key + ".bin"))  # there, and not found
+    assert all(_deleted(handed))  # the program that ran took its state in place
+    exec_cache.configure(False)
+    ref.run()
+    _same_results(eng, ref)
+
+
+def test_a_failed_execute_does_not_run_again_on_consumed_arguments(tmp_path):
+    """`cache.call` falls back to the jitted call when the loaded
+    executable fails, but not once the failed call has consumed its donated
+    state: there is nothing left to run on, and what happened is raised."""
+    from primesim_tpu.sim.engine import run_loop
+
+    cfg, tr = _cfg(), _trace()
+    cache = ExecCache(str(tmp_path / "exec"))
+    eng = Engine(cfg, tr, chunk_steps=CHUNK)
+    entry, statics, dynamics, kw = _fused_args(eng)
+    assert cache.ensure(run_loop, entry, statics, dynamics, kw)
+    (key,) = cache._memo
+    exe, calls = cache._memo[key], []
+
+    def fn(*args, **kwargs):
+        calls.append(args)
+        return run_loop(*args, **kwargs)
+
+    def refuses(*args):  # fails before it touches an argument
+        raise RuntimeError("stale artifact")
+
+    cache._memo[key] = refuses
+    out = cache.call(fn, entry, statics, dynamics, kw)
+    assert len(calls) == 1 and cache.warnings[-1]["stage"] == "execute"
+    assert all(_deleted(eng.state)) and not any(_deleted(out[0]))
+
+    def dies_after(*args):  # the device ran; the call dies afterwards
+        exe(*args)
+        raise RuntimeError("UNAVAILABLE: died after the work")
+
+    eng = Engine(cfg, tr, chunk_steps=CHUNK)
+    entry, statics, dynamics, kw = _fused_args(eng)
+    cache._memo[key] = dies_after
+    with pytest.raises(RuntimeError, match="died after the work"):
+        cache.call(fn, entry, statics, dynamics, kw)
+    assert len(calls) == 1  # not run again
+    assert cache.warnings[-1]["stage"] == "execute" and cache.stats["errors"] == 2
 
 
 # ---- composes with faults, timing variants, fleets -------------------------

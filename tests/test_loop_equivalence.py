@@ -10,8 +10,16 @@ chunked sharer vector, the stride prefetcher on a moesi torus).
 
 This is the guard ROADMAP D16 names: whoever folds the chunked host loop
 into `run_loop(max_chunks=1)` extends this file first.
+
+Since PR 54 the two also differ in who owns the state: the solo fused loop
+is GIVEN it (`run_loop` donates `st`: the arrays handed in are deleted and
+the result lies in their buffers), the chunked walk and the fleet's fused
+loop keep their source. The same equalities hold, for `Engine` and
+`FleetEngine`, and a fused run after `load_checkpoint` works on the loaded
+arrays.
 """
 
+import jax
 import numpy as np
 import pytest
 
@@ -22,6 +30,7 @@ from primesim_tpu.config.machine import (
     small_test_config,
 )
 from primesim_tpu.sim.engine import Engine
+from primesim_tpu.sim.fleet import FleetEngine
 from primesim_tpu.trace import synth
 from primesim_tpu.trace.format import fold_ins
 
@@ -114,9 +123,13 @@ def test_fused_loop_equals_chunked_host_loop(make_cfg, make_trace):
     cfg, trace = make_cfg(), make_trace()
     # chunks short enough that every run crosses several drains and rebases
     fused = Engine(cfg, trace, chunk_steps=4)
+    handed = fused.state
     fused.run()
+    assert all(x.is_deleted() for x in jax.tree.leaves(handed))  # the loop owned it
     chunked = Engine(cfg, trace, chunk_steps=4)
+    built = chunked.state
     chunked.run_chunked()
+    assert not any(x.is_deleted() for x in jax.tree.leaves(built))  # the walk kept its source
     assert fused.steps_run == chunked.steps_run > 4
     assert int(fused.cycle_base) == int(chunked.cycle_base)
     np.testing.assert_array_equal(fused.cycles, chunked.cycles, err_msg="cycles")
@@ -133,3 +146,84 @@ def test_fused_loop_equals_chunked_host_loop(make_cfg, make_trace):
         np.testing.assert_array_equal(
             np.asarray(leaf), np.asarray(others[name]),
             err_msg=f"state leaf {name}")
+
+
+FLEET_CASES = [
+    pytest.param(MACHINES[m], [GENERATOR_TRACES[g] for g in gens], id=f"{m}-{'+'.join(gens)}")
+    for m, gens in (
+        ("plain", ("fft_like", "false_sharing", "stream")),
+        ("router-dram", ("barrier_phases", "lock_contention", "uniform_random")),
+        ("zoo", ("readers_writer", "pointer_chase", "fft_like")),
+    )
+]
+OVERRIDES = [{}, {"dram_lat": 150}, {"quantum": 200, "llc_lat": 14}]
+
+
+@pytest.mark.parametrize("make_cfg, make_traces", FLEET_CASES)
+def test_fused_fleet_loop_equals_chunked_fleet_walk(make_cfg, make_traces):
+    """`FleetEngine.run()` (`fleet_run_loop`, which keeps its source) against
+    `run_steps` to the end (`fleet_run_chunk`): machines of three
+    lengths, so the fused loop freezes the finished ones, which the walk
+    steps on: every leaf but `step` and the counter block, whose stat rows
+    a finished router machine's steps still count (`FleetEngine.run_steps`)."""
+    cfg, traces = make_cfg(), [make() for make in make_traces]
+    fused = FleetEngine(cfg, traces, OVERRIDES, chunk_steps=4)
+    handed = fused.state
+    fused.run()
+    assert not any(x.is_deleted() for x in jax.tree.leaves(handed))
+    chunked = FleetEngine(cfg, traces, OVERRIDES, chunk_steps=4)
+    built = chunked.state
+    chunked.run_steps(10_000_000)
+    assert chunked.done() and not any(x.is_deleted() for x in jax.tree.leaves(built))
+    np.testing.assert_array_equal(fused.steps_run, chunked.steps_run)
+    assert len(set(fused.steps_run.tolist())) > 1 and fused.steps_run.min() > 4
+    np.testing.assert_array_equal(fused.cycle_base, chunked.cycle_base)
+    np.testing.assert_array_equal(fused.cycles, chunked.cycles, err_msg="cycles")
+    for name, row in fused.counters.items():
+        np.testing.assert_array_equal(row, chunked.counters[name], err_msg=f"counter {name}")
+    others = dict(_leaves(chunked.state))
+    for name, leaf in _leaves(fused.state):
+        if name not in ("step", "counters"):
+            np.testing.assert_array_equal(
+                np.asarray(leaf), np.asarray(others[name]), err_msg=f"state leaf {name}")
+
+
+@pytest.mark.parametrize("kind", ["engine", "fleet"])
+def test_a_fused_run_after_load_checkpoint_owns_the_loaded_state(tmp_path, kind):
+    """A checkpointed walk, then `load_checkpoint` into a fresh engine and a
+    fused `run()`: the loaded arrays are the solo loop's to consume (the
+    engine built them for nobody else; the fleet's loop leaves them), and a
+    SECOND `run()` on the finished engine takes the first one's result.
+    Bit-exact with one uninterrupted run."""
+    cfg = _router_dram_cfg()
+    traces = [GENERATOR_TRACES[g]() for g in ("fft_like", "barrier_phases")]
+
+    def make():
+        if kind == "engine":
+            return Engine(cfg, traces[0], chunk_steps=4)
+        return FleetEngine(cfg, traces, OVERRIDES[:2], chunk_steps=4)
+
+    whole = make()
+    whole.run()
+    walked = make()
+    walked.run_steps(8)
+    path = str(tmp_path / "mid.npz")
+    walked.save_checkpoint(path)
+    resumed = make()
+    built = resumed.state
+    resumed.load_checkpoint(path)
+    loaded = resumed.state
+    resumed.run()
+    owned = kind == "engine"  # `run_loop` donates, `fleet_run_loop` does not
+    assert all(x.is_deleted() for x in jax.tree.leaves(loaded)) == owned
+    assert any(x.is_deleted() for x in jax.tree.leaves(loaded)) == owned
+    assert not any(x.is_deleted() for x in jax.tree.leaves(built))  # never handed over
+    np.testing.assert_array_equal(resumed.cycles, whole.cycles)
+    np.testing.assert_array_equal(resumed.steps_run, whole.steps_run)
+    for name, row in whole.counters.items():
+        np.testing.assert_array_equal(resumed.counters[name], row, err_msg=name)
+    finished = resumed.state
+    resumed.run()  # at END: no chunk runs, the state changes hands all the same
+    assert all(x.is_deleted() for x in jax.tree.leaves(finished)) == owned
+    np.testing.assert_array_equal(resumed.cycles, whole.cycles)
+    np.testing.assert_array_equal(resumed.steps_run, whole.steps_run)

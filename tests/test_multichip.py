@@ -520,8 +520,11 @@ def test_device_loops_key_on_the_mesh_of_their_arguments():
         assert run_loop._cache_size() == n0 + i + 1
     for e in engines:  # fresh arrays of the same layouts: nothing compiles
         again = Engine(cfg, trace, chunk_steps=8, mesh=e.mesh)
-        run_loop(cfg, 8, again.events, again.state, jnp.asarray(1, jnp.int32),
-                 has_sync=again.has_sync)
+        handed = again.state  # the loop owns it (PR 54): `again` has none to read now
+        out = run_loop(cfg, 8, again.events, handed, jnp.asarray(1, jnp.int32),
+                       has_sync=again.has_sync)
+        assert handed.dirm.is_deleted() and not out[0].dirm.is_deleted()
+        assert mesh_of(out[0]) == e.mesh
     assert run_loop._cache_size() == n0 + 3
     np.testing.assert_array_equal(engines[1].cycles, engines[0].cycles)
     np.testing.assert_array_equal(engines[2].cycles, engines[0].cycles)

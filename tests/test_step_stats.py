@@ -425,12 +425,18 @@ def test_a_sharded_fleets_sample_holds_the_rows_its_block_carries():
 
 # ---- where the job's bytes lay: the sample's `place` ----------------------
 
+READINGS = ("alloc", "alloc_built", "alloc_run")  # as init opens, as it closes, as wait closes
+
+
 def _counting(monkeypatch, held):
-    """An allocator that counts, which the CPU's does not."""
+    """An allocator that counts, which the CPU's does not: `held` at every
+    reading, or, where `held` is a function, `held(n)` at the n-th."""
     from primesim_tpu.sim import engine, fleet
 
-    monkeypatch.setattr(engine, "alloc_now", lambda: held)
-    monkeypatch.setattr(fleet, "alloc_now", lambda: held)
+    n = iter(range(10**6))
+    read = (lambda: held(next(n))) if callable(held) else (lambda: held)
+    monkeypatch.setattr(engine, "alloc_now", read)
+    monkeypatch.setattr(fleet, "alloc_now", read)
 
 
 def _place_job(kind):
@@ -451,29 +457,35 @@ def _place_job(kind):
 def test_a_fused_job_commits_where_its_bytes_lay(monkeypatch, kind, counts):
     """`place`, the fifth key of a job's sample (DESIGN.md §15): the chips
     in mesh order and what each one's allocator said as the engine's build
-    began (nothing to count on the CPU)."""
+    began, as it ended and as the job's wait ended (PR 54), every fused
+    job of both engines (nothing to count on the CPU: `{}` each)."""
     from primesim_tpu.sim.engine import ALLOC_KEYS
 
-    if counts:
-        _counting(monkeypatch, {i: {k: 1000 * j + i for j, k in enumerate(ALLOC_KEYS)}
-                                for i in range(8)})
+    if counts:  # the n-th reading says n in its ten-thousands
+        _counting(monkeypatch, lambda n: {
+            i: {k: 10000 * n + 1000 * j + i for j, k in enumerate(ALLOC_KEYS)}
+            for i in range(8)})
     eng, mesh = _place_job(kind)
     sample = process_store().samples()[-1]
     assert sample is eng.last_job and sample["place"] is eng.place
     chips = [0] if mesh is None else [d.id for d in mesh.devices.flat]
-    assert sample["place"] == {"devices": chips, "alloc": {
-        k: [1000 * j + i for i in chips] for j, k in enumerate(ALLOC_KEYS)} if counts else {}}
+    assert sample["place"] == {"devices": chips, **{reading: {
+        k: [10000 * n + 1000 * j + i for i in chips] for j, k in enumerate(ALLOC_KEYS)}
+        if counts else {} for n, reading in enumerate(READINGS)}}
     json.dumps(sample)
 
 
 def test_an_allocator_that_counts_for_some_chips_alone_counts_for_none(monkeypatch):
-    _counting(monkeypatch, {0: {"bytes_in_use": 1, "largest_free_block_bytes": 2}})
-    assert _place_job("four")[0].place["alloc"] == {}
-    assert _place_job("solo")[0].place["alloc"] == {
-        "bytes_in_use": [1], "largest_free_block_bytes": [2]}
+    _counting(monkeypatch, {0: {
+        "bytes_in_use": 1, "largest_free_block_bytes": 2, "peak_bytes_in_use": 3}})
+    four, solo = _place_job("four")[0].place, _place_job("solo")[0].place
+    for reading in READINGS:
+        assert four[reading] == {}
+        assert solo[reading] == {
+            "bytes_in_use": [1], "largest_free_block_bytes": [2], "peak_bytes_in_use": [3]}
 
 
-def test_alloc_now_keeps_the_two_counts_of_a_device_that_has_both(monkeypatch):
+def test_alloc_now_keeps_the_three_counts_of_a_device_that_has_them_all(monkeypatch):
     import jax
 
     from primesim_tpu.sim import engine
@@ -486,13 +498,19 @@ def test_alloc_now_keeps_the_two_counts_of_a_device_that_has_both(monkeypatch):
             return self._stats
 
     monkeypatch.setattr(jax, "local_devices", lambda: [
-        Device(0, {"bytes_in_use": 5, "largest_free_block_bytes": 7, "num_allocs": 1}),
-        Device(1, {"bytes_in_use": 5}), Device(2, None)])
-    assert engine.alloc_now() == {0: {"bytes_in_use": 5, "largest_free_block_bytes": 7}}
+        Device(0, {"bytes_in_use": 5, "largest_free_block_bytes": 7,
+                   "peak_bytes_in_use": 9, "num_allocs": 1}),
+        Device(1, {"bytes_in_use": 5, "largest_free_block_bytes": 7}), Device(2, None)])
+    assert engine.alloc_now() == {0: {
+        "bytes_in_use": 5, "largest_free_block_bytes": 7, "peak_bytes_in_use": 9}}
 
 
-def test_a_second_fused_run_carries_the_builds_place(monkeypatch):
-    _counting(monkeypatch, {0: {"bytes_in_use": 3 << 20, "largest_free_block_bytes": 1 << 33}})
+def test_a_second_fused_run_carries_the_builds_place_and_its_own_reading(monkeypatch):
+    """`alloc` and `alloc_built` are the engine's, read once; `alloc_run` is
+    the job's, and an earlier job's sample keeps its own."""
+    _counting(monkeypatch, lambda n: {0: {
+        "bytes_in_use": n << 20, "largest_free_block_bytes": 1 << 33,
+        "peak_bytes_in_use": n << 21}})
     cfg, trace = MACHINES["rung3"]()
     eng = Engine(cfg, trace, chunk_steps=8)
     with pytest.raises(RuntimeError, match="max_steps exceeded"):
@@ -500,13 +518,20 @@ def test_a_second_fused_run_carries_the_builds_place(monkeypatch):
     first = eng.last_job
     with pytest.raises(RuntimeError, match="max_steps exceeded"):
         eng.run(max_steps=8)
-    assert eng.last_job is not first and eng.last_job["place"] == first["place"] == {
-        "devices": [0],
-        "alloc": {"bytes_in_use": [3 << 20], "largest_free_block_bytes": [1 << 33]}}
+
+    def reading(n):
+        return {"bytes_in_use": [n << 20], "largest_free_block_bytes": [1 << 33],
+                "peak_bytes_in_use": [n << 21]}
+
+    assert eng.last_job is not first
+    assert first["place"] == {"devices": [0], "alloc": reading(0),
+                              "alloc_built": reading(1), "alloc_run": reading(2)}
+    assert eng.last_job["place"] == {**first["place"], "alloc_run": reading(3)}
 
 
 def test_a_recorder_writes_place_through_dump_jsonl(monkeypatch, tmp_path):
-    _counting(monkeypatch, {0: {"bytes_in_use": 9, "largest_free_block_bytes": 11}})
+    _counting(monkeypatch, {0: {
+        "bytes_in_use": 9, "largest_free_block_bytes": 11, "peak_bytes_in_use": 13}})
     cfg, trace = MACHINES["rung3"]()
     rec = Recorder("basic", metrics_path=str(tmp_path / "m.jsonl"))
     eng = Engine(cfg, trace, chunk_steps=32)
@@ -516,6 +541,7 @@ def test_a_recorder_writes_place_through_dump_jsonl(monkeypatch, tmp_path):
     rec.finalize()
     line = json.loads(open(tmp_path / "m.jsonl").read().splitlines()[0])
     assert line["place"] == eng.place and line["place"]["alloc"]["bytes_in_use"] == [9]
+    assert line["place"]["alloc_run"]["peak_bytes_in_use"] == [13]
     fleet = _fleet(cfg, [trace] * 2, None, rec=Recorder("basic"))
     assert fleet.last_job["place"] == fleet.place and fleet.last_job["label"] == "fleet"
 
