@@ -95,7 +95,8 @@ def _job_detail(eng) -> dict | None:
     host spans in ms (where the wall went: `init` the engine's build,
     outside the timed wall) and `place`, the chips the machine lies on and
     what their allocators held as it was laid, once it was (`alloc_built`)
-    and as the job's wait ended (`alloc_run`: one machine, or two).
+    and as the job's wait ended (`alloc_run`: one machine, or two), and
+    the bytes of the state a chip holds (`state_bytes`).
     None for an engine that ran no fused job (the chunked paths)."""
     sample = getattr(eng, "last_job", None)
     if sample is None:

@@ -43,8 +43,9 @@ class MetricStore:
     (``bytes_in_use``, ``largest_free_block_bytes``,
     ``peak_bytes_in_use``): ``alloc`` as the engine's build began,
     ``alloc_built`` as it ended, ``alloc_run`` as the job's wait ended,
-    while the engine still named the state it had handed the loop; plain
-    ints, kept as given.
+    while the engine still named the state it had handed the loop; and
+    ``state_bytes``, the bytes of that state a device, counted from the
+    shapes; plain ints, kept as given.
 
     ``seq`` is a global monotonically increasing chunk index (it keeps
     counting even after the ring starts dropping, so the slowest-chunk

@@ -22,6 +22,7 @@ bandwidth ... is the wall-clock make-or-break".
 from __future__ import annotations
 
 import functools
+import math
 import time
 
 import jax
@@ -323,14 +324,22 @@ def job_place(held: dict, built: dict, state) -> dict:
     closed: its `peak_bytes_in_use` less `alloc`'s `bytes_in_use` is what
     the build passed through (one machine; two while `init_state`
     concatenated `dirm` from its parts). Each `{}` where the platform
-    counts none. A buffer's own device address is not to be had:
-    `unsafe_buffer_pointer()` answers with a host address on the TPU
-    (PERF.md section 7 (n))."""
+    counts none. And `state_bytes`, the bytes of `state` a device (the sum
+    over its leaves of that device's share; counted from the shapes, so on
+    every platform): what the differences above are multiples of, so that a
+    reader needs no arithmetic on shapes. A buffer's own device address is
+    not to be had: `unsafe_buffer_pointer()` answers with a host address on
+    the TPU (PERF.md section 7 (n))."""
     devices = [s.device.id for s in state.cycles.addressable_shards]
+    # a device's share of every leaf (a whole leaf without a mesh)
+    a_device = sum(
+        math.prod(x.sharding.shard_shape(x.shape)) * x.dtype.itemsize
+        for x in jax.tree.leaves(state))
     return {
         "devices": devices,
         "alloc": _alloc_of(held, devices),
         "alloc_built": _alloc_of(built, devices),
+        "state_bytes": [a_device] * len(devices),
     }
 
 

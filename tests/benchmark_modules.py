@@ -40,6 +40,22 @@ def load_benchmark_tests(name: str = "test_benchmark"):
             sys.modules["conftest"] = mine
 
 
+class LiveBytes:
+    """An allocator that counts, which the CPU's does not, to stand behind
+    `sim.engine.alloc_now` in a test of lifetimes: the bytes of the
+    process's live arrays at every reading, and the most it has read."""
+
+    peak = 0
+
+    def __call__(self):
+        import jax
+
+        held = sum(a.nbytes for a in jax.live_arrays())
+        self.peak = max(self.peak, held)
+        return {0: {"bytes_in_use": held, "largest_free_block_bytes": 0,
+                    "peak_bytes_in_use": self.peak}}
+
+
 def assert_reference_equals_golden(reference, machine: dict, ev, gold=None):
     """A plain reference (a module with `RefSim` and `COUNTERS`) against
     the golden model on one machine and one folded trace: the step count,

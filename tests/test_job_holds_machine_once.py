@@ -22,7 +22,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from benchmark_modules import ROOT
+from benchmark_modules import ROOT, LiveBytes
 
 from primesim_tpu.config.machine import (
     CacheConfig,
@@ -274,6 +274,8 @@ GEOMETRIES = {
     "rung3": lambda: _shipped("rung3", 16, 4, 4),
     "rung3-sync": lambda: _shipped("rung3-sync", 16, 4, 4),
     "rung4-x4": lambda: _shipped("rung4-x4", 64, 8, 8, sharer_chunk_words=1),
+    # the same machine whole on one device (PR 55: `rung4.fft-m18-4k`)
+    "rung4": lambda: _shipped("rung4", 64, 8, 8, sharer_chunk_words=1),
     "rung5": lambda: _shipped("rung5", 64, 8, 8, sharer_group=4),
     "two-words-16-banks": lambda: MachineConfig(
         n_cores=64, n_banks=16,
@@ -337,3 +339,60 @@ def test_the_one_chip_build_holds_each_large_leaf_once():
     least = min(leaves["dirm"].aval.size, leaves["l1"].aval.size)
     for e in jaxpr.eqns:
         assert all(v.aval.size < least for v in e.invars if hasattr(v, "aval")), e
+
+
+# ---- (c) nothing of a job outlives it (PR 55) -------------------------------
+
+def test_the_second_job_in_a_row_finds_nothing_of_the_first(monkeypatch):
+    """`rung4.fft-m18-4k`'s machine is 9.7 GB of a chip's 15.75: a second
+    copy is `RESOURCE_EXHAUSTED`, not a figure in `hbm_peak_gb`. So what a
+    job leaves behind is held to nothing, at a small size, through the
+    benchmark's own `runners/solo.py` (`warm_up`, then `run_job` twice, as
+    `measure.run_cell` calls them) with the collector OFF: an engine, a
+    result or a sample that kept its job's state alive in a reference cycle
+    would still lie there when the next engine is built (`timed_job`
+    collects only after that). From `place`: every job's engine was built
+    over the same bytes, the process's peak never passed two machines, and
+    a job held its machine once."""
+    import gc
+
+    import cells  # benchmark/ is on the path (benchmark_modules)
+    import measure
+    import trafficgen
+    from primesim_tpu.obs import process_store
+    from primesim_tpu.sim import engine
+    from primesim_tpu.trace.format import Trace
+
+    cfg = GEOMETRIES["rung4"]()
+    with open(os.path.join(ROOT, "benchmark", "traffic", "fft-m18-4k.json")) as f:
+        traffic = json.load(f)
+    ev = trafficgen.make_trace(traffic, cfg.n_cores, 404)
+    trace = Trace(ev, measure._lengths(ev))
+    run = {"chunk_steps": CHUNK, "step_impl": "xla", "devices": 1}
+    solo = cells.load_runner("solo")
+    monkeypatch.setattr(engine, "alloc_now", LiveBytes())
+    gc.collect()
+    under = engine.alloc_now()[0]["bytes_in_use"]  # other tests' arrays, if any
+    monkeypatch.setattr(gc, "collect", lambda *a: 0)  # `timed_job`'s own
+    gc.disable()
+    try:
+        solo.warm_up(cfg, run, trace, None, True)  # a traced run's: it lowers again
+        jobs = [solo.run_job(cfg, run, trace, ev, None, None) for _ in range(2)]
+        after = engine.alloc_now()[0]["bytes_in_use"]
+    finally:
+        gc.enable()
+    assert jobs[0]["digest"] == jobs[1]["digest"] and not jobs[0]["not_at_end"]
+    first, second = (s["place"] for s in process_store().samples()[-2:])
+    state = first["state_bytes"][0]
+    assert state == _state_bytes(init_state(cfg)) and second["state_bytes"] == [state]
+    # nothing of the warm-up under the first job, nothing of the first under the second
+    assert first["alloc"]["bytes_in_use"] == second["alloc"]["bytes_in_use"] == [under] == [after]
+    events = jobs[0]["events_shape"]
+    trace_bytes = _state_bytes(Engine(cfg, trace, chunk_steps=CHUNK).events)
+    assert trace_bytes >= int(np.prod(events[0])) * events[1]
+    for place in (first, second):
+        # built: the state and the trace; run: the same and the loop's small results
+        assert place["alloc_built"]["bytes_in_use"] == [under + state + trace_bytes]
+        held = place["alloc_run"]["bytes_in_use"][0] - under
+        assert state + trace_bytes <= held < state + trace_bytes + state // 10
+        assert place["alloc_run"]["peak_bytes_in_use"][0] - under < 2 * state

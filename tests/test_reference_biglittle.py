@@ -136,3 +136,49 @@ def test_biglittle_reference_loads_as_the_harness_loads_it():
     assert biglittle.COUNTERS == reference.COUNTERS
     assert biglittle.UnsupportedMachine is reference.UnsupportedMachine
     cells._refuse_foreign_imports(biglittle.__file__)  # raises on an import of the program or JAX
+
+
+# ---- the one-chip program, the golden model and the reference, one machine --
+
+@pytest.mark.parametrize("cores,chunk", [
+    (64, 1),  # two words a way, in blocks of one
+    (64, 2),  # one block
+    (256, 8),  # rung 4's `sharer_chunk_words`: eight words a way, one block
+    (512, 8),  # sixteen words a way, two blocks of eight
+])
+def test_the_one_chip_program_equals_golden_equals_the_reference(cores, chunk):
+    """`rung4.fft-m18-4k`'s machine kind at a small size, through the path
+    the cell runs (`Engine` on one device, the fused loop, no mesh): rung 4's
+    CPI pattern of eight, its caches and latencies, the full map walked in
+    blocks, an `fft_like` trace of the cell's parity size. Per-core cycles,
+    every counter and the step count, three ways."""
+    import json
+    import os
+
+    import measure
+    from primesim_tpu.config.machine import MachineConfig
+    from primesim_tpu.golden.sim import GoldenSim
+    from primesim_tpu.sim.engine import Engine
+    from primesim_tpu.trace.format import Trace
+
+    with open(os.path.join(ROOT, "benchmark", "configs", "rung4.json")) as f:
+        machine = json.load(f)["machine"]
+    assert machine["core"]["cpi_pattern"] == [1, 1, 1, 1, 3, 3, 3, 3]
+    machine = {**machine, "n_cores": cores, "n_banks": 64, "sharer_chunk_words": chunk,
+               "noc": {**machine["noc"], "mesh_x": 8, "mesh_y": 8}}
+    ev = cells.load_generator("fft_like")(cores, 55, n_phases=2, points_per_core=4, ins_per_mem=8)
+    cfg = MachineConfig.from_dict(machine)
+    assert cfg.n_sharer_words == cores // 32 and cfg.sharer_group == 1
+    trace = Trace(ev, measure._lengths(ev))
+    gold = GoldenSim(cfg, trace)
+    gold.run()
+    ref = assert_reference_equals_golden(biglittle, machine, ev, gold)
+    eng = Engine(cfg, trace, chunk_steps=8)
+    eng.run()
+    assert eng.mesh is None and eng.steps_run == -(-ref.step_count // 8) * 8
+    assert np.array_equal(eng.cycles, np.asarray(ref.cycles))
+    assert len(set(eng.cycles[:8].tolist())) > 1  # big and LITTLE cores part
+    counters = eng.counters
+    for k in biglittle.COUNTERS:
+        assert np.array_equal(counters[k], np.asarray(ref.counters[k])), k
+    assert sum(ref.counters["invalidations"]) and sum(ref.counters["probes"])

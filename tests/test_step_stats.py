@@ -10,6 +10,7 @@ benchmark's readers of that sample.
 import hashlib
 import json
 
+import jax
 import numpy as np
 import pytest
 
@@ -469,8 +470,15 @@ def test_a_fused_job_commits_where_its_bytes_lay(monkeypatch, kind, counts):
     sample = process_store().samples()[-1]
     assert sample is eng.last_job and sample["place"] is eng.place
     chips = [0] if mesh is None else [d.id for d in mesh.devices.flat]
-    assert sample["place"] == {"devices": chips, **{reading: {
-        k: [10000 * n + 1000 * j + i for i in chips] for j, k in enumerate(ALLOC_KEYS)}
+    # `state_bytes` (PR 55): a chip's share of every leaf as the engine was
+    # built, counted from the shapes, so on the CPU too: the whole state
+    # without a mesh, on a mesh the cut leaves' quarter and the others whole
+    total = sum(x.nbytes for x in jax.tree.leaves(eng.state))
+    a_chip = sample["place"]["state_bytes"][0]
+    assert total / len(chips) <= a_chip <= total
+    assert (a_chip == total) == (mesh is None)
+    assert sample["place"] == {"devices": chips, "state_bytes": [a_chip] * len(chips), **{
+        reading: {k: [10000 * n + 1000 * j + i for i in chips] for j, k in enumerate(ALLOC_KEYS)}
         if counts else {} for n, reading in enumerate(READINGS)}}
     json.dumps(sample)
 
@@ -525,7 +533,8 @@ def test_a_second_fused_run_carries_the_builds_place_and_its_own_reading(monkeyp
 
     assert eng.last_job is not first
     assert first["place"] == {"devices": [0], "alloc": reading(0),
-                              "alloc_built": reading(1), "alloc_run": reading(2)}
+                              "alloc_built": reading(1), "alloc_run": reading(2),
+                              "state_bytes": first["place"]["state_bytes"]}
     assert eng.last_job["place"] == {**first["place"], "alloc_run": reading(3)}
 
 
