@@ -319,15 +319,17 @@ class mesh_jit:
 
 
 def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
-              whole=(), core_axis=0):
-    """`reduce_rows(table[slot], *per_slot, *whole, core_axis=core_axis)`:
-    a tuple of arrays of `slot`'s shape, the few words a reader wants of
-    the rows `slot` of `table` [R, W]. `slot` is `[C, K]` (`core_axis` 0,
-    K rows a core) or `[K, C]` (`core_axis` 1); `per_slot` are int32
-    values of its shape the reduction needs beside the rows, `whole`
-    values every chip has in full (an iota, a replicated table).
-    `reduce_rows` always sees every core's rows, in core order, along the
-    axis it is told.
+              whole=()):
+    """`reduce_rows(table[slot], *per_slot, *whole)`: a tuple of arrays of
+    `slot`'s shape, the few words a reader wants of the rows `slot` of
+    `table` [R, W]. `slot` is `[K, C]`, K rows a core and the cores along
+    the last axis, on one device and on a mesh: the gather yields
+    `[K*C, W]`, which splits into `[K, C, W]` as it lies, where
+    `[C, K, W]` is a copy that pads K to the tile's 8 rows (rung 4 on one
+    chip: 0.73 ms of a 4.8 ms step; PERF.md section 6, PR 56). `per_slot`
+    are int32 values of `slot`'s shape the reduction needs beside the
+    rows, `whole` values every chip has in full (an iota, a replicated
+    table). `reduce_rows` always sees every core's rows, in core order.
 
     Without a mesh it is exactly that expression. On a mesh `table` is
     sharded by row and `slot` names any row, and left to the partitioner
@@ -340,13 +342,9 @@ def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
     the RECORD (not the row: a zero row could match a line 0) where the
     slot is another chip's, and the records are summed. One chip holds
     each slot, so the sum is that chip's record to the bit, and
-    C*K*len(record) words cross chips. The chip works on `[K, C, ...]`
-    whatever the caller's order: its gather yields `[K*C, W]`, which
-    splits into `[K, C, W]` as it lies, where `[C, K, W]` is a copy that
-    pads K to the tile's 8 rows. A caller that hands `[K, C]` slots
-    (`core_axis` 1) has the same on one device."""
+    C*K*len(record) words cross chips."""
     if mesh is None:
-        return reduce_rows(table[slot], *per_slot, *whole, core_axis=core_axis)
+        return reduce_rows(table[slot], *per_slot, *whole)
     rows = table.shape[0] // mesh.shape[AXIS]
 
     def on_chip(tab, packed, *whole):
@@ -355,7 +353,7 @@ def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
         local = slot - jax.lax.axis_index(AXIS) * rows
         mine = (local >= 0) & (local < rows)
         record = reduce_rows(
-            tab[jnp.clip(local, 0, rows - 1)], *per_slot, *whole, core_axis=1
+            tab[jnp.clip(local, 0, rows - 1)], *per_slot, *whole
         )
         dtypes[:] = [r.dtype for r in record]
         record = jnp.stack(
@@ -364,15 +362,10 @@ def read_rows(mesh: Mesh | None, table, slot, reduce_rows, per_slot=(),
         return jax.lax.psum(record, AXIS)
 
     dtypes: list = []  # of the record's fields, as `reduce_rows` gives them
-    packed = jnp.stack((slot, *per_slot), axis=-1)
-    if core_axis == 0:
-        packed = jnp.swapaxes(packed, 0, 1)
     record = jax.shard_map(
         on_chip, mesh=mesh, out_specs=P(),
         in_specs=(P(AXIS), P(None, AXIS), *(P() for _ in whole)),
-    )(table, packed, *whole)
-    if core_axis == 0:
-        record = jnp.swapaxes(record, 0, 1)
+    )(table, jnp.stack((slot, *per_slot), axis=-1), *whole)
     return tuple(record[..., i].astype(dt) for i, dt in enumerate(dtypes))
 
 

@@ -323,21 +323,19 @@ def _l1_probe(cfg: MachineConfig, arange_c, l1, dirm, line,
     return w1cols, tag_rows, lru_rows, weff
 
 
-def _way_record(cfg: MachineConfig, rows, pway, core, core_axis=1):
+def _way_record(cfg: MachineConfig, rows, pway, core):
     """What the probe reads of the directory rows `rows` [W1, C, DW] its
     way pointers name, at the way `pway` [W1, C] within each row, for the
     cores `core` [C]: the entry's tag, its owner, the core's own sharer
     bit; under the coarse vector the entry's invalidation epoch too. A
-    tuple of [W1, C] arrays ([C, W1, DW], [C, W1] in and [C, W1] out where
-    `core_axis` is 0). The sibling of `_run_record`: a function of one
-    row and of values every chip has, so `sharding.read_rows` runs it on
-    the chip that holds the row. Every word is a select out of the row in
-    hand (`_pick`)."""
+    tuple of [W1, C] arrays. The sibling of `_run_record`: a function of
+    one row and of values every chip has, so `sharding.read_rows` runs it
+    on the chip that holds the row. Every word is a select out of the row
+    in hand (`_pick`)."""
     W2, NW = cfg.llc.ways, cfg.n_sharer_words
     MW = llc_meta_width(cfg)
     pairs = rows[..., : 2 * W2]  # (tag, owner) a way
-    g_c = core >> (cfg.sharer_group.bit_length() - 1)
-    g_c = g_c[None, :] if core_axis == 1 else g_c[:, None]
+    g_c = (core >> (cfg.sharer_group.bit_length() - 1))[None, :]
     vsh = _pick(rows[..., MW:], pway * NW + (g_c >> 5))
     record = [
         _pick(pairs, 2 * pway),
@@ -389,7 +387,7 @@ def _validate_ways(cfg, arange_c, tag_rows, state_rows, ptr_rows, eph_rows,
     vtag, vown, vbit, *veph = (
         v.T for v in read_rows(
             mesh, dirm, ptr_w // W2, functools.partial(_way_record, cfg),
-            per_slot=(ptr_w % W2,), whole=(arange_c,), core_axis=1,
+            per_slot=(ptr_w % W2,), whole=(arange_c,),
         )
     )  # [C, W1] each
     if cfg.sharer_group > 1:
@@ -565,16 +563,15 @@ def _fault(cfg: MachineConfig, events, st: MachineState, arange_c, acc):
     return st, deadb
 
 
-def _run_record(cfg: MachineConfig, rows, line, core, core_axis=0):
+def _run_record(cfg: MachineConfig, rows, line, core):
     """What a local run reads of its candidates' home rows `rows`
-    [C, K, DW] (`dirm` rows: metadata AND sharers) for the lines `line`
-    [C, K] of the cores `core` [C]: whether a way holds the line, that
+    [K, C, DW] (`dirm` rows: metadata AND sharers) for the lines `line`
+    [K, C] of the cores `core` [C]: whether a way holds the line, that
     way's owner, the core's own sharer bit; under the coarse vector the
     way's invalidation epoch too, under moesi the number of sharers
-    recorded. A tuple of [C, K] arrays; [K, C, DW], [K, C] in and [K, C]
-    out where `core_axis` is 1. A function of one row and of values every
-    chip has, so `sharding.read_rows` runs it on the chip that holds the
-    row."""
+    recorded. A tuple of [K, C] arrays. A function of one row and of
+    values every chip has, so `sharding.read_rows` runs it on the chip
+    that holds the row."""
     W2, NW = cfg.llc.ways, cfg.n_sharer_words
     MW = llc_meta_width(cfg)
     pmeta = rows[:, :, : 2 * W2].reshape(*line.shape, W2, 2)
@@ -584,11 +581,10 @@ def _run_record(cfg: MachineConfig, rows, line, core, core_axis=0):
     # way and word picks out of rows already in hand: selects, not
     # gathers (`_pick`); `argmax` keeps first-match order
     pown = _pick(pmeta[..., 1], pmway)
-    g_c0 = core >> (cfg.sharer_group.bit_length() - 1)
-    along = (slice(None), None) if core_axis == 0 else (None, slice(None))
+    g_c = (core >> (cfg.sharer_group.bit_length() - 1))[None, :]
     # the self sharer word rides the row gather: in-register select
-    pshw = _pick(rows[:, :, MW:], pmway * NW + (g_c0[along] >> 5))
-    pbit = ((pshw >> (g_c0[along] & 31)) & 1) != 0
+    pshw = _pick(rows[:, :, MW:], pmway * NW + (g_c >> 5))
+    pbit = ((pshw >> (g_c & 31)) & 1) != 0
     record = [pmhas, pown, pbit]
     if cfg.sharer_group > 1:
         record.append(_pick(rows[:, :, 3 * W2 : 4 * W2], pmway))
@@ -596,7 +592,7 @@ def _run_record(cfg: MachineConfig, rows, line, core, core_axis=0):
         psh_all = rows[:, :, MW:].reshape(*line.shape, W2, NW)
         pwords = _pick(
             jnp.swapaxes(psh_all, 2, 3), pmway[:, :, None]
-        )  # [C, K, NW]: the matching way's sharer words
+        )  # [K, C, NW]: the matching way's sharer words
         record.append(jnp.sum(jax.lax.population_count(pwords), axis=2))
     return tuple(record)
 
@@ -685,11 +681,14 @@ def _local(cfg: MachineConfig, events, st: MachineState, arange_c, deadb, acc,
             pslot = pbank * S2 + pbset
             # the home rows are read where they live (`_run_record`): of a
             # row the run wants the words below, and on a mesh only those
-            # cross chips
-            pmhas, pown, pbit, *prest = read_rows(
-                mesh, st.dirm, pslot, functools.partial(_run_record, cfg),
-                per_slot=(pline,), whole=(arange_c,),
-            )
+            # cross chips. Candidates first, as `_validate_ways` reads its
+            # ways: the gather's `[K*C, DW]` result is `[K, C, DW]` as it lies
+            pmhas, pown, pbit, *prest = (
+                v.T for v in read_rows(
+                    mesh, st.dirm, pslot.T, functools.partial(_run_record, cfg),
+                    per_slot=(pline.T,), whole=(arange_c,),
+                )
+            )  # [C, rl+1] each
             pmatch_l = (ptagr == pline[:, :, None]) & (pstater != I)
             plhit = jnp.any(pmatch_l, axis=2)
             plway = jnp.argmax(pmatch_l, axis=2).astype(jnp.int32)

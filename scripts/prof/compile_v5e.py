@@ -21,7 +21,8 @@ printed). Writes the compiled module's text (for
 `hlo_same.py compare`), and prints the compiler's bytes a chip
 (arguments, outputs, what of the outputs is laid in an argument's
 buffer (`alias`: the donated state, PR 54: a job then holds its machine
-once), temporaries), every collective with its shape
+once), temporaries, and the generated code's bytes, which lie in HBM
+before the buffers: ROADMAP S13), every collective with its shape
 and the tail of its `op_name`, which holds the phase scope, and every
 `sort` with its operands' shape and layout, the dimension it sorts, its
 scoped memory and what made each operand (PR 48), and every
@@ -210,7 +211,8 @@ def main(conf_path: str, out_path: str, devices: int | None = None,
     print(f"a chip: arguments {mem.argument_size_in_bytes / 1e9:.3f} GB, outputs "
           f"{mem.output_size_in_bytes / 1e9:.3f} GB, of them aliased to an argument "
           f"{mem.alias_size_in_bytes / 1e9:.3f} GB, temporaries "
-          f"{mem.temp_size_in_bytes / 1e9:.3f} GB")
+          f"{mem.temp_size_in_bytes / 1e9:.3f} GB, generated code "
+          f"{mem.generated_code_size_in_bytes} bytes")
     for line in text.splitlines():
         found = _COLLECTIVE.match(line)
         if found:

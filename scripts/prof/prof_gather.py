@@ -3,6 +3,7 @@ array-layout choices and behind `sim/step.py::_l1_set_read` having one form.
 
     python scripts/prof/prof_gather.py          # the L1 set read's two forms
     python scripts/prof/prof_gather.py rows     # the probe's way read: rows / elements
+    python scripts/prof/prof_gather.py local    # the local run's row read: candidates / cores first
     python scripts/prof/prof_gather.py writes   # phase 4.A's L1 write: select / scatter
     python scripts/prof/prof_gather.py events   # the local run's candidates: blocks / elements
     python scripts/prof/prof_gather.py raw      # row / element gather, row scatter
@@ -18,13 +19,27 @@ dispatch; us an iteration, and ns a gathered word for the gather.
 `rows`: the probe's read of the directory entries its W1 way pointers name
 (`sim/step.py::_validate_ways`), in the form the step has (ONE gather of whole
 `dirm` rows at `[W1, C]` slots through `sharding.read_rows`, the words selected
-out of each row: `_way_record`), ways first and cores first, against the three
-(coarse vector: four) ELEMENT gathers at the same rows that it replaced in
-PR 36, kept here as `ways_elements`; at rows of 768 / 1536 / 4608 / 8704 /
+out of each row: `_way_record`), ways first and cores first (the order
+`read_rows` lost with `core_axis` in PR 56, kept here as `way_record_cores1st`),
+against the three (coarse vector: four) ELEMENT gathers at the same rows that it
+replaced in PR 36, kept here as `ways_elements`; at rows of 768 / 1536 / 4608 / 8704 /
 16896 bytes x C in {1024, 4096, 16384}, a table of 524288 rows (262144 of the
 widest), inside one `fori_loop` on pointers that change every iteration. The
 evidence that `_validate_ways` needs no second form, and where the two would
 cross (PERF.md section 6, PR 36).
+
+`local`: the local run's read of its K = `local_run_len` + 1 = 9 candidate
+home rows a core alone (`sim/step.py::_local`: ONE gather of `C * 9` whole
+`dirm` rows through `sharding.read_rows`, then `_run_record`'s selects and
+sums), candidates first (`[K, C]` slots: the gather's `[K*C, DW]` result is
+`[K, C, DW]` as it lies; the form the step has since PR 56) against cores first
+(`[C, K]` slots: `[C, K, DW]` is a copy that pads K = 9 to the tile's 16 rows;
+the step's form on one chip until PR 56, kept here as `run_record_cores1st`),
+at the three machines whose cells show it: 1024 cores with a full map (rows of
+384 words), rung 4's 4096 (1152) and rung 5's 16384 under the coarse vector
+(192, the epoch too), each on a table of its machine's rows but rung 4's, which
+is cut to a quarter; inside one `fori_loop` on lines that change every
+iteration; us a read and ns a row (PERF.md section 6, PR 56).
 
 `writes`: phase 4.A's write of the fused L1 array (`sim/step.py::
 _commit_writes`), 7 + 2 * 8 = 23 words a core, in the form the step has (each
@@ -141,6 +156,23 @@ def ways_elements(cfg, dirm, ptr_rows, core):
     return tuple(record)
 
 
+def way_record_cores1st(cfg, rows, pway, core):
+    """`_way_record` on `[C, W1, DW]` rows and `[C, W1]` ways: the order
+    `read_rows` took with `core_axis=0` until PR 56."""
+    from primesim_tpu.sim.state import llc_meta_width
+    from primesim_tpu.sim.step import _pick
+
+    W2, NW, MW = cfg.llc.ways, cfg.n_sharer_words, llc_meta_width(cfg)
+    pairs = rows[..., : 2 * W2]
+    g_c = (core >> (cfg.sharer_group.bit_length() - 1))[:, None]
+    vsh = _pick(rows[..., MW:], pway * NW + (g_c >> 5))
+    record = [_pick(pairs, 2 * pway), _pick(pairs, 2 * pway + 1),
+              ((vsh >> (g_c & 31)) & 1) != 0]
+    if cfg.sharer_group > 1:
+        record.append(_pick(rows[..., 3 * W2 : 4 * W2], pway))
+    return tuple(record)
+
+
 def way_read_forms(widths=(768, 1536, 4608, 8704, 16896),
                    cores=(1024, 4096, 16384), table_bytes=4.6e9):
     from types import SimpleNamespace
@@ -150,17 +182,21 @@ def way_read_forms(widths=(768, 1536, 4608, 8704, 16896),
 
     W2 = 8
 
-    def rows_form(core_axis, cfg, dirm, ptr_rows, core):
-        ptr = ptr_rows.T if core_axis == 1 else ptr_rows
+    def ways1st(cfg, dirm, ptr_rows, core):
+        ptr = ptr_rows.T
         return read_rows(
             None, dirm, ptr // W2, functools.partial(_way_record, cfg),
-            per_slot=(ptr % W2,), whole=(core,), core_axis=core_axis)
+            per_slot=(ptr % W2,), whole=(core,))
+
+    def cores1st(cfg, dirm, ptr_rows, core):
+        return way_record_cores1st(
+            cfg, dirm[ptr_rows // W2], ptr_rows % W2, core)
 
     # a full map's record (3 words), then the coarse vector's (the epoch too)
-    forms = (("ways1st", functools.partial(rows_form, 1), 1),
-             ("cores1st", functools.partial(rows_form, 0), 1),
+    forms = (("ways1st", ways1st, 1),
+             ("cores1st", cores1st, 1),
              ("elements", ways_elements, 1),
-             ("ways1st_c", functools.partial(rows_form, 1), 64),
+             ("ways1st_c", ways1st, 64),
              ("elements_c", ways_elements, 64))
     rng = np.random.default_rng(0)
     print(f"device {jax.devices()[0].device_kind}; us an iteration, {ITER} in "
@@ -202,6 +238,88 @@ def way_read_forms(widths=(768, 1536, 4608, 8704, 16896),
                 f"{us[name]:10.1f}" for name, _, _ in forms)
                   + f"  {us['ways1st'] * 1e3 / n:6.1f}"
                   f"  {us['elements'] * 1e3 / (3 * n):7.1f}", flush=True)
+        del dirm
+
+
+def run_record_cores1st(cfg, rows, line, core):
+    """`_run_record` on `[C, K, DW]` rows and `[C, K]` lines: what `_local`
+    read on one chip until PR 56 (`read_rows` at `core_axis=0`)."""
+    from primesim_tpu.sim.state import llc_meta_width
+    from primesim_tpu.sim.step import _pick
+
+    W2, NW, MW = cfg.llc.ways, cfg.n_sharer_words, llc_meta_width(cfg)
+    pmeta = rows[:, :, : 2 * W2].reshape(*line.shape, W2, 2)
+    pmmatch = pmeta[..., 0] == line[:, :, None]
+    pmway = jnp.argmax(pmmatch, axis=2).astype(jnp.int32)
+    g_c = (core >> (cfg.sharer_group.bit_length() - 1))[:, None]
+    pshw = _pick(rows[:, :, MW:], pmway * NW + (g_c >> 5))
+    record = [jnp.any(pmmatch, axis=2), _pick(pmeta[..., 1], pmway),
+              ((pshw >> (g_c & 31)) & 1) != 0]
+    if cfg.sharer_group > 1:
+        record.append(_pick(rows[:, :, 3 * W2 : 4 * W2], pmway))
+    return tuple(record)
+
+
+def local_read_forms(machines=(("1024 full", 1024, 1024, 1, 1),
+                               ("rung4", 4096, 4096, 1, 4),
+                               ("rung5", 16384, 4096, 64, 1))):
+    """(name, cores, banks, sharer_group, the share of the machine's rows
+    the table holds)."""
+    from primesim_tpu.config.machine import CacheConfig, MachineConfig
+    from primesim_tpu.parallel.sharding import read_rows
+    from primesim_tpu.sim.state import dirm_width
+    from primesim_tpu.sim.step import _run_record
+
+    K = 9
+
+    def cands1st(cfg, dirm, slot, line, core):
+        return read_rows(
+            None, dirm, slot.T, functools.partial(_run_record, cfg),
+            per_slot=(line.T,), whole=(core,))
+
+    def cores1st(cfg, dirm, slot, line, core):
+        return run_record_cores1st(cfg, dirm[slot], line, core)
+
+    forms = (("cands1st", cands1st), ("cores1st", cores1st))
+    rng = np.random.default_rng(0)
+    print(f"device {jax.devices()[0].device_kind}; us an iteration, {ITER} in "
+          f"a loop; K {K}")
+    print("   machine      C     DW     rows  "
+          + "  ".join(f"{n:>10s}" for n, _ in forms)
+          + "  ns_row_cands  ns_row_cores  cores/cands")
+    for name, C, B, group, cut in machines:
+        # only the directory's geometry is read: LLC slices of 512 sets x 8
+        cfg = MachineConfig(n_cores=C, n_banks=B, sharer_group=group,
+                            llc=CacheConfig(512 * 8 * 64, 8, 64, 10))
+        DW, R = dirm_width(cfg), B * cfg.llc.sets // cut
+        dirm = jax.jit(lambda R=R, DW=DW: (
+            jax.lax.broadcasted_iota(jnp.int32, (R, DW), 0) * 40503
+            + jax.lax.broadcasted_iota(jnp.int32, (R, DW), 1)))()
+        line0 = jnp.asarray(rng.integers(0, 2**30, (C, K), dtype=np.int32))
+        core = jnp.arange(C, dtype=jnp.int32)
+        us = {}
+        for form_name, form in forms:
+            def loop(dirm, line0, core, form=form):
+                def body(i, acc):
+                    line = line0 + i * 7919
+                    # a line a way of its row holds, for every other core
+                    slot = line % R
+                    held = slot * 40503 + 2 * (line % 8)
+                    line = jnp.where(core[:, None] % 2 == 0, held, line)
+                    rec = [r.astype(jnp.int32)
+                           for r in form(cfg, dirm, slot, line, core)]
+                    if rec[0].shape[0] != C:
+                        rec = [r.T for r in rec]
+                    return acc ^ functools.reduce(jnp.bitwise_xor, rec)
+                return jax.lax.fori_loop(
+                    0, ITER, body, jnp.zeros((C, K), jnp.int32))
+            us[form_name] = timeit(loop, dirm, line0, core, n=3) / ITER * 1e6
+        n = C * K
+        print(f"{name:>10s} {C:6d} {DW:6d} {R:8d}  " + "  ".join(
+            f"{us[n_]:10.1f}" for n_, _ in forms)
+              + f"  {us['cands1st'] * 1e3 / n:12.1f}"
+              f"  {us['cores1st'] * 1e3 / n:12.1f}"
+              f"  {us['cores1st'] / us['cands1st']:11.2f}", flush=True)
         del dirm
 
 
@@ -432,6 +550,7 @@ def raw():
 
 
 if __name__ == "__main__":
-    {("rows",): way_read_forms, ("writes",): write_forms,
+    {("rows",): way_read_forms, ("local",): local_read_forms,
+     ("writes",): write_forms,
      ("events",): event_read_forms, ("raw",): raw}.get(
         tuple(sys.argv[1:]), set_read_forms)()

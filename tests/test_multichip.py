@@ -149,17 +149,25 @@ def _run_record_oracle(cfg, dirm, pslot, pline):
     out = [match.any(2), pick(meta[..., 1], way), ((word >> (g & 31)) & 1) != 0]
     if cfg.sharer_group > 1:
         out.append(pick(rows[:, :, 3 * W2 : 4 * W2], way))
+    if cfg.coherence == "moesi":
+        words = np.stack(
+            [pick(rows[:, :, MW:], way * NW + w) for w in range(NW)], axis=2)
+        out.append(np.bitwise_count(words.view(np.uint32)).sum(2).astype(np.int32))
     return out
 
 
-@pytest.mark.parametrize("machine", ["plain", "chunked", "coarse"])
-def test_run_record_on_the_rows_own_chip(machine):
+@pytest.mark.parametrize("devices", [1, 4, 8])
+@pytest.mark.parametrize("machine", ["plain", "chunked", "coarse", "moesi"])
+def test_run_record_on_the_rows_own_chip(machine, devices):
     """`read_rows` + `_run_record` alone, with and without a mesh, against
-    the numpy oracle on a random directory. Core 0's candidates ask for
-    line 0 in a slot of the LAST shard whose row holds other tags, while
-    every other shard's row at the clamped index is all zeros: a chip that
-    let such a row answer (masking the row and not the record) would find
-    a tag 0 there and report a hit."""
+    the numpy oracle on a random directory: `read_rows` has one order,
+    `[K, C]` slots, and the record of `[K, C, DW]` rows is the oracle's
+    `[C, K]` record transposed, field by field (under moesi the sharer
+    count too). Core 0's candidates ask for line 0 in a slot of the LAST
+    shard whose row holds other tags, while every other shard's row at
+    the clamped index is all zeros: a chip that let such a row answer
+    (masking the row and not the record) would find a tag 0 there and
+    report a hit."""
     import functools
 
     import jax.numpy as jnp
@@ -182,31 +190,29 @@ def test_run_record_on_the_rows_own_chip(machine):
         np.take_along_axis(held, way[..., None], 2)[..., 0],
         rng.integers(0, 2**31 - 1, (C, K), dtype=np.int32),
     ).astype(np.int32)
-    per = R // 8
+    per = R // 8  # a shard of eight; every second one ends a shard of four
     dirm[per - 1 :: per] = 0  # the row every chip reads past its own shard
     dirm[0::per] = 0  # ... and before it
     dirm[R - 2, 0 : 2 * W2 : 2] = np.arange(1, W2 + 1)  # tags, none of them 0
     pslot[0], pline[0] = R - 2, 0
     want = _run_record_oracle(cfg, dirm, pslot, pline)
+    assert len(want) == 3 + (machine in ("coarse", "moesi"))
     assert not want[0][0].any()  # line 0 is in no way of its home row
 
+    mesh = tile_mesh(devices) if devices > 1 else None
+    table = jnp.asarray(dirm)
+    if mesh is not None:
+        table = jax.device_put(table, state_shardings(mesh).dirm)
     reduce_rows = functools.partial(_run_record, cfg)
     core = jnp.arange(C, dtype=jnp.int32)
-    for mesh in (None, tile_mesh(8)):
-        table = jnp.asarray(dirm)
-        if mesh is not None:
-            table = jax.device_put(table, state_shardings(mesh).dirm)
-        got = jax.jit(
-            lambda t, sl, ln, mesh=mesh: read_rows(
-                mesh, t, sl, reduce_rows, per_slot=(ln,), whole=(core,)
-            )
-        )(table, jnp.asarray(pslot), jnp.asarray(pline))
-        assert len(got) == len(want)
-        for i, (g, w) in enumerate(zip(got, want)):
-            assert g.dtype == (jnp.bool_ if w.dtype == bool else jnp.int32)
-            np.testing.assert_array_equal(
-                np.asarray(g), w, err_msg=f"field {i}, mesh {mesh is not None}"
-            )
+    got = jax.jit(lambda t, sl, ln: read_rows(
+        mesh, t, sl, reduce_rows, per_slot=(ln,), whole=(core,)
+    ))(table, jnp.asarray(pslot.T), jnp.asarray(pline.T))
+    assert len(got) == len(want)
+    for i, (g, w) in enumerate(zip(got, want)):
+        assert g.shape == (K, C)
+        assert g.dtype == (jnp.bool_ if w.dtype == bool else jnp.int32)
+        np.testing.assert_array_equal(np.asarray(g).T, w, err_msg=f"field {i}")
 
 
 def _validate_ways_oracle(cfg, dirm, core, tag_rows, state_rows, ptr_rows,
@@ -339,7 +345,7 @@ def test_validate_ways_reads_rows_like_elements(machine, case, devices):
     def both(table, tag_rows, state_rows, ptr_rows, eph_rows=None):
         rec = read_rows(
             mesh, table, ptr_rows.T // W2, functools.partial(_way_record, cfg),
-            per_slot=(ptr_rows.T % W2,), whole=(jnp.asarray(core),), core_axis=1)
+            per_slot=(ptr_rows.T % W2,), whole=(jnp.asarray(core),))
         return rec, _validate_ways(
             cfg, jnp.asarray(core), tag_rows, state_rows, ptr_rows, eph_rows,
             table, mesh)
@@ -348,13 +354,9 @@ def test_validate_ways_reads_rows_like_elements(machine, case, devices):
     np.testing.assert_array_equal(np.asarray(got), weff)
     assert len(got_rec) == len(record) == (4 if G > 1 else 3)
     for i, (g_, w_) in enumerate(zip(got_rec, record)):
+        assert g_.shape == (W1, C)  # `read_rows`' one order, on one device too
         assert g_.dtype == (jnp.bool_ if w_.dtype == bool else jnp.int32)
         np.testing.assert_array_equal(np.asarray(g_).T, w_, err_msg=f"field {i}")
-    if devices == 1:  # cores first is the same record, transposed
-        rec0 = _way_record(cfg, table[ptr_rows // W2], jnp.asarray(ptr_rows % W2),
-                           jnp.asarray(core), core_axis=0)
-        for g_, w_ in zip(rec0, record):
-            np.testing.assert_array_equal(np.asarray(g_), w_)
 
 
 def _join_cases():

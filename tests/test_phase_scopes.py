@@ -292,6 +292,57 @@ def test_step_picks_out_of_the_l1_row_without_a_gather(machine):
         cfg.n_cores, cfg.l1.ways * cfg.n_cores]
 
 
+# the local run's row read on the three kinds of directory row: the full
+# sharer map (two words at 64 cores), rung 5's coarse vector (one bit to 64
+# cores, an epoch a way) and moesi (the run also counts a line's sharers)
+LOCAL_RUN_MACHINES = {
+    "full_map": dict(n_cores=64, n_banks=16, local_run_len=8,
+                     noc={"mesh_x": 8, "mesh_y": 8}),
+    "coarse_64": dict(n_cores=128, n_banks=32, local_run_len=8,
+                      sharer_group=64, noc={"mesh_x": 16, "mesh_y": 8}),
+    "moesi": dict(n_cores=64, n_banks=16, local_run_len=8, coherence="moesi",
+                  noc={"mesh_x": 8, "mesh_y": 8}),
+}
+
+
+@pytest.mark.parametrize("machine", sorted(LOCAL_RUN_MACHINES))
+def test_local_run_holds_its_home_rows_candidates_first(machine):
+    """`_local` reads its K = `local_run_len` + 1 candidate home rows a core
+    as `[K, C]` slots (`sharding.read_rows`' one order), on one device too:
+    the `dirm` gather of its jaxpr yields `(K, C, DW)`, which is the
+    gathered `[K*C, DW]` bytes as they lie, and nothing in the phase has
+    the shape `(C, K, DW)`, the copy that padded K = 9 to the tile's 16
+    rows on the chip (a seventh of rung 4's step on one chip; PERF.md
+    section 6, PR 56)."""
+    from primesim_tpu.sim.step import _local
+
+    cfg = MachineConfig.from_dict(LOCAL_RUN_MACHINES[machine])
+    C, K, DW = cfg.n_cores, cfg.local_run_len + 1, dirm_width(cfg)
+    assert K not in (C, DW) and C != DW
+    eng = Engine(
+        cfg, synth.fft_like(C, n_phases=1, points_per_core=4, seed=3),
+        chunk_steps=8)
+    traced = jax.make_jaxpr(lambda ev, st: _local(
+        cfg, ev, st, jnp.arange(C, dtype=jnp.int32), None, {}))(
+            eng.events, eng.state)
+    shapes, gathered = set(), []
+
+    def walk(jaxpr):
+        for eqn in jaxpr.eqns:
+            outs = [v.aval.shape for v in eqn.outvars]
+            shapes.update(outs)
+            if (eqn.primitive.name == "gather"
+                    and eqn.invars[0].aval.shape == eng.state.dirm.shape):
+                gathered.extend(outs)
+            for sub in jax.core.jaxprs_in_params(eqn.params):
+                walk(sub)
+
+    walk(traced.jaxpr)
+    assert gathered == [(K, C, DW)]
+    assert (C, K, DW) not in shapes
+    assert (K, C) in shapes and (C, K) in shapes  # the record, turned for the lanes
+
+
 @pytest.mark.parametrize("machine", ["plain", "coarse", "rung3"])
 def test_step_edits_the_l1_row_without_a_scatter(machine):
     """The write-side twin: each core edits its own L1 row by a select over
