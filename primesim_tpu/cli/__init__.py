@@ -25,6 +25,7 @@ import time
 
 
 def _parse_synth(spec: str, n_cores: int, fold: bool):
+    from ..obs.span import span
     from ..trace import synth
     from ..trace.format import fold_ins
 
@@ -46,7 +47,9 @@ def _parse_synth(spec: str, n_cores: int, fold: bool):
                     f"bad synth arg {pair!r}: value must be an integer"
                 ) from None
     try:
-        tr = synth.GENERATORS[name](n_cores, **kw)
+        # a shape's seconds on the profiler's clock, under the shape's name
+        with span(f"synth.{name}"):
+            tr = synth.GENERATORS[name](n_cores, **kw)
     except TypeError as e:
         raise SystemExit(f"synth {name!r}: {e}") from None
     return fold_ins(tr) if fold else tr
@@ -1184,6 +1187,12 @@ def cmd_coordinator(ns) -> int:
 def cmd_synth(ns) -> int:
     tr = _parse_synth(ns.spec, ns.cores, ns.fold)
     tr.save(ns.out)
+    name, _, args = ns.spec.partition(":")
+    if name == "moe_decode_like":  # what the shape holds, as one JSON line
+        from ..trace.synth import moe_decode_describe
+
+        kw = {k: int(v) for k, v in (pair.split("=") for pair in args.split(",") if pair)}
+        print(json.dumps(moe_decode_describe(ns.cores, **kw)))
     print(
         f"wrote {ns.out}: {tr.n_cores} cores x {tr.max_len} events "
         f"({tr.total_instructions():,} instructions)",
@@ -2210,7 +2219,16 @@ def build_parser() -> argparse.ArgumentParser:
     c.set_defaults(fn=cmd_capture)
 
     s = sub.add_parser("synth", help="generate a synthetic PTPU trace file")
-    s.add_argument("spec", help="generator spec name[:k=v,...]")
+    s.add_argument(
+        "spec",
+        help="generator spec name[:k=v,...], integers only: a shape of "
+             "trace/synth.py::GENERATORS (uniform_random, stream, "
+             "pointer_chase, false_sharing, fft_like, readers_writer, "
+             "lock_contention, barrier_phases, ocean_like, ycsb_like, "
+             "moe_decode_like; the last takes its popularity exponent in "
+             "thousandths, skew_milli=500 for 0.5, and prints what it holds "
+             "as a JSON line)",
+    )
     s.add_argument("--cores", type=int, required=True)
     s.add_argument("--out", required=True)
     s.add_argument("--fold", action="store_true")

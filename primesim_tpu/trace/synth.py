@@ -24,6 +24,11 @@ expected statistics are analyzable:
 - ``ycsb_like``       — YCSB core workload A on a shared in-memory hash
                         table: zipfian keys, whole-record reads, one-field
                         updates in place (hot records serialise at their homes)
+- ``moe_decode_like`` — one decode step of one routed-expert layer of a
+                        sparse-expert model (DeepSeek-V3's widths), an expert to
+                        64 cores: private cold weight streams, activations
+                        read across experts, producer-consumer hand-offs,
+                        and most experts with no token at all
 
 All generators are deterministic given ``seed``.
 """
@@ -529,6 +534,190 @@ def ycsb_like(
     return from_event_lists(per_core)
 
 
+def _moe_plan(n_cores, seed, tokens, hidden, inter, experts, top_k, n_group, topk_group,
+              skew_milli, gate_rows, up_rows, down_rows, ins_per_mem, line):
+    """What `moe_decode_like` and `moe_decode_describe` share: the checks,
+    the layout, the routing drawn from the seed, and every core's count
+    of references."""
+    per = experts // n_group if n_group >= 1 and experts % n_group == 0 else 0
+    if tokens < 1 or experts < 1 or n_cores != 64 * experts:
+        raise ValueError("tokens >= 1, and an expert is 64 cores: n_cores = 64 * experts")
+    if not per or not 1 <= topk_group <= n_group or not 1 <= top_k <= topk_group * per:
+        raise ValueError("n_group divides experts; topk_group of them hold top_k experts")
+    if hidden < 1 or inter < 1 or hidden % (8 * line) or inter % (8 * line):
+        raise ValueError("an eighth of a gate row and of a down row is whole lines")
+    gseg, dseg = hidden // (8 * line), inter // (8 * line)  # lines a row segment
+    full = (inter // 8, inter // 8, hidden // 8)  # row segments a core holds: gate, up, down
+    rows = (gate_rows, up_rows, down_rows)
+    if skew_milli < 0 or ins_per_mem < 1 or any(not 1 <= r <= f for r, f in zip(rows, full)):
+        raise ValueError("skew_milli >= 0, ins_per_mem >= 1, 1 <= rows <= what a core holds")
+    # only what a step can touch is laid out: of every kind of row the
+    # segments that `tokens` visits reach, rounded to an odd count of lines
+    held = [min(f, tokens * r) for r, f in zip(rows, full)]
+    up_at, down_at = held[0] * gseg, (held[0] + held[1]) * gseg
+    stride = (down_at + held[2] * dseg) | 1
+    inter_base = tokens * hidden
+    out_base = inter_base + experts * inter
+    w_base = -(-(out_base + experts * 2 * hidden) // (4096 * line)) * 4096 * line
+    if w_base + n_cores * stride * line > 2**31:
+        raise ValueError("the weights a step touches do not fit under 2^31: fewer tokens or rows")
+
+    rng = _rng(seed)
+    weight = np.empty(experts)
+    weight[rng.permutation(experts)] = np.arange(1, experts + 1) ** (-skew_milli / 1000.0)
+    # a weighted draw without replacement takes the smallest of Exp(1) / weight
+    group_key = -np.log1p(-rng.random((tokens, n_group))) / weight.reshape(n_group, per).sum(1)
+    expert_key = -np.log1p(-rng.random((tokens, experts))) / weight
+    route, home = [], []
+    for t in range(tokens):
+        groups = np.argsort(group_key[t], kind="stable")[:topk_group]
+        key = np.where(np.isin(np.arange(experts) // per, groups), expert_key[t], np.inf)
+        chosen = np.argsort(key, kind="stable")[:top_k]
+        home.append(int(chosen[0]))
+        route.append(sorted(int(e) for e in chosen))
+    visits = [[t for t in range(tokens) if e in route[t]] for e in range(experts)]
+    classes = {"activation": gseg, "weight": (gate_rows + up_rows) * gseg + down_rows * dseg,
+               "intermediate": 1 + dseg, "output": 1}
+    refs = np.repeat([sum(classes.values()) * len(v) for v in visits], 64)
+    for t in range(tokens):
+        refs[64 * home[t] + t % 64] += top_k
+    return {
+        "rng": rng, "gseg": gseg, "dseg": dseg, "held": held, "up_at": up_at,
+        "down_at": down_at, "stride": stride, "inter_base": inter_base, "out_base": out_base,
+        "w_base": w_base, "route": route, "home": home, "visits": visits, "classes": classes,
+        "refs": refs,
+    }
+
+
+def moe_decode_like(
+    n_cores: int,
+    seed: int = 0,
+    tokens: int = 8,
+    hidden: int = 7168,
+    inter: int = 2048,
+    experts: int = 256,
+    top_k: int = 8,
+    n_group: int = 8,
+    topk_group: int = 4,
+    skew_milli: int = 500,
+    gate_rows: int = 1,
+    up_rows: int = 1,
+    down_rows: int = 4,
+    ins_per_mem: int = 8,
+    line: int = 64,
+) -> Trace:
+    """One decode step of one routed-expert layer, `y = W_down (silu(W_gate
+    x) * (W_up x))` an expert, for a batch of `tokens`, as the memory
+    references of its cores. The defaults are DeepSeek-V3's published
+    widths (`hidden_size`, `moe_intermediate_size`, `n_routed_experts`,
+    `num_experts_per_tok`, `n_group`, `topk_group`), weights, activations
+    and the intermediate at one byte a value (FP8), the output at two.
+
+    Expert e is the cores 64 e .. 64 e + 63, core 8 i + j of them block
+    (i, j) of an 8 x 8 blocking of each matrix: `inter` / 8 rows of
+    `hidden` / 8 bytes of gate and of up, `hidden` / 8 rows of `inter` / 8
+    bytes of down (a row segment: 14 and 4 lines at the defaults).
+
+    Routing: expert popularity is rank ** -(`skew_milli` / 1000) over a
+    seeded permutation of the experts; the expert groups are `experts` /
+    `n_group` consecutive experts. A token draws `topk_group` groups by
+    their summed popularity, then `top_k` experts inside them by their
+    own, both without replacement; the first expert drawn is its home.
+
+    A visit (a token at an expert, on each of its 64 cores; an expert
+    takes its tokens in rising order): LD the token's activation block j;
+    LD `gate_rows` gate and `up_rows` up row segments; ST its 1/64 of the
+    expert's intermediate; LD block j of the intermediate; LD `down_rows`
+    down row segments; ST the first word of its 1/64 of the expert's
+    output. Visit v streams the row segments v * rows .. (v + 1) * rows - 1
+    (mod what the core holds), so as at full size no weight line is met
+    twice. After its visits, core t mod 64 of token t's home combines it:
+    LD that slice of the output of each of its experts, in rising order.
+    The intermediate and the output are an expert's scratch, written anew
+    each visit. Before every reference a batch of 1 .. 2 * `ins_per_mem`
+    instructions.
+
+    Addresses: the tokens' activations from 0, the experts' intermediates,
+    their outputs, then from the next 256 KB every core's weights, core
+    after core: only the segments `tokens` visits can reach, rounded up to
+    an odd number of lines, so the cores' streams spread over every bank.
+    """
+    p = _moe_plan(n_cores, seed, tokens, hidden, inter, experts, top_k, n_group, topk_group,
+                  skew_milli, gate_rows, up_rows, down_rows, ins_per_mem, line)
+    gseg, dseg, held = p["gseg"], p["dseg"], p["held"]
+    batch = p["rng"].integers(1, 2 * ins_per_mem + 1, (n_cores, int(p["refs"].max())))
+    own_inter, own_out = inter // 64, 2 * hidden // 64  # bytes of an expert's scratch a core writes
+
+    def segments(base, seg_lines, first, n, of):
+        """LD the lines of `n` row segments from segment `first` (mod `of`)."""
+        return [(EV_LD, base + (((first + r) % of) * seg_lines + l) * line)
+                for r in range(n) for l in range(seg_lines)]
+
+    per_core = []
+    for c in range(n_cores):
+        e, q = divmod(c, 64)
+        j = q % 8
+        w = p["w_base"] + c * p["stride"] * line
+        scratch, out = p["inter_base"] + e * inter, p["out_base"] + e * 2 * hidden
+        refs: list[tuple] = []
+        for v, t in enumerate(p["visits"][e]):
+            refs += [(EV_LD, t * hidden + (j * gseg + l) * line) for l in range(gseg)]
+            refs += segments(w, gseg, v * gate_rows, gate_rows, held[0])
+            refs += segments(w + p["up_at"] * line, gseg, v * up_rows, up_rows, held[1])
+            refs.append((EV_ST, scratch + q * own_inter))
+            refs += [(EV_LD, scratch + (j * dseg + l) * line) for l in range(dseg)]
+            refs += segments(w + p["down_at"] * line, dseg, v * down_rows, down_rows, held[2])
+            refs.append((EV_ST, out + q * own_out))
+        for t in range(q, tokens, 64):
+            if p["home"][t] == e:
+                refs += [(EV_LD, p["out_base"] + x * 2 * hidden + q * own_out) for x in p["route"][t]]
+        evs: list[tuple] = []
+        for k, (kind, addr) in enumerate(refs):
+            evs.append((EV_INS, int(batch[c, k]), 0))
+            evs.append((kind, 4, addr))
+        per_core.append(evs)
+    return from_event_lists(per_core)
+
+
+def moe_decode_describe(
+    n_cores: int,
+    seed: int = 0,
+    tokens: int = 8,
+    hidden: int = 7168,
+    inter: int = 2048,
+    experts: int = 256,
+    top_k: int = 8,
+    n_group: int = 8,
+    topk_group: int = 4,
+    skew_milli: int = 500,
+    gate_rows: int = 1,
+    up_rows: int = 1,
+    down_rows: int = 4,
+    ins_per_mem: int = 8,
+    line: int = 64,
+) -> dict:
+    """What `moe_decode_like` of the same arguments holds, without building
+    it: visits an expert, references a class, and the events of the
+    fullest and of the mean core (a reference is two events unfolded, an
+    INS batch and the LD or ST; END not counted)."""
+    p = _moe_plan(n_cores, seed, tokens, hidden, inter, experts, top_k, n_group, topk_group,
+                  skew_milli, gate_rows, up_rows, down_rows, ins_per_mem, line)
+    per_expert = [len(v) for v in p["visits"]]
+    n_visits = sum(per_expert)
+    references = {k: 64 * n * n_visits for k, n in p["classes"].items()}
+    references["output"] += top_k * tokens  # the combiners' loads
+    return {
+        "visits": {"all": n_visits, "fullest_expert": max(per_expert),
+                   "mean_expert": n_visits / experts,
+                   "experts_without": per_expert.count(0)},
+        "references_a_visit": p["classes"],
+        "references": references,
+        "events": {"fullest_core": 2 * int(p["refs"].max()),
+                   "mean_core": 2 * float(p["refs"].mean())},
+        "bytes_laid_out": p["w_base"] + n_cores * p["stride"] * line,
+    }
+
+
 GENERATORS = {
     "uniform_random": uniform_random,
     "stream": stream,
@@ -540,4 +729,5 @@ GENERATORS = {
     "barrier_phases": barrier_phases,
     "ocean_like": ocean_like,
     "ycsb_like": ycsb_like,
+    "moe_decode_like": moe_decode_like,
 }
